@@ -51,6 +51,7 @@ from geomesa_tpu.fault import fault_point
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.ingest import sort as shsort
 from geomesa_tpu.ingest.splits import (
+    START_METHOD,
     ConverterConfig,
     plan_splits,
     run_split_guarded,
@@ -526,7 +527,7 @@ def ingest_files(
         else:
             import multiprocessing as mp
 
-            ctx = mp.get_context("fork")
+            ctx = mp.get_context(START_METHOD)
             with ctx.Pool(min(workers, len(splits))) as pool:
                 # imap streams results in SPLIT order: the ordered feed
                 # overlaps conversion, and error aggregation stays

@@ -34,6 +34,13 @@ from geomesa_tpu.sft import FeatureType
 # a split per ~32 MB keeps task granularity reasonable for big files
 SPLIT_BYTES = 32 << 20
 
+# how the split pools start their workers. The driver process owns the
+# store, so by the time it ingests it usually holds the accelerator and
+# JAX's threads; a forked child would inherit both (JAX warns the fork
+# can deadlock). Spawned workers start from a fresh interpreter and get
+# their task by pickle, like a mapper gets its job config.
+START_METHOD = "spawn"
+
 
 @dataclass
 class ConverterConfig:

@@ -115,7 +115,7 @@ def ingest_files(
 
     import multiprocessing as mp
 
-    ctx = mp.get_context("fork")
+    ctx = mp.get_context(_splits.START_METHOD)
     with ctx.Pool(workers) as pool:
         # imap streams results in SPLIT order: commits overlap conversion,
         # only ~workers results are in flight (not the whole dataset), and

@@ -12,6 +12,8 @@ a toolchain (set GEOMESA_TPU_NO_NATIVE=1 to force the fallback).
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -21,22 +23,32 @@ import numpy as np
 
 _DIR = Path(__file__).resolve().parent
 _SRC = _DIR / "geomesa_native.cpp"
-_LIB = _DIR / "build" / "libgeomesa_native.so"
 
+_log = logging.getLogger(__name__)
 _lock = threading.Lock()
 _lib: "ctypes.CDLL | None | bool" = None  # None = untried, False = unavailable
 
 
-def _build() -> bool:
-    _LIB.parent.mkdir(exist_ok=True)
+def _lib_path() -> Path:
+    """The artefact is named by a hash of the source it was built from:
+    a ``build/`` left by another tree (the directory is not committed,
+    but a copied checkout can carry one) is never what gets loaded."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _DIR / "build" / f"libgeomesa_native-{digest}.so"
+
+
+def _build(lib: Path) -> bool:
+    lib.parent.mkdir(exist_ok=True)
+    tmp = lib.with_name(f".{lib.name}.{os.getpid()}.tmp")
     # -ffp-contract=off: the point-in-polygon ray cast promises bit-exact
     # parity with numpy's two-rounding float sequence; fused multiply-adds
     # (default under -O3 on FMA targets) would round differently for
     # points lying exactly on slanted edges
     base = [
         "g++", "-O3", "-ffp-contract=off", "-shared", "-fPIC",
-        str(_SRC), "-o", str(_LIB),
+        str(_SRC), "-o", str(tmp),
     ]
+    why = ""
     for extra in (["-fopenmp"], []):  # prefer threaded; fall back
         try:
             r = subprocess.run(
@@ -44,10 +56,23 @@ def _build() -> bool:
                 capture_output=True,
                 timeout=120,
             )
-            if r.returncode == 0:
-                return True
-        except (OSError, subprocess.TimeoutExpired):
-            return False
+        except (OSError, subprocess.TimeoutExpired) as e:
+            why = repr(e)
+            break
+        if r.returncode == 0:
+            os.replace(tmp, lib)  # atomic: concurrent loaders never see half a file
+            for stale in lib.parent.glob("libgeomesa_native*.so"):
+                if stale != lib:
+                    stale.unlink(missing_ok=True)
+            return True
+        why = r.stderr.decode(errors="replace")
+    tmp.unlink(missing_ok=True)
+    # the numpy twins keep every entry point exact, at a several-fold
+    # ingest cost nobody should pay without knowing
+    _log.warning(
+        "geomesa_tpu.native: building %s failed, using the numpy fallbacks:\n%s",
+        _SRC.name, why,
+    )
     return False
 
 
@@ -61,13 +86,14 @@ def _load():
         if os.environ.get("GEOMESA_TPU_NO_NATIVE"):
             _lib = False
             return None
+        path = _lib_path()
         try:
-            if not _LIB.exists() or _LIB.stat().st_mtime < _SRC.stat().st_mtime:
-                if not _build():
-                    _lib = False
-                    return None
-            lib = ctypes.CDLL(str(_LIB))
-        except OSError:
+            if not path.exists() and not _build(path):
+                _lib = False
+                return None
+            lib = ctypes.CDLL(str(path))
+        except OSError as e:
+            _log.warning("geomesa_tpu.native: loading %s failed: %s", path.name, e)
             _lib = False
             return None
         u64p = np.ctypeslib.ndpointer(np.uint64, flags="C_CONTIGUOUS")

@@ -39,21 +39,11 @@ from geomesa_tpu.storage.table import IndexTable
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the graduated API (jax.shard_map,
-    ``check_vma``) when present, else the pre-0.6 experimental home
-    (``check_rep``). Replication checking is off either way — the scan
-    bodies index shard-local blocks, which the checker cannot see
-    through."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    """jax.shard_map with replication checking off: the scan bodies index
+    shard-local blocks, which the checker cannot see through."""
+    return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 

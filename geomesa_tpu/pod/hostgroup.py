@@ -227,7 +227,7 @@ def make_host_group(
     driver = (driver or conf.POD_DRIVER.get() or "auto").lower()
     if driver not in ("auto", "sim", "distributed"):
         raise ValueError(f"unknown pod driver {driver!r}")
-    procs = int(getattr(jax, "process_count", lambda: 1)())
+    procs = jax.process_count()
     if driver == "auto":
         driver = "distributed" if procs > 1 else "sim"
 

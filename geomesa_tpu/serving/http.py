@@ -130,6 +130,16 @@ class DataServer:
         from geomesa_tpu.tiles import TilePyramid
 
         self.tiles = TilePyramid(self.cold, metrics=self.metrics)
+        # same rule for pyarrow: its first import on a handler thread of
+        # a process that already runs JAX segfaulted in the first
+        # fmt=arrow response (PR 21 smoke); on the constructing thread
+        # it is safe. Absent pyarrow stays a 501 at request time.
+        from geomesa_tpu.io.arrow import _pa
+
+        try:
+            _pa()
+        except RuntimeError:
+            pass
         self.ops = OpsRoutes(self.cold, lam=self.lam, audit=audit)
         self.leader_url = leader_url
         self.host = host if host is not None else str(conf.SERVE_HOST.get())

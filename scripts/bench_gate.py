@@ -18,6 +18,11 @@ Usage:
         GEOMESA_BENCH_GEOFENCE_OUT=/tmp/BENCH_GEOFENCE.json python bench.py
     python scripts/bench_gate.py --fresh /tmp/BENCH_GEOFENCE.json
 
+``bench.py`` runs only on a TPU (it exits 2 and prints no row anywhere
+else), so a fresh file comes from the chip; a gate between a chip run and
+a committed ``"platform": "cpu"`` record compares two different machines
+and proves nothing (ROADMAP S1 replaces these records with cells).
+
 The default --baseline is inferred from the fresh file's name
 (BENCH_STREAM* gates against the committed BENCH_STREAM.json, everything
 else against BENCH_PIP_JOIN.json). The gate refuses to compare a file

@@ -14,7 +14,10 @@ is RAM, so the layout, sort, scan, decode and refinement paths are the
 real ones; only the kernel backend differs (XLA gather vs Pallas DMA).
 
 Usage: JAX_PLATFORMS=cpu python scripts/validate_1b.py  [N override via
-GEOMESA_1B_N]
+GEOMESA_1B_N]. The platform is whatever JAX is given from outside (no
+default is set here) and the output row names it (``platform``,
+``device_kind``): a row that says ``cpu`` is a layout validation, never
+a chip time.
 """
 
 import json
@@ -22,10 +25,7 @@ import os
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -204,7 +204,8 @@ def main():
         "append_2m_s": round(append_s, 1),
         "post_append_exact": True,
         "compact_s": round(compact_s, 1),
-        "backend": jax.default_backend(),
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
     }), flush=True)
 
 

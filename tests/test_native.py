@@ -374,3 +374,31 @@ class TestNativePointsInPolygon:
         got = native.points_in_polygon(p[:, 0], p[:, 1], [tri], [0])
         want = geo.points_in_ring(p[:, 0], p[:, 1], tri)
         np.testing.assert_array_equal(got, want)
+
+
+class TestBuildArtefact:
+    """PR 21: the library that loads is the one built from the committed
+    source, and a failed build says so."""
+
+    def test_artefact_is_named_by_source_hash(self):
+        import hashlib
+
+        digest = hashlib.sha256(native._SRC.read_bytes()).hexdigest()[:16]
+        assert native._lib_path().name == f"libgeomesa_native-{digest}.so"
+        lib = native._load()
+        if lib is not None:
+            assert digest in lib._name
+
+    def test_failed_build_is_logged_with_compiler_stderr(self, tmp_path, monkeypatch, caplog):
+        import logging
+        import subprocess
+        import types
+
+        def fake_run(cmd, **kw):
+            return types.SimpleNamespace(returncode=1, stderr=b"fatal error: no such header")
+
+        monkeypatch.setattr(subprocess, "run", fake_run)
+        with caplog.at_level(logging.WARNING, logger="geomesa_tpu.native"):
+            assert native._build(tmp_path / "build" / "libgeomesa_native-x.so") is False
+        assert "no such header" in caplog.text and "numpy fallbacks" in caplog.text
+        assert not list((tmp_path / "build").glob("*.so"))
