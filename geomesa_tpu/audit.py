@@ -19,8 +19,9 @@ class AuditedEvent:
     """One query's audit record (reference QueryEvent). ``trace_id``
     cross-references the observability tier (docs/observability.md):
     when tracing is armed it carries the query's trace id, the same id
-    the slow-query ring and the Chrome export (``pid``) use — so an
-    audit row, a slow capture and a trace lane join on one key."""
+    the slow-query ring and the Chrome export (``args.trace_id``) use —
+    so an audit row, a slow capture and a trace's events join on one
+    key."""
 
     type_name: str
     filter: str

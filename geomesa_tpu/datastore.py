@@ -26,6 +26,8 @@ from geomesa_tpu import fault
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.predicates import Filter, INCLUDE, Include, PointColumn
 from geomesa_tpu.index import AttributeIndex, S2Index, S3Index, XZ2Index, XZ3Index, Z2Index, Z3Index
+from geomesa_tpu.obs.trace import span as _ospan
+from geomesa_tpu.obs.trace import tracer as _otracer
 from geomesa_tpu.planning.errors import check_deadline
 from geomesa_tpu.planning.explain import Explainer
 from geomesa_tpu.planning.planner import QueryPlanner
@@ -1574,16 +1576,12 @@ class DataStore:
         sampling knob, captured into the slow-query ring when over
         ``geomesa.obs.slow.ms``, and appended to ``explain`` as a
         per-phase breakdown."""
-        from geomesa_tpu.obs.trace import phase_breakdown, tracer
+        from geomesa_tpu.obs.trace import phase_breakdown
 
-        with tracer().trace("query", type=type_name) as trace:
+        with _otracer().trace("query", type=type_name) as trace:
             plan = self.planner.plan(type_name, f, limit=limit, explain=explain)
             if trace is not None:
-                trace.fingerprint = {
-                    "type": type_name,
-                    "strategy": plan.strategy,
-                    "filter": str(plan.filter),
-                }
+                trace.fingerprint = _plan_fingerprint(plan)
             out = self.planner.execute(plan, explain=explain, hints=hints)
         if explain is not None and trace is not None:
             for line in phase_breakdown(trace):
@@ -1601,11 +1599,19 @@ class DataStore:
         """Run several queries with pipelined device work: all scans
         dispatch before any result is pulled, so the per-query device
         round-trip overlaps across the batch (throughput-oriented; the
-        per-query results are identical to sequential ``query`` calls)."""
-        plans = [
-            self.planner.plan(type_name, f, limit=limit) for f in filters
-        ]
-        return self.planner.execute_many(plans, hints=hints)
+        per-query results are identical to sequential ``query`` calls).
+
+        Traced as ONE root ``query_many``: a ``plan`` per member, the
+        ``dispatch`` that stages them all, then each member's ``scan``
+        and ``decode`` (``member`` = its position)."""
+        with _otracer().trace("query_many", type=type_name) as trace:
+            plans = [
+                self.planner.plan(type_name, f, limit=limit) for f in filters
+            ]
+            if trace is not None:
+                trace.root.annotate(members=len(plans))
+                trace.fingerprint = {"type": type_name, "members": len(plans)}
+            return self.planner.execute_many(plans, hints=hints)
 
     def record_query(self, plan, hits: int, scan_s: float) -> None:
         """Audit + metrics sink for every executed plan — the planner calls
@@ -1652,11 +1658,9 @@ class DataStore:
                     self.accuracy.reset(plan.type_name)
         if self.metrics is not None:
             self.metrics.counter("geomesa.query.count")
-            self.metrics.counter("geomesa.query.hits", max(hits, 0))
             if plan.warnings:
                 # degraded-mode answer: results excluded quarantined data
                 self.metrics.counter("geomesa.query.degraded")
-            self.metrics.timer_update("geomesa.query.plan", plan.planning_s)
             # query latency is a live HISTOGRAM (docs/observability.md):
             # p50/p99 read straight off the registry instead of offline
             # bench post-processing; the attached SLO tracker consumes
@@ -1737,7 +1741,8 @@ class DataStore:
             # adaptive cost gate: measured compositions for this type are
             # losing to the plain scan — fall back until a re-probe
             return None
-        comp = cache.tiles.compose(self, type_name, f)
+        with _ospan("probe", tier="tiles"):
+            comp = cache.tiles.compose(self, type_name, f)
         if comp is not None and explain is not None:
             status = "hit" if comp.tiles_filled == 0 else "partial"
             explain(
@@ -1822,14 +1827,20 @@ class DataStore:
         # pinned pair: the residue gather must resolve the scan's
         # ordinals against the chunk list the table was built over, not
         # whatever a concurrent fold publishes mid-scan
-        table, chunks = self.pin_scan_state(type_name, plan.index)
-        ordinals, certain = table.scan(plan.config)
+        with _ospan("dispatch", index=plan.index):
+            table, chunks = self.pin_scan_state(type_name, plan.index)
+            finish_scan = table.scan_submit(plan.config)
+        with _ospan("scan", index=plan.index):
+            ordinals, certain = finish_scan()
         self._agg_check_deadline(deadline, "raster aggregation scan")
         cert_ords = ordinals[certain]
         unc = ordinals[~certain]
         if len(unc):
-            sub = self.gather(type_name, unc, chunks=chunks)
-            m = plan.filter.evaluate(sub.batch)
+            with _ospan("decode", candidates=len(unc)) as sp:
+                sp.event("gather")
+                sub = self.gather(type_name, unc, chunks=chunks)
+                sp.event("refine")
+                m = plan.filter.evaluate(sub.batch)
             self._agg_check_deadline(deadline, "raster residue refinement")
             hits = np.concatenate([cert_ords, unc[m]])
         else:
@@ -1917,7 +1928,21 @@ class DataStore:
         any grid is pulled, so the per-tile link roundtrip overlaps across
         the batch. ``requests`` is a sequence of (filter, envelope) pairs
         (envelope None = whole world). Results are identical to sequential
-        :meth:`density` calls."""
+        :meth:`density` calls.
+
+        Traced as one root ``density``: per request a ``plan`` and, on
+        the device path, a ``dispatch`` (the grid kernel enqueued) and an
+        ``agg`` (its ``wait`` and ``pull``); the host path shows the row
+        query's ``dispatch``, ``scan`` and ``decode``."""
+        with _otracer().trace(
+            "density", type=type_name, requests=len(requests)
+        ) as trace:
+            return self._density_many(
+                type_name, requests, width, height, weight, explain, trace
+            )
+
+    def _density_many(self, type_name, requests, width, height, weight,
+                      explain, trace) -> list[np.ndarray]:
         from geomesa_tpu.filter import ecql
         from geomesa_tpu.planning.planner import mask_decides_filter
 
@@ -1928,6 +1953,8 @@ class DataStore:
             if envelope is None:
                 envelope = (-180.0, -90.0, 180.0, 90.0)
             plan = self.planner.plan(type_name, f)
+            if trace is not None and trace.fingerprint is None:
+                trace.fingerprint = _plan_fingerprint(plan)
             cfg = plan.config
             # gate on plan.filter: interceptors may have rewritten it
             fast_eligible = (
@@ -1947,9 +1974,10 @@ class DataStore:
                 self.record_query(plan, 0, 0.0)
                 staged.append(("empty", None))
             else:
-                finish = self.table(type_name, plan.index).density_submit(
-                    cfg, envelope, width, height
-                )
+                with _ospan("dispatch", index=plan.index):
+                    finish = self.table(type_name, plan.index).density_submit(
+                        cfg, envelope, width, height
+                    )
                 staged.append(("device", (plan, finish)))
 
         out: list = []
@@ -1964,7 +1992,8 @@ class DataStore:
                 # tile's pull, not the whole batch's wall clock)
                 deadline = self._agg_deadline()
                 t0 = time.perf_counter()
-                grid = finish()
+                with _ospan("agg", index=plan.index):
+                    grid = finish()
                 self._agg_check_deadline(deadline, "density scan")
                 self.record_query(plan, int(grid.sum()), time.perf_counter() - t0)
                 out.append(grid)
@@ -2147,11 +2176,20 @@ class DataStore:
             and not self.interceptors  # an interceptor may hide rows
         ):
             return self.row_count(type_name)
+        # one root ``count``: ``plan``, then ``probe`` where the tile
+        # cache answers, else the row path's ``dispatch``, ``scan`` and
+        # ``decode``
+        with _otracer().trace("count", type=type_name) as trace:
+            return self._count(type_name, f, trace)
+
+    def _count(self, type_name: str, f, trace) -> int:
         from geomesa_tpu.filter import ecql
 
         if isinstance(f, str):
             f = ecql.parse(f)
         plan = self.planner.plan(type_name, f)
+        if trace is not None:
+            trace.fingerprint = _plan_fingerprint(plan)
         if self.cache is not None:
             t0 = time.perf_counter()
             comp = self._tile_compose(type_name, plan.filter)
@@ -2399,6 +2437,15 @@ def _observe_sketch(stats, idx, keys) -> None:
         idx.name, keys.bins, keys.zs,
         dims * getattr(idx.sfc, "precision", 21),
     )
+
+
+def _plan_fingerprint(plan) -> dict:
+    """The slow-query log's identity of a planned operation."""
+    return {
+        "type": plan.type_name,
+        "strategy": plan.strategy,
+        "filter": str(plan.filter),
+    }
 
 
 def _exact_bounds(fc: FeatureCollection) -> Optional[tuple]:

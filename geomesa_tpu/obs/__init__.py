@@ -42,6 +42,7 @@ from geomesa_tpu.obs.trace import (
     Trace,
     TraceBuffer,
     Tracer,
+    event,
     install,
     phase_breakdown,
     span,
@@ -61,6 +62,7 @@ __all__ = [
     "TelemetryRecorder",
     "default_objectives",
     "error_factor",
+    "event",
     "install",
     "ops_report",
     "phase_breakdown",

@@ -23,6 +23,21 @@ records a wall-clock anchor so exports are absolute. ``Tracer.dump``
 writes Chrome trace-event JSON (``chrome://tracing`` / Perfetto
 ``ph:"X"`` complete events, microsecond units).
 
+A leaf span can be cut into **segments** (:meth:`Span.event`: one
+``perf_counter`` and a list append) where children would change the
+parent's self time. Two dearer instruments are kept for RETAINED traces
+(a sampling hit), never for trees built only for the slow log: a span
+opened with ``cpu=True`` (a phase that never sleeps by design: ``plan``,
+``decode``, ``encode``) reads ``time.thread_time`` at both ends, so
+``dur_s - cpu_s`` is what its thread waited for the interpreter lock (a
+syscall: 6 us on the host of a v5e, PERF.md section 6, which is why no
+other span reads it); and every span or segment that opens and closes on
+one thread is a ``jax.profiler.TraceAnnotation("geomesa:<span>
+[.<segment>]")`` host event, so a profiler session shows it on the
+thread's own line beside the device ops (no session active: a no-op;
+``jax`` is imported by the first retained span, never before). All of it
+rides ``attrs``, the one carrier every reader and exporter passes on.
+
 Locking: ``Tracer._lock`` (LOCKS rank 76, hot) guards only the
 retention rings and the sampling counter — it is taken once per root
 begin/end, never per child span (children append to their trace's own
@@ -35,6 +50,7 @@ from __future__ import annotations
 
 import itertools
 import json
+import os
 import threading
 import time
 from typing import Optional
@@ -43,6 +59,19 @@ from geomesa_tpu import conf
 
 _ids = itertools.count(1)
 _tls = threading.local()
+_profiler = None  # jax.profiler, imported by the first retained span
+
+
+def _annotation(name: str, trace_id: int):
+    """An ENTERED profiler annotation ``name`` carrying the trace id."""
+    global _profiler
+    if _profiler is None:
+        import jax.profiler
+
+        _profiler = jax.profiler
+    ann = _profiler.TraceAnnotation(name, trace=trace_id)
+    ann.__enter__()
+    return ann
 
 
 class Span:
@@ -52,11 +81,15 @@ class Span:
     rely on ``list.append`` being atomic under the GIL. Only the append
     is concurrent; no span is ever mutated after finish, and readers
     (retention, export) run after the root ends. A free-threaded
-    runtime would need a per-trace lock here."""
+    runtime would need a per-trace lock here.
+
+    ``segments`` and ``cpu_s`` (``attrs``) exist only on spans the
+    ``with`` forms opened: both ends of those, and every
+    :meth:`event` between them, are taken on one thread."""
 
     __slots__ = (
         "trace", "span_id", "parent_id", "name", "attrs", "t0", "dur_s",
-        "tid",
+        "tid", "_cpu0", "_marks", "_anns",
     )
 
     def __init__(self, trace: "Trace", name: str, parent_id: Optional[int],
@@ -69,6 +102,48 @@ class Span:
         self.t0 = time.perf_counter() if t0 is None else t0
         self.dur_s = 0.0
         self.tid = threading.get_ident()
+        self._cpu0 = None   # thread_time at open (retained, cpu=True)
+        self._marks = None  # [(segment, perf_counter)]
+        self._anns = None   # open profiler annotations, innermost last
+
+    def _open(self, cpu: bool = False) -> "Span":
+        """What a ``with`` form adds on entry to a span of a RETAINED
+        trace: the profiler annotation and, asked for, the thread's CPU
+        clock."""
+        if self.trace.retain:
+            self._anns = [_annotation("geomesa:" + self.name, self.trace.trace_id)]
+            if cpu:
+                self._cpu0 = time.thread_time()
+        return self
+
+    def event(self, name: str) -> bool:
+        """Close the running segment and start segment ``name``, which
+        runs to the next ``event`` or the span's end: one clock read and
+        a list append, no object. ``finish`` folds them into
+        ``attrs["segments"] = {name: wall_s}`` (a name marked twice
+        sums). Call it on the thread that finishes the span."""
+        mark = (name, time.perf_counter())
+        if self._marks is None:
+            self._marks = [mark]
+        else:
+            self._marks.append(mark)
+        anns = self._anns
+        if anns is not None:
+            if len(anns) > 1:
+                anns.pop().__exit__(None, None, None)
+            anns.append(_annotation(
+                f"geomesa:{self.name}.{name}", self.trace.trace_id
+            ))
+        return True
+
+    def add(self, name: str, n) -> None:
+        """Add ``n`` to the numeric attribute ``name`` (block and slot
+        counts that several dispatches of one span accumulate)."""
+        a = self.attrs
+        if a is None:
+            self.attrs = {name: n}
+        else:
+            a[name] = a.get(name, 0) + n
 
     def annotate(self, **attrs) -> "Span":
         """Attach attributes after the fact (hit counts, strategies)."""
@@ -79,8 +154,32 @@ class Span:
         return self
 
     def finish(self, end: Optional[float] = None) -> None:
-        self.dur_s = (time.perf_counter() if end is None else end) - self.t0
+        now = time.perf_counter() if end is None else end
+        self.dur_s = now - self.t0
+        if self._marks is not None or self._anns is not None:
+            self._fold(now)
         self.trace.spans.append(self)
+
+    def _fold(self, now: float) -> None:
+        extra: dict = {}
+        if self._cpu0 is not None:
+            extra["cpu_s"] = max(time.thread_time() - self._cpu0, 0.0)
+        anns, self._anns = self._anns, None
+        while anns:
+            anns.pop().__exit__(None, None, None)
+        marks = self._marks
+        if marks:
+            segs: dict = {}
+            for k, (name, t) in enumerate(marks):
+                t1 = marks[k + 1][1] if k + 1 < len(marks) else now
+                segs[name] = segs.get(name, 0.0) + (t1 - t)
+            extra["segments"] = segs
+        if not extra:
+            return
+        if self.attrs is None:
+            self.attrs = extra
+        else:
+            self.attrs.update(extra)
 
 
 class Trace:
@@ -88,16 +187,17 @@ class Trace:
     child, flat with parent ids (tree shape reconstructs from ids)."""
 
     __slots__ = (
-        "trace_id", "name", "spans", "root", "t_wall", "retain",
+        "trace_id", "name", "spans", "root", "t_wall", "retain", "capture",
         "fingerprint",
     )
 
-    def __init__(self, name: str, retain: bool):
+    def __init__(self, name: str, retain: bool, capture: bool = True):
         self.trace_id = next(_ids)
         self.name = name
         self.spans: list[Span] = []
         self.t_wall = time.time()
         self.retain = retain
+        self.capture = capture  # may the slow-query log take it
         # slow-log identity (set by the query path once planned): the
         # plan fingerprint the capture carries
         self.fingerprint: Optional[dict] = None
@@ -126,6 +226,7 @@ class Trace:
                     "span_id": s.span_id,
                     "parent_id": s.parent_id,
                     "name": s.name,
+                    "tid": s.tid,
                     "start_ms": round((s.t0 - self.root.t0) * 1e3, 3),
                     "dur_ms": round(s.dur_s * 1e3, 3),
                     **({"attrs": s.attrs} if s.attrs else {}),
@@ -150,6 +251,12 @@ class _NullSpan:
     def annotate(self, **attrs):
         return self
 
+    def event(self, name) -> bool:
+        return False
+
+    def add(self, name, n) -> None:
+        return None
+
 
 NULL_SPAN = _NullSpan()
 
@@ -157,16 +264,17 @@ NULL_SPAN = _NullSpan()
 class _SpanCtx:
     """Context manager activating a child span on this thread."""
 
-    __slots__ = ("span", "_prev")
+    __slots__ = ("span", "_cpu", "_prev")
 
-    def __init__(self, span: Span):
+    def __init__(self, span: Span, cpu: bool = False):
         self.span = span
+        self._cpu = cpu
         self._prev = None
 
     def __enter__(self) -> Span:
         self._prev = getattr(_tls, "span", None)
         _tls.span = self.span
-        return self.span
+        return self.span._open(self._cpu)
 
     def __exit__(self, *exc) -> None:
         self.span.finish()
@@ -230,7 +338,10 @@ class Tracer:
         self._lock = witness(threading.Lock(), "Tracer._lock")
         self.buffer = TraceBuffer(conf.OBS_TRACE_BUFFER.get())  # guarded-by: _lock
         self.slow: list[dict] = []   # guarded-by: _lock
-        self._n_roots = 0            # guarded-by: _lock
+        # roots seen per root NAME: each kind of root is sampled 1/N of
+        # its own, so kinds that alternate (a request's ``http`` and
+        # ``query``) cannot alias one of them out of the buffer
+        self._n_roots: dict = {}     # guarded-by: _lock
         self.metrics = metrics
 
     # -- arming / roots ---------------------------------------------------
@@ -238,11 +349,17 @@ class Tracer:
     def armed(self) -> bool:
         return conf.OBS_TRACE_SAMPLE.get() > 0 or conf.OBS_SLOW_MS.get() > 0
 
-    def begin(self, name: str, **attrs) -> Optional[Trace]:
+    def begin(self, name: str, capture: bool = True,
+              **attrs) -> Optional[Trace]:
         """Open a root trace (sampling decided here), or None when
         disarmed. Does NOT activate it — pair with :meth:`activate`
         (the serving scheduler begins in the caller thread and
         activates per hop); :meth:`trace` composes both.
+
+        ``capture=False``: a root the slow-QUERY log never takes (the
+        transport's ``http``, the dispatcher's ``batch``: the query
+        roots inside them are what it captures) — built only when
+        sampled, so the always-on slow log pays nothing for it.
 
         Sampling gates the whole tree, not just retention: with the
         slow log off, a sampled-out root returns None and its operation
@@ -257,13 +374,21 @@ class Tracer:
         retain = False
         if sample > 0:
             with self._lock:
-                self._n_roots += 1
-                retain = self._n_roots % sample == 0
-        if not retain and slow_ms <= 0:
+                n = self._n_roots[name] = self._n_roots.get(name, 0) + 1
+                retain = n % sample == 0
+        if not retain and (slow_ms <= 0 or not capture):
             return None  # never retained, never slow-captured: free
-        tr = Trace(name, retain)
+        tr = Trace(name, retain, capture)
         if attrs:
             tr.root.annotate(**attrs)
+        cur = getattr(_tls, "span", None)
+        if cur is not None:
+            # begun inside another operation on this thread (a request's
+            # ``query`` inside its ``http``): one identifier on both
+            # trees, ``http_trace`` here and ``query_trace`` there
+            outer = cur.trace
+            tr.root.annotate(**{outer.name + "_trace": outer.trace_id})
+            outer.root.annotate(**{name + "_trace": tr.trace_id})
         return tr
 
     def end(self, trace: Optional[Trace], fingerprint: Optional[dict] = None) -> None:
@@ -274,7 +399,9 @@ class Tracer:
             return
         trace.root.finish()
         slow_ms = conf.OBS_SLOW_MS.get()
-        is_slow = slow_ms > 0 and trace.wall_s * 1e3 >= slow_ms
+        is_slow = (
+            trace.capture and slow_ms > 0 and trace.wall_s * 1e3 >= slow_ms
+        )
         retained = trace.retain
         if not (retained or is_slow):
             return
@@ -306,23 +433,27 @@ class Tracer:
         if is_slow:
             m.counter("geomesa.obs.slow_queries")
 
-    def trace(self, name: str, **attrs):
+    def trace(self, name: str, capture: bool = True, **attrs):
         """``begin`` + activate + ``end`` as one context manager,
-        yielding the Trace (or None when disarmed)."""
-        return _RootCtx(self, name, attrs)
+        yielding the Trace (or None when disarmed). Opened and closed
+        on one thread, so its root, retained, is a profiler annotation
+        like any ``with`` span."""
+        return _RootCtx(self, name, capture, attrs)
 
     # -- propagation ------------------------------------------------------
     def current(self) -> Optional[Span]:
         return getattr(_tls, "span", None)
 
-    def span(self, name: str, **attrs):
+    def span(self, name: str, cpu: bool = False, **attrs):
         """A child span under this thread's active span — the hot-path
         entry: one thread-local probe and the shared null context when
-        untraced."""
+        untraced. ``cpu=True``: a phase that never sleeps by design; on
+        a retained trace it also carries ``cpu_s``, its thread's CPU
+        time."""
         cur = getattr(_tls, "span", None)
         if cur is None:
             return NULL_SPAN
-        return _SpanCtx(Span(cur.trace, name, cur.span_id, attrs or None))
+        return _SpanCtx(Span(cur.trace, name, cur.span_id, attrs or None), cpu)
 
     def activate(self, span: Optional[Span]):
         """Adopt an existing span as this thread's active context (the
@@ -362,7 +493,7 @@ class Tracer:
         with self._lock:
             self.buffer = TraceBuffer(conf.OBS_TRACE_BUFFER.get())
             self.slow = []
-            self._n_roots = 0
+            self._n_roots = {}
 
     def chrome_payload(self) -> dict:
         """Every retained trace (buffer + slow ring, deduped by trace
@@ -390,19 +521,22 @@ class Tracer:
 
 
 class _RootCtx:
-    __slots__ = ("_tracer", "_name", "_attrs", "_trace", "_act")
+    __slots__ = ("_tracer", "_name", "_capture", "_attrs", "_trace", "_act")
 
-    def __init__(self, tracer: Tracer, name: str, attrs: dict):
+    def __init__(self, tracer: Tracer, name: str, capture: bool, attrs: dict):
         self._tracer = tracer
         self._name = name
+        self._capture = capture
         self._attrs = attrs
         self._trace = None
         self._act = None
 
     def __enter__(self) -> Optional[Trace]:
-        self._trace = self._tracer.begin(self._name, **self._attrs)
+        self._trace = self._tracer.begin(
+            self._name, self._capture, **self._attrs
+        )
         if self._trace is not None:
-            self._act = _Activation(self._trace.root)
+            self._act = _Activation(self._trace.root._open())
             self._act.__enter__()
         return self._trace
 
@@ -414,17 +548,23 @@ class _RootCtx:
 
 def _chrome_events(td: dict) -> list[dict]:
     """Chrome trace-event ``ph:"X"`` complete events for one trace
-    dict, pid = trace id (one lane per trace), ts in microseconds."""
+    dict: one process, a lane per real thread (``Span.tid``), ``ts`` in
+    microseconds from the trace's wall-clock anchor — so the traces of
+    concurrent requests line up on one timeline, each span on the
+    thread that ran it. ``args`` carries the trace id beside the
+    span's attributes."""
     out = []
+    pid = os.getpid()
+    base_us = td["t_wall"] * 1e6
     for s in td["spans"]:
         out.append({
             "name": s["name"],
             "ph": "X",
-            "pid": td["trace_id"],
-            "tid": 0 if s["parent_id"] is None else s["parent_id"],
-            "ts": round(s["start_ms"] * 1e3, 1),
+            "pid": pid,
+            "tid": s["tid"],
+            "ts": round(base_us + s["start_ms"] * 1e3, 1),
             "dur": round(s["dur_ms"] * 1e3, 1),
-            "args": s.get("attrs", {}),
+            "args": {"trace_id": td["trace_id"], **s.get("attrs", {})},
         })
     return out
 
@@ -464,7 +604,23 @@ def install(t: Tracer) -> Tracer:
     return t
 
 
-def span(name: str, **attrs):
+def span(name: str, cpu: bool = False, **attrs):
     """Module-level child-span helper — ``obs.span("scan")`` from any
     hot path; the disarmed cost is one thread-local probe."""
-    return TRACER.span(name, **attrs)
+    return TRACER.span(name, cpu, **attrs)
+
+
+def event(name: str) -> bool:
+    """Start segment ``name`` of this thread's active span
+    (:meth:`Span.event`) — for code that runs inside a caller's span
+    without being handed it (the table under the planner's ``scan``).
+    False, after one thread-local probe, when nothing is traced."""
+    cur = getattr(_tls, "span", None)
+    return cur is not None and cur.event(name)
+
+
+def add(name: str, n) -> None:
+    """:meth:`Span.add` on this thread's active span, if any."""
+    cur = getattr(_tls, "span", None)
+    if cur is not None:
+        cur.add(name, n)

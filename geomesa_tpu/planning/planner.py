@@ -25,6 +25,7 @@ from geomesa_tpu.filter import ecql
 from geomesa_tpu.filter.extract import extract_ids
 from geomesa_tpu.filter.predicates import Filter, Include
 from geomesa_tpu.index.api import ScanConfig
+from geomesa_tpu.obs.trace import NULL_SPAN as _NULL_SPAN
 from geomesa_tpu.obs.trace import span as _ospan
 from geomesa_tpu.obs.trace import tracer as _otracer
 from geomesa_tpu.planning.explain import Explainer, ExplainNull
@@ -285,7 +286,7 @@ class QueryPlanner:
             guard = intercept
         t0 = time.perf_counter()
         exp = explain or ExplainNull()
-        with _ospan("plan", type=type_name):
+        with _ospan("plan", cpu=True, type=type_name):
             if isinstance(f, str):
                 f = ecql.parse(f)
             from geomesa_tpu.filter.predicates import normalize_antimeridian
@@ -616,7 +617,8 @@ class QueryPlanner:
         )
 
     def _submit_simple(self, plan, exp, hints, skip_visibility=False,
-                       finish_scan=None, deadline=None, chunks=None):
+                       finish_scan=None, deadline=None, chunks=None,
+                       member=None):
         """Dispatch a simple index-scan plan's device work now; return
         ``finish()`` -> FeatureCollection. ONE implementation serves both
         the synchronous path (_execute calls finish immediately) and the
@@ -639,7 +641,10 @@ class QueryPlanner:
         ``finish_scan``: an already-dispatched scan's finish (submit_many's
         fused group scans); default dispatches this plan's own scan.
         ``chunks``: the chunk snapshot captured when that scan was
-        dispatched (submit_many); default captures one here."""
+        dispatched (submit_many); default captures one here.
+        ``member``: this plan's position in a submit_many batch, carried
+        on its ``scan`` and ``decode`` spans."""
+        tag = {} if member is None else {"member": member}
         if finish_scan is None:
             with _ospan("dispatch", index=plan.index):
                 table, chunks = self.store.pin_scan_state(
@@ -652,26 +657,30 @@ class QueryPlanner:
         def finish(deadline=deadline) -> FeatureCollection:
             if deadline is None:
                 deadline = self._deadline(hints)
-            with _ospan("scan", index=plan.index):
+            with _ospan("scan", index=plan.index, **tag):
                 with exp.span(f"Device scan [{plan.index}]"):
                     # single-chip and distributed tables share one engine
                     # and one contract: (ordinals, certainty vector)
                     ordinals, certain = finish_scan()
                 check_deadline(deadline, "scan result pull")
             exp(f"Candidates: {len(ordinals)}")
-            with _ospan("decode", candidates=len(ordinals)):
+            with _ospan(
+                "decode", cpu=True, candidates=len(ordinals), **tag
+            ) as sp:
+                sp.event("gather")
                 candidates = self.store.gather(
                     plan.type_name, ordinals, chunks=chunks
                 )
                 return self._refine_and_post(
                     plan, candidates, certain, hints, exp, deadline,
-                    skip_visibility,
+                    skip_visibility, span=sp,
                 )
 
         return finish
 
     def _refine_and_post(
-        self, plan, candidates, certain, hints, exp, deadline, skip_visibility=False
+        self, plan, candidates, certain, hints, exp, deadline,
+        skip_visibility=False, span=_NULL_SPAN,
     ):
         """Refinement tiers (reference Z3IndexKeySpace.useFullFilter,
         Z3IndexKeySpace.scala:240-254, automatic since round 3):
@@ -679,7 +688,11 @@ class QueryPlanner:
           rows (wide & ~inner; f32/offset rounding) re-check on host;
         - `loose` hint: accept the widened mask outright (reference
           LOOSE_BBOX semantics);
-        - otherwise: exact full-filter refinement over all candidates."""
+        - otherwise: exact full-filter refinement over all candidates.
+
+        ``span``: the caller's ``decode`` span, cut here into its
+        ``refine`` and ``post`` segments."""
+        span.event("refine")
         decided = mask_decides_filter(
             plan.filter, plan.config, self.store.get_schema(plan.type_name)
         )
@@ -714,6 +727,7 @@ class QueryPlanner:
         # over-selection (a z2 scan serving a temporal filter) to the
         # sketches, flagging fresh stats stale forever.
         self._note_actual(plan, len(candidates), exp)
+        span.event("post")
         return self._post(candidates, plan, hints, exp, skip_visibility)
 
     @staticmethod
@@ -746,7 +760,7 @@ class QueryPlanner:
         )
 
     def submit(self, plan: QueryPlan, explain: Explainer | None = None,
-               hints=None, deadline=None):
+               hints=None, deadline=None, member=None):
         """Stage one query: dispatch its device scan NOW, return a zero-arg
         ``finish()`` producing the FeatureCollection. Plans without a
         simple index scan (unions, id lookups, full scans) fall back to
@@ -761,7 +775,7 @@ class QueryPlanner:
         if hints is not None:
             hints.validate()
         return self._record_wrap(plan, self._submit_simple(
-            plan, exp, hints, deadline=deadline
+            plan, exp, hints, deadline=deadline, member=member
         ))
 
     def _record_wrap(self, plan, inner):
@@ -817,6 +831,12 @@ class QueryPlanner:
             per = [hints] * len(plans)
         exps = aligned(explains, "explains")
         dls = aligned(deadlines, "deadlines")
+        with _ospan("dispatch", members=len(plans)):
+            return self._stage_many(plans, per, exps, dls)
+
+    def _stage_many(self, plans, per, exps, dls) -> list:
+        """submit_many's staging, under its ``dispatch`` span (a member
+        that dispatches alone nests its own ``dispatch`` inside)."""
         finishes: list = [None] * len(plans)
         groups: dict[tuple, list[int]] = {}
         for j, plan in enumerate(plans):
@@ -840,7 +860,7 @@ class QueryPlanner:
                 for j in idxs:
                     finishes[j] = self.submit(
                         plans[j], explain=exps[j], hints=per[j],
-                        deadline=dls[j],
+                        deadline=dls[j], member=j,
                     )
                 continue
             scan_fins = many([plans[j].config for j in idxs])
@@ -849,6 +869,7 @@ class QueryPlanner:
                 finishes[j] = self._record_wrap(plan, self._submit_simple(
                     plan, exps[j] or ExplainNull(), per[j],
                     finish_scan=scan_fin, deadline=dls[j], chunks=chunks,
+                    member=j,
                 ))
         return finishes
 

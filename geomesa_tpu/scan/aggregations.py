@@ -42,6 +42,12 @@ from geomesa_tpu.scan import block_kernels as bk
 # per-slot bounds stats lane layout: [count, xmin, xmax, ymin, ymax, 0...]
 STAT_LANES = 8
 
+#: device-op names (see block_kernels.SCAN_NAME): the Pallas kernels carry
+#: them, the XLA twins' fusions show them as their ``op_name`` scope
+POPS_NAME = "geomesa_pops"
+DENSITY_NAME = "geomesa_density"
+BOUNDS_NAME = "geomesa_bounds"
+
 
 def _rep_xy(cols: dict, extent: bool):
     """Representative coordinates per row: the point, or the bbox centroid
@@ -83,16 +89,18 @@ def _popcount_slots(plane):
     jax.jit,
     static_argnames=("col_names", "has_boxes", "has_windows", "extent", "interpret"),
 )
+@jax.named_scope(POPS_NAME)
 def _pops_pallas(cols3, bids, boxes, wins, *, col_names, has_boxes, has_windows, extent, interpret):
     wide, _ = bk._pallas_block_scan(
         cols3, jnp.maximum(bids, 0), boxes, wins,
         col_names=col_names, has_boxes=has_boxes, has_windows=has_windows,
-        extent=extent, interpret=interpret,
+        extent=extent, interpret=interpret, name=POPS_NAME,
     )
     return _popcount_slots(wide)
 
 
 @partial(jax.jit, static_argnames=("col_names", "has_boxes", "has_windows", "extent"))
+@jax.named_scope(POPS_NAME)
 def _pops_xla(cols3, bids, boxes, wins, *, col_names, has_boxes, has_windows, extent):
     wide, _ = bk._xla_block_scan(
         cols3, jnp.maximum(bids, 0), boxes, wins,
@@ -133,6 +141,7 @@ def block_density(
     jax.jit,
     static_argnames=("col_names", "has_boxes", "has_windows", "extent", "width", "height"),
 )
+@jax.named_scope(DENSITY_NAME)
 def _xla_density(
     cols3, bids, boxes, wins, grid_bounds, *,
     col_names, has_boxes, has_windows, extent, width, height,
@@ -275,6 +284,7 @@ def _pallas_density(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hp, wp), jnp.float32),
         interpret=interpret,
+        name=DENSITY_NAME,
     )(bids, boxes, wins, gb, *cols3)
     return grid[:height, :width]
 
@@ -312,6 +322,7 @@ def _bounds_stack(w, x, y):
 
 
 @partial(jax.jit, static_argnames=("col_names", "has_boxes", "has_windows", "extent"))
+@jax.named_scope(BOUNDS_NAME)
 def _xla_bounds(cols3, bids, boxes, wins, *, col_names, has_boxes, has_windows, extent):
     gathered = {n: c[jnp.maximum(bids, 0)] for n, c in zip(col_names, cols3)}
     w, _ = bk._masks(gathered, boxes, wins, has_boxes, has_windows, extent)
@@ -380,6 +391,7 @@ def _pallas_bounds(cols3, bids, boxes, wins, *, col_names, has_boxes, has_window
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, 8, bk.LANES), jnp.float32),
         interpret=interpret,
+        name=BOUNDS_NAME,
     )(bids, boxes, wins, *cols3)
     return stats[:, 0, :STAT_LANES]
 

@@ -653,6 +653,15 @@ def skip_inner_plane(has_boxes: bool, extent: bool) -> bool:
     return extent and has_boxes
 
 
+#: Device-op names the program chooses: a Pallas kernel's ``name`` becomes
+#: its HLO instruction name, a ``jax.named_scope`` the ``op_name`` of the
+#: XLA twin's fusions. The profiler's trace, and whatever reduces it
+#: (benchmark/kernels/*.json match on these), find a kernel by them, and
+#: they survive a renamed Python function.
+SCAN_NAME = "geomesa_block_scan"
+SCAN_MULTI_NAME = "geomesa_block_scan_multi"
+
+
 def _make_pallas_kernel(
     col_names, has_boxes, has_windows, extent, pack, n_edges=0, n_rints=0
 ):
@@ -681,16 +690,18 @@ def _make_pallas_kernel(
     jax.jit,
     static_argnames=(
         "col_names", "has_boxes", "has_windows", "extent", "interpret",
-        "n_edges", "n_rints",
+        "n_edges", "n_rints", "name",
     ),
 )
 def _pallas_block_scan(
     cols3, bids, boxes, wins, edges=None, rast=None, *, col_names, has_boxes,
-    has_windows, extent, interpret, n_edges=0, n_rints=0,
+    has_windows, extent, interpret, n_edges=0, n_rints=0, name=SCAN_NAME,
 ):
     """cols3: tuple of [n_blocks, SUB, 128] device arrays ordered by
     col_names. bids: i32 [M] candidate block ids (pads repeat block 0; host
-    ignores pad slots). Returns (wide, inner) [M, PACK, 128] i32 planes."""
+    ignores pad slots). Returns (wide, inner) [M, PACK, 128] i32 planes.
+    ``name``: the device op's name (the pops aggregation reuses this
+    kernel under its own)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -731,6 +742,7 @@ def _pallas_block_scan(
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((M, PACK, LANES), jnp.int32)] * n_out,
         interpret=interpret,
+        name=name,
     )(bids, boxes, wins, *extra, *cols3)
     return (out[0], None) if n_out == 1 else (out[0], out[1])
 
@@ -741,6 +753,7 @@ def _pallas_block_scan(
         "col_names", "has_boxes", "has_windows", "extent", "n_edges", "n_rints"
     ),
 )
+@jax.named_scope(SCAN_NAME)
 def _xla_block_scan(
     cols3, bids, boxes, wins, edges=None, rast=None, *, col_names, has_boxes,
     has_windows, extent, n_edges=0, n_rints=0,
@@ -910,6 +923,7 @@ def _pallas_block_scan_multi(
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((M, PACK, LANES), jnp.int32)] * n_out,
         interpret=interpret,
+        name=SCAN_MULTI_NAME,
     )(*args, *cols3)
     return (out[0], None) if n_out == 1 else (out[0], out[1])
 
@@ -920,6 +934,7 @@ def _pallas_block_scan_multi(
         "col_names", "has_boxes", "has_windows", "extent", "n_edges", "n_rints"
     ),
 )
+@jax.named_scope(SCAN_MULTI_NAME)
 def _xla_block_scan_multi(
     cols3, bids, qids, boxes, wins, edges=None, spip=None, rasts=None, *,
     col_names, has_boxes, has_windows, extent, n_edges=0, n_rints=0,

@@ -64,6 +64,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from http.client import HTTPConnection, HTTPException
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qs, quote, urlparse
@@ -71,6 +72,9 @@ from urllib.parse import parse_qs, quote, urlparse
 import numpy as np
 
 from geomesa_tpu import conf
+from geomesa_tpu.obs.trace import NULL_SPAN
+from geomesa_tpu.obs.trace import span as _ospan
+from geomesa_tpu.obs.trace import tracer as _otracer
 from geomesa_tpu.serving.scheduler import ServingRejected
 from geomesa_tpu.serving.tenancy import TenantRegistry
 
@@ -340,22 +344,20 @@ class DataServer:
         self.metrics.counter("geomesa.tiles.served")
         return 200, ctype, body, extra
 
-    def _query(self, type_name: str, query: dict, headers):
-        from geomesa_tpu.planning.errors import QueryGuardError, QueryTimeout
-        from geomesa_tpu.security import VIS_FIELD_KEY, VisibilityError
-        from geomesa_tpu.streaming.replica import StaleRead
-
+    def _query_params(self, type_name: str, query: dict, headers):
+        """One query request's identity and parameters, validated:
+        ``(error response, None)`` or ``(None, parameters)``."""
         req_auths, tenant, err = self._identity(headers)
         if err is not None:
-            return err
+            return err, None
         try:
             sft = self._schema(type_name)
         except KeyError:
-            return self._client_error(404, f"unknown type {type_name!r}")
+            return self._client_error(404, f"unknown type {type_name!r}"), None
         cql = _first(query, "cql") or "INCLUDE"
         fmt = (_first(query, "fmt") or "geojson").lower()
         if fmt not in ("geojson", "arrow"):
-            return self._client_error(400, f"unknown fmt {fmt!r}")
+            return self._client_error(400, f"unknown fmt {fmt!r}"), None
         try:
             limit = _int(query, "limit")
             offset = _int(query, "offset")
@@ -364,12 +366,28 @@ class DataServer:
             staleness = headers.get(STALENESS_HEADER)
             staleness = float(staleness) if staleness is not None else None
         except ValueError as e:
-            return self._client_error(400, f"bad parameter: {e}")
+            return self._client_error(400, f"bad parameter: {e}"), None
         hints = None
         if offset is not None or sort_by is not None:
             from geomesa_tpu.planning.hints import QueryHints
 
             hints = QueryHints(sort_by=sort_by, offset=offset)
+        return None, (
+            req_auths, tenant, sft, cql, fmt, limit, page_rows, hints,
+            staleness,
+        )
+
+    def _query(self, type_name: str, query: dict, headers):
+        from geomesa_tpu.planning.errors import QueryGuardError, QueryTimeout
+        from geomesa_tpu.security import VIS_FIELD_KEY, VisibilityError
+        from geomesa_tpu.streaming.replica import StaleRead
+
+        with _ospan("http.parse"):
+            err, params = self._query_params(type_name, query, headers)
+        if err is not None:
+            return err
+        (req_auths, tenant, sft, cql, fmt, limit, page_rows, hints,
+         staleness) = params
         try:
             fc = self._execute(
                 type_name, cql, limit, hints, tenant, staleness
@@ -403,6 +421,9 @@ class DataServer:
                 if not m.all():
                     fc = fc.mask(m)
         extra = {ROWS_HEADER: str(len(fc))}
+        cur = _otracer().current()
+        if cur is not None:  # the request's ``http`` root
+            cur.trace.root.annotate(fmt=fmt, rows=len(fc))
         if fmt == "arrow":
             try:
                 return 200, ARROW_CTYPE, _arrow_chunks(fc, page_rows), extra
@@ -418,21 +439,57 @@ class DataServer:
         return self.cold.get_schema(type_name)
 
     def _execute(self, type_name, cql, limit, hints, tenant, staleness):
-        if self.replica is not None:
-            fc = self.replica.query(
-                cql, hints=hints, max_staleness_ms=staleness,
-                tenant=tenant, block=False,
-            )
-        elif self.lam is not None:
-            fc = self.lam.query(cql, hints=hints, tenant=tenant, block=False)
-        else:
-            fc = self.sched.submit(
-                type_name, cql, limit=limit, hints=hints, block=False,
-                tenant=tenant,
-            ).result()
+        # ``http.wait``: this thread plans and is admitted (``submit``:
+        # the request's ``query`` root has those phases), then blocks on
+        # the future until the dispatcher resolves it
+        with _ospan("http.wait") as sp:
+            if self.replica is not None:
+                fc = self.replica.query(
+                    cql, hints=hints, max_staleness_ms=staleness,
+                    tenant=tenant, block=False,
+                )
+            elif self.lam is not None:
+                fc = self.lam.query(
+                    cql, hints=hints, tenant=tenant, block=False
+                )
+            else:
+                sp.event("submit")
+                fut = self.sched.submit(
+                    type_name, cql, limit=limit, hints=hints, block=False,
+                    tenant=tenant,
+                )
+                sp.event("future")
+                fc = fut.result()
         if limit is not None and len(fc) > limit:
             fc = fc.take(np.arange(limit))
         return fc
+
+    def write_chunks(self, payload, wfile) -> int:
+        """Drain a chunk generator to the socket with chunked framing;
+        returns the payload bytes written. The generators are lazy, so
+        the GeoJSON / Arrow encoding of the whole answer runs HERE, chunk
+        by chunk between the writes: the ``encode`` span holds both, and
+        ``write_s`` is the part spent inside ``wfile.write``."""
+        with _ospan("encode", cpu=True) as sp:
+            timed = sp is not NULL_SPAN
+            sent = chunks = 0
+            write_s = 0.0
+            for chunk in payload:
+                if not chunk:
+                    continue
+                frame = b"%x\r\n%s\r\n" % (len(chunk), chunk)
+                if timed:
+                    t = time.perf_counter()
+                    wfile.write(frame)
+                    write_s += time.perf_counter() - t
+                else:
+                    wfile.write(frame)
+                sent += len(chunk)
+                chunks += 1
+            wfile.write(b"0\r\n\r\n")
+            if timed:
+                sp.annotate(bytes=sent, chunks=chunks, write_s=write_s)
+        return sent
 
     # -- POST -------------------------------------------------------------
     def handle_post(self, path: str, headers, rfile):
@@ -569,9 +626,10 @@ def _arrow_chunks(fc, page_rows: int):
     _pa()
     import pyarrow.ipc as ipc
 
-    table = to_arrow_table(fc)
-
     def gen():
+        # the table is built on the first pull, as the GeoJSON features
+        # are: all of a response's encoding runs where it is written
+        table = to_arrow_table(fc)
         sink = _ArrowSink()
         with ipc.new_stream(sink, table.schema) as writer:
             if table.num_rows:
@@ -624,7 +682,8 @@ def _handler_class(server: DataServer):
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"  # chunked responses need 1.1
 
-        def _respond(self, result) -> None:
+        def _respond(self, result) -> int:
+            """Write one response; returns the payload bytes written."""
             code, ctype, payload, extra = result
             try:
                 if hasattr(payload, "__next__"):  # a chunk generator
@@ -634,13 +693,7 @@ def _handler_class(server: DataServer):
                         self.send_header(k, v)
                     self.send_header("Transfer-Encoding", "chunked")
                     self.end_headers()
-                    for chunk in payload:
-                        if chunk:
-                            self.wfile.write(
-                                b"%x\r\n%s\r\n" % (len(chunk), chunk)
-                            )
-                    self.wfile.write(b"0\r\n\r\n")
-                    return
+                    return server.write_chunks(payload, self.wfile)
                 body = payload.encode() if isinstance(payload, str) else payload
                 self.send_response(code)
                 self.send_header("Content-Type", ctype)
@@ -649,29 +702,37 @@ def _handler_class(server: DataServer):
                 self.send_header("Content-Length", str(len(body)))
                 self.end_headers()
                 self.wfile.write(body)
+                return len(body)
             except (BrokenPipeError, ConnectionResetError):
-                pass  # client went away mid-response
+                return 0  # client went away mid-response
 
-        def _handle(self, fn) -> None:
-            try:
-                result = fn()
-            except (BrokenPipeError, ConnectionResetError):
-                return
-            except Exception as e:  # defensive: a worker must not die
-                result = server._client_error(
-                    500, f"{type(e).__name__}: {e}"
-                )
-            self._respond(result)
+        def _handle(self, method: str, route) -> None:
+            """One request under its root ``http``, from the request
+            parsed to the last byte written (``capture=False``: the slow
+            log takes the ``query`` root inside, not the transport)."""
+            url = urlparse(self.path)
+            with _otracer().trace(
+                "http", capture=False, method=method, path=url.path
+            ) as trace:
+                try:
+                    result = route(url)
+                except (BrokenPipeError, ConnectionResetError):
+                    return
+                except Exception as e:  # defensive: a worker must not die
+                    result = server._client_error(
+                        500, f"{type(e).__name__}: {e}"
+                    )
+                sent = self._respond(result)
+                if trace is not None:
+                    trace.root.annotate(status=result[0], bytes=sent)
 
         def do_GET(self):  # noqa: N802 (stdlib naming)
-            url = urlparse(self.path)
-            self._handle(lambda: server.handle_get(
+            self._handle("GET", lambda url: server.handle_get(
                 url.path, parse_qs(url.query), self.headers
             ))
 
         def do_POST(self):  # noqa: N802 (stdlib naming)
-            url = urlparse(self.path)
-            self._handle(lambda: server.handle_post(
+            self._handle("POST", lambda url: server.handle_post(
                 url.path, self.headers, self.rfile
             ))
 

@@ -598,6 +598,23 @@ class QueryScheduler:
         self.metrics.counter("geomesa.serving.batches")
         self.metrics.counter("geomesa.serving.batched_queries", len(leaders))
 
+        from geomesa_tpu.obs.trace import tracer
+
+        # one root ``batch`` a fused batch, in THIS thread, from the
+        # staging to the last member resolved: its ``dispatch`` child is
+        # the real span of what every member's retroactive ``dispatch``
+        # repeats, and each member's ``query`` root names it
+        # (``batch_trace``)
+        otr = tracer()
+        with otr.trace(
+            "batch", capture=False, members=len(live), leaders=len(leaders),
+            coalesced=len(live) - len(leaders),
+        ) as btrace:
+            self._run_batch(live, leaders, followers, cache, tick, btrace)
+
+    def _run_batch(self, live, leaders, followers, cache, tick, btrace) -> None:
+        """Stage the leaders' scans in one ``submit_many``, then resolve
+        every member in turn (``_dispatch``'s second half)."""
         from geomesa_tpu.obs.trace import phase_breakdown, tracer
 
         otr = tracer()
@@ -645,6 +662,8 @@ class QueryScheduler:
                     root, "dispatch", t0=t_sm0, end=t_dispatch,
                     batch=len(leaders),
                 )
+                if btrace is not None:
+                    root.annotate(batch_trace=btrace.trace_id)
         for j, (it, fin) in enumerate(zip(leaders, finishes)):
             group = [it] + followers.get(j, [])
             for g in group:

@@ -31,6 +31,8 @@ from __future__ import annotations
 import numpy as np
 
 from geomesa_tpu.index.api import IndexKeySpace, ScanConfig, WriteKeys
+from geomesa_tpu.obs.trace import add as _oadd
+from geomesa_tpu.obs.trace import event as _oevent
 from geomesa_tpu.planning.errors import check_deadline
 from geomesa_tpu.scan import block_kernels as bk
 
@@ -247,6 +249,21 @@ class SortedKeys:
                 if z > a:
                     (contained if c else overlap).append((a, z))
         return _merge_spans(overlap), _merge_spans(contained)
+
+def _await_device(arrays) -> bool:
+    """Under a caller's span (its ``scan`` or ``agg``), cut the pull in
+    two: segment ``wait`` until the device has the result (the kernel and
+    whatever queued before it), then ``pull``, which the caller's
+    ``device_get`` fills (the rest of the copy started at dispatch).
+    Untraced, nothing: ``device_get`` waits for both, as it always did."""
+    traced = _oevent("wait")
+    if traced:
+        import jax
+
+        jax.block_until_ready(arrays)
+        _oevent("pull")
+    return traced
+
 
 def _take(col: np.ndarray, perm: np.ndarray) -> np.ndarray:
     from geomesa_tpu import native
@@ -597,10 +614,16 @@ class IndexTable(SortedKeys):
         jax dispatch is asynchronous — submitting several queries' kernels
         before pulling any result overlaps their device work and hides the
         per-pull link latency behind computation (DataStore.query_many).
+
+        Under a caller's ``dispatch`` span it marks the segments
+        ``prune`` (spans, candidate blocks, padding) and ``enqueue`` (the
+        jitted call), and counts ``blocks`` (candidates) and ``slots``
+        (what the kernel's bucket pads them to).
         """
         if config.disjoint or self.n == 0:
             return lambda: (np.zeros(0, np.int64), np.zeros(0, bool))
         check_deadline(deadline, "range pruning")
+        _oevent("prune")
         overlap, contained = self.candidate_spans_split(config)
         has_pred = config.boxes is not None or config.windows is not None
 
@@ -694,6 +717,7 @@ class IndexTable(SortedKeys):
             return [self.scan_submit(c, deadline=deadline) for c in configs]
 
         n_q = len(configs)
+        _oevent("prune")
         finishes: list = [None] * n_q
         # groups: variant key -> [(j, config, bids_padded?, ...)]
         groups: dict[tuple, list] = {}
@@ -789,6 +813,7 @@ class IndexTable(SortedKeys):
             and sum(len(m[2]) for m in members) < self.fused_pack_capacity // 8
         ):
             for j, config, blocks, overlap, contained in members:
+                _oevent("prune")  # back from the last member's enqueue
                 finishes[j] = self._make_finish(
                     self._device_scan_submit(blocks, config),
                     config, overlap, contained, deadline,
@@ -807,12 +832,14 @@ class IndexTable(SortedKeys):
         return boxes, wins
 
     @staticmethod
-    def _fused_pull(wide, inner):
+    def _fused_pull(wide, inner, members: int = 0):
         """Start the async device->host copies for a fused chunk's planes
         NOW (see _device_scan_submit on why) and return a memoized
         ``group_pull() -> (wide_h, inner_h)``: the chunk pulls ONCE, on
         its first member's finish, and members decode lazily. Shared by
-        the single-device and distributed dispatches."""
+        the single-device and distributed dispatches. The member whose
+        finish makes the pull carries its ``wait`` and ``pull`` segments
+        and ``group`` = ``members``; the others show none."""
         import jax
 
         for plane in (wide, inner):
@@ -822,6 +849,8 @@ class IndexTable(SortedKeys):
 
         def group_pull():
             if "planes" not in pulled:
+                if _await_device((wide, inner)):
+                    _oadd("group", members)
                 wide_h, inner_h = jax.device_get((wide, inner))
                 pulled["planes"] = (
                     np.asarray(wide_h),
@@ -890,6 +919,7 @@ class IndexTable(SortedKeys):
         if self._fused_route_single(members, finishes, deadline):
             return
         check_deadline(deadline, "device scan dispatch")
+        _oevent("prune")  # back from the last chunk's enqueue
         boxes, wins = self._fused_param_stacks(members)
         chunk_e, edges, pip = self._chunk_edge_stack(members)
         chunk_r, rasts, has_rast = self._chunk_raster_stack(members)
@@ -907,24 +937,29 @@ class IndexTable(SortedKeys):
             np.concatenate(bid_parts), self.n_blocks, bucket=slots
         )
         self._record_scan(names, len(bids))
+        _oadd("blocks", n_real)
+        _oadd("slots", len(bids))
+        _oadd("groups", 1)
         qids = np.zeros(len(bids), np.int32)
         qids[:n_real] = np.concatenate(qid_parts)
         spip = None
         if chunk_e or chunk_r:
             spip = poly_slot[qids].astype(np.int32)
             spip[n_real:] = 0  # pad slots keep the (cheaper) box leg
+        _oevent("enqueue")
         wide, inner = bk.block_scan_multi(
             self._cols_args(names), bids, qids, boxes, wins,
             col_names=names, has_boxes=has_boxes, has_windows=has_windows,
             extent=self.extent, edges=edges, spip=spip, n_edges=chunk_e,
             rasts=rasts, n_rints=chunk_r,
         )
-        group_pull = self._fused_pull(wide, inner)
+        group_pull = self._fused_pull(wide, inner, len(members))
 
         def member_finish(k):
             j, config, blocks, overlap, contained = members[k]
             s, e = segs[k]
             wide_h, inner_h = group_pull()
+            _oevent("bits")
             check_deadline(deadline, "bitmask decode")
             rows, certain = bk.decode_bits_pair(
                 np.ascontiguousarray(wide_h[s:e]),
@@ -1046,6 +1081,9 @@ class IndexTable(SortedKeys):
         boxes, wins = self._params(config)
         names = self._scan_cols(config)
         self._record_scan(names, len(bids))
+        _oadd("blocks", n_real)
+        _oadd("slots", len(bids))
+        _oevent("enqueue")
         wide, inner = bk.block_scan(
             self._cols_args(names), bids, boxes, wins,
             **self._scan_kernel_kwargs(config, names),
@@ -1059,9 +1097,11 @@ class IndexTable(SortedKeys):
                 plane.copy_to_host_async()
 
         def finish():
+            _await_device((wide, inner))
             # inner is None on extent box scans (skip_inner_plane): pull
             # and decode the wide plane only — half the per-query bytes
             wide_h, inner_h = jax.device_get((wide, inner))
+            _oevent("bits")
             inner_h = None if inner_h is None else np.asarray(inner_h)
             return bk.decode_bits_pair(np.asarray(wide_h), inner_h, bids, n_real)
 
@@ -1094,17 +1134,25 @@ class IndexTable(SortedKeys):
         from geomesa_tpu.scan import aggregations
 
         blocks = self._full_or(blocks)
-        bids, _ = bk.pad_bids(blocks, self.n_blocks, pad=-1)
+        bids, n_real = bk.pad_bids(blocks, self.n_blocks, pad=-1)
         boxes, wins = self._params(config)
         names = self._agg_cols(config)
         self._record_scan(names, len(bids))
+        _oadd("blocks", n_real)
+        _oadd("slots", len(bids))
+        _oevent("enqueue")
         grid = aggregations.block_density(
             self._cols_args(names), bids, boxes, wins, grid_bounds,
             width=width, height=height, **self._kernel_kwargs(config, names),
         )
         if hasattr(grid, "copy_to_host_async"):
             grid.copy_to_host_async()
-        return lambda: np.asarray(jax.device_get(grid))
+
+        def finish():
+            _await_device(grid)
+            return np.asarray(jax.device_get(grid))
+
+        return finish
 
     def _device_bounds(self, blocks, config):
         """(count, envelope | None) over wide-predicate hits."""
@@ -1185,6 +1233,7 @@ class IndexTable(SortedKeys):
         kernel before pulling any grid (DataStore.density_many)."""
         if config.disjoint or self.n == 0:
             return lambda: np.zeros((height, width), dtype=np.float32)
+        _oevent("prune")
         blocks = self._agg_blocks(config)
         if len(blocks) == 0:
             return lambda: np.zeros((height, width), dtype=np.float32)

@@ -19,6 +19,8 @@ Layers:
 """
 
 import json
+import os
+import sys
 import threading
 import time
 
@@ -97,15 +99,66 @@ def test_disarmed_tracing_is_noop():
     assert ds.slow_queries() == []
 
 
-def test_disarmed_query_smoke_no_trace_state():
-    """End to end through DataStore.query: the disarmed path leaves no
-    tracer state behind (buffer, slow ring, root counter all zero)."""
+def test_disarmed_query_smoke_no_trace_state(monkeypatch):
+    """End to end through every traced entry point (query, query_many,
+    count, density, a served request in both formats): the disarmed path
+    leaves no tracer state behind (buffer, slow ring, root counters all
+    empty), allocates no span, reads no thread clock and enters no
+    profiler annotation."""
+    from geomesa_tpu.obs import trace as otrace
+    from geomesa_tpu.serving import DataClient
+    from geomesa_tpu.serving.http import DataServer
+
     _disarm()
+    made = {"spans": 0, "cpu": 0, "annotations": 0}
+    span_init, thread_time = otrace.Span.__init__, time.thread_time
+
+    def counted_init(self, *a, **kw):
+        made["spans"] += 1
+        span_init(self, *a, **kw)
+
+    def counted_thread_time():
+        made["cpu"] += 1
+        return thread_time()
+
+    def counted_annotation(*a, **kw):
+        made["annotations"] += 1
+
+    monkeypatch.setattr(otrace.Span, "__init__", counted_init)
+    monkeypatch.setattr(otrace.time, "thread_time", counted_thread_time)
+    monkeypatch.setattr(otrace, "_annotation", counted_annotation)
     ds = _store(n=500)
     ds.query("t", Q)
+    ds.query_many("t", [Q, "BBOX(geom, -10, -10, 30, 30)"])
+    ds.count("t", Q)
+    ds.density("t", Q, width=32, height=32)
+    srv = DataServer(ds, port=0).start()
+    try:
+        with DataClient(srv.url) as c:
+            assert len(c.query("t", Q)["features"])
+            assert c.query("t", Q, fmt="arrow")
+    finally:
+        srv.close()
+        ds.close()
+    assert made == {"spans": 0, "cpu": 0, "annotations": 0}
     t = obs.tracer()
     with t._lock:
-        assert len(t.buffer) == 0 and t.slow == [] and t._n_roots == 0
+        assert len(t.buffer) == 0 and t.slow == [] and not t._n_roots
+
+
+def test_null_span_event_allocates_nothing():
+    """The disarmed forms of ``event`` and ``add``: the shared null
+    span's methods and the module-level probes return without a single
+    allocation."""
+    _disarm()
+    for _ in range(10):  # settle lazily-made method caches
+        NULL_SPAN.event("wait"), obs.event("wait"), NULL_SPAN.add("blocks", 3)
+    before = sys.getallocatedblocks()
+    for _ in range(2000):
+        assert NULL_SPAN.event("wait") is False
+        assert obs.event("wait") is False
+        NULL_SPAN.add("blocks", 3)
+    assert sys.getallocatedblocks() - before < 8
 
 
 # -- layer 2: trace vs explain --------------------------------------------
@@ -285,6 +338,197 @@ def test_wal_spans_inside_write_trace(tmp_path):
     assert reg.snapshot()["histograms"]["geomesa.stream.wal.fsync"]["count"] >= 1
 
 
+# -- layer 3b: every operation under one root, phases inside -------------
+
+MANY = [f"BBOX(geom, {-30 + 3 * i}, -20, {10 + 3 * i}, 20)" for i in range(12)]
+
+#: operation -> (root name, the direct children that must cover its wall)
+ROOTED = {
+    "query": ("query", {"plan", "dispatch", "scan", "decode"}),
+    "query_many": ("query_many", {"plan", "dispatch", "scan", "decode"}),
+    "count": ("count", {"plan", "dispatch", "scan", "decode"}),
+    "density": ("density", {"plan", "dispatch", "agg"}),
+    "served-geojson": ("http", {"http.parse", "http.wait", "encode"}),
+    "served-arrow": ("http", {"http.parse", "http.wait", "encode"}),
+}
+#: a clock read on either side of a span's ends
+SLACK_S = 2e-4
+
+
+def _run_rooted(op, ds, client):
+    if op == "query":
+        return ds.query("t", Q)
+    if op == "query_many":
+        return ds.query_many("t", MANY)
+    if op == "count":
+        return ds.count("t", Q)
+    if op == "density":
+        return ds.density("t", Q, width=64, height=64)
+    return client.query("t", Q, fmt=op.removeprefix("served-"))
+
+
+@pytest.mark.parametrize("op", sorted(ROOTED))
+def test_every_operation_is_one_root_with_covering_phases(op):
+    """Each entry point of the two benchmark cells opens exactly ONE
+    root of its own name; the root's phases cover at least 90% of its
+    wall (best of a few warm runs: a collector pause is not a phase);
+    every span's segments lie within it; where a span reads the CPU clock
+    its CPU time lies within its wall time;
+    a served request's ``http`` root and its ``query`` root name each
+    other."""
+    from geomesa_tpu.serving import DataClient
+    from geomesa_tpu.serving.http import DataServer
+
+    _arm(sample=1)
+    ds = _store()
+    srv = client = None
+    if op.startswith("served"):
+        srv = DataServer(ds, port=0).start()
+        client = DataClient(srv.url)
+    root_name, want = ROOTED[op]
+    try:
+        for _ in range(3):  # warm kernels, imports, connections
+            _run_rooted(op, ds, client)
+        best = 0.0
+        for _ in range(5):
+            obs.install(obs.Tracer())
+            _run_rooted(op, ds, client)
+            if srv is not None:
+                # the handler ends its root after the client has read
+                # the last byte, the dispatcher its ``batch`` after the
+                # last member is resolved
+                deadline = time.monotonic() + 5.0
+                while time.monotonic() < deadline and not (
+                    {"http", "query", "batch"}
+                    <= {t.name for t in obs.tracer().traces()}
+                ):
+                    time.sleep(0.005)
+            traces = obs.tracer().traces()
+            roots = [t for t in traces if t.name == root_name]
+            assert len(roots) == 1, [t.name for t in traces]
+            if srv is None:
+                assert [t.name for t in traces] == [root_name]
+            tr = roots[0]
+            phases = tr.phases()
+            assert want <= {s.name for s in phases}, [s.name for s in phases]
+            covered = sum(s.dur_s for s in phases)
+            assert covered <= tr.wall_s * 1.001 + SLACK_S
+            best = max(best, covered / tr.wall_s)
+            for s in tr.spans:
+                a = s.attrs or {}
+                # the phases that never sleep by design read the CPU clock
+                assert ("cpu_s" in a) == (s.name in ("plan", "decode", "encode"))
+                if "cpu_s" in a:
+                    assert 0.0 <= a["cpu_s"] <= s.dur_s + SLACK_S, (s.name, a)
+                segs = a.get("segments", {})
+                assert all(w >= 0.0 for w in segs.values())
+                assert sum(segs.values()) <= s.dur_s + SLACK_S
+            by_name = {s.name: s for s in tr.spans}
+            if op == "query_many":
+                members = sorted(
+                    s.attrs["member"] for s in tr.spans if s.name == "scan"
+                )
+                assert members == list(range(len(MANY)))
+                assert tr.root.attrs["members"] == len(MANY)
+                pulls = [s for s in tr.spans if s.name == "scan"
+                         and "wait" in s.attrs["segments"]]
+                # one pull serves the fused group: one member carries it
+                assert len(pulls) == 1 and pulls[0].attrs["group"] == len(MANY)
+            if op in ("query", "count"):
+                assert set(by_name["scan"].attrs["segments"]) == {
+                    "wait", "pull", "bits"}
+                assert set(by_name["decode"].attrs["segments"]) == {
+                    "gather", "refine", "post"}
+                d = by_name["dispatch"].attrs
+                assert set(d["segments"]) == {"prune", "enqueue"}
+                assert 1 <= d["blocks"] <= d["slots"]
+            if op == "density":
+                assert set(by_name["agg"].attrs["segments"]) == {"wait", "pull"}
+            if srv is not None:
+                query = next(t for t in traces if t.name == "query")
+                batch = next(t for t in traces if t.name == "batch")
+                assert tr.root.attrs["query_trace"] == query.trace_id
+                assert query.root.attrs["http_trace"] == tr.trace_id
+                assert query.root.attrs["batch_trace"] == batch.trace_id
+                assert tr.root.attrs["status"] == 200
+                assert tr.root.attrs["fmt"] == op.removeprefix("served-")
+                assert tr.root.attrs["rows"] > 0
+                enc = by_name["encode"].attrs
+                assert enc["bytes"] == tr.root.attrs["bytes"] > 0
+                assert 0.0 <= enc["write_s"] <= by_name["encode"].dur_s
+                assert {"dispatch"} <= {s.name for s in batch.phases()}
+        assert best >= 0.9, best
+    finally:
+        if client is not None:
+            client.close()
+        if srv is not None:
+            srv.close()
+        ds.close()
+
+
+def test_profiler_annotations_only_for_retained_traces(monkeypatch):
+    """Spans and segments of a RETAINED trace are ``geomesa:`` profiler
+    annotations carrying the trace id, properly nested; a tree built
+    only for the slow log enters none and reads no CPU clock."""
+    import jax.profiler
+
+    from geomesa_tpu.obs import trace as otrace
+
+    cpu_reads = []
+    thread_time = time.thread_time
+    monkeypatch.setattr(
+        otrace.time, "thread_time",
+        lambda: cpu_reads.append(1) or thread_time(),
+    )
+
+    live: list = []
+    seen: list = []
+
+    class FakeAnnotation:
+        def __init__(self, name, **kw):
+            self.name, self.kw = name, kw
+
+        def __enter__(self):
+            live.append(self.name)
+            seen.append((self.name, self.kw))
+
+        def __exit__(self, *exc):
+            assert live.pop() == self.name  # innermost first
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+    ds = _store()
+    _arm(sample=0, slow_ms=0.0001)  # every tree built, none retained
+    ds.query("t", Q)
+    assert len(ds.slow_queries()) == 1 and seen == [] and cpu_reads == []
+    _arm(sample=1)
+    ds.query("t", Q)
+    assert live == []
+    assert len(cpu_reads) == 4  # both ends of ``plan`` and of ``decode``
+    names = [n for n, _ in seen]
+    for want in ("geomesa:query", "geomesa:plan", "geomesa:dispatch",
+                 "geomesa:dispatch.prune", "geomesa:dispatch.enqueue",
+                 "geomesa:scan", "geomesa:scan.wait", "geomesa:scan.pull",
+                 "geomesa:scan.bits", "geomesa:decode",
+                 "geomesa:decode.gather", "geomesa:decode.refine",
+                 "geomesa:decode.post"):
+        assert names.count(want) == 1, (want, names)
+    tid = obs.tracer().traces()[-1].trace_id
+    assert all(kw == {"trace": tid} for _, kw in seen)
+    # retroactive spans and roots that end on another thread cannot be
+    # annotations: a served query's root and its queue phases are not
+    seen.clear()
+    sched = ds.serve()
+    try:
+        sched.submit("t", Q).result(30)
+    finally:
+        sched.close()
+        ds.close()
+    names = {n for n, _ in seen}
+    assert "geomesa:batch" in names and "geomesa:plan" in names
+    assert not names & {"geomesa:query", "geomesa:queue", "geomesa:admit",
+                        "geomesa:batch.wait"}
+
+
 # -- layer 4: surfaces ----------------------------------------------------
 
 
@@ -367,8 +611,14 @@ def test_chrome_trace_export(tmp_path):
     names = {ev["name"] for ev in events}
     assert {"query", "plan", "scan"} <= names
     # slow-ring traces export once even when also sampled
-    ids = [ev["pid"] for ev in events if ev["name"] == "query"]
+    ids = [ev["args"]["trace_id"] for ev in events if ev["name"] == "query"]
     assert len(ids) == len(set(ids))
+    # one process, a lane per real thread, on the wall clock: traces of
+    # concurrent requests line up, each span on the thread that ran it
+    assert {ev["pid"] for ev in events} == {os.getpid()}
+    assert {ev["tid"] for ev in events} == {threading.get_ident()}
+    now_us = time.time() * 1e6
+    assert all(now_us - 60e6 < ev["ts"] <= now_us for ev in events)
 
 
 def test_concurrent_tracing_keeps_trees_separate():

@@ -539,7 +539,7 @@ def test_telemetry_recorder_rings_window_and_bound():
 def test_debug_surfaces_slow_filter_audit_trace_crossref(tmp_path):
     """/debug/slow filters by type; /debug/audit rows carry the trace
     id that cross-references the slow capture and the Chrome export
-    (pid); /stats serves the sketches; unknown paths 404."""
+    (``args.trace_id``); /stats serves the sketches; unknown paths 404."""
     conf.OBS_SLOW_MS.set(0.0001)  # everything is "slow"
     ds = _store(n=500, audit=True)
     sft2 = FeatureType.from_spec("u", SPEC)
@@ -567,8 +567,8 @@ def test_debug_surfaces_slow_filter_audit_trace_crossref(tmp_path):
         assert trace_ids == slow_ids
         _, body = _get(srv.url + "/debug/trace")
         chrome = json.loads(body)
-        pids = {ev["pid"] for ev in chrome["traceEvents"]}
-        assert trace_ids <= pids
+        exported = {ev["args"]["trace_id"] for ev in chrome["traceEvents"]}
+        assert trace_ids <= exported
         # /stats serves the sketch bundle per type
         _, body = _get(srv.url + "/stats")
         stats = json.loads(body)
