@@ -50,6 +50,15 @@ class NormalizedDimension:
         i = np.floor((d - self.min) * self._normalizer).astype(np.int64)
         return np.clip(i, 0, self.max_index)
 
+    def normalize_one(self, d: float) -> int:
+        """:meth:`normalize` of one float as a Python int: the same f64
+        arithmetic without the NumPy round trip (a plan normalizes six
+        query corners a decomposition). NaN clamps to 0."""
+        v = (d - self.min) * self._normalizer
+        if not v >= 0.0:
+            return 0
+        return int(v) if v < self.bins else self.max_index
+
     def denormalize(self, i):
         """Map bin ordinal(s) to the bin-center value."""
         i = np.asarray(i, dtype=np.float64)

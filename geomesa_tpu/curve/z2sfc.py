@@ -13,7 +13,9 @@ import numpy as np
 
 from geomesa_tpu.curve.normalize import NormalizedLat, NormalizedLon
 from geomesa_tpu.curve.zorder import Z2
-from geomesa_tpu.curve.zranges import IndexRange, ZBox, zranges
+from geomesa_tpu.curve.zranges import (
+    IndexRange, ranges_from_arrays, with_inner, zranges_arrays,
+)
 
 
 class Z2SFC:
@@ -48,26 +50,38 @@ class Z2SFC:
         max_recurse: int | None = None,
         inner: bool = False,
     ) -> list[IndexRange]:
-        """Covering z-ranges for (xmin, ymin, xmax, ymax) boxes.
+        """:meth:`ranges_arrays` as one ``IndexRange`` a range."""
+        return ranges_from_arrays(
+            *self.ranges_arrays(bounds, max_ranges, max_recurse, inner)
+        )
+
+    def ranges_arrays(
+        self,
+        bounds: Sequence[tuple[float, float, float, float]],
+        max_ranges: int | None = None,
+        max_recurse: int | None = None,
+        inner: bool = False,
+    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Covering z-ranges for (xmin, ymin, xmax, ymax) boxes:
+        ``(lower u64[k], upper u64[k], contained bool[k])``.
 
         Boxes must be axis-ordered (min <= max per dimension); callers split
         antimeridian-crossing boxes into two, as the reference's do.
         ``inner=True``: classify containment 2 cells inward so contained
-        rows are certain f64 hits (see Z3SFC.ranges).
+        rows are certain f64 hits (see Z3SFC.ranges_arrays).
         """
-        boxes = []
-        inner_boxes: list[ZBox] | None = [] if inner else None
+        los, his = [], []
         for (xmin, ymin, xmax, ymax) in bounds:
             if xmin > xmax or ymin > ymax:
                 raise ValueError(f"inverted bbox: {(xmin, ymin, xmax, ymax)}")
-            lo = (int(self.lon.normalize(xmin)), int(self.lat.normalize(ymin)))
-            hi = (int(self.lon.normalize(xmax)), int(self.lat.normalize(ymax)))
-            boxes.append(ZBox(lo, hi))
-            if inner:
-                inner_boxes.append(
-                    ZBox(tuple(v + 2 for v in lo), tuple(max(v - 2, 0) for v in hi))
-                )
-        return zranges(
-            Z2, boxes, max_ranges=max_ranges, max_recurse=max_recurse,
-            inner_boxes=inner_boxes,
+            los.append((self.lon.normalize_one(xmin), self.lat.normalize_one(ymin)))
+            his.append((self.lon.normalize_one(xmax), self.lat.normalize_one(ymax)))
+        return zranges_arrays(
+            Z2,
+            *with_inner(
+                np.array(los, dtype=np.uint64).reshape(-1, 2),
+                np.array(his, dtype=np.uint64).reshape(-1, 2),
+                inner,
+            ),
+            max_ranges=max_ranges, max_recurse=max_recurse,
         )

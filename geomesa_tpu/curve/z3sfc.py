@@ -16,7 +16,10 @@ import numpy as np
 from geomesa_tpu.curve.binnedtime import MAX_OFFSET, TimePeriod
 from geomesa_tpu.curve.normalize import NormalizedLat, NormalizedLon, NormalizedTime
 from geomesa_tpu.curve.zorder import Z3
-from geomesa_tpu.curve.zranges import IndexRange, ZBox, zranges
+from geomesa_tpu.curve.zranges import (
+    IndexRange, ranges_from_arrays, with_inner, zranges_arrays,
+    zranges_arrays_each,
+)
 
 _INSTANCES: dict[TimePeriod, "Z3SFC"] = {}
 
@@ -68,10 +71,24 @@ class Z3SFC:
         max_recurse: int | None = None,
         inner: bool = False,
     ) -> list[IndexRange]:
-        """Covering z-ranges for spatial boxes x time-offset windows.
+        """:meth:`ranges_arrays` as one ``IndexRange`` a range."""
+        return ranges_from_arrays(
+            *self.ranges_arrays(bounds, times, max_ranges, max_recurse, inner)
+        )
+
+    def ranges_arrays(
+        self,
+        bounds: Sequence[tuple[float, float, float, float]],
+        times: Sequence[tuple[float, float]],
+        max_ranges: int | None = None,
+        max_recurse: int | None = None,
+        inner: bool = False,
+    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Covering z-ranges for spatial boxes x time-offset windows:
+        ``(lower u64[k], upper u64[k], contained bool[k])``.
 
         Reference Z3SFC.ranges:59-67 — the cartesian product of spatial
-        bounds and (in-bin) time windows becomes one ZBox each.
+        bounds and (in-bin) time windows becomes one box each.
 
         ``inner=True`` additionally classifies containment against ordinals
         shrunk 2 cells inward per dimension, making contained-range rows
@@ -79,30 +96,54 @@ class Z3SFC:
         absorbs normalize() floor rounding on both the query bounds and the
         stored values.
         """
-        boxes = []
-        inner_boxes: list[ZBox] | None = [] if inner else None
+        mins, maxes = self._corners(bounds, times)  # [nb, nt, 3]
+        return zranges_arrays(
+            Z3, *with_inner(mins.reshape(-1, 3), maxes.reshape(-1, 3), inner),
+            max_ranges=max_ranges, max_recurse=max_recurse,
+        )
+
+    def ranges_arrays_by_window(
+        self,
+        bounds: Sequence[tuple[float, float, float, float]],
+        times: Sequence[tuple[float, float]],
+        inner: bool = False,
+    ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+        """One :meth:`ranges_arrays` decomposition a time window (each
+        the union of ``bounds`` under that window alone), all in one native
+        call: ``(lower, upper, contained, counts i64[len(times)])``, window
+        w's ranges after window w-1's, ``counts[w]`` of them."""
+        mins, maxes = self._corners(bounds, times)
+        return zranges_arrays_each(
+            Z3,
+            *with_inner(
+                np.ascontiguousarray(mins.transpose(1, 0, 2)),
+                np.ascontiguousarray(maxes.transpose(1, 0, 2)),
+                inner,
+            ),
+        )
+
+    def _corners(self, bounds, times) -> tuple[np.ndarray, np.ndarray]:
+        """The min and max corner ordinals of every bounds x times box,
+        u64 ``[len(bounds), len(times), 3]`` each."""
+        los, his = [], []
         for (xmin, ymin, xmax, ymax) in bounds:
             if xmin > xmax or ymin > ymax:
                 raise ValueError(f"inverted bbox: {(xmin, ymin, xmax, ymax)}")
             for (tmin, tmax) in times:
                 if tmin > tmax:
                     raise ValueError(f"inverted time window: {(tmin, tmax)}")
-                lo = (
-                    int(self.lon.normalize(xmin)),
-                    int(self.lat.normalize(ymin)),
-                    int(self.time.normalize(tmin)),
-                )
-                hi = (
-                    int(self.lon.normalize(xmax)),
-                    int(self.lat.normalize(ymax)),
-                    int(self.time.normalize(tmax)),
-                )
-                boxes.append(ZBox(lo, hi))
-                if inner:
-                    inner_boxes.append(
-                        ZBox(tuple(v + 2 for v in lo), tuple(max(v - 2, 0) for v in hi))
-                    )
-        return zranges(
-            Z3, boxes, max_ranges=max_ranges, max_recurse=max_recurse,
-            inner_boxes=inner_boxes,
+                los.append((
+                    self.lon.normalize_one(xmin),
+                    self.lat.normalize_one(ymin),
+                    self.time.normalize_one(tmin),
+                ))
+                his.append((
+                    self.lon.normalize_one(xmax),
+                    self.lat.normalize_one(ymax),
+                    self.time.normalize_one(tmax),
+                ))
+        shape = (len(bounds), len(times), 3)
+        return (
+            np.array(los, dtype=np.uint64).reshape(shape),
+            np.array(his, dtype=np.uint64).reshape(shape),
         )

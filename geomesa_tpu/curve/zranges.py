@@ -50,6 +50,27 @@ class ZBox:
     maxes: tuple[int, ...]
 
 
+def with_inner(mins: np.ndarray, maxes: np.ndarray, inner: bool):
+    """``(mins, maxes, imins, imaxes)`` for :func:`zranges_arrays` from
+    the boxes' corner ordinals (u64, dimensions last). ``inner`` adds the
+    boxes shrunk 2 cells inward per dimension (None, None otherwise); a
+    box under 4 cells wide inverts: never contained."""
+    if not inner:
+        return mins, maxes, None, None
+    two = np.uint64(2)
+    return mins, maxes, mins + two, np.maximum(maxes, two) - two
+
+
+def ranges_from_arrays(lower, upper, contained) -> list[IndexRange]:
+    """The object view of :func:`zranges_arrays`' result, for callers that
+    want one ``IndexRange`` a range (tests, ``explain``); the plan path
+    keeps the arrays."""
+    return [
+        IndexRange(lo, hi, c)
+        for lo, hi, c in zip(lower.tolist(), upper.tolist(), contained.tolist())
+    ]
+
+
 def zranges(
     curve,
     boxes: Sequence[ZBox],
@@ -57,20 +78,72 @@ def zranges(
     max_recurse: int | None = None,
     inner_boxes: "Sequence[ZBox] | None" = None,
 ) -> list[IndexRange]:
-    """Covering z-ranges for the union of ``boxes`` on ``curve``.
-
-    curve: Z2 or Z3 from geomesa_tpu.curve.zorder (needs .dims,
-    .bits_per_dim, .index, .decode).
-
-    ``inner_boxes`` (aligned with ``boxes``) classify *containment*: a cell
-    is contained only when fully inside some inner box. Callers pass boxes
-    shrunk below the f64 query bounds so contained-range rows are certain
-    hits needing no refinement; default (None) classifies against the outer
-    boxes — ordinal-level containment, the reference ZN.zranges behavior.
-    Inner boxes may be inverted (mins > maxes) to mean "never contained".
-    """
+    """Covering z-ranges for the union of ``boxes`` on ``curve``, as
+    objects: a view of :func:`zranges_arrays`, which documents the
+    arguments. ``inner_boxes`` is aligned with ``boxes``."""
     if not boxes:
         return []
+    mins = np.array([b.mins for b in boxes], dtype=np.uint64)  # [nbox, dims]
+    maxes = np.array([b.maxes for b in boxes], dtype=np.uint64)
+    if inner_boxes is None:
+        imins = imaxes = None
+    else:
+        imins = np.array([b.mins for b in inner_boxes], dtype=np.uint64)
+        imaxes = np.array([b.maxes for b in inner_boxes], dtype=np.uint64)
+    return ranges_from_arrays(
+        *zranges_arrays(curve, mins, maxes, imins, imaxes, max_ranges, max_recurse)
+    )
+
+
+def zranges_arrays(
+    curve,
+    mins: np.ndarray,
+    maxes: np.ndarray,
+    imins: "np.ndarray | None" = None,
+    imaxes: "np.ndarray | None" = None,
+    max_ranges: int | None = None,
+    max_recurse: int | None = None,
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Covering z-ranges for a union of boxes on ``curve``:
+    ``(lower u64[k], upper u64[k], contained bool[k])``, sorted by lower.
+
+    curve: Z2 or Z3 from geomesa_tpu.curve.zorder (needs .dims,
+    .bits_per_dim, .index, .decode). ``mins`` / ``maxes`` are the boxes'
+    per-dimension normalized ordinals, u64 ``[nbox, dims]``.
+
+    ``imins`` / ``imaxes`` (aligned with the boxes) classify
+    *containment*: a cell is contained only when fully inside some inner
+    box. Callers pass boxes shrunk below the f64 query bounds so
+    contained-range rows are certain hits needing no refinement; default
+    (None) classifies against the outer boxes — ordinal-level
+    containment, the reference ZN.zranges behavior. Inner boxes may be
+    inverted (mins > maxes) to mean "never contained".
+    """
+    inner = imins is not None
+    return zranges_arrays_each(
+        curve, mins[None], maxes[None],
+        imins[None] if inner else None, imaxes[None] if inner else None,
+        max_ranges, max_recurse,
+    )[:3]
+
+
+def zranges_arrays_each(
+    curve,
+    mins: np.ndarray,
+    maxes: np.ndarray,
+    imins: "np.ndarray | None" = None,
+    imaxes: "np.ndarray | None" = None,
+    max_ranges: int | None = None,
+    max_recurse: int | None = None,
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """:func:`zranges_arrays` for ``nq`` unions of ``nbox`` boxes each
+    (u64 ``[nq, nbox, dims]``), one decomposition a union, all in one
+    native call: ``(lower, upper, contained, counts i64[nq])`` with union
+    q's ranges after union q-1's, ``counts[q]`` of them. ``max_ranges``
+    bounds each union on its own."""
+    nq, nbox = mins.shape[0], mins.shape[1]
+    if nq == 0 or nbox == 0:
+        return (*ranges_to_arrays([]), np.zeros(nq, dtype=np.int64))
     if max_ranges is None:
         from geomesa_tpu.conf import SCAN_RANGES_TARGET
 
@@ -78,39 +151,45 @@ def zranges(
     if max_ranges < 1:
         raise ValueError(f"max_ranges must be >= 1: {max_ranges}")
     max_recurse = DEFAULT_MAX_RECURSE if max_recurse is None else max_recurse
-    dims = curve.dims
-    bits_per_dim = curve.bits_per_dim
-    total_bits = dims * bits_per_dim
-    children = 1 << dims
-
-    for b in boxes:
-        for d in range(dims):
-            if b.mins[d] > b.maxes[d]:
-                raise ValueError(f"inverted box on dim {d}: {b.mins} > {b.maxes}")
-
-    mins = np.array([b.mins for b in boxes], dtype=np.uint64)  # [nbox, dims]
-    maxes = np.array([b.maxes for b in boxes], dtype=np.uint64)
-    if inner_boxes is None:
+    inverted = mins > maxes
+    if inverted.any():
+        q, b, d = np.argwhere(inverted)[0]
+        raise ValueError(
+            f"inverted box on dim {d}: {tuple(mins[q, b].tolist())} > "
+            f"{tuple(maxes[q, b].tolist())}"
+        )
+    if imins is None:
         imins, imaxes = mins, maxes
-    else:
-        # inverted inner dims (mins > maxes) never contain anything
-        imins = np.array([b.mins for b in inner_boxes], dtype=np.uint64)
-        imaxes = np.array([b.maxes for b in inner_boxes], dtype=np.uint64)
 
     from geomesa_tpu import native
 
     nat = native.zranges(
-        dims, bits_per_dim, mins, maxes, imins, imaxes, max_ranges, max_recurse
+        curve.dims, curve.bits_per_dim, mins, maxes, imins, imaxes,
+        max_ranges, max_recurse,
     )
     if nat is not None:
-        lo, hi, cont = nat
-        return [
-            IndexRange(int(l), int(h), bool(c))
-            for l, h, c in zip(lo.tolist(), hi.tolist(), cont.tolist())
-        ]
+        return nat
+    each = [
+        _zranges_py(
+            curve, mins[q], maxes[q], imins[q], imaxes[q], max_ranges, max_recurse
+        )
+        for q in range(nq)
+    ]
+    return (
+        *ranges_to_arrays([r for ranges in each for r in ranges]),
+        np.array([len(ranges) for ranges in each], dtype=np.int64),
+    )
 
-    zmins = [int(curve.index(*b.mins)) for b in boxes]
-    zmaxes = [int(curve.index(*b.maxes)) for b in boxes]
+
+def _zranges_py(
+    curve, mins, maxes, imins, imaxes, max_ranges: int, max_recurse: int
+) -> list[IndexRange]:
+    """The decomposition in plain Python: what runs without the native
+    library, and the reference the native tier is tested against."""
+    dims = curve.dims
+    children = 1 << dims
+    zmins = [int(curve.index(*row)) for row in mins.tolist()]
+    zmaxes = [int(curve.index(*row)) for row in maxes.tolist()]
 
     # longest common prefix over all corner z-values, aligned to dims bits
     lcp = longest_common_prefix(curve, *(zmins + zmaxes))

@@ -106,21 +106,23 @@ class Z2Index:
                 poly=poly,
                 rast=rast,
             )
-        ranges = self.sfc.ranges(bounds, inner=True)
-        if not ranges:
+        range_lo, range_hi, range_contained = self.sfc.ranges_arrays(
+            bounds, inner=True
+        )
+        if len(range_lo) == 0:
             return ScanConfig.empty(self.name)
         return ScanConfig(
             index=self.name,
-            range_bins=np.zeros(len(ranges), dtype=np.int32),
-            range_lo=np.array([r.lower for r in ranges], dtype=np.uint64),
-            range_hi=np.array([r.upper for r in ranges], dtype=np.uint64),
+            range_bins=np.zeros(len(range_lo), dtype=np.int32),
+            range_lo=range_lo,
+            range_hi=range_hi,
             boxes=widen_boxes(bounds),
             windows=None,
             # the device PIP tier answers polygon queries exactly (host
             # refines only the uncertainty band), so the mask decides the
             # filter; contained-range certainty stays bbox-only
             geom_precise=bounds_exact or poly is not None,
-            range_contained=np.array([r.contained for r in ranges], dtype=bool),
+            range_contained=range_contained,
             contained_exact=bool(bounds_exact),
             boxes_inner=shrink_boxes(bounds),
             poly=poly,

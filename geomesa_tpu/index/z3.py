@@ -222,24 +222,25 @@ class Z3Index:
         range_bins, range_lo, range_hi, range_cont = [], [], [], []
         windows = np.stack([bins, los, his], axis=1).astype(np.int64)
         windows_inner = np.stack([bins, ilos, ihis], axis=1).astype(np.int64)
-        for lo_off, hi_off in set(zip(los.tolist(), his.tolist())):
-            ranges = self.sfc.ranges(
-                bounds, [(float(lo_off), float(hi_off))], inner=True
-            )
-            if not ranges:
+        offsets = list(set(zip(los.tolist(), his.tolist())))
+        wlo, whi, wcont, counts = self.sfc.ranges_arrays_by_window(
+            bounds, [(float(lo), float(hi)) for lo, hi in offsets], inner=True
+        )
+        # wcont: the 2-cell inner margin (Z3SFC.ranges_arrays inner=True)
+        # exceeds one offset unit in every period, so contained cells'
+        # offsets are strictly inside the query interval even when its
+        # endpoints are not offset-aligned — contained rows are certain at
+        # ms precision
+        end = 0
+        for (lo_off, hi_off), n in zip(offsets, counts.tolist()):
+            start, end = end, end + n
+            if n == 0:
                 continue
-            rlo = np.array([r.lower for r in ranges], dtype=np.uint64)
-            rhi = np.array([r.upper for r in ranges], dtype=np.uint64)
-            # the 2-cell inner margin (Z3SFC.ranges inner=True) exceeds one
-            # offset unit in every period, so contained cells' offsets are
-            # strictly inside the query interval even when its endpoints are
-            # not offset-aligned — contained rows are certain at ms precision
-            rc = np.array([r.contained for r in ranges], dtype=bool)
             for k in np.flatnonzero((los == lo_off) & (his == hi_off)):
-                range_bins.append(np.full(len(rlo), bins[k], dtype=np.int32))
-                range_lo.append(rlo)
-                range_hi.append(rhi)
-                range_cont.append(rc)
+                range_bins.append(np.full(n, bins[k], dtype=np.int32))
+                range_lo.append(wlo[start:end])
+                range_hi.append(whi[start:end])
+                range_cont.append(wcont[start:end])
         if not range_bins:
             return ScanConfig.empty(self.name)
         bounds_exact = geoms.precise and _bounds_only(geoms.values)

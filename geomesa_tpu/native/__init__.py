@@ -126,13 +126,13 @@ def _load():
         lib.points_in_polygon_cpp.argtypes = [
             f64p, f64p, ctypes.c_int64, f64p, i64p, ctypes.c_int64, i32p, u8p
         ]
-        lib.zranges_cpp.argtypes = [
-            ctypes.c_int32, ctypes.c_int32, ctypes.c_int64,
+        lib.zranges_each_cpp.argtypes = [
+            ctypes.c_int32, ctypes.c_int32, ctypes.c_int64, ctypes.c_int64,
             u64p, u64p, u64p, u64p,
             ctypes.c_int64, ctypes.c_int64,
-            u64p, u64p, u8p, ctypes.c_int64,
+            u64p, u64p, u8p, i64p, ctypes.c_int64,
         ]
-        lib.zranges_cpp.restype = ctypes.c_int64
+        lib.zranges_each_cpp.restype = ctypes.c_int64
         lib.bitmask_count.argtypes = [i32p, ctypes.c_int64, ctypes.c_int64]
         lib.bitmask_count.restype = ctypes.c_int64
         lib.bitmask_decode_pair.argtypes = [
@@ -420,10 +420,12 @@ def counting_argsort(keys, n_buckets: int) -> "np.ndarray | None":
 
 def zranges(dims, bits_per_dim, mins, maxes, inner_mins, inner_maxes,
             max_ranges, max_recurse):
-    """Covering z-ranges of a union of ordinal boxes (C++ BFS + zdiv
-    tightening; see geomesa_native.cpp zranges_cpp). Containment is
+    """Covering z-ranges of ``nq`` unions of ordinal boxes, one
+    decomposition each (C++ BFS + zdiv tightening; see geomesa_native.cpp
+    zranges_cpp). The boxes are u64 ``[nq, nbox, dims]``; containment is
     classified against the inner boxes. Returns (lo u64[k], hi u64[k],
-    contained bool[k]) or None when native is unavailable."""
+    contained bool[k], counts i64[nq]), query q's ranges after query
+    q-1's, or None when native is unavailable."""
     lib = _load()
     if lib is None:
         return None
@@ -431,20 +433,21 @@ def zranges(dims, bits_per_dim, mins, maxes, inner_mins, inner_maxes,
     maxes = np.ascontiguousarray(maxes, dtype=np.uint64)
     inner_mins = np.ascontiguousarray(inner_mins, dtype=np.uint64)
     inner_maxes = np.ascontiguousarray(inner_maxes, dtype=np.uint64)
-    nbox = len(mins) // dims if mins.ndim == 1 else len(mins)
-    cap = max(int(max_ranges) * 2 + 64, 256)
+    nq, nbox = mins.shape[0], mins.shape[1]
+    cap = nq * max(int(max_ranges) * 2 + 64, 256)
     lo = np.empty(cap, dtype=np.uint64)
     hi = np.empty(cap, dtype=np.uint64)
     cont = np.empty(cap, dtype=np.uint8)
-    n = lib.zranges_cpp(
-        dims, bits_per_dim, nbox,
+    counts = np.empty(nq, dtype=np.int64)
+    n = lib.zranges_each_cpp(
+        dims, bits_per_dim, nq, nbox,
         mins.reshape(-1), maxes.reshape(-1),
         inner_mins.reshape(-1), inner_maxes.reshape(-1),
-        int(max_ranges), int(max_recurse), lo, hi, cont, cap,
+        int(max_ranges), int(max_recurse), lo, hi, cont, counts, cap,
     )
     if n < 0:
         return None
-    return lo[:n].copy(), hi[:n].copy(), cont[:n].astype(bool)
+    return lo[:n].copy(), hi[:n].copy(), cont[:n].astype(bool), counts
 
 
 def points_in_polygon(px, py, rings, ring_part) -> "np.ndarray | None":

@@ -370,26 +370,40 @@ static int classify(const uint64_t* lo, const uint64_t* hi, int dims, int64_t nb
 
 struct ZRange { uint64_t lo, hi; uint8_t contained; };
 
+// The bit patterns zdiv needs at z-bit i (dim i % dims, 1-based dim-local
+// bit bl = i / dims + 1): all of that dim's bits up to bl, bit bl alone,
+// and the bits below bl. They depend on the curve alone, so a
+// decomposition builds them once: a zdiv call walks up to 63 bits, and it
+// runs about once a range, so interleaving them there would cost more than
+// the BFS itself.
+struct ZDivMasks { uint64_t upto[64], top[64], below[64]; };
+
+static void zdiv_masks(const ZCurveOps& ops, ZDivMasks* m) {
+  int total = ops.dims * ops.bits_per_dim;
+  for (int i = 0; i < total; ++i) {
+    int dim = i % ops.dims;
+    int bl = i / ops.dims + 1;
+    m->upto[i] = ops.split((1ull << bl) - 1) << dim;
+    m->top[i] = ops.split(1ull << (bl - 1)) << dim;
+    m->below[i] = ops.split((1ull << (bl - 1)) - 1) << dim;
+  }
+}
+
 // Tropf/Herzog LITMAX/BIGMIN: mirrors curve/zorder.py zdiv.
-static void zdiv_cpp(const ZCurveOps& ops, uint64_t zmin, uint64_t zmax,
-                     uint64_t zval, uint64_t* litmax_out, uint64_t* bigmin_out) {
-  int dims = ops.dims;
-  int total = dims * ops.bits_per_dim;
+static void zdiv_cpp(const ZCurveOps& ops, const ZDivMasks& m, uint64_t zmin,
+                     uint64_t zmax, uint64_t zval, uint64_t* litmax_out,
+                     uint64_t* bigmin_out) {
+  int total = ops.dims * ops.bits_per_dim;
   uint64_t litmax = zmin, bigmin = zmax;
   uint64_t zmin_ = zmin, zmax_ = zmax;
   for (int i = total - 1; i >= 0; --i) {
     uint64_t bit = 1ull << i;
-    int dim = i % dims;
-    int bl = i / dims + 1;  // 1-based dim-local bit index
     int v = (zval & bit) ? 1 : 0;
     int mn = (zmin_ & bit) ? 1 : 0;
     int mx = (zmax_ & bit) ? 1 : 0;
-    uint64_t mask = ops.split((1ull << bl) - 1) << dim;
     if (v == 0 && mn == 0 && mx == 1) {
-      uint64_t pat_hi = ops.split(1ull << (bl - 1)) << dim;
-      uint64_t pat_lo = ops.split(((1ull << (bl - 1)) - 1)) << dim;
-      bigmin = (zmin_ & ~mask) | pat_hi;
-      zmax_ = (zmax_ & ~mask) | pat_lo;
+      bigmin = (zmin_ & ~m.upto[i]) | m.top[i];
+      zmax_ = (zmax_ & ~m.upto[i]) | m.below[i];
     } else if (v == 0 && mn == 1 && mx == 1) {
       bigmin = zmin_;
       break;
@@ -397,10 +411,8 @@ static void zdiv_cpp(const ZCurveOps& ops, uint64_t zmin, uint64_t zmax,
       litmax = zmax_;
       break;
     } else if (v == 1 && mn == 0 && mx == 1) {
-      uint64_t pat_hi = ops.split(1ull << (bl - 1)) << dim;
-      uint64_t pat_lo = ops.split(((1ull << (bl - 1)) - 1)) << dim;
-      litmax = (zmax_ & ~mask) | pat_lo;
-      zmin_ = (zmin_ & ~mask) | pat_hi;
+      litmax = (zmax_ & ~m.upto[i]) | m.below[i];
+      zmin_ = (zmin_ & ~m.upto[i]) | m.top[i];
     }
   }
   *litmax_out = litmax;
@@ -533,6 +545,8 @@ extern "C" int64_t zranges_cpp(int32_t dims, int32_t bits_per_dim, int64_t nbox,
 
   // tighten endpoints to in-union z-values (zdiv post-pass; mirrors
   // curve/zranges.py _tighten_ranges against the *outer* boxes)
+  ZDivMasks masks;
+  zdiv_masks(ops, &masks);
   std::vector<ZRange> out;
   for (auto& r : merged) {
     bool has_lo = false, has_hi = false;
@@ -543,11 +557,11 @@ extern "C" int64_t zranges_cpp(int32_t dims, int32_t bits_per_dim, int64_t nbox,
       uint64_t cand;
       if (r.lo <= zmin) cand = zmin;
       else if (in_some_box(ops, r.lo, 1, mins + b * dims, maxes + b * dims)) cand = r.lo;
-      else { uint64_t lm, bm; zdiv_cpp(ops, zmin, zmax, r.lo, &lm, &bm); cand = bm; }
+      else { uint64_t lm, bm; zdiv_cpp(ops, masks, zmin, zmax, r.lo, &lm, &bm); cand = bm; }
       if (cand <= r.hi && (!has_lo || cand < lo)) { lo = cand; has_lo = true; }
       if (r.hi >= zmax) cand = zmax;
       else if (in_some_box(ops, r.hi, 1, mins + b * dims, maxes + b * dims)) cand = r.hi;
-      else { uint64_t lm, bm; zdiv_cpp(ops, zmin, zmax, r.hi, &lm, &bm); cand = lm; }
+      else { uint64_t lm, bm; zdiv_cpp(ops, masks, zmin, zmax, r.hi, &lm, &bm); cand = lm; }
       if (cand >= r.lo && (!has_hi || cand > hi)) { hi = cand; has_hi = true; }
     }
     if (!has_lo || !has_hi || lo > hi) continue;
@@ -561,6 +575,32 @@ extern "C" int64_t zranges_cpp(int32_t dims, int32_t bits_per_dim, int64_t nbox,
     out_cont[i] = out[i].contained;
   }
   return (int64_t)out.size();
+}
+
+// nq decompositions in one call (a z3 plan has one per distinct offset
+// window): query q is the union of its own nbox boxes; its ranges follow
+// query q-1's in the outputs and counts[q] says how many they are.
+// Returns the total written, or -1 if cap was too small.
+extern "C" int64_t zranges_each_cpp(int32_t dims, int32_t bits_per_dim, int64_t nq,
+                    int64_t nbox,
+                    const uint64_t* mins, const uint64_t* maxes,
+                    const uint64_t* imins, const uint64_t* imaxes,
+                    int64_t max_ranges, int64_t max_recurse,
+                    uint64_t* out_lo, uint64_t* out_hi, uint8_t* out_cont,
+                    int64_t* counts, int64_t cap) {
+  int64_t total = 0, stride = nbox * dims;
+  for (int64_t q = 0; q < nq; ++q) {
+    int64_t n = zranges_cpp(dims, bits_per_dim, nbox,
+                            mins + q * stride, maxes + q * stride,
+                            imins + q * stride, imaxes + q * stride,
+                            max_ranges, max_recurse,
+                            out_lo + total, out_hi + total, out_cont + total,
+                            cap - total);
+    if (n < 0) return -1;
+    counts[q] = n;
+    total += n;
+  }
+  return total;
 }
 
 // ---------------------------------------------------------------------------

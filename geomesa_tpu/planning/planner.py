@@ -253,8 +253,11 @@ class QueryPlanner:
                     memo.move_to_end(key)
                     return memo[key]
                 epoch = self._memo_epoch
-        with _ospan("plan.decompose", index=idx.name):
+        with _ospan("plan.decompose", index=idx.name) as sp:
             cfg = idx.scan_config(f)
+            if sp is not _NULL_SPAN and sp.trace.retain and cfg is not None:
+                # what it emitted: tells a cheap decomposition from a small one
+                sp.annotate(ranges=len(cfg.range_lo))
         with self._memo_lock:
             if self._memo_epoch != epoch:
                 # a mutation invalidated mid-compute: this decomposition
