@@ -21,6 +21,15 @@ device_put), and ONE batched pull returns every device's planes, sized in
 KB. Aggregations (pops/density/bounds) run the shared kernels per shard
 and merge with ``psum`` or a host fold — the coprocessor-aggregation tier
 collapsed into XLA collectives over ICI.
+
+Under a caller's spans (docs/observability.md) the hooks mark what the
+single-chip hooks mark, under the same names (``prune``, ``enqueue``,
+``wait``, ``pull``, ``bits``; ``blocks``, ``slots``, ``groups``), and
+name what only a mesh does: the segments ``deal`` (candidates split per
+device, the [D, M] arrays filled) and ``merge`` (the devices' rows into
+one ascending answer), the counters ``devices``, ``blocks_max`` (the
+fullest device's real candidates) and ``splits`` (fused chunks cut in
+two by skew). Untraced, each mark is one thread-local probe.
 """
 
 from __future__ import annotations
@@ -33,9 +42,24 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from geomesa_tpu.index.api import IndexKeySpace, ScanConfig, WriteKeys
+from geomesa_tpu.obs.trace import add as _oadd
+from geomesa_tpu.obs.trace import event as _oevent
+from geomesa_tpu.obs.trace import tracer as _otracer
 from geomesa_tpu.scan import aggregations
 from geomesa_tpu.scan import block_kernels as bk
-from geomesa_tpu.storage.table import IndexTable
+from geomesa_tpu.storage.table import IndexTable, _await_device
+
+
+def _count_deal(n_real, slots: int) -> None:
+    """One dispatch's deal on the active span: real candidates over all
+    devices (``n_real``: one count a device), kernel slots (D x M), the
+    fullest device's candidates, D."""
+    span = _otracer().current()
+    if span is not None:
+        span.add("blocks", int(sum(n_real)))
+        span.add("slots", int(slots))
+        span.add("blocks_max", int(max(n_real)))
+        span.annotate(devices=len(n_real))
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
@@ -222,7 +246,10 @@ class DistributedIndexTable(IndexTable):
     def _split_blocks(self, blocks: np.ndarray, pad: int = 0):
         """Global candidate blocks -> ([D, M] i32 local block ids padded to
         one mesh-wide static bucket, per-device real counts [D]). Past the
-        largest bucket every device scans all its local blocks."""
+        largest bucket every device scans all its local blocks. The
+        caller's span gets the segment ``deal`` and the deal's counters;
+        what follows is ``prune`` again, as on one chip."""
+        _oevent("deal")
         D = self.n_devices
         per = [blocks[blocks % D == d] // D for d in range(D)]
         mx = max(len(p) for p in per)
@@ -235,18 +262,25 @@ class DistributedIndexTable(IndexTable):
         for d, p in enumerate(per):
             bids2[d, : len(p)] = p
             n_real[d] = len(p)
+        _count_deal(n_real, bids2.size)
+        _oevent("prune")
         return bids2, n_real
 
     def _merge_device_rows(self, parts):
         """[(rows, certain)] per device (each ascending) -> globally
-        ascending (rows, certain)."""
+        ascending (rows, certain). Segment ``merge`` of the caller's
+        ``scan``; what follows (``_post_decode``) is ``bits`` again."""
+        _oevent("merge")
         parts = [(r, c) for r, c in parts if len(r)]
         if not parts:
-            return np.zeros(0, np.int64), np.zeros(0, bool)
-        rows = np.concatenate([r for r, _ in parts])
-        cert = np.concatenate([c for _, c in parts])
-        order = np.argsort(rows, kind="stable")
-        return rows[order], cert[order]
+            out = np.zeros(0, np.int64), np.zeros(0, bool)
+        else:
+            rows = np.concatenate([r for r, _ in parts])
+            cert = np.concatenate([c for _, c in parts])
+            order = np.argsort(rows, kind="stable")
+            out = rows[order], cert[order]
+        _oevent("bits")
+        return out
 
     # -- fused multi-query scan (round 6) --------------------------------
     @property
@@ -287,6 +321,7 @@ class DistributedIndexTable(IndexTable):
             # candidate skew overflowed one device's static slot bucket
             # (members' blocks clustered on one residue class): split the
             # chunk and recurse — bottoms out at the per-query route
+            _oadd("splits", 1)
             half = len(members) // 2
             self._submit_fused_chunk(
                 members[:half], names, has_boxes, has_windows, finishes, deadline
@@ -318,6 +353,7 @@ class DistributedIndexTable(IndexTable):
         `_post_decode` itself after offsetting shard rows."""
         from geomesa_tpu.planning.errors import check_deadline
 
+        _oevent("deal")
         D = self.n_devices
         slots = self.fused_slots
         # member-major per-device split: global block g -> device g % D,
@@ -327,12 +363,15 @@ class DistributedIndexTable(IndexTable):
         ]
         counts = [sum(len(p) for p in row) for row in per]
         if max(counts) > slots:
+            _oevent("prune")
             return None
         check_deadline(deadline, "device scan dispatch")
+        _oevent("prune")  # the parameter stacks, as on one chip
         boxes, wins = self._fused_param_stacks(members)
         chunk_e, edges, pip = self._chunk_edge_stack(members)
         chunk_r, rasts, has_rast = self._chunk_raster_stack(members)
         poly_slot = pip | has_rast
+        _oevent("deal")
         bids2 = np.zeros((D, slots), np.int32)
         qids2 = np.zeros((D, slots), np.int32)
         spip2 = np.zeros((D, slots), np.int32)
@@ -348,6 +387,8 @@ class DistributedIndexTable(IndexTable):
                 segs[q][d] = (pos, pos + nb)
                 pos += nb
         self._record_scan(names, bids2.size)
+        _count_deal(counts, bids2.size)
+        _oadd("groups", 1)
         fn = _dist_scan_multi(
             self.mesh, names, has_boxes, has_windows, self.extent, chunk_e,
             chunk_r,
@@ -355,15 +396,18 @@ class DistributedIndexTable(IndexTable):
         extra = (() if not chunk_e else (edges,)) + (
             () if not chunk_r else (rasts,)
         )
+        _oevent("enqueue")
         out = fn(
             bids2, qids2, spip2, boxes, wins, *extra,
             *self._cols_args(names),
         )
         wide, inner = out if isinstance(out, tuple) else (out, None)
-        group_pull = self._fused_pull(wide, inner)
+        group_pull = self._fused_pull(wide, inner, len(members))
+        _oevent("prune")  # back from the enqueue: the next chunk's staging
 
         def raw_finish(k):
             wide_h, inner_h = group_pull()
+            _oevent("bits")
             check_deadline(deadline, "bitmask decode")
             parts = []
             for d in range(D):
@@ -398,6 +442,7 @@ class DistributedIndexTable(IndexTable):
         extra = (() if not n_edges else (kw["edges"],)) + (
             () if not n_rints else (kw["rast"],)
         )
+        _oevent("enqueue")
         out = fn(bids2, boxes, wins, *extra, *self._cols_args(names))  # dispatched now
         # async device->host copies: see IndexTable._device_scan_submit
         for plane in out if isinstance(out, tuple) else (out,):
@@ -405,11 +450,13 @@ class DistributedIndexTable(IndexTable):
                 plane.copy_to_host_async()
 
         def finish():
+            _await_device(out)
             if skip:
                 wide_h, inner_h = np.asarray(jax.device_get(out)), None
             else:
                 wide_h, inner_h = jax.device_get(out)
                 wide_h, inner_h = np.asarray(wide_h), np.asarray(inner_h)
+            _oevent("bits")
             parts = []
             for d in range(D):
                 nr = int(n_real[d])
@@ -433,7 +480,10 @@ class DistributedIndexTable(IndexTable):
         names = kw["col_names"]
         self._record_scan(names, bids2.size)
         fn = _dist_pops(self.mesh, names, kw["has_boxes"], kw["has_windows"], kw["extent"])
-        pops2 = np.asarray(jax.device_get(fn(bids2, boxes, wins, *self._cols_args(names))))
+        _oevent("enqueue")
+        out = fn(bids2, boxes, wins, *self._cols_args(names))
+        _await_device(out)
+        pops2 = np.asarray(jax.device_get(out))
         pops, gbids = [], []
         for d in range(D):
             nr = int(n_real[d])
@@ -454,10 +504,16 @@ class DistributedIndexTable(IndexTable):
             self.mesh, names, kw["has_boxes"], kw["has_windows"], kw["extent"],
             width, height,
         )
+        _oevent("enqueue")
         grid = fn(bids2, boxes, wins, grid_bounds, *self._cols_args(names))
         if hasattr(grid, "copy_to_host_async"):
             grid.copy_to_host_async()
-        return lambda: np.asarray(jax.device_get(grid))
+
+        def finish():
+            _await_device(grid)
+            return np.asarray(jax.device_get(grid))
+
+        return finish
 
     def _device_bounds(self, blocks, config):
         bids2, n_real = self._split_blocks(blocks, pad=-1)
@@ -466,7 +522,10 @@ class DistributedIndexTable(IndexTable):
         kw = self._kernel_kwargs(config, names)
         self._record_scan(names, bids2.size)
         fn = _dist_bounds(self.mesh, names, kw["has_boxes"], kw["has_windows"], kw["extent"])
-        stats = np.asarray(jax.device_get(fn(bids2, boxes, wins, *self._cols_args(names))))
+        _oevent("enqueue")
+        out = fn(bids2, boxes, wins, *self._cols_args(names))
+        _await_device(out)
+        stats = np.asarray(jax.device_get(out))
         # fold only real slots from each device
         parts = [stats[d, : int(n_real[d])] for d in range(self.n_devices)]
         return aggregations.reduce_bounds(np.concatenate(parts), None)
