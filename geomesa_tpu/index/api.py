@@ -11,7 +11,7 @@ vectorized mask over gathered tiles).
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Optional, Protocol, runtime_checkable
 
 import numpy as np
@@ -108,6 +108,11 @@ class ScanConfig:
     # implies membership, unlike bbox containment), and out cells inside
     # the bbox are pruned before any device work.
     rast: Optional[np.ndarray] = None
+    # the candidate row spans of these ranges over ONE sorted table, held
+    # by that table's ``scan_spans`` as (weakref to the table, spans) so
+    # the planner's cost() and the dispatch that follows compute them
+    # once; no part of the config's value
+    _spans: Optional[tuple] = field(default=None, init=False, repr=False, compare=False)
 
     @staticmethod
     def empty(index: str) -> "ScanConfig":

@@ -384,14 +384,15 @@ def bitmask_decode(wide, bids, n_real: int, block: int):
     return rows
 
 
-def merge_rows_spans(spans, rows, cert):
-    """(rows, certain) union of contained spans (certain) and ascending
-    kernel rows, deduplicated — one C++ two-pointer pass, or None."""
+def merge_rows_spans(lo, hi, rows, cert):
+    """(rows, certain) union of contained spans [lo[k], hi[k]) (certain)
+    and ascending kernel rows, deduplicated — one C++ two-pointer pass,
+    or None."""
     lib = _load()
     if lib is None:
         return None
-    lo = np.ascontiguousarray([s[0] for s in spans], dtype=np.int64)
-    hi = np.ascontiguousarray([s[1] for s in spans], dtype=np.int64)
+    lo = np.ascontiguousarray(lo, dtype=np.int64)
+    hi = np.ascontiguousarray(hi, dtype=np.int64)
     rows = np.ascontiguousarray(rows, dtype=np.int64)
     cert8 = np.ascontiguousarray(cert, dtype=np.uint8)
     cap = int((hi - lo).sum()) + len(rows)

@@ -332,9 +332,9 @@ class DistributedIndexTable(IndexTable):
             return
 
         def member_finish(k):
-            j, config, blocks, overlap, contained = members[k]
+            j, config, blocks, spans = members[k]
             rows, certain = raw[k]()
-            return self._post_decode(rows, certain, config, overlap, contained)
+            return self._post_decode(rows, certain, config, spans)
 
         for k, (j, *_rest) in enumerate(members):
             finishes[j] = lambda k=k: member_finish(k)

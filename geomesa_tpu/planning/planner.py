@@ -475,7 +475,7 @@ class QueryPlanner:
             table = self.store.table(type_name, index_name)
         except KeyError:
             return mult  # no data written yet
-        rows = sum(hi - lo for lo, hi in table.candidate_spans(cfg))
+        rows = table.candidate_spans(cfg).n_rows()
         return (rows + 1) * mult
 
     # -- execution -------------------------------------------------------

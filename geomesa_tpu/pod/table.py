@@ -282,7 +282,7 @@ class PodIndexTable(IndexTable):
             fault_point("pod.dispatch")
             mem = host_members[h]
             sub_members = [
-                (i, members[k][1], loc, (), ())
+                (i, members[k][1], loc, None)
                 for i, (k, loc) in enumerate(mem)
             ]
             raw = self.shards[h]._fused_raw_finishes(
@@ -298,14 +298,14 @@ class PodIndexTable(IndexTable):
             )
 
         def member_finish(k):
-            j, config, blocks, overlap, contained = members[k]
+            j, config, blocks, spans = members[k]
             parts = []
             for h, raws in host_raw:
                 fn = raws.get(k)
                 if fn is not None:
                     parts.append((h, *fn()))
             rows, certain = self._merge_host_rows(parts)
-            return self._post_decode(rows, certain, config, overlap, contained)
+            return self._post_decode(rows, certain, config, spans)
 
         for k, (j, *_rest) in enumerate(members):
             finishes[j] = lambda k=k: member_finish(k)

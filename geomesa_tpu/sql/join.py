@@ -510,8 +510,7 @@ def spatial_join_indexed(
             exacts.append(False)
             continue
         if adaptive and not rect and not cfg.disjoint:
-            spans = table.candidate_spans(cfg)
-            cand_rows = sum(hi - lo for lo, hi in spans)
+            cand_rows = table.candidate_spans(cfg).n_rows()
             if cand_rows > broad_frac * max(table.n, 1):
                 approx = fr.raster_for(g)
                 if approx is not None:

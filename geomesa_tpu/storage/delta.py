@@ -17,6 +17,7 @@ from __future__ import annotations
 import numpy as np
 
 from geomesa_tpu.index.api import ScanConfig, WriteKeys
+from geomesa_tpu.storage.table import RowSpans
 
 
 def concat_keys(parts: list[WriteKeys], consume: bool = False) -> WriteKeys:
@@ -229,9 +230,12 @@ class TieredTable:
     def candidate_spans(self, config: ScanConfig):
         """Cost-estimator view: main spans plus the whole delta as one
         pseudo-span (a cheap upper bound — the delta is scanned linearly)."""
-        spans = list(self.main.candidate_spans(config))
+        spans = self.main.candidate_spans(config)
         if len(self.delta.zs):
-            spans.append((self.main.n, self.main.n + len(self.delta.zs)))
+            n = self.main.n
+            spans = RowSpans(
+                np.append(spans.lo, n), np.append(spans.hi, n + len(self.delta.zs))
+            )
         return spans
 
     def bounds_stats(self, config: ScanConfig):

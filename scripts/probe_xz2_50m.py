@@ -61,7 +61,7 @@ for qw, q in mk(2, 40):
     t1 = time.perf_counter()
     res = ds.query("bld", q)
     t_q = time.perf_counter() - t1
-    cont_rows = sum(z - a for a, z in contained)
+    cont_rows = contained.n_rows()
     rows_out.append((t_q, qw, len(overlap), len(contained), cont_rows,
                      len(blocks), len(bids), t_spans, len(res.ids)))
 rows_out.sort(reverse=True)

@@ -33,6 +33,7 @@ from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.parallel import make_mesh
 from geomesa_tpu.parallel.dtable import DistributedIndexTable
 from geomesa_tpu.sft import FeatureType
+from geomesa_tpu.storage.table import NO_SPANS, ScanSpans
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
@@ -261,8 +262,8 @@ def test_skew_splits_a_fused_chunk_and_changes_no_answer(mesh_ds, traced):
     on_one = np.arange(0, table.n_blocks, CHIPS, dtype=np.int64)  # residue class 0
     n_members = table.fused_slots // len(on_one) + 4
     assert n_members * len(on_one) > table.fused_slots >= (n_members + 1) // 2 * len(on_one)
-    overlap, contained = table.candidate_spans_split(config)
-    members = [(j, config, on_one, overlap, []) for j in range(n_members)]
+    spans = ScanSpans(table.candidate_spans_split(config)[0], NO_SPANS)
+    members = [(j, config, on_one, spans) for j in range(n_members)]
     names = table._scan_cols(config)
     finishes = [None] * n_members
     with obs.tracer().trace("query_many"):
@@ -274,7 +275,7 @@ def test_skew_splits_a_fused_chunk_and_changes_no_answer(mesh_ds, traced):
     assert a["blocks"] == n_members * len(on_one) == a["blocks_max"]  # all on one device
     assert a["slots"] == 2 * CHIPS * table.fused_slots
     single = table._make_finish(
-        table._device_scan_submit(on_one, config), config, overlap, [], None)()
+        table._device_scan_submit(on_one, config), config, spans, None)()
     assert len(single[0]) > 0
     for rows, certain in fused:
         assert np.array_equal(rows, single[0]) and np.array_equal(certain, single[1])
@@ -349,6 +350,54 @@ def test_one_chip_spans_are_as_before(op, bench, one_ds, requests, traced):
         assert segs in ({"wait", "pull", "bits"}, {"bits"}, set())
     if op == "density":
         assert set(_spans(tr, "agg")[0].attrs["segments"]) == {"wait", "pull"}
+
+
+@pytest.mark.parametrize("op", ["query", "query_many"])
+def test_a_plan_costed_on_a_swapped_mesh_table_recomputes_its_spans(
+        op, bench, cols, requests, traced):
+    """The spans ``cost()`` leaves in a config's slot name the mesh table
+    they index: the dispatch that follows finds them (``spans_reused`` =
+    members), and after a write has swapped the table the same plans
+    recompute theirs and answer with the new rows too."""
+    ds = _store(cols, make_mesh(CHIPS))
+    members = requests["query_many"][0]["members"][:8] if op == "query_many" else [
+        _wide_request(requests)]
+    filters = [bench.requests.ecql(m) for m in members]
+
+    def run(plans):
+        with obs.tracer().trace(op):
+            outs = ds.planner.execute_many(plans) if op == "query_many" else [
+                ds.planner.execute(plans[0])]
+        return outs, [s for s in _spans(traced()[-1], "dispatch") if "spans_reused" in s.attrs]
+
+    plans = [ds.planner.plan(TYPE, f) for f in filters]
+    old = {p.index: ds.table(TYPE, p.index) for p in plans}
+    outs, dispatches = run(plans)
+    assert sum(s.attrs["spans_reused"] for s in dispatches) == len(plans)
+    for m, fc in zip(members, outs):
+        assert np.array_equal(_ids(fc), bench.reference.ref_ids(cols, m["box"], m["win"]))
+    # the same rows again under new ids: every answer doubles
+    sft = ds.get_schema(TYPE)
+    names = np.array(["", "POLICE", "ARMY", "COURT"])
+    conf.COMPACT_MIN_ROWS.set(1000)
+    try:
+        ds.write(TYPE, FeatureCollection.from_columns(sft, np.arange(N, 2 * N, dtype=np.int64), {
+            "actor1Name": names[np.arange(N) % 4],
+            "numMentions": (np.arange(N) % 7).astype(np.int32),
+            "dtg": cols.t, "geom": (cols.x.copy(), cols.y.copy())}), check_ids=False)
+    finally:
+        conf.COMPACT_MIN_ROWS.clear()
+    for index, table in old.items():
+        new = ds.table(TYPE, index)
+        assert new is not table and isinstance(new, DistributedIndexTable)
+    outs, dispatches = run(plans)
+    assert dispatches and all(s.attrs["spans_reused"] == 0 for s in dispatches)
+    hits = 0
+    for m, fc in zip(members, outs):
+        want = bench.reference.ref_ids(cols, m["box"], m["win"])
+        assert np.array_equal(_ids(fc), np.sort(np.concatenate([want, want + N])))
+        hits += len(want)
+    assert hits > 0
 
 
 # -------------------------------------------------------------- (e) the cell

@@ -186,7 +186,7 @@ class TestBitmaskDecode:
     def test_merge_rows_spans_matches_numpy(self):
         from geomesa_tpu import native
         from geomesa_tpu.storage.table import (
-            _merge_sorted_rows, _rows_in_spans, _span_rows,
+            RowSpans, _merge_sorted_rows, _rows_in_spans, _span_rows,
         )
 
         if not native.available():
@@ -202,9 +202,10 @@ class TestBitmaskDecode:
                 end = pos + int(rng.integers(1, 30))
                 spans.append((pos, end))
                 pos = end
+            spans = RowSpans(*np.array(spans, np.int64).T)
             rows = np.unique(rng.integers(0, pos + 50, 60)).astype(np.int64)
             cert = rng.uniform(size=len(rows)) < 0.5
-            got = native.merge_rows_spans(spans, rows, cert)
+            got = native.merge_rows_spans(spans.lo, spans.hi, rows, cert)
             assert got is not None
             dup = _rows_in_spans(rows, spans)
             want_rows, want_cert = _merge_sorted_rows(
