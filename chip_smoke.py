@@ -4,8 +4,7 @@
     python chip_smoke.py --chips 4    # the mesh path and its reference, nothing else
 
 One process, one store built through the public API, GDELT-shaped data made
-from ``--seed`` (bench.py's generator). Every answer is compared with a
-plain NumPy pass over the generator's columns: row queries, counts and
+from ``--seed``. Every answer is compared with a plain NumPy pass over the generator's columns: row queries, counts and
 tiles under the store's exact f64 semantics, the gather-free device
 aggregations (density, bounds, Count() estimate) under the semantics they
 document - f32 columns, box edges one ulp wide, whole-second time offsets;
@@ -31,8 +30,6 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
-
-from bench import box_queries, gdelt_points, link_readings, time_windows
 
 TYPE = "gdelt"
 SPEC = "dtg:Date,*geom:Point:srid=4326"
@@ -76,6 +73,76 @@ def check(ok, what: str) -> None:
 
 
 # ------------------------------------------------------------------ data
+
+
+def gdelt_points(n, rng):
+    """World-wide events clustered around population centers: uniform
+    background + gaussian clusters."""
+    n_clustered = n // 2
+    n_uniform = n - n_clustered
+    cx = rng.uniform(-160, 160, 64)
+    cy = rng.uniform(-55, 65, 64)
+    which = rng.integers(0, 64, n_clustered)
+    x = np.concatenate(
+        [
+            rng.uniform(-180, 180, n_uniform),
+            np.clip(cx[which] + rng.normal(0, 3.0, n_clustered), -180, 180),
+        ]
+    )
+    y = np.concatenate(
+        [
+            rng.uniform(-90, 90, n_uniform),
+            np.clip(cy[which] + rng.normal(0, 2.0, n_clustered), -90, 90),
+        ]
+    )
+    return x, y
+
+
+def box_queries(rng, n_queries):
+    """Selectivity mix: city-scale through continent-scale boxes."""
+    out = []
+    for _ in range(n_queries):
+        w = float(rng.choice([1.0, 2.0, 5.0, 10.0, 20.0, 40.0]))
+        h = w / 2
+        qx = rng.uniform(-175, 175 - w)
+        qy = rng.uniform(-85, 85 - h)
+        out.append((qx, qy, qx + w, qy + h))
+    return out
+
+
+def time_windows(rng, n_queries, t0, span_ms):
+    out = []
+    for _ in range(n_queries):
+        dur_ms = int(rng.choice([6, 24, 72, 168, 24 * 14]) * 3600_000)
+        start = int(t0 + rng.integers(0, span_ms - dur_ms))
+        out.append((start, start + dur_ms))
+    return out
+
+
+def link_readings(shape, n):
+    """``n`` readings of the host<->device link for one f32 ``shape``:
+    (device_get seconds, dispatch + device_get seconds) per reading, and
+    the array's bytes. Every reading pulls a FRESH device array: a
+    jax.Array keeps its host copy after the first device_get, so
+    re-pulling one times a host memcpy (what this probe read before
+    PR 21: "0.0 ms, 61 GB/s")."""
+    import jax
+    import jax.numpy as jnp
+
+    bump = jax.jit(lambda a: a + 1)
+    a = bump(jnp.zeros(shape, jnp.float32))
+    jax.device_get(a)  # compile + settle
+    pulls, trips = [], []
+    for _ in range(n):
+        t0 = time.perf_counter()
+        a = bump(a)
+        a.block_until_ready()
+        t1 = time.perf_counter()
+        jax.device_get(a)
+        t2 = time.perf_counter()
+        pulls.append(t2 - t1)
+        trips.append(t2 - t0)
+    return pulls, trips, a.nbytes
 
 
 class Columns:
@@ -295,7 +362,7 @@ def _ring_box(ring):
 
 
 def make_queries(seed: int, n_each: int) -> dict:
-    """The query mix, from the seed: bench.py's box and window mix (city
+    """The query mix, from the seed: boxes and windows (city
     to continent scale, 6 h to 2 weeks), and untimed polygons with 6
     edges (below geomesa.raster.min.edges: the device point-in-polygon
     tier) and with 24 edges (raster-approximated)."""

@@ -94,12 +94,6 @@ def _d(name, path, rank, hot=False, fields=(), doc=""):
 #: tiers. Rank numbers are sparse on purpose (new locks slot between
 #: neighbors without renumbering). Outermost (lowest rank) first.
 LOCKS: dict[str, LockDecl] = {d.name: d for d in [
-    _d("HostGroup._probe_lock", "geomesa_tpu/pod/hostgroup.py", 6,
-       fields=("link_rtts_ms", "slot_caps"),
-       doc="per-host link profile (probed RTTs + derived fused slot "
-           "caps): a LEAF acquired before any store/table lock — "
-           "profiles install at group construction, before tables "
-           "build, and shard builds only READ the caps after release"),
     _d("PodStore._route_lock", "geomesa_tpu/pod/store.py", 8,
        fields=("_next_id",),
        doc="pod-level id assignment for ownership routing: ranks BELOW "

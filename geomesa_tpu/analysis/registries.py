@@ -135,10 +135,6 @@ CONTROLLERS: dict[str, str] = {
         "result-cache admission cost threshold, tuned against the "
         "cache-hit rate (cache/result.py admission gate)"
     ),
-    "fused_chunk_slots": (
-        "fused transfer chunk slot count, derived from measured link "
-        "RTT on the doubling ladder (scan/block_kernels.py)"
-    ),
     "fold_slice_rows": (
         "incremental fold slice size, tuned against the slice-pause "
         "p99 (datastore.fold_upsert)"

@@ -182,12 +182,6 @@ POD_DRIVER = SystemProperty(
     "'sim' (in-process per-host device slices), or 'auto' (distributed "
     "when launched under a multi-process jax runtime, else sim)",
 )
-POD_LINK_PROBE = SystemProperty(
-    "geomesa.pod.link.probe", False, _parse_bool,
-    "measure each host's pull link at host-group construction and derive "
-    "PER-HOST fused slot caps from the probes (off = deterministic "
-    "design-point shapes on every host; see docs/distributed.md)",
-)
 
 
 # -- raster-interval polygon approximations + adaptive spatial joins
@@ -732,12 +726,6 @@ TUNING_BURN_RELEASE = SystemProperty(
     "geomesa.tuning.burn.release", 1.0, float,
     "burn rate at or below which engaged burn shedding releases "
     "(hysteresis gap against admission flapping)",
-)
-SCAN_FUSED_SLOTS = SystemProperty(
-    "geomesa.scan.fused.slots", 0, int,
-    "pinned fused transfer chunk slot count (power-of-two ladder "
-    "rung); 0 = automatic (the link-probe constants, or the compiled "
-    "default) — the knob the fused_chunk_slots controller writes",
 )
 
 

@@ -12,9 +12,9 @@ Arming and cost: tracing is armed when ``geomesa.obs.trace.sample`` > 0
 or ``geomesa.obs.slow.ms`` > 0 (the always-on slow-query log needs span
 trees to capture). The knobs are read once per ROOT; a child
 :func:`span` on a thread with no active trace is a single thread-local
-probe returning a shared null context — the disarmed no-op the
-``BENCH_OBS.json`` overhead gate pins. Armed, a span is one small
-object append; sampling decides at root creation whether the finished
+probe returning a shared null context — the disarmed no-op. Armed,
+a span is one small object append;
+sampling decides at root creation whether the finished
 tree is RETAINED in the bounded :class:`TraceBuffer` (slow roots are
 always retained into the slow-query ring, independent of sampling).
 

@@ -47,7 +47,7 @@ from geomesa_tpu.obs.trace import event as _oevent
 from geomesa_tpu.obs.trace import tracer as _otracer
 from geomesa_tpu.scan import aggregations
 from geomesa_tpu.scan import block_kernels as bk
-from geomesa_tpu.storage.table import IndexTable, _await_device
+from geomesa_tpu.storage.table import FUSED_CHUNK_SLOTS, IndexTable, _await_device
 
 
 def _count_deal(n_real, slots: int) -> None:
@@ -256,7 +256,7 @@ class DistributedIndexTable(IndexTable):
         if mx > bk.M_BUCKETS[-1]:
             per = [np.arange(self.blocks_local, dtype=np.int64)] * D
             mx = self.blocks_local
-        m = bk.m_bucket_of(mx)  # single-query ladder: link floor applies
+        m = bk.bucket_of(mx)
         bids2 = np.full((D, m), pad, np.int32)
         n_real = np.zeros(D, np.int64)
         for d, p in enumerate(per):
@@ -288,12 +288,8 @@ class DistributedIndexTable(IndexTable):
         """PER-DEVICE slot bucket of the canonical fused shape: the
         single-chip clamp applied to the LOCAL block count (each device
         scans its own round-robin share, so a mesh table's fused dispatch
-        is D lists of this size, not one global list). ``_slot_cap`` is a
-        per-shard probed cap (pod host groups set one per host)."""
-        return min(
-            bk.fused_slot_cap(self._slot_cap),
-            bk.bucket_of(max(1, self.blocks_local)),
-        )
+        is D lists of this size, not one global list)."""
+        return min(FUSED_CHUNK_SLOTS, bk.bucket_of(max(1, self.blocks_local)))
 
     @property
     def fused_pack_capacity(self) -> int:

@@ -21,15 +21,6 @@ slices:
   path — per-host shard builds, cross-host fused dispatch, per-host
   WAL/standing shards — runs identically, so the full matrix pins on
   the CPU CI host.
-
-The group also owns the PER-HOST link profile (ISSUE 20 satellite:
-``derive_link_constants`` assumed one link RTT for the whole pod, so
-one slow host inflated every host's pad-slot amortization bucket):
-:meth:`probe_links` measures each host's pull RTT,
-:meth:`set_link_profile` derives one fused slot cap per host through
-the shared ``doubling_ladder`` rule, and ``PodIndexTable`` stamps each
-shard's ``_slot_cap`` from it — a slow host pays its own bigger bucket,
-its peers keep theirs.
 """
 
 from __future__ import annotations
@@ -38,8 +29,6 @@ import json
 import os
 import subprocess
 import sys
-import threading
-import time
 
 import numpy as np
 
@@ -119,13 +108,6 @@ class HostGroup:
         self.device_slices = tuple(tuple(s) for s in slices)
         self._meshes: dict = {}
         self._flat_mesh = None
-        from geomesa_tpu.lockwitness import witness
-
-        self._probe_lock = witness(
-            threading.Lock(), "HostGroup._probe_lock"
-        )
-        self.link_rtts_ms: list = [None] * self.hosts  # guarded-by: _probe_lock
-        self.slot_caps: list = [None] * self.hosts     # guarded-by: _probe_lock
 
     # -- meshes ----------------------------------------------------------
     def mesh(self, h: int):
@@ -143,66 +125,13 @@ class HostGroup:
         """ONE host-major mesh over every device in the group — the
         single-process `DistributedIndexTable` view of the same devices
         (the differential baseline the pod table pins bit-identity
-        against, and the equal-device-budget bench comparator)."""
+        against)."""
         from jax.sharding import Mesh
 
         if self._flat_mesh is None:
             flat = [d for s in self.device_slices for d in s]
             self._flat_mesh = Mesh(np.array(flat), (SHARD_AXIS,))
         return self._flat_mesh
-
-    # -- per-host link profile -------------------------------------------
-    def set_link_profile(
-        self, rtts_ms: list, pull_mb_s: "list | None" = None
-    ) -> list:
-        """Install per-host measured link RTTs and derive each host's
-        fused slot cap through the shared ``derive_link_constants`` /
-        ``doubling_ladder`` rule — PER HOST, so one slow host's bigger
-        amortization bucket never inflates its peers' pad-slot work.
-        Returns the derived caps (None entries keep the design-point
-        default for that host)."""
-        from geomesa_tpu.scan import block_kernels as bk
-
-        if len(rtts_ms) != self.hosts:
-            raise ValueError(f"need {self.hosts} RTTs, got {len(rtts_ms)}")
-        caps = []
-        for h, rtt in enumerate(rtts_ms):
-            if rtt is None:
-                caps.append(None)
-                continue
-            mbps = None if pull_mb_s is None else pull_mb_s[h]
-            caps.append(int(bk.derive_link_constants(rtt, mbps)["fused_chunk_slots"]))
-        with self._probe_lock:
-            self.link_rtts_ms = list(rtts_ms)
-            self.slot_caps = caps
-        return caps
-
-    def probe_links(self, samples: int = 3) -> list:
-        """Measure each host's device->host pull RTT (min over
-        ``samples`` small round-trips against the host's first device)
-        and install the profile. Gated off by default
-        (``geomesa.pod.link.probe``) so tests and CI keep deterministic
-        design-point shapes; the bench/pod driver opts in."""
-        import jax
-
-        rtts = []
-        for h in range(self.hosts):
-            dev = self.device_slices[h][0]
-            buf = jax.device_put(np.zeros(1024, np.float32), dev)
-            jax.block_until_ready(buf)
-            best = None
-            for _ in range(max(1, samples)):
-                t0 = time.perf_counter()
-                np.asarray(jax.device_get(buf))
-                dt = (time.perf_counter() - t0) * 1e3
-                best = dt if best is None else min(best, dt)
-            rtts.append(best)
-        self.set_link_profile(rtts)
-        return rtts
-
-    def slot_cap(self, h: int) -> "int | None":
-        with self._probe_lock:
-            return self.slot_caps[h]
 
     def __repr__(self):  # pragma: no cover - debugging aid
         return (
@@ -270,7 +199,4 @@ def make_host_group(
             dph = len(devs) // hosts
         slices = host_major_slices(devs, hosts, dph)
 
-    group = HostGroup(driver, slices)
-    if conf.POD_LINK_PROBE.get():
-        group.probe_links()
-    return group
+    return HostGroup(driver, slices)

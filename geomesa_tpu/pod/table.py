@@ -117,9 +117,6 @@ class PodIndexTable(IndexTable):
                 # order, no per-shard re-sort
                 sorted_state=np.arange(rows_ph, dtype=np.int64),
             )
-            cap = self.group.slot_cap(h)
-            if cap is not None:
-                shard._slot_cap = cap  # per-host probed link (satellite)
             self.shards.append(shard)
 
     # -- accounting (no coordinator-resident device columns) -------------

@@ -147,8 +147,8 @@ def _xla_density(
     col_names, has_boxes, has_windows, extent, width, height,
 ):
     """XLA fallback: block-granular gather + scatter-add. Fine on CPU;
-    on TPU the serialized scatter was measured at ~116 ms for M=1024
-    (scripts/probe_agg.py) vs ~15 ms for the Pallas matmul histogram."""
+    on TPU the scatter serializes, which is why the Pallas matmul
+    histogram exists."""
     gathered = {n: c[jnp.maximum(bids, 0)] for n, c in zip(col_names, cols3)}
     w, _ = bk._masks(gathered, boxes, wins, has_boxes, has_windows, extent)
     x, y = _rep_xy(gathered, extent)
@@ -198,8 +198,8 @@ def _make_density_kernel(col_names, has_boxes, has_windows, extent, width, heigh
     one-hot planes: for each row r with pixel (py, px), grid = Ay^T-style
     contraction of Ay[h, r] = (py_r == h) against Ax[w, r] = (px_r == w)
     masked — both built with broadcasted_iota compares in VMEM, contracted
-    on the MXU (measured ~143 TFLOP/s, scripts/probe_agg.py). The grid
-    accumulates in VMEM across grid steps (init at step 0), padded to
+    on the MXU. The grid accumulates in VMEM
+    across grid steps (init at step 0), padded to
     (8, 128)-aligned (hp, wp); the host slices to (height, width)."""
     import jax.experimental.pallas as pl
 

@@ -2,8 +2,8 @@
 
 This is the round-3 redesign of the scan hot path, driven by the link
 characteristics of the installation it was designed on, a remote chip
-behind a slow link (PERF.md keeps those figures as design history; what a
-local v5e measures is in its "On-chip bring-up (PR 21)" section):
+behind a slow link (design history; what a local v5e measures is in
+PERF.md section 5):
 
 - explicit ``device_put``/``jnp.asarray`` costs ~66 ms per call, but numpy
   arrays passed *as jit arguments* transfer in ~0.05 ms -> all query
@@ -78,104 +78,10 @@ FUSED_E_BUCKETS = (16, 64, 256)
 # deliberately COARSE (geomesa.raster.kernel.intervals, default 16):
 # the raster-derived z-ranges already prune out-cell rows host-side at
 # full resolution, so the kernel intervals only classify rows within
-# straddling blocks — measured on the 2M-point CPU bench, 16 coalesced
-# intervals kept the wide plane within ~2x of exact while cutting the
-# kernel to ~1/25 of the 256-edge-ladder PIP cost (PERF.md §13).
+# straddling blocks.
 R_BUCKETS = (16, 32, 64, 256)
 FUSED_R_BUCKETS = (16, 32, 64, 256)
 PALLAS_MAX_RINTS = 64  # unrolled interval checks; larger R rides XLA
-
-
-# -- measured-link re-derivation (round 11; VERDICT weak #8) --------------
-# The constants above were hand-tuned against the ROUND-3 remote link
-# (~66 ms pull floor, ~30 MB/s — PERF.md §1); a local v5e pulls in well
-# under a millisecond (PERF.md, PR 21). ``derive_link_constants`` re-derives
-# the two floor-amortization constants from the probe bench.py runs at
-# start (dimensionless ratios against the 66 ms design point, so the
-# rule degrades to the hand-tuned values on a link like the original):
-#
-# - the fused-chunk SLOT CAP scales with the pull floor: a chunk must
-#   hold enough slots that one dispatch's fixed cost stays amortized,
-#   and on a sub-ms link a 2048-slot canonical shape just multiplies
-#   mid-size batches' pad-slot scan work (the PR 3 small-table clamp,
-#   generalized to the link) — floor 256, cap the hand-tuned 2048;
-# - the single-query M-bucket FLOOR rises on a fast link: the small 32/
-#   64 buckets exist to shave pull bytes at ~30 MB/s, which a >=200 MB/s
-#   or sub-5 ms link makes irrelevant — padding small queries to M=128
-#   costs ~nothing and drops two warmup compiles per kernel variant.
-#
-# Both applied via set_link_constants BEFORE tables build/warm (bench
-# start); tests/defaults never tune, so shapes stay deterministic.
-DESIGN_LINK_RTT_MS = 66.0
-_LINK_CONSTANTS = {
-    "fused_chunk_slots": None,  # None = the hand-tuned FUSED_CHUNK_SLOTS
-    "m_floor": M_BUCKETS[0],
-    "link_rtt_ms": None,
-}
-
-
-def derive_link_constants(rtt_ms: float, pull_mb_s: "float | None" = None) -> dict:
-    """Pure derivation (no state change): the fused-chunk slot cap and
-    M-bucket floor a measured link profile calls for."""
-    from geomesa_tpu.storage.table import FUSED_CHUNK_SLOTS
-    from geomesa_tpu.tuning.primitives import doubling_ladder
-
-    want = FUSED_CHUNK_SLOTS * max(float(rtt_ms), 1e-3) / DESIGN_LINK_RTT_MS
-    slots = doubling_ladder(want, 256, FUSED_CHUNK_SLOTS)
-    fast = rtt_ms <= 5.0 or (pull_mb_s is not None and pull_mb_s >= 200.0)
-    return {
-        "fused_chunk_slots": slots,
-        "m_floor": 128 if fast else M_BUCKETS[0],
-        "link_rtt_ms": round(float(rtt_ms), 2),
-    }
-
-
-def set_link_constants(constants: "dict | None") -> None:
-    """Install (or, with None, reset) a derived link profile. Call BEFORE
-    building/warming tables: the constants participate in kernel compile
-    keys, so changing them afterwards re-pays warmup compiles."""
-    if constants is None:
-        _LINK_CONSTANTS.update(
-            fused_chunk_slots=None, m_floor=M_BUCKETS[0], link_rtt_ms=None
-        )
-    else:
-        _LINK_CONSTANTS.update(constants)
-
-
-def link_constants() -> dict:
-    """The active link-derived constants (the bench records them in its
-    artifact row so a changed deployment link is visible in the record)."""
-    from geomesa_tpu.storage.table import FUSED_CHUNK_SLOTS
-
-    out = dict(_LINK_CONSTANTS)
-    if out["fused_chunk_slots"] is None:
-        out["fused_chunk_slots"] = FUSED_CHUNK_SLOTS
-    return out
-
-
-def fused_slot_cap(local_cap: "int | None" = None) -> int:
-    """The fused-chunk slot cap in force (IndexTable.fused_slots clamps
-    to min(this, the table's own block-count bucket)). Resolution:
-    the ``geomesa.scan.fused.slots`` knob when pinned nonzero (how the
-    tuning tier's fused_chunk_slots controller actuates), else
-    ``local_cap`` (a PER-HOST probed cap — pod host groups derive one
-    per shard so a slow host's bigger amortization bucket never inflates
-    its peers' pad-slot work), else the probed link constants, else the
-    compiled default — so an untuned, unprobed store keeps today's
-    deterministic shapes."""
-    from geomesa_tpu import conf
-
-    pinned = int(conf.SCAN_FUSED_SLOTS.get() or 0)
-    if pinned > 0:
-        return pinned
-    if local_cap is not None:
-        return int(local_cap)
-    cap = _LINK_CONSTANTS["fused_chunk_slots"]
-    if cap is not None:
-        return int(cap)
-    from geomesa_tpu.storage.table import FUSED_CHUNK_SLOTS
-
-    return FUSED_CHUNK_SLOTS
 
 
 def fused_e_bucket(n: int) -> int:
@@ -994,7 +900,7 @@ def block_scan_multi(
     planes are per-slot exactly like :func:`block_scan`; each query's rows
     decode from its contiguous slot segment. Amortizes the per-dispatch
     overhead that serialized many-small-query workloads (the indexed
-    spatial join's 256 per-polygon scans — bench.py config 4).
+    spatial join's 256 per-polygon scans).
 
     PIP fusion (round 6): ``n_edges`` > 0 adds a [Q, n_edges, 128]
     ``edges`` stack (pack_edges blocks zero-padded to the chunk's
@@ -1016,8 +922,7 @@ def block_scan_multi(
     n_edges, n_rints). Production callers use the canonical fixed chunk
     shape — ``IndexTable.fused_slots`` x FUSED_CHUNK_Q (storage.table) —
     so ONE compiled variant per (columns, flags, E bucket, R bucket)
-    serves every batch; :func:`bucket_q` is a test-only helper for
-    hand-built param stacks.
+    serves every batch.
     """
     if use_pallas() and n_edges <= PALLAS_MAX_EDGES and n_rints <= PALLAS_MAX_RINTS:
         interpret = jax.default_backend() != "tpu"
@@ -1031,18 +936,6 @@ def block_scan_multi(
         col_names=col_names, has_boxes=has_boxes, has_windows=has_windows,
         extent=extent, n_edges=n_edges, n_rints=n_rints,
     )
-
-
-def bucket_q(q: int) -> int:
-    """Static Q bucket: power of two >= q, floor 8. TEST-ONLY — production
-    fused dispatches pad their param stacks to the canonical FUSED_CHUNK_Q
-    (storage.table._submit_fused_chunk); this helper sizes hand-built
-    stacks in kernel-level tests. Pad query rows are all-zero params no
-    slot references (pad slots carry qid 0 and are ignored at decode)."""
-    m = 8
-    while m < q:
-        m *= 2
-    return m
 
 
 # --------------------------------------------------------------- decode
@@ -1119,12 +1012,7 @@ def _bids_sorted(bids: np.ndarray, n_real: int) -> bool:
 def bucket_of(n: int) -> int:
     """Static M bucket for an n-block candidate list: the smallest fixed
     bucket >= n, or the next power of two past the largest bucket (full
-    scans — still one static shape per table). Floor-free: the
-    link-derived M floor applies only to the SINGLE-QUERY candidate
-    ladder (:func:`m_bucket_of`), never to the fused-chunk slot sizing
-    that also derives from this ladder — flooring slots would inflate
-    small tables' fused chunks with pad-slot scan work, the exact waste
-    the slot-cap derivation exists to remove."""
+    scans — still one static shape per table)."""
     for m in M_BUCKETS:
         if n <= m:
             return m
@@ -1132,14 +1020,6 @@ def bucket_of(n: int) -> int:
     while m < n:
         m *= 2
     return m
-
-
-def m_bucket_of(n: int) -> int:
-    """Single-query candidate-list bucket: :func:`bucket_of` raised to
-    the link-derived M floor (set_link_constants) — on fast links the
-    32/64 buckets stop earning their warmup compiles and every small
-    query pads to the floor instead."""
-    return max(bucket_of(n), int(_LINK_CONSTANTS["m_floor"]))
 
 
 def pad_bids(
@@ -1154,7 +1034,7 @@ def pad_bids(
     bucket — the distributed table pads every device's list to the same M.
     """
     n = len(blocks)
-    m = bucket if bucket is not None else m_bucket_of(n)
+    m = bucket if bucket is not None else bucket_of(n)
     out = np.full(m, pad, np.int32)
     out[:n] = blocks
     return out, n

@@ -75,8 +75,8 @@ class _AdaptiveGate:
             ewma.update_cost(seconds, units)
 
     def pick(self, n_cand: int, n_edges: int, boundary_frac: float) -> str:
-        # cold-start priors from the measured CPU bench (PERF.md §13);
-        # real measurements take over after the first partitions
+        # cold-start priors from a CPU run; real measurements take over
+        # after the first partitions
         pip = self._pip.value_or(4e-9)
         cls = self._cls.value_or(2e-8)
         plain = n_cand * n_edges * pip

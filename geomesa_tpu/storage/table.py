@@ -62,9 +62,8 @@ _SENTINELS = {
 # ladder). ``fused_slots`` is FUSED_CHUNK_SLOTS clamped down to the
 # table's own block-count bucket: the kernel's scan cost is proportional
 # to slots whether they are real or pads, so a 123-block table padding to
-# 2048 slots would scan 16x its own size per dispatch (the serving
-# bench's CPU regression at 32 clients). The fixed size also bounds
-# device memory: plane bytes — and, on the XLA fallback, the column
+# 2048 slots would scan 16x its own size per dispatch. The fixed size also
+# bounds device memory: plane bytes — and, on the XLA fallback, the column
 # gathers — scale with the chunk's slot count, not the whole batch.
 # 2048 slots = 4.2M rows per dispatch at the default tile; greedy packing
 # keeps pad waste small, and members broader than half a chunk take the
@@ -91,8 +90,7 @@ def _device_fold_enabled() -> bool:
     O(touched)-vs-O(table) LINK transfer is the cost that matters — on
     the CPU backend every "transfer" is a memcpy, while the plan's
     eager device ops re-specialize per slice shape, so the host
-    gather + upload path is strictly faster there (measured: ~3x lower
-    slice pause on the CPU stream bench)."""
+    gather + upload path is strictly faster there."""
     import jax
 
     from geomesa_tpu.conf import STREAM_FOLD_DEVICE
@@ -523,11 +521,6 @@ class IndexTable(SortedKeys):
         multiple of the mesh size)."""
         return n_blocks
 
-    # per-table probed slot cap: pod host groups stamp one per host shard
-    # so a slow host's bigger amortization bucket stays its own (None =
-    # the process-wide link constants)
-    _slot_cap: "int | None" = None
-
     @property
     def fused_slots(self) -> int:
         """Slot count of THIS table's canonical fused-dispatch shape:
@@ -535,12 +528,8 @@ class IndexTable(SortedKeys):
         bucket (see the constants' doctrine note) — still one static
         shape per (columns, flags), but a small table never scans a
         multiple of its own size in pad slots. For the distributed table
-        this is the PER-DEVICE slot bucket. The cap itself is
-        link-derived (bk.fused_slot_cap: the hand-tuned 2048 on the 66 ms
-        design link, smaller on a measured fast link — bench.py installs
-        the probe-derived constants before warmup, per host via
-        ``_slot_cap`` under a pod host group)."""
-        return min(bk.fused_slot_cap(self._slot_cap), bk.bucket_of(self.n_blocks))
+        this is the PER-DEVICE slot bucket."""
+        return min(FUSED_CHUNK_SLOTS, bk.bucket_of(self.n_blocks))
 
     @property
     def fused_pack_capacity(self) -> int:
@@ -654,7 +643,7 @@ class IndexTable(SortedKeys):
         rebuild: every value is a copy of an old-table or delta value
         (tests/test_streaming_tier.py pins cols3 equality both ways).
         ``rows_uploaded`` records the rows that actually crossed the
-        link — the fold's O(touched) claim, surfaced by the bench."""
+        link — the fold's O(touched) claim."""
         import jax
         import jax.numpy as jnp
 
@@ -823,9 +812,8 @@ class IndexTable(SortedKeys):
 
         Per-query dispatch overhead (~2 ms submit + serialized kernel
         launches) dominated many-small-query workloads: the indexed
-        spatial join's 256 per-polygon scans spent ~2.1 s of which <10 ms
-        was host refinement (bench.py config 4, on the installation of
-        the time). Round 6 widened
+        spatial join's 256 per-polygon scans spent nearly all their time
+        there, not in host refinement. Round 6 widened
         eligibility to EVERY kernel-backed config: polygon-INTERSECTS
         members fuse through the chunk's [Q, E, 128] edge stack (the
         device PIP tier, selected per slot), extent/XZ members fuse on

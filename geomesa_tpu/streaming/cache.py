@@ -202,8 +202,8 @@ class StreamingFeatureCache:
         single-threaded (there are no readers to interleave with), so
         the live tier's reader-friendly ``_LOCK_CHUNK`` chunking buys
         nothing here, and the per-record apply loop was the WAL replay
-        bottleneck (BENCH_WAL ``wal_replay``). ``xy``: the batch's
-        decoded [n, 2] point coordinates when the WAL record carried
+        bottleneck. ``xy``: the batch's decoded [n, 2]
+        point coordinates when the WAL record carried
         the geometry column packed (``unpack_upsert_xy``) — skips
         per-row Point attribute reads. Falls back to :meth:`upsert`
         when listeners are attached (events must fire per message)."""
@@ -248,8 +248,8 @@ class StreamingFeatureCache:
         Replay interleaves bulk upserts with flush-watermark evictions
         that drain most of them right back out — at 1M replayed rows
         the per-row index insert/remove churn was the single largest
-        recovery cost (BENCH_WAL ``wal_replay``), all of it for entries
-        that never serve a query (recovery is single-threaded; the
+        recovery cost, all of it for entries that never
+        serve a query (recovery is single-threaded; the
         store is not visible until ``recover`` returns)."""
         with self._lock:
             self._replaying = True

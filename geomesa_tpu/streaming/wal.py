@@ -228,7 +228,7 @@ def unpack_upsert_xy(rec: dict, geom_field: "str | None") -> tuple:
     n = int(rec["n"])
     # tagged values are always dicts — a column with none (plain
     # strings/numbers, the common case) skips the per-value decode calls
-    # and keeps the json-decoded list as-is (BENCH_WAL wal_replay)
+    # and keeps the json-decoded list as-is
     cols = {
         k: (
             [_dec_value(v) for v in vs]
@@ -243,7 +243,6 @@ def unpack_upsert_xy(rec: dict, geom_field: "str | None") -> tuple:
             xy = a
         # flat per-axis tolist() feeds the million Point constructors
         # native floats without allocating an [x, y] list per row
-        # (measured ~1.15x over scalar indexing; BENCH_WAL wal_replay)
         xs = a[:, 0].tolist()
         ys = a[:, 1].tolist()
         cols[k] = [geo.Point(px, py) for px, py in zip(xs, ys)]

@@ -3,9 +3,9 @@
 No imaging dependency: a PNG is a signature + IHDR + (optional PLTE) +
 one zlib-compressed IDAT of filter-0 scanlines + IEND, all assembled
 with ``struct`` + ``zlib``. Everything here is bit-deterministic in the
-input grid — same counts in, same bytes out — which is what lets the
-bench compare a served tile against its from-scratch oracle by raw byte
-equality (BENCH_TILES.json ``identical``).
+input grid — same counts in, same bytes out — which is what lets a
+test compare a served tile against its from-scratch oracle by raw byte
+equality.
 
 Renderings (one per tile kind, docs/tiles.md):
 

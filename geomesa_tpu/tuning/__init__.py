@@ -1,7 +1,7 @@
 """Self-tuning controller tier (docs/tuning.md): the loop that turns
 the store's existing telemetry — estimate-accuracy windows, live
-histograms and counters, SLO burn rates, link probe constants — into
-bounded online decisions. ``DataStore.attach_tuning()`` is the entry
+histograms and counters, SLO burn rates — into bounded online
+decisions. ``DataStore.attach_tuning()`` is the entry
 point; ``geomesa.tuning.enabled`` arms it; disarmed behavior is
 bit-identical to a store without this package."""
 
@@ -16,7 +16,6 @@ from geomesa_tpu.tuning.primitives import (
     DEFAULT_ALPHA,
     CostEwma,
     ProbeGate,
-    doubling_ladder,
     ewma_step,
 )
 from geomesa_tpu.tuning.reweight import IndexReweighter
@@ -31,6 +30,5 @@ __all__ = [
     "KnobController",
     "ProbeGate",
     "TuningManager",
-    "doubling_ladder",
     "ewma_step",
 ]

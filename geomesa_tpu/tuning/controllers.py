@@ -30,11 +30,10 @@ from typing import Optional
 @dataclass(frozen=True)
 class ControllerSpec:
     """One auto-tuned knob's declaration. ``objective_kind`` selects
-    the reading: ``counter`` (per-pulse delta of a monotonic counter),
-    ``quantile`` (live histogram p99), or ``gauge`` (last set value).
-    ``policy`` is ``hill`` (bounded hill-climb on the objective) or
-    ``derive`` (closed-form from the objective reading — the link
-    probe's ladder). ``relax_dir`` is the direction (+1/-1) to step
+    the reading: ``counter`` (per-pulse delta of a monotonic counter)
+    or ``quantile`` (live histogram p99). ``policy`` is ``hill``
+    (bounded hill-climb on the objective), the only one there is.
+    ``relax_dir`` is the direction (+1/-1) to step
     when the objective collapses below its best: the spec author knows
     which way "more permissive" lies; the controller must not guess."""
 
@@ -71,22 +70,6 @@ CONTROLLER_SPECS: "tuple[ControllerSpec, ...]" = (
         doc="result-cache admission cost threshold vs cache-hit rate: "
             "when hits collapse (the workload's scans got cheaper than "
             "the frozen threshold), relax the floor so repeats cache",
-    ),
-    ControllerSpec(
-        name="fused_chunk_slots",
-        knob="geomesa.scan.fused.slots",
-        lo=256.0,
-        hi=2048.0,
-        objective="geomesa.tuning.link.rtt",
-        objective_kind="gauge",
-        higher_is_better=False,
-        step=0.25,
-        policy="derive",
-        integral=True,
-        relax_dir=1,
-        doc="fused transfer chunk slots derived from the measured link "
-            "RTT on the doubling ladder (scan/block_kernels.py): slower "
-            "links amortize more rows per round trip",
     ),
     ControllerSpec(
         name="fold_slice_rows",

@@ -2,9 +2,9 @@
 
 One :class:`TuningManager` per DataStore closes ISSUE 19's loop: the
 sensors the store already carries (EstimateAccuracy windows, the live
-metric histograms/counters, the SLO tracker's burn rates, the link
-probe constants) feed three actuator legs — plan-feedback index
-reweighting (reweight.py), bounded knob hill-climbs (controllers.py)
+metric histograms/counters, the SLO tracker's burn rates) feed
+three actuator legs — plan-feedback index reweighting
+(reweight.py), bounded knob hill-climbs (controllers.py)
 and SLO-burn admission shedding (burnshed.py). ``DataStore.
 attach_tuning()`` builds and wires one; ``geomesa.tuning.enabled``
 arms it. DISARMED IS FREE: an unarmed manager never pulses, the
@@ -162,48 +162,21 @@ class TuningManager:
             return None
         self._last_reading[spec.name] = reading
         current = float(prop.get() or 0.0)
-        if spec.policy == "derive":
-            # closed-form: the link probe's ladder, re-derived from the
-            # live RTT gauge (reading) instead of a one-shot bench probe
-            from geomesa_tpu.scan import block_kernels as bk
-
-            derived = bk.derive_link_constants(reading)["fused_chunk_slots"]
-            nxt = float(min(spec.hi, max(spec.lo, derived)))
-            if current == nxt or (current == 0.0 and bk.fused_slot_cap() == int(nxt)):
-                return None  # auto path already lands there: hold
-            why = (
-                f"link rtt {reading:.2f}ms -> {int(nxt)} slots on the "
-                f"doubling ladder"
-            )
-        else:
-            ctl = self.controllers[spec.name]
-            proposed = ctl.propose(current, reading)
-            if proposed is None:
-                return None
-            nxt = proposed
-            why = (
-                f"objective {spec.objective} read {reading:.6g} "
-                f"({'higher' if spec.higher_is_better else 'lower'} is "
-                f"better): step {current:.6g} -> {nxt:.6g} within "
-                f"[{spec.lo:g}, {spec.hi:g}]"
-            )
+        nxt = self.controllers[spec.name].propose(current, reading)
+        if nxt is None:
+            return None
+        why = (
+            f"objective {spec.objective} read {reading:.6g} "
+            f"({'higher' if spec.higher_is_better else 'lower'} is "
+            f"better): step {current:.6g} -> {nxt:.6g} within "
+            f"[{spec.lo:g}, {spec.hi:g}]"
+        )
         return self._apply(spec, current, nxt, why, metrics)
 
     def _reading(self, spec, metrics) -> Optional[float]:
         """Resolve one objective reading; None = no signal this pulse
-        (unseeded counter baseline, never-observed histogram, no link
-        probe yet) — the controller holds rather than moves blind."""
-        if spec.objective_kind == "gauge":
-            # the link gauge is OURS to sense: exported from the scan
-            # tier's probed constants so it exists as a real metric
-            from geomesa_tpu.scan import block_kernels as bk
-
-            rtt = bk.link_constants().get("link_rtt_ms")
-            if rtt is None:
-                return None
-            if metrics is not None:
-                metrics.gauge("geomesa.tuning.link.rtt", float(rtt))
-            return float(rtt)
+        (unseeded counter baseline, never-observed histogram) — the
+        controller holds rather than moves blind."""
         if metrics is None:
             return None
         if spec.objective_kind == "counter":

@@ -1,13 +1,11 @@
 """Shared measured-cost controller primitives (docs/tuning.md).
 
-Four independent feedback gates grew hand-rolled before this tier
+Three independent feedback gates grew hand-rolled before this tier
 existed: the tile compose cost gate (cache/tiles.py), the adaptive
-join gate from arXiv 1802.09488 (sql/join.py), standing's
-host-vs-fused match gate (streaming/standing.py), and the bench link
-probe's constant derivation (scan/block_kernels.py). They all reduce
-to three moves — blend a measured per-unit cost into an EWMA, back
-off with periodic re-probes after losing, and snap a continuous
-target onto a power-of-two ladder. This module IS those moves,
+join gate from arXiv 1802.09488 (sql/join.py) and standing's
+host-vs-fused match gate (streaming/standing.py). They all reduce
+to two moves — blend a measured per-unit cost into an EWMA, and back
+off with periodic re-probes after losing. This module IS those moves,
 extracted once; the gates import from here and their decisions stay
 bit-identical on their test matrices (pinned by the differential
 tests in tests/test_tuning.py).
@@ -103,16 +101,3 @@ class ProbeGate:
             self.blocked = 0
             return True
         return False
-
-
-def doubling_ladder(want: float, base: int, cap: int) -> int:
-    """Snap a continuous target onto the power-of-two ladder from
-    ``base`` up to ``cap``: the smallest rung >= ``want`` (``cap``
-    when the target overshoots it). Bit-identical to the link probe's
-    original slot loop — device-side buffer sizes must stay on the
-    compiled bucket grid, so controllers never write an off-ladder
-    value."""
-    step = base
-    while step < want and step < cap:
-        step *= 2
-    return step

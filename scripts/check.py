@@ -2,8 +2,7 @@
 """geomesa-lint runner: the repo's static-analysis gate (docs/analysis.md).
 
 Runs every shipped rule (geomesa_tpu.analysis) over geomesa_tpu/ +
-scripts/ + docs/*.md and fails loudly on new findings — the same exit
-convention as scripts/bench_gate.py, so CI treats both gates alike:
+scripts/ + docs/*.md and fails loudly on new findings. Exit codes:
 
     0 = clean (no findings beyond the suppression baseline)
     1 = findings (each printed as path:line: [rule-id] message + fix)

@@ -23,15 +23,15 @@ BAD = ControllerSpec(
 # the disciplined twin: registered name, declared knob, ordered literal
 # bounds, emitted objective — zero controller-registry findings
 GOOD = ControllerSpec(
-    name="fused_chunk_slots",
-    knob="geomesa.scan.fused.slots",
-    lo=256.0,
-    hi=2048.0,
-    objective="geomesa.tuning.link.rtt",
-    objective_kind="gauge",
+    name="fold_slice_rows",
+    knob="geomesa.stream.fold.slice.rows",
+    lo=8192.0,
+    hi=262144.0,
+    objective="geomesa.stream.fold.slice",
+    objective_kind="quantile",
     higher_is_better=False,
-    step=0.0,
-    policy="derive",
+    step=0.25,
+    policy="hill",
     integral=True,
-    doc="fixture twin of the shipped derive controller",
+    doc="fixture twin of the shipped fold-slice controller",
 )

@@ -3,8 +3,7 @@
 The Pallas TPU path cannot compile on CPU, but interpret mode runs the
 same kernel logic (including the MXU one-hot matmul histogram and the
 per-slot bounds blocks); parity with the XLA block-gather implementations
-pins the contract. TPU-compiled parity is asserted by scripts/probe_agg.py
-on hardware (see PERF.md).
+pins the contract.
 """
 
 import numpy as np

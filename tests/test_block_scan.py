@@ -386,57 +386,26 @@ class TestColumnProjection:
         assert table.last_scan_bytes < bytes_full
 
 
-class TestLinkDerivedConstants:
-    """Round 11 (VERDICT weak #8): the fused-chunk slot cap and M-bucket
-    floor re-derive from the measured link probe instead of the 66 ms-era
-    hand tuning; bench.py installs them before warmup."""
+@pytest.mark.parametrize("n_blocks", [1, 33, 128, 3000])
+def test_fused_shape_is_a_function_of_block_count(n_blocks):
+    """The canonical fused-dispatch shape is decided in one place from
+    one input: the table's block count against FUSED_CHUNK_SLOTS. No
+    link profile, knob or per-table stamp can move it."""
+    from geomesa_tpu.storage.table import FUSED_CHUNK_SLOTS, IndexTable
 
-    def teardown_method(self):
-        bk.set_link_constants(None)  # never leak tuning into other tests
-
-    def test_design_link_reproduces_hand_tuning(self):
-        from geomesa_tpu.storage.table import FUSED_CHUNK_SLOTS
-
-        d = bk.derive_link_constants(66.0, 30.0)
-        assert d["fused_chunk_slots"] == FUSED_CHUNK_SLOTS
-        assert d["m_floor"] == bk.M_BUCKETS[0]
-
-    def test_fast_link_shrinks_chunks_and_raises_floor(self):
-        d = bk.derive_link_constants(0.4, 2000.0)
-        assert d["fused_chunk_slots"] == 256
-        assert d["m_floor"] == 128
-        # intermediate links scale between the endpoints
-        mid = bk.derive_link_constants(20.0, 30.0)
-        assert 256 <= mid["fused_chunk_slots"] <= 1024
-        assert mid["m_floor"] == bk.M_BUCKETS[0]
-
-    def test_install_changes_bucket_and_cap_then_resets(self):
-        base_bucket = bk.m_bucket_of(10)
-        bk.set_link_constants(bk.derive_link_constants(0.4, 2000.0))
-        try:
-            # the floor applies ONLY to the single-query candidate
-            # ladder — fused slot sizing (bucket_of) must stay unfloored
-            # or small tables' chunks would inflate with pad-slot work
-            assert bk.bucket_of(10) == 32
-            assert bk.m_bucket_of(10) == 128
-            assert len(bk.pad_bids(np.arange(10), 100)[0]) == 128
-            assert bk.m_bucket_of(300) == 512   # ladder above floor intact
-            assert bk.fused_slot_cap() == 256
-            assert bk.link_constants()["m_floor"] == 128
-            # a table built now clamps its fused shape to the new cap
-            ds = DataStore(tile=64)
-            sft = FeatureType.from_spec("lk", "*geom:Point:srid=4326")
-            ds.create_schema(sft)
-            n = 40_000
-            rng = np.random.default_rng(3)
-            ds.write("lk", FeatureCollection.from_columns(
-                sft, np.arange(n).astype(str),
-                {"geom": (rng.uniform(-60, 60, n), rng.uniform(-45, 45, n))},
-            ), check_ids=False)
-            ds.compact("lk")
-            t = ds.table("lk", ds.indexes("lk")[0].name)
-            assert t.fused_slots <= 256
-        finally:
-            bk.set_link_constants(None)
-        assert bk.m_bucket_of(10) == base_bucket
-        assert bk.fused_slot_cap() == 2048
+    table = IndexTable.__new__(IndexTable)
+    table.n_blocks = n_blocks
+    assert FUSED_CHUNK_SLOTS == 2048
+    assert table.fused_slots == min(2048, bk.bucket_of(n_blocks))
+    assert table.fused_pack_capacity == table.fused_slots
+    # the single-query ladder is bucket_of and nothing else
+    assert len(bk.pad_bids(np.arange(n_blocks), n_blocks)[0]) == bk.bucket_of(n_blocks)
+    # and the kernel module holds no link profile, slot cap or bucket
+    # floor beside its ladders: these are all the names that size a grid
+    sizing = {n for n in vars(bk)
+              if any(w in n.lower() for w in ("link", "cap", "floor", "bucket"))}
+    assert sizing == {
+        "M_BUCKETS", "E_BUCKETS", "R_BUCKETS", "FUSED_E_BUCKETS",
+        "FUSED_R_BUCKETS", "bucket_of", "r_bucket_of", "fused_e_bucket",
+        "fused_r_bucket",
+    }, sizing

@@ -3,8 +3,7 @@
 Covers the ISSUE 2 tentpole: canonical fingerprints (``a AND b`` ==
 ``b AND a``), LRU/TTL/cost-aware admission, single-flight stampede
 protection, generation-based invalidation, tile-aggregate composition
-exactness, per-query bypass/pin hints, explain/metrics wiring, and the
-slow-marked bench scenario (BENCH_CACHE.json)."""
+exactness, per-query bypass/pin hints and explain/metrics wiring."""
 
 import json
 import threading
@@ -657,27 +656,3 @@ class TestStreamingInterplay:
                   ids=["hot0"])
         lam.persist_hot()
         assert len(ds.query("t", Q)) == n0 + 1
-
-
-# -- bench scenario (satellite: CI/tooling; slow-marked) -------------------
-
-@pytest.mark.slow
-def test_bench_cache_scenario(tmp_path, monkeypatch):
-    import bench
-
-    monkeypatch.setenv("GEOMESA_BENCH_CACHE_N", "400000")
-    monkeypatch.setenv("GEOMESA_BENCH_CACHE_QUERIES", "8")
-    out = tmp_path / "BENCH_CACHE.json"
-    rec = bench.config_cache(out_path=str(out))
-    assert out.exists()
-    data = json.loads(out.read_text())
-    repeat = data["repeat_query"]
-    assert repeat["hit_rate"] >= 0.99
-    # acceptance: >= 5x latency reduction on a warm cache
-    assert repeat["speedup"] >= 5.0, repeat
-    shifted = data["shifted_bbox"]
-    # either interior tiles composed, or the adaptive cost gate decided
-    # composing loses on this backend/scale and protected the workload —
-    # both are the tile tier working; which one wins is data-dependent
-    assert shifted["tiles_reused_frac"] > 0.0 or shifted["gated"] > 0
-    assert rec["metric"] == "cache_repeat_query_speedup"

@@ -8,8 +8,7 @@ so the bounded gather genuinely runs MANY spans per column:
   table bit-identical (counts, ids, sorted keys) to one built with the
   default span;
 - **bounded memory** — compaction peak RSS stays under the DECLARED
-  column-set multiple (the ``compaction.peak_over_column_set``
-  criterion BENCH_INGEST.json records at 100M rows): ~one transient
+  column-set multiple: ~one transient
   column family, never a doubled column set. Run in a fresh SUBPROCESS
   with a phase-scoped sampler, so other tests' allocator history can't
   pollute the measurement.
@@ -96,9 +95,9 @@ class TestStreamedExactness:
 _RSS_SCRIPT = r"""
 import gc, json, os, sys, time
 os.environ["JAX_PLATFORMS"] = "cpu"
-sys.path.insert(0, {root!r})
+sys.path[:0] = [{root!r}, os.path.join({root!r}, "tests")]
 import numpy as np
-from bench import _RssSampler, _ingest_column_set_bytes, _malloc_trim, _rss_bytes
+from rss_probe import _RssSampler, _ingest_column_set_bytes, _malloc_trim, _rss_bytes
 from geomesa_tpu import conf
 from geomesa_tpu.datastore import DataStore
 from geomesa_tpu.features import FeatureCollection
@@ -152,9 +151,7 @@ class TestBoundedRss:
         forced to 64Ki rows (dozens of spans per column) the compaction
         peak stays under PEAK_OVER_COLUMN_SET_MAX x the column set —
         measured in a fresh subprocess whose RSS history is exactly
-        (interpreter + jax + this store), the same accounting
-        BENCH_INGEST.json's ``compaction.peak_over_column_set`` row
-        uses at 100M rows."""
+        (interpreter + jax + this store)."""
         n = 1_500_000
         out = subprocess.run(
             [sys.executable, "-c",

@@ -17,6 +17,8 @@ import inspect
 import os
 import re
 
+import pytest
+
 _ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 
@@ -417,12 +419,8 @@ def test_ingest_doc_apis_exist():
 
 
 def test_fused_coverage_doc_honest():
-    """docs/serving.md "Fused coverage" + PERF.md §12 stay honest: every
-    constant, API and file the matrix names is real and matches the
-    code, and BENCH_FUSED.json (when present) actually shows the fused
-    path faster with bit-identical results, as both docs claim."""
-    import json
-
+    """docs/serving.md "Fused coverage" stays honest: every constant and
+    API the matrix names is real and matches the code."""
     from geomesa_tpu.scan import block_kernels as bk
     from geomesa_tpu.storage.table import IndexTable
     from geomesa_tpu.parallel.dtable import DistributedIndexTable
@@ -455,34 +453,11 @@ def test_fused_coverage_doc_honest():
     for p in ("edges", "spip", "n_edges"):
         assert p in sig, p
 
-    # the bench the docs point at exists and is registered (source-level
-    # contract, like the ingest fault points — bench.py is not a package)
-    bench_src = open(os.path.join(root, "bench.py")).read()
-    assert "def config_fused" in bench_src
-    assert '"fused": config_fused' in bench_src
-    assert "BENCH_FUSED.json" in bench_src
-    assert "BENCH_FUSED.json" in text
-
-    # honesty of the recorded numbers: fused faster than both baselines,
-    # results identical, on every non-skipped row
-    path = os.path.join(root, "BENCH_FUSED.json")
-    if os.path.exists(path):
-        payload = json.load(open(path))
-        timed = [r for r in payload["rows"] if "speedup" in r]
-        assert timed, "BENCH_FUSED.json has no timed rows"
-        for r in timed:
-            assert r["identical"] is True, r["scenario"]
-            assert r["fused_ms"] < r["per_query_ms"], r["scenario"]
-            assert r["speedup"] >= 2.0, r["scenario"]  # the round-6 bar
-
 
 def test_joins_doc_honest():
-    """docs/joins.md + PERF.md §13 stay honest: every API, knob, metric,
-    constant and artifact the raster/adaptive-join doc names is real, and
-    BENCH_PIP_JOIN.json (when present) actually shows the raster path
-    faster with bit-identical results, as the doc claims."""
+    """docs/joins.md stays honest: every API, knob, metric and constant
+    the raster/adaptive-join doc names is real."""
     import inspect
-    import json
 
     from geomesa_tpu import conf
     from geomesa_tpu import geometry as geo
@@ -551,28 +526,6 @@ def test_joins_doc_honest():
     for c in join_metrics:
         reg.counter(c)
     assert reg.counter_value("geomesa.join.in_cap_fallback") == 1
-
-    # the bench + gate the doc points at exist and are registered
-    bench_src = open(os.path.join(root, "bench.py")).read()
-    assert "def config_pip_join" in bench_src
-    assert '"pip_join": config_pip_join' in bench_src
-    assert os.path.exists(os.path.join(root, "scripts", "bench_gate.py"))
-    assert "BENCH_PIP_JOIN.json" in text
-
-    # honesty of the recorded numbers: raster faster than exact,
-    # bit-identity computed in-bench, the >= 5x acceptance on the PIP
-    # batch and the polygon join
-    path = os.path.join(root, "BENCH_PIP_JOIN.json")
-    if os.path.exists(path):
-        payload = json.load(open(path))
-        rows = {r["scenario"]: r for r in payload["rows"]}
-        pip = rows["z2_polygon_pip_batch"]
-        assert pip["identical"] is True
-        assert pip["speedup"] >= 5.0
-        assert pip["raster_ms_per_q"] < pip["exact_ms_per_q"]
-        join = rows["z2_polygon_join"]
-        assert join["identical"] is True
-        assert join["speedup"] >= 5.0
 
 
 def test_analysis_rule_catalog_documented():
@@ -784,8 +737,7 @@ def test_standing_doc_honest():
     """docs/standing.md stays honest the registry way: every standing
     API it names is real, every geomesa.standing.* knob and metric is
     declared at runtime and cited by the doc (knobs by config.md too),
-    the fault points exist in the source, and the documented bench +
-    gate wiring is real."""
+    and the fault points exist in the source."""
     import inspect
 
     from geomesa_tpu import process as P
@@ -853,17 +805,7 @@ def test_standing_doc_honest():
     text = reg.render_prometheus()
     assert "geomesa_standing_subscriptions 1" in text
     assert 'geomesa_standing_latency_seconds_bucket{le="' in text
-    # bench + gate wiring (source-level contract, like config_fused)
-    bench_src = open(os.path.join(_ROOT, "bench.py")).read()
-    assert "def config_standing" in bench_src
-    assert '"standing": config_standing' in bench_src
-    assert "BENCH_GEOFENCE.json" in bench_src
-    gate_src = open(
-        os.path.join(_ROOT, "scripts", "bench_gate.py")
-    ).read()
-    assert "standing_geofence" in gate_src
     doc = open(os.path.join(_ROOT, "docs", "standing.md")).read()
-    assert "BENCH_GEOFENCE.json" in doc
     # every `lam.X` / `engine.X` the doc mentions in backticks resolves
     for name in re.findall(r"`lam\.(\w+)", doc):
         assert hasattr(S.LambdaStore, name), f"lam.{name}"
@@ -875,8 +817,8 @@ def test_replication_doc_honest():
     """docs/replication.md stays honest the registry way: every
     replication API it names is real, every geomesa.replica.* knob and
     metric is declared at runtime and cited by the doc (knobs by
-    config.md too), the fault points and fencing hooks exist in the
-    source, and the documented bench + gate wiring is real."""
+    config.md too), and the fault points and fencing hooks exist in the
+    source."""
     import inspect
 
     from geomesa_tpu import streaming as S
@@ -940,17 +882,7 @@ def test_replication_doc_honest():
             reg.timer_update(n, 0.01)
     text = reg.render_prometheus()
     assert 'geomesa_replica_staleness_ms_seconds_bucket{le="' in text
-    # bench + gate wiring (source-level contract, like config_standing)
-    bench_src = open(os.path.join(_ROOT, "bench.py")).read()
-    assert "def config_replica" in bench_src
-    assert '"replica": config_replica' in bench_src
-    assert "BENCH_REPLICA.json" in bench_src
-    gate_src = open(
-        os.path.join(_ROOT, "scripts", "bench_gate.py")
-    ).read()
-    assert "BENCH_REPLICA" in gate_src
     doc = open(os.path.join(_ROOT, "docs", "replication.md")).read()
-    assert "BENCH_REPLICA.json" in doc
     # every `fol.X` / `ship.X` the doc mentions in backticks resolves
     for name in re.findall(r"`fol\.(\w+)", doc):
         assert hasattr(S.ReplicaStore, name), f"fol.{name}"
@@ -973,8 +905,8 @@ def test_tiles_doc_honest():
     """docs/tiles.md stays honest the registry way: every tile API it
     names is real, every geomesa.tiles.* knob and metric is declared
     at runtime and cited by the doc (knobs by config.md too), the
-    fault points exist in the source, and the documented endpoint,
-    CLI, bench and gate wiring is real."""
+    fault points exist in the source, and the documented endpoint and
+    CLI wiring is real."""
     import inspect
 
     from geomesa_tpu import cli
@@ -1054,19 +986,7 @@ def test_tiles_doc_honest():
     text = reg.render_prometheus()
     assert 'geomesa_tiles_fetch_seconds_bucket{le="' in text
     assert "geomesa_tiles_served 1" in text
-    # bench + gate wiring (source-level contract, like config_replica)
-    bench_src = open(os.path.join(_ROOT, "bench.py")).read()
-    assert "def config_tiles" in bench_src
-    assert '"tiles": config_tiles' in bench_src
-    assert "BENCH_TILES.json" in bench_src
-    gate_src = open(
-        os.path.join(_ROOT, "scripts", "bench_gate.py")
-    ).read()
-    assert "tiles_serving" in gate_src
-    assert "tiles_invalidation" in gate_src
-    assert "BENCH_TILES" in gate_src
     doc = open(os.path.join(_ROOT, "docs", "tiles.md")).read()
-    assert "BENCH_TILES.json" in doc
     # every `pyramid.X` the doc mentions in backticks resolves
     for name in re.findall(r"`pyramid\.(\w+)", doc):
         assert hasattr(TilePyramid, name), f"pyramid.{name}"
@@ -1076,9 +996,8 @@ def test_tuning_doc_honest():
     """docs/tuning.md stays honest the registry way: every tuning API
     it names is real, every geomesa.tuning.* knob and metric is
     declared at runtime and cited by the doc (and the knobs by
-    config.md), the controller table matches the machine-checked
-    CONTROLLERS registry, and the bench + gate wiring the doc promises
-    exists."""
+    config.md), and the controller table matches the machine-checked
+    CONTROLLERS registry."""
     from geomesa_tpu import tuning
     from geomesa_tpu.analysis.registries import CONTROLLERS
     from geomesa_tpu.datastore import DataStore
@@ -1086,7 +1005,7 @@ def test_tuning_doc_honest():
 
     for name in ("TuningManager", "IndexReweighter", "BurnShed",
                  "KnobController", "ControllerSpec", "CONTROLLER_SPECS",
-                 "CostEwma", "ProbeGate", "ewma_step", "doubling_ladder"):
+                 "CostEwma", "ProbeGate", "ewma_step"):
         assert hasattr(tuning, name), name
     for m in ("attach_tuning", "tuning_report", "record_query"):
         assert hasattr(DataStore, m), m
@@ -1096,9 +1015,9 @@ def test_tuning_doc_honest():
     # cited by both the subsystem doc and the operator index
     knobs, metrics = _area_names("geomesa.tuning.")
     assert len(knobs) == 9 and len(metrics) >= 5, (knobs, metrics)
-    _assert_runtime_declared(knobs + ["geomesa.scan.fused.slots"])
+    _assert_runtime_declared(knobs)
     _assert_documented("tuning.md", knobs + metrics)
-    _assert_documented("config.md", knobs + ["geomesa.scan.fused.slots"])
+    _assert_documented("config.md", knobs)
     # the controller table is the registry, verbatim: every registered
     # controller (and its steered knob) appears in the doc
     doc = open(os.path.join(_ROOT, "docs", "tuning.md")).read()
@@ -1115,17 +1034,6 @@ def test_tuning_doc_honest():
     assert "/debug/tuning" in doc
     assert "/debug/tuning" in inspect.getsource(ops_mod.OpsRoutes.handle)
     assert hasattr(cli, "cmd_tune")
-    # bench + gate wiring (source-level contract, like config_tiles)
-    bench_src = open(os.path.join(_ROOT, "bench.py")).read()
-    assert "def config_drift" in bench_src
-    assert '"drift": config_drift' in bench_src
-    assert "BENCH_DRIFT.json" in bench_src
-    gate_src = open(
-        os.path.join(_ROOT, "scripts", "bench_gate.py")
-    ).read()
-    assert "config_drift" in gate_src
-    assert "BENCH_DRIFT" in gate_src
-    assert "BENCH_DRIFT.json" in doc
     # every `ds.X` the guide mentions in backticks resolves
     for name in re.findall(r"`ds\.(\w+)", doc):
         assert hasattr(DataStore, name), f"ds.{name}"
@@ -1135,8 +1043,8 @@ def test_distributed_doc_honest():
     """docs/distributed.md stays honest the registry way: every pod API
     it names is real, every geomesa.pod.* knob is declared at runtime
     and cited by the doc (and config.md's index), the fault points and
-    locks exist in the source/registry, and the documented probe,
-    scale-driver, bench and gate wiring is real."""
+    locks exist in the source/registry, and the documented probe is
+    real."""
     import inspect
 
     import geomesa_tpu.pod.store as pod_store
@@ -1147,8 +1055,7 @@ def test_distributed_doc_honest():
     for name in ("HostGroup", "PodIndexTable", "PodStore",
                  "PodUnsupported", "make_host_group", "probe_capability"):
         assert hasattr(pod, name), name
-    for m in ("mesh", "flat_mesh", "set_link_profile", "probe_links",
-              "slot_cap"):
+    for m in ("mesh", "flat_mesh"):
         assert hasattr(pod.HostGroup, m), m
     for m in ("write", "delete", "bulk_load", "subscribe", "unsubscribe",
               "drain_alerts", "query", "count", "flush", "checkpoint",
@@ -1164,8 +1071,8 @@ def test_distributed_doc_honest():
     # subsystem doc and the operator index (the pod tier declares no
     # metrics of its own — its shards report through the scan tier's)
     knobs, metrics = _area_names("geomesa.pod.")
-    assert len(knobs) == 4 and not metrics, (knobs, metrics)
-    _assert_runtime_declared(knobs + ["geomesa.scan.fused.slots"])
+    assert len(knobs) == 3 and not metrics, (knobs, metrics)
+    _assert_runtime_declared(knobs)
     _assert_documented("distributed.md", knobs)
     _assert_documented("config.md", knobs)
     # documented fault points exist at source level on both seams
@@ -1177,29 +1084,14 @@ def test_distributed_doc_honest():
     # concurrency table shows (below every host store lock)
     from geomesa_tpu.analysis.lockmodel import LOCKS
 
-    for name in ("HostGroup._probe_lock", "PodStore._route_lock"):
-        assert name in LOCKS, name
-        assert LOCKS[name].rank < LOCKS["DataStore._write_lock"].rank
-    # probe + scale-driver wiring (single-provenance 1B run)
+    assert "PodStore._route_lock" in LOCKS
+    assert (LOCKS["PodStore._route_lock"].rank
+            < LOCKS["DataStore._write_lock"].rank)
+    # the capability probe the doc points at
     doc = open(os.path.join(_ROOT, "docs", "distributed.md")).read()
     assert os.path.exists(
         os.path.join(_ROOT, "scripts", "probe_multiprocess.py")
     )
-    assert os.path.exists(
-        os.path.join(_ROOT, "scripts", "run_pod_scale.py")
-    )
-    assert "scripts/run_pod_scale.py" in doc
-    assert "SCALE_1B.json" in doc
-    # bench + gate wiring (source-level contract, like config_replica)
-    bench_src = open(os.path.join(_ROOT, "bench.py")).read()
-    assert "def config_pod" in bench_src
-    assert '"pod": config_pod' in bench_src
-    assert "BENCH_POD.json" in bench_src
-    gate_src = open(
-        os.path.join(_ROOT, "scripts", "bench_gate.py")
-    ).read()
-    assert "BENCH_POD" in gate_src
-    assert "BENCH_POD.json" in doc
     # every `group.X` / `pod.X` the guide mentions in backticks resolves
     for name in re.findall(r"`group\.(\w+)", doc):
         assert hasattr(pod.HostGroup, name), f"group.{name}"
@@ -1210,3 +1102,52 @@ def test_distributed_doc_honest():
         assert (
             hasattr(pod.PodStore, name.split(".", 1)[0]) or name in fault_points
         ), f"pod.{name}"
+
+
+def _doc_pages():
+    docs = os.path.join(_ROOT, "docs")
+    pages = sorted(f"docs/{n}" for n in os.listdir(docs) if n.endswith(".md"))
+    return pages + ["README.md"]
+
+
+#: a repo path as a doc writes it: under one of the tree's directories,
+#: or a bare file name - a file at the root (its records are upper-case:
+#: ``BENCHMARK.json``; ``metadata.json`` is a saved store's own file) or
+#: a module named without its package (``conf.py``)
+_DOC_PATH = re.compile(
+    r"^(?:\.\./|\./)*"
+    r"((?:geomesa_tpu|tests|scripts|benchmark|docs|examples)/[\w./-]*"
+    r"|[\w-]+\.(?:py|md)|[A-Z][A-Z0-9_]*\.jsonl?)"
+    r"(?::\d+(?:-\d+)?)?$"
+)
+
+
+@functools.lru_cache(maxsize=1)
+def _module_basenames():
+    return {
+        n for _, _, files in os.walk(os.path.join(_ROOT, "geomesa_tpu"))
+        for n in files if n.endswith(".py")
+    }
+
+
+@pytest.mark.parametrize("page", _doc_pages())
+def test_docs_name_only_files_that_exist(page):
+    """Every repo path a page names in backticks or links to exists:
+    a deleted file's last mention goes with the file. Globs and
+    ``<rev>:path`` forms are not checked."""
+    text = open(os.path.join(_ROOT, page)).read()
+    here = os.path.dirname(os.path.join(_ROOT, page))
+    named = re.findall(r"`([^`\n]+)`", text)
+    named += [t.split("#")[0] for t in re.findall(r"\]\(([^)\s]+)\)", text)]
+    missing = []
+    for token in named:
+        m = _DOC_PATH.match(token.strip())
+        if m is None or "*" in token or "<" in token:
+            continue
+        path = m.group(1)
+        # a link is relative to its page, a backticked path to the root
+        if not (os.path.exists(os.path.join(_ROOT, path))
+                or os.path.exists(os.path.join(here, path))
+                or path in _module_basenames()):
+            missing.append(token)
+    assert not missing, f"{page} names files that do not exist: {missing}"

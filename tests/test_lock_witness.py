@@ -273,14 +273,12 @@ def _workload(tmp_path, metrics=None):
     finally:
         dsv.close()
         srv.close()
-    # multi-host pod tier (docs/distributed.md), constructed armed: the
-    # link-profile install crosses HostGroup._probe_lock, and id-less
-    # writes cross PodStore._route_lock on the auto-id counter before
-    # the pod.wal.route hop fans the batch out to its owning hosts
+    # multi-host pod tier (docs/distributed.md), constructed armed:
+    # id-less writes cross PodStore._route_lock on the auto-id counter
+    # before the pod.wal.route hop fans the batch out to its owning hosts
     from geomesa_tpu.pod import PodStore, make_host_group
 
     pg = make_host_group(hosts=2, devices_per_host=1, driver="sim")
-    pg.set_link_profile([10.0, 40.0])
     pod = PodStore(FeatureType.from_spec("p", SPEC), pg)
     try:
         pod.write([
@@ -358,8 +356,8 @@ def _workload(tmp_path, metrics=None):
         sched.close()
         conf.OBS_TRACE_SAMPLE.clear()
         # the armed controllers write through GLOBAL conf: reset the
-        # four steered knobs so later tests see stock defaults
-        for prop in (conf.CACHE_MIN_COST, conf.SCAN_FUSED_SLOTS,
+        # three steered knobs so later tests see stock defaults
+        for prop in (conf.CACHE_MIN_COST,
                      conf.STREAM_FOLD_SLICE_ROWS, conf.STREAM_CHUNK_ROWS):
             prop.clear()
         obs.install(obs.Tracer())  # drop the witness-wrapped tracer

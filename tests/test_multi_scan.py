@@ -14,6 +14,18 @@ from geomesa_tpu.filter.predicates import BBox, During, Intersects
 from geomesa_tpu.scan import block_kernels as bk
 
 
+def bucket_q(q: int) -> int:
+    """Static Q bucket: power of two >= q, floor 8. TEST-ONLY — production
+    fused dispatches pad their param stacks to the canonical FUSED_CHUNK_Q
+    (storage.table._submit_fused_chunk); this helper sizes hand-built
+    stacks in kernel-level tests. Pad query rows are all-zero params no
+    slot references (pad slots carry qid 0 and are ignored at decode)."""
+    m = 8
+    while m < q:
+        m *= 2
+    return m
+
+
 def make_store(n=60_000, seed=11, index="z3", mesh=None):
     rng = np.random.default_rng(seed)
     x = rng.uniform(-60, 60, n)
@@ -561,8 +573,8 @@ class TestMultiKernelParity:
     def test_interpret_parity_boxes(self):
         cols3 = self._cols()
         q = 3
-        boxes = np.zeros((bk.bucket_q(q), 8, bk.LANES), np.float32)
-        wins = np.zeros((bk.bucket_q(q), 8, bk.LANES), np.int32)
+        boxes = np.zeros((bucket_q(q), 8, bk.LANES), np.float32)
+        wins = np.zeros((bucket_q(q), 8, bk.LANES), np.int32)
         rng = np.random.default_rng(14)
         for k in range(q):
             x0, y0 = rng.uniform(-40, 20, 2)
@@ -592,8 +604,8 @@ class TestMultiKernelParity:
             for _ in range(4)
         )
         q = 2
-        boxes = np.zeros((bk.bucket_q(q), 8, bk.LANES), np.float32)
-        wins = np.zeros((bk.bucket_q(q), 8, bk.LANES), np.int32)
+        boxes = np.zeros((bucket_q(q), 8, bk.LANES), np.float32)
+        wins = np.zeros((bucket_q(q), 8, bk.LANES), np.int32)
         for k in range(q):
             xx, yy = rng.uniform(-40, 10, 2)
             boxes[k] = bk.pack_boxes(np.array([[xx, yy, xx + 30, yy + 25]]), None)
@@ -617,9 +629,9 @@ class TestMultiKernelParity:
         cols3 = self._cols(seed=17)
         q = 3
         E = 16
-        boxes = np.zeros((bk.bucket_q(q), 8, bk.LANES), np.float32)
-        wins = np.zeros((bk.bucket_q(q), 8, bk.LANES), np.int32)
-        edges = np.zeros((bk.bucket_q(q), E, bk.LANES), np.float32)
+        boxes = np.zeros((bucket_q(q), 8, bk.LANES), np.float32)
+        wins = np.zeros((bucket_q(q), 8, bk.LANES), np.int32)
+        edges = np.zeros((bucket_q(q), E, bk.LANES), np.float32)
         rng = np.random.default_rng(18)
         tri = geo.from_wkt("POLYGON ((-30 -20, 20 -25, 5 30, -30 -20))")
         packed = bk.pack_edges(tri)

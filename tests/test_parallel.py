@@ -105,6 +105,23 @@ def test_mesh_sizes():
             )
 
 
+def test_mesh_fused_shape_is_a_function_of_local_block_count():
+    """A four-device table's fused dispatch is four lists of
+    bucket_of(blocks_local) slots: the per-device bucket comes from the
+    local block count alone, the packer fills four times that."""
+    from geomesa_tpu.scan import block_kernels as bk
+    from geomesa_tpu.storage.table import FUSED_CHUNK_SLOTS
+
+    ds = _store(make_mesh(4), n=600_000, tile=64)
+    table = ds.table("pts", "z3")
+    # past the ladder's floor, so the bucket is the block count's own
+    assert table.n_devices == 4 and table.blocks_local > bk.M_BUCKETS[0]
+    assert table.fused_slots == min(
+        FUSED_CHUNK_SLOTS, bk.bucket_of(table.blocks_local)
+    )
+    assert table.fused_pack_capacity == 4 * table.fused_slots
+
+
 def test_distributed_certainty_vector(stores):
     """The mesh table returns the same exactness tier as the single-chip
     table: identical ordinals AND identical certain flags (VERDICT r3 #1)."""

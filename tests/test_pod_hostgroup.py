@@ -8,22 +8,14 @@
   into H synthetic hosts (the CPU-CI path every pod test runs on); the
   distributed driver demands a real multi-process world and raises
   :class:`PodUnsupported` — with the capability probe's machine-readable
-  reason — anywhere it cannot run (tests skip, not fail);
-- **per-host link profile** (ISSUE 20 satellite): measured RTTs derive
-  one fused slot cap PER HOST through the shared
-  ``derive_link_constants`` / ``doubling_ladder`` rule, so one slow
-  host's bigger amortization bucket never inflates its peers' pad-slot
-  work; ``PodIndexTable`` stamps each shard's ``_slot_cap`` from it.
+  reason — anywhere it cannot run (tests skip, not fail).
 """
 
-import numpy as np
 import pytest
 
 from geomesa_tpu import conf
 from geomesa_tpu.parallel.mesh import host_major_slices
 from geomesa_tpu.pod import PodUnsupported, make_host_group, probe_capability
-from geomesa_tpu.scan import block_kernels as bk
-from geomesa_tpu.storage.table import FUSED_CHUNK_SLOTS
 
 
 class _Dev:
@@ -123,66 +115,3 @@ class TestDistributedDriver:
         differential matrix keys off."""
         with pytest.raises(PodUnsupported):
             make_host_group(driver="distributed")
-
-
-class TestPerHostLinkProfile:
-    def test_caps_ride_the_doubling_ladder(self):
-        group = make_host_group(hosts=4, devices_per_host=2, driver="sim")
-        caps = group.set_link_profile([66.0, 0.4, None, 16.5])
-        # design-point RTT keeps the hand-tuned cap; a fast link snaps
-        # to the 256 floor; a quarter-design link lands on 512; None
-        # leaves that host on the process-wide default
-        assert caps == [FUSED_CHUNK_SLOTS, 256, None, 512]
-        assert [group.slot_cap(h) for h in range(4)] == caps
-        assert group.link_rtts_ms == [66.0, 0.4, None, 16.5]
-        # the per-host cap flows through the table-level resolution
-        assert bk.fused_slot_cap(caps[1]) == 256
-        assert bk.fused_slot_cap(None) == FUSED_CHUNK_SLOTS
-
-    def test_wrong_length_rejected(self):
-        group = make_host_group(hosts=2, devices_per_host=2, driver="sim")
-        with pytest.raises(ValueError, match="RTTs"):
-            group.set_link_profile([1.0])
-
-    def test_probe_links_installs_a_profile(self):
-        group = make_host_group(hosts=2, devices_per_host=2, driver="sim")
-        rtts = group.probe_links(samples=1)
-        assert len(rtts) == 2 and all(r is not None and r >= 0 for r in rtts)
-        assert all(
-            group.slot_cap(h) in (256, 512, 1024, FUSED_CHUNK_SLOTS)
-            for h in range(2)
-        )
-
-    def test_pinned_knob_beats_per_host_cap(self):
-        conf.SCAN_FUSED_SLOTS.set(512)
-        try:
-            assert bk.fused_slot_cap(2048) == 512
-        finally:
-            conf.SCAN_FUSED_SLOTS.clear()
-
-    def test_shards_stamp_their_host_cap(self):
-        """PodIndexTable gives every host shard ITS host's probed cap:
-        the slow host's shard amortizes over a bigger bucket while the
-        fast host keeps the floor (one table, two different canonical
-        fused shapes — per host, never process-global)."""
-        from geomesa_tpu.datastore import DataStore
-        from geomesa_tpu.features import FeatureCollection
-        from geomesa_tpu.sft import FeatureType
-
-        group = make_host_group(hosts=2, devices_per_host=2, driver="sim")
-        group.set_link_profile([0.4, 66.0])
-        ds = DataStore(mesh=group)
-        sft = FeatureType.from_spec("lp", "dtg:Date,*geom:Point:srid=4326")
-        ds.create_schema(sft)
-        rng = np.random.default_rng(0)
-        n = 1500
-        t0 = np.datetime64("2024-01-01T00:00:00", "ms").astype(np.int64)
-        ds.write("lp", FeatureCollection.from_columns(
-            sft, [str(i) for i in range(n)],
-            {"dtg": t0 + rng.integers(0, 86400_000, n),
-             "geom": (rng.uniform(-60, 60, n), rng.uniform(-30, 30, n))},
-        ))
-        ds.compact("lp")
-        table = next(t for (tn, _), t in ds._tables.items() if tn == "lp")
-        assert table.shards[0]._slot_cap == 256
-        assert table.shards[1]._slot_cap == FUSED_CHUNK_SLOTS

@@ -199,25 +199,6 @@ class TestFusedCrossHost:
             assert _ids(a) == _ids(b)
 
 
-class TestHeterogeneousSlotCaps:
-    def test_mixed_link_profile_stays_bit_identical(self):
-        """Satellite: per-host probed caps change each shard's canonical
-        fused SHAPE (a slow host amortizes over a bigger bucket) but
-        never the RESULTS — the differential holds with hosts on
-        deliberately different ladder rungs."""
-        group = make_host_group(hosts=4, devices_per_host=2, driver="sim")
-        group.set_link_profile([0.4, 66.0, 8.25, None])
-        pod = _point_store(group, n=8000, seed=11)
-        flat = _point_store(group.flat_mesh(), n=8000, seed=11)
-        caps = {s._slot_cap for s in pod.table("pts", "z2").shards}
-        assert len(caps) > 1  # genuinely heterogeneous shapes
-        for q in (Q_PTS[0], Q_PTS[2], Q_PTS[3]):
-            assert _ids(pod.query("pts", q)) == _ids(flat.query("pts", q))
-        for a, b in zip(pod.query_many("pts", Q_PTS[:4]),
-                        flat.query_many("pts", Q_PTS[:4])):
-            assert _ids(a) == _ids(b)
-
-
 class TestPodFaultPoints:
     def test_dispatch_fault_surfaces_and_recovers(self, stores):
         """pod.dispatch / pod.join are real seams: an injected IO error

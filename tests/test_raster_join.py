@@ -428,114 +428,6 @@ class TestJoinProcessSelectivity:
         assert len(out2) == n
 
 
-class TestBenchGate:
-    def _load_gate(self):
-        import importlib.util
-        import os
-
-        path = os.path.join(
-            os.path.dirname(__file__), "..", "scripts", "bench_gate.py"
-        )
-        spec = importlib.util.spec_from_file_location("bench_gate", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def _payload(self, cost, identical=True):
-        return {"rows": [
-            {"scenario": "z2_polygon_pip_batch", "raster_ms_per_q": cost,
-             "exact_ms_per_q": cost * 10, "identical": identical},
-        ]}
-
-    def test_pass_regress_and_identity(self, tmp_path):
-        import json
-
-        gate = self._load_gate()
-        base = tmp_path / "base.json"
-        base.write_text(json.dumps(self._payload(1.0)))
-        ok = tmp_path / "ok.json"
-        ok.write_text(json.dumps(self._payload(1.1)))
-        bad = tmp_path / "bad.json"
-        bad.write_text(json.dumps(self._payload(1.5)))
-        broken = tmp_path / "broken.json"
-        broken.write_text(json.dumps(self._payload(0.5, identical=False)))
-        assert gate.gate(str(ok), str(base), 0.20) == 0
-        assert gate.gate(str(bad), str(base), 0.20) == 1
-        assert gate.gate(str(broken), str(base), 0.20) == 1
-        assert gate.gate(str(tmp_path / "missing.json"), str(base), 0.2) == 2
-        # a self-comparison can never detect a regression: refused
-        assert gate.gate(str(base), str(base), 0.20) == 2
-
-    def _stream_payload(self, rps, identical=True):
-        return {"rows": [
-            {"scenario": "stream_sustained", "streamed_rows_per_s": rps,
-             "legacy_rows_per_s": rps / 3.0, "identical": identical},
-        ]}
-
-    def test_stream_scenario_direction_aware(self, tmp_path):
-        """Throughput scenarios regress DOWNWARD: the gate must fail on
-        falling rows/s and pass on rising rows/s (the inverse of the
-        cost scenarios), and still enforce the identical flag."""
-        import json
-
-        gate = self._load_gate()
-        base = tmp_path / "BENCH_STREAM.json"
-        base.write_text(json.dumps(self._stream_payload(30_000.0)))
-        ok = tmp_path / "BENCH_STREAM_ok.json"
-        ok.write_text(json.dumps(self._stream_payload(33_000.0)))
-        slower_ok = tmp_path / "BENCH_STREAM_slower.json"
-        slower_ok.write_text(json.dumps(self._stream_payload(27_000.0)))
-        bad = tmp_path / "BENCH_STREAM_bad.json"
-        bad.write_text(json.dumps(self._stream_payload(20_000.0)))
-        broken = tmp_path / "BENCH_STREAM_broken.json"
-        broken.write_text(json.dumps(self._stream_payload(50_000.0, False)))
-        assert gate.gate(str(ok), str(base), 0.20) == 0
-        assert gate.gate(str(slower_ok), str(base), 0.20) == 0  # within 20%
-        assert gate.gate(str(bad), str(base), 0.20) == 1
-        assert gate.gate(str(broken), str(base), 0.20) == 1
-
-    def test_default_baseline_inference(self, tmp_path):
-        gate = self._load_gate()
-        repo = str(tmp_path)
-        assert gate.default_baseline("/x/BENCH_STREAM_fresh.json", repo) == (
-            f"{repo}/BENCH_STREAM.json"
-        )
-        assert gate.default_baseline("/x/fresh.json", repo) == (
-            f"{repo}/BENCH_PIP_JOIN.json"
-        )
-        assert gate.default_baseline("/x/BENCH_WAL_fresh.json", repo) == (
-            f"{repo}/BENCH_WAL.json"
-        )
-
-    def _wal_payload(self, rps, ratio, identical=True):
-        return {"rows": [
-            {"scenario": "stream_wal", "wal_interval_rows_per_s": rps,
-             "nowal_rows_per_s": rps / ratio,
-             "interval_over_nowal": ratio, "identical": identical},
-        ]}
-
-    def test_wal_within_run_overhead_bound(self, tmp_path):
-        """The ISSUE 10 acceptance bound is checked on the FRESH file
-        alone: sync=interval throughput must stay within 15% of the
-        same run's no-WAL path, regardless of how the baseline did."""
-        import json
-
-        gate = self._load_gate()
-        base = tmp_path / "BENCH_WAL.json"
-        base.write_text(json.dumps(self._wal_payload(50_000.0, 0.95)))
-        ok = tmp_path / "BENCH_WAL_ok.json"
-        ok.write_text(json.dumps(self._wal_payload(51_000.0, 0.90)))
-        heavy = tmp_path / "BENCH_WAL_heavy.json"
-        heavy.write_text(json.dumps(self._wal_payload(52_000.0, 0.70)))
-        slow = tmp_path / "BENCH_WAL_slow.json"
-        slow.write_text(json.dumps(self._wal_payload(30_000.0, 0.95)))
-        assert gate.gate(str(ok), str(base), 0.20) == 0
-        # overhead bound fails even though throughput beat the baseline
-        assert gate.gate(str(heavy), str(base), 0.20) == 1
-        # and the baseline comparison still guards absolute throughput
-        assert gate.gate(str(slow), str(base), 0.20) == 1
-
-
 class TestValidators:
     def _sft(self):
         return FeatureType.from_spec(

@@ -11,9 +11,8 @@ Three layers:
   family — the PR 5 fused E-bucket grouping-key bug, the pre-PR 3
   unlocked MetricsRegistry mutation, an annotated scheduler queue
   mutated outside its condition — and each must be caught;
-- **the gate convention**: scripts/check.py exits 0/1/2 exactly like
-  scripts/bench_gate.py (0 clean, 1 findings, 2 unusable input), so CI
-  treats both gates alike.
+- **the gate convention**: scripts/check.py exits 0 clean, 1 findings,
+  2 unusable input.
 """
 
 from __future__ import annotations
@@ -376,7 +375,7 @@ def test_controller_registry_matches_specs():
     from geomesa_tpu.analysis.registries import CONTROLLERS
     from geomesa_tpu.tuning.controllers import CONTROLLER_SPECS
 
-    assert len(CONTROLLERS) >= 4
+    assert len(CONTROLLERS) >= 3
     for name, doc in CONTROLLERS.items():
         assert name == name.lower() and " " not in name, name
         assert doc, name
@@ -430,13 +429,42 @@ def test_baseline_and_inline_suppression(tmp_path):
     assert not r2.findings and r2.suppressed
 
 
-# -- layer 3: the shared gate exit-code convention ------------------------
+def test_scan_layer_imports_nothing_above_it():
+    """The kernels, the curves, the native tier and the indexes are the
+    bottom of the program: none of their modules imports the tuning,
+    serving, planning, pod or streaming tier, at module level or inside
+    a function."""
+    import ast
+
+    above = {"tuning", "serving", "planning", "pod", "streaming"}
+    project = Project.load(ROOT)
+    found = []
+    for layer in ("scan", "curve", "native", "index"):
+        for sf in project.python_files(under=f"geomesa_tpu/{layer}/"):
+            pkg = sf.relpath.split("/")[:-1]  # the module's package
+            for node in ast.walk(sf.tree):
+                if isinstance(node, ast.Import):
+                    mods = [a.name for a in node.names]
+                elif isinstance(node, ast.ImportFrom):
+                    stem = pkg[: len(pkg) - node.level + 1] if node.level else []
+                    mod = ".".join(stem + ([node.module] if node.module else []))
+                    # ``from geomesa_tpu import tuning`` names it too
+                    mods = [mod] + [f"{mod}.{a.name}" for a in node.names]
+                else:
+                    continue
+                found += [
+                    f"{sf.relpath}:{node.lineno}: {mod}" for mod in mods
+                    if mod.startswith("geomesa_tpu.")
+                    and mod.split(".")[1] in above
+                ]
+    assert not found, "\n".join(found)
+
+
+# -- layer 3: the gate's exit-code convention ------------------------
 
 
 class TestCheckGateExitCodes:
-    """scripts/check.py exits exactly like scripts/bench_gate.py
-    (whose 0/1/2 contract is pinned by test_raster_join.TestBenchGate):
-    0 clean, 1 findings, 2 unusable input."""
+    """scripts/check.py exits 0 clean, 1 findings, 2 unusable input."""
 
     def _run(self, *args):
         proc = subprocess.run(

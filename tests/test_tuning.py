@@ -2,9 +2,9 @@
 
 Four pinned surfaces, per ISSUE 19:
 
-1. **Gate differentials** — the four pre-existing measured-cost gates
-   (tile compose gate, adaptive join gate, standing match gate, link
-   slot ladder) migrated onto tuning/primitives.py; each test replays
+1. **Gate differentials** — the three pre-existing measured-cost gates
+   (tile compose gate, adaptive join gate, standing match gate)
+   migrated onto tuning/primitives.py; each test replays
    the PRE-migration arithmetic inline as a reference implementation
    and asserts the migrated gate produces the identical DECISION
    sequence over seeded inputs (decisions, not internal floats: the
@@ -38,7 +38,6 @@ from geomesa_tpu.tuning.controllers import CONTROLLER_SPECS, KnobController
 from geomesa_tpu.tuning.primitives import (
     CostEwma,
     ProbeGate,
-    doubling_ladder,
     ewma_step,
 )
 from geomesa_tpu.tuning.reweight import IndexReweighter
@@ -48,7 +47,6 @@ Q = "bbox(geom, -10, -10, 10, 10)"
 
 _TUNED_KNOBS = (
     "CACHE_MIN_COST",
-    "SCAN_FUSED_SLOTS",
     "STREAM_FOLD_SLICE_ROWS",
     "STREAM_CHUNK_ROWS",
 )
@@ -57,13 +55,10 @@ _TUNED_KNOBS = (
 @pytest.fixture(autouse=True)
 def _clean_tuned_state():
     """Armed controllers write through GLOBAL conf; every test leaves
-    the steered knobs (and the link-probe constants) as it found them."""
+    the steered knobs as it found them."""
     yield
     for name in _TUNED_KNOBS:
         getattr(conf, name).clear()
-    from geomesa_tpu.scan import block_kernels as bk
-
-    bk.set_link_constants(None)
 
 
 def _mkstore(metrics=None, cache=None, n=512, seed=7):
@@ -119,13 +114,6 @@ def test_cost_ewma_drops_non_positive_samples():
     assert e.update_cost(0.0, 10) is None     # zero seconds: no signal
     assert e.update_cost(2.0, 4) == 0.5       # first sample seeds
     assert e.value_or(7.5) == 0.5
-
-
-def test_doubling_ladder_edges():
-    assert doubling_ladder(0.0, 256, 2048) == 256
-    assert doubling_ladder(256.0, 256, 2048) == 256
-    assert doubling_ladder(256.0001, 256, 2048) == 512
-    assert doubling_ladder(1e9, 256, 2048) == 2048
 
 
 class _LegacyTilesGate:
@@ -307,37 +295,6 @@ def test_standing_gate_differential():
             saw_mask = True
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert saw_mask
-
-
-def test_link_ladder_differential():
-    from geomesa_tpu.scan.block_kernels import (
-        DESIGN_LINK_RTT_MS,
-        derive_link_constants,
-    )
-    from geomesa_tpu.storage.table import FUSED_CHUNK_SLOTS
-
-    def legacy_slots(rtt_ms):
-        want = (
-            FUSED_CHUNK_SLOTS * max(float(rtt_ms), 1e-3) / DESIGN_LINK_RTT_MS
-        )
-        slots = 256
-        while slots < want and slots < FUSED_CHUNK_SLOTS:
-            slots *= 2
-        return slots
-
-    sweep = [1e-6, 1e-3, 0.01, 0.5, 1.0, 2.0, 3.0, 5.0, 8.0, 10.0,
-             20.0, 40.0, 100.0, 1000.0, 1e6]
-    # exact power-of-two boundaries, and a hair either side of each
-    target = 256
-    while target <= FUSED_CHUNK_SLOTS:
-        rtt = target * DESIGN_LINK_RTT_MS / FUSED_CHUNK_SLOTS
-        sweep += [rtt, rtt * (1 - 1e-9), rtt * (1 + 1e-9)]
-        target *= 2
-    for rtt in sweep:
-        assert (
-            derive_link_constants(rtt)["fused_chunk_slots"]
-            == legacy_slots(rtt)
-        ), f"rtt={rtt}"
 
 
 # -- 2. disarmed == today, bit-identical ---------------------------------
@@ -524,34 +481,6 @@ def test_manager_pulse_steers_cache_min_cost(tmp_path):
         report = mgr.report()
         assert report["pulses"] == 3
         assert d in report["decisions"]
-    finally:
-        ds.close()
-
-
-def test_manager_derive_controller_follows_link_rtt():
-    from geomesa_tpu.scan import block_kernels as bk
-
-    reg = MetricsRegistry()
-    ds = _mkstore(metrics=reg)
-    try:
-        mgr = ds.attach_tuning(enabled=True)
-        # no link probe yet: no reading, no move
-        assert mgr.pulse() == []
-        bk.set_link_constants(bk.derive_link_constants(20.0))
-        derived = bk.derive_link_constants(20.0)["fused_chunk_slots"]
-        # knob unpinned (0) and the auto path already lands on the
-        # derived value: hold — the controller must not pin what the
-        # probe constants already deliver
-        assert mgr.pulse() == []
-        assert int(conf.SCAN_FUSED_SLOTS.get() or 0) == 0
-        # a stale pinned value diverging from the live RTT gets re-derived
-        pinned = 256 if derived != 256 else 512
-        conf.SCAN_FUSED_SLOTS.set(pinned)
-        [d] = mgr.pulse()
-        assert d["controller"] == "fused_chunk_slots"
-        assert d["to"] == derived
-        assert int(conf.SCAN_FUSED_SLOTS.get()) == derived
-        assert reg.gauges.get("geomesa.tuning.link.rtt") == pytest.approx(20.0)
     finally:
         ds.close()
 
