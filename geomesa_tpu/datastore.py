@@ -1839,6 +1839,7 @@ class DataStore:
             with _ospan("decode", candidates=len(unc)) as sp:
                 sp.event("gather")
                 sub = self.gather(type_name, unc, chunks=chunks)
+                sp.add("gather_native", int(sub.gathered_native))
                 sp.event("refine")
                 m = plan.filter.evaluate(sub.batch)
             self._agg_check_deadline(deadline, "raster residue refinement")

@@ -12,6 +12,8 @@ predicates evaluate over (geomesa_tpu.filter.predicates).
 
 from __future__ import annotations
 
+import math
+import operator
 from dataclasses import dataclass
 from typing import Mapping, Sequence
 
@@ -20,6 +22,12 @@ import numpy as np
 from geomesa_tpu import geometry as geo
 from geomesa_tpu.filter.predicates import PointColumn
 from geomesa_tpu.sft import COLUMN_DTYPES, FeatureType
+
+
+#: rows x bytes a row from which ``FeatureCollection.take`` hands the
+#: answer to one native call; under it NumPy's indexing of each column is
+#: the faster (the sweep on the chip's host: PERF.md section 5)
+NATIVE_TAKE_BYTES = 5 << 16
 
 
 def _date_to_millis(v) -> int:
@@ -89,27 +97,29 @@ class FeatureCollection:
         return col.geometries()
 
     def take(self, idx) -> "FeatureCollection":
+        """The rows ``idx`` of every column, in one pass. Each array of
+        the result is what ``np.asarray(col)[idx]`` gives: same dtype,
+        C-contiguous, its own data; ordinals out of range raise
+        IndexError and negative ones count from the end. An answer under
+        NATIVE_TAKE_BYTES is NumPy's fancy indexing and nothing else: no
+        ctypes, the interpreter lock kept. A larger one is ONE native
+        call for ids, point coordinates and every fixed-width column,
+        ``<U`` strings as bytes (native.gather_columns: one lock release,
+        a thread team above its own floor of bytes); packed geometries
+        and object columns keep their routes beside it."""
         idx = np.asarray(idx)
-        # the threaded native gather beats numpy's serial fancy indexing on
-        # large pulls (the multi-million-row result gather was the last
-        # host-bound stage of big queries, PERF.md §4b); u32-indexable
-        # columns route through it, everything else falls back
-        idx_u32 = None
-        if idx.dtype.kind in "iu" and len(idx) and len(self.ids) < (1 << 32):
-            lo, hi = int(idx.min()), int(idx.max())
-            # negative (python-style) or out-of-range indices fall back to
-            # numpy, which raises IndexError — the C++ gather is unchecked
-            if lo >= 0 and hi < len(self.ids):
-                idx_u32 = idx.astype(np.uint32, copy=False)
+        rowb = self._row_bytes()
+        done, answer = {}, None
+        if (
+            idx.ndim == 1
+            and idx.dtype.kind in "iu"
+            and len(idx) * rowb >= NATIVE_TAKE_BYTES
+        ):
+            done, answer = self._gather_native(idx)
 
         def g(col):
-            if idx_u32 is not None:
-                from geomesa_tpu import native
-
-                out = native.take(np.asarray(col), idx_u32)
-                if out is not None:
-                    return out
-            return np.asarray(col)[idx]
+            out = done.get(id(col))
+            return np.asarray(col)[idx] if out is None else out
 
         cols = {}
         for name, col in self.columns.items():
@@ -119,7 +129,79 @@ class FeatureCollection:
                 cols[name] = col.take(idx)
             else:
                 cols[name] = g(col)
-        return FeatureCollection(self.sft, g(self.ids), cols)
+        out = FeatureCollection(self.sft, g(self.ids), cols)
+        out.__dict__["_rowb"] = rowb
+        if answer is not None:
+            # the outputs' addresses are in hand: a take of the answer (the
+            # refinement's mask) builds no table of its own
+            out.__dict__["_gather"] = ((out.ids, *cols.values()), answer)
+            out.__dict__["_native"] = True
+        return out
+
+    @property
+    def gathered_native(self) -> bool:
+        """True for a collection that ``take``'s native call made (the
+        ``decode`` span's ``gather_native``)."""
+        return "_native" in self.__dict__
+
+    def _arrays(self):
+        """ids, a PointColumn's x and y, every other column as it is."""
+        for col in (self.ids, *self.columns.values()):
+            if isinstance(col, PointColumn):
+                yield col.x
+                yield col.y
+            else:
+                yield col
+
+    def _row_bytes(self) -> int:
+        """Bytes a row over ids and columns, for ``take``'s choice alone:
+        kept on the collection and handed to what ``take`` makes of it."""
+        b = self.__dict__.get("_rowb")
+        if b is None:
+            b = self.__dict__["_rowb"] = sum(
+                a.dtype.itemsize * math.prod(a.shape[1:])
+                for a in self._arrays()
+                if isinstance(a, np.ndarray) and a.ndim
+            )
+        return b
+
+    def _gather_native(self, idx: np.ndarray):
+        """({id(source array): its rows ``idx``}, the native.ColumnTable
+        of those rows), or ({}, None) where NumPy has to answer: ordinals
+        negative or out of range (the native copy is unchecked), no
+        library. The table of source addresses is built once a collection
+        and kept while ``ids`` and the columns are the objects it was
+        built from."""
+        n = len(self.ids)
+        if int(idx.min()) < 0 or int(idx.max()) >= n:
+            return {}, None
+        from geomesa_tpu import native
+
+        held = (self.ids, *self.columns.values())
+        kept = self.__dict__.get("_gather")
+        if (
+            kept is not None
+            and len(kept[0]) == len(held)
+            and all(map(operator.is_, kept[0], held))
+        ):
+            table = kept[1]
+        else:
+            fit = {
+                id(a): a for a in self._arrays()
+                if native.ColumnTable.fits(a) and len(a) == n
+            }
+            table = native.ColumnTable(list(fit.values()))
+            self.__dict__["_gather"] = (held, table)
+        answer = native.gather_columns(table, idx)
+        if answer is None:
+            return {}, None
+        return dict(zip(map(id, table.arrays), answer.arrays)), answer
+
+    def __getstate__(self):
+        # the table holds addresses of this process: it does not travel
+        state = dict(self.__dict__)
+        state.pop("_gather", None)
+        return state
 
     def mask(self, m: np.ndarray) -> "FeatureCollection":
         return self.take(np.nonzero(np.asarray(m))[0])

@@ -14,6 +14,7 @@ from __future__ import annotations
 import ctypes
 import hashlib
 import logging
+import math
 import os
 import subprocess
 import threading
@@ -123,6 +124,13 @@ def _load():
             getattr(lib, name).argtypes = [
                 tp, u32p, ctypes.c_int64, ctypes.c_int64, tp
             ]
+        # raw pointers, no ndpointer checks: ColumnTable and gather_columns
+        # below validate what they pass
+        lib.gather_columns.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64,
+        ]
+        lib.gather_columns.restype = None
         lib.points_in_polygon_cpp.argtypes = [
             f64p, f64p, ctypes.c_int64, f64p, i64p, ctypes.c_int64, i32p, u8p
         ]
@@ -276,7 +284,11 @@ _GATHERS = {
 
 
 def take(src: np.ndarray, idx: np.ndarray) -> "np.ndarray | None":
-    """out[i] = src[idx[i]] for the supported dtypes, or None."""
+    """out[i] = src[idx[i]] for ONE 1-D array of the supported dtypes, or
+    None: a serial loop behind an ``ndpointer``-checked call, for the
+    table builds that permute a key column at a time (storage/table.py).
+    ``idx`` is unchecked and must fit uint32. An answer's rows of many
+    columns go through :func:`gather_columns`."""
     lib = _load()
     name = _GATHERS.get(src.dtype)
     if lib is None or name is None or src.ndim != 1:
@@ -285,6 +297,88 @@ def take(src: np.ndarray, idx: np.ndarray) -> "np.ndarray | None":
     idx = np.ascontiguousarray(idx, dtype=np.uint32)
     out = np.empty(len(idx), dtype=src.dtype)
     getattr(lib, name)(src, idx, len(idx), out)
+    return out
+
+
+class ColumnTable:
+    """What :func:`gather_columns` needs of a set of columns, made once:
+    the arrays (kept alive here for as long as their addresses are), a
+    ``c_void_p`` array of their data pointers, a ``c_int64`` array of the
+    bytes an item (for an N-D array, a row of the trailing axes) and how
+    to allocate an answer's arrays. ``like``: the table these arrays were
+    gathered from, whose widths and recipe they share."""
+
+    __slots__ = ("arrays", "srcs", "widths", "row_bytes", "_recipe")
+
+    def __init__(self, arrays, like: "ColumnTable | None" = None):
+        self.arrays = arrays
+        if like is None:
+            addresses = [a.ctypes.data for a in arrays]  # read-only arrays too
+            widths = [a.dtype.itemsize * math.prod(a.shape[1:]) for a in arrays]
+            self.widths = (ctypes.c_int64 * len(arrays))(*widths)
+            self.row_bytes = sum(widths)
+            # np.empty zero-fills a `<U` array (a serial pass over a third
+            # of an answer's bytes, under the interpreter lock): those are
+            # allocated as raw bytes and renamed, which leaves the array
+            # the owner of its data; the gather overwrites every item
+            self._recipe = [
+                (np.dtype(f"V{a.dtype.itemsize}") if a.dtype.kind == "U" else a.dtype,
+                 a.dtype, a.shape[1:])
+                for a in arrays
+            ]
+        else:
+            # fresh and writable: the cheaper way to an address (an empty
+            # array exports no byte)
+            addresses = [
+                _ADDRESS(_BYTE.from_buffer(a)) if a.nbytes else a.ctypes.data
+                for a in arrays
+            ]
+            self.widths, self.row_bytes, self._recipe = like.widths, like.row_bytes, like._recipe
+        self.srcs = (ctypes.c_void_p * len(arrays))(*addresses)
+
+    @staticmethod
+    def fits(a) -> bool:
+        """True for what a bytewise copy is right for: a C-contiguous
+        ndarray of fixed-width items that hold no object pointers (their
+        reference counts)."""
+        return (
+            type(a) is np.ndarray and a.ndim >= 1 and a.flags.c_contiguous
+            and not a.dtype.hasobject
+        )
+
+    def empty(self, n: int) -> list:
+        """Uninitialised arrays for ``n`` rows of every column."""
+        outs = []
+        for raw, dtype, tail in self._recipe:
+            out = np.empty((n,) + tail, raw)
+            if raw is not dtype:
+                out.dtype = dtype
+            outs.append(out)
+        return outs
+
+
+_BYTE, _ADDRESS = ctypes.c_char, ctypes.addressof
+
+
+def gather_columns(table: ColumnTable, idx: np.ndarray) -> "ColumnTable | None":
+    """``[a[idx] for a in table.arrays]`` in one native call, threaded
+    over columns and runs of rows above a floor of bytes
+    (geomesa_native.cpp): fresh C-contiguous arrays of the same dtypes,
+    returned as their own ColumnTable (the outputs' addresses are in hand,
+    so a take of the answer builds nothing). ``idx``: 1-D, of an integer
+    dtype, every ordinal in ``[0, len(a))``: the copy is unchecked, the
+    caller bounds them. None when the library is not there."""
+    lib = _load()
+    if lib is None:
+        return None
+    if idx.dtype.itemsize not in (4, 8):
+        idx = idx.astype(np.int64)
+    idx = np.ascontiguousarray(idx)
+    out = ColumnTable(table.empty(len(idx)), like=table)
+    lib.gather_columns(
+        table.srcs, table.widths, out.srcs, len(out.arrays),
+        idx.ctypes.data, idx.dtype.itemsize, len(idx),
+    )
     return out
 
 

@@ -20,6 +20,9 @@
 #include <cstring>
 #include <utility>
 #include <vector>
+#ifdef _OPENMP
+#include <omp.h>
+#endif
 
 extern "C" {
 
@@ -270,6 +273,79 @@ extern "C" void gather_rows_f32(const float* src, const uint32_t* idx,
     float* o = out + i * width;
     for (int64_t w = 0; w < width; ++w) o[w] = s[w];
   }
+}
+
+// ------------------------------------------------- one gather an answer
+// FeatureCollection.take for large answers: out[c][i] = src[c][idx[i]] for
+// every fixed-width column c of a collection in ONE call (one ctypes
+// crossing, one interpreter-lock release), items copied as bytes so a
+// `<U24` column is a 96-byte item like any other. Work is cut into
+// (column, run of GATHER_RUN rows) tasks: a run's ordinals stay in L1
+// across its columns, and columns x runs give a team enough tasks whether
+// the answer is 27 columns wide or 2. A team gets one thread a
+// GATHER_TEAM_BYTES of output, GATHER_MAX_THREADS at most, and under two
+// of them the caller copies alone (a served store has sixteen handler
+// threads, and libgomp keeps a pool a calling thread). The reads miss the
+// caches row by row, so threads add misses in flight, not arithmetic.
+// The constants are from a sweep on the chip's host (PERF.md section 5).
+// Ordinals are unchecked here: the caller has bounded them.
+static const int64_t GATHER_RUN = 256;
+static const int64_t GATHER_TEAM_BYTES = 65536;
+static const int64_t GATHER_MAX_THREADS = 12;
+static const int64_t GATHER_AHEAD = 8;
+
+template <typename I, typename W>
+static inline void gather_words(const char* src, char* out, const I* idx,
+                                int64_t lo, int64_t hi) {
+  for (int64_t i = lo; i < hi; ++i) {
+    W v;  // memcpy of a constant size is one load and one store, aligned or not
+    std::memcpy(&v, src + (int64_t)idx[i] * (int64_t)sizeof(W), sizeof(W));
+    std::memcpy(out + i * (int64_t)sizeof(W), &v, sizeof(W));
+  }
+}
+
+template <typename I>
+static void gather_run(const char* src, int64_t w, char* out, const I* idx,
+                       int64_t lo, int64_t hi) {
+  if (w == 4) return gather_words<I, uint32_t>(src, out, idx, lo, hi);
+  if (w == 8) return gather_words<I, uint64_t>(src, out, idx, lo, hi);
+  for (int64_t i = lo; i < hi; ++i) {
+    if (i + GATHER_AHEAD < hi)
+      __builtin_prefetch(src + (int64_t)idx[i + GATHER_AHEAD] * w);
+    std::memcpy(out + i * w, src + (int64_t)idx[i] * w, (size_t)w);
+  }
+}
+
+template <typename I>
+static void gather_columns_t(const char* const* srcs, const int64_t* widths,
+                             char* const* outs, int64_t ncols, const I* idx,
+                             int64_t n) {
+  const int64_t runs = (n + GATHER_RUN - 1) / GATHER_RUN;
+  const int64_t tasks = runs * ncols;
+#ifdef _OPENMP
+  int64_t row_bytes = 0;
+  for (int64_t c = 0; c < ncols; ++c) row_bytes += widths[c];
+  const int threads = (int)std::min<int64_t>(
+      std::min<int64_t>(omp_get_max_threads(), GATHER_MAX_THREADS),
+      std::max<int64_t>(n * row_bytes / GATHER_TEAM_BYTES, 1));
+#endif
+#pragma omp parallel for schedule(dynamic, 1) num_threads(threads) if (threads > 1)
+  for (int64_t t = 0; t < tasks; ++t) {
+    const int64_t c = t % ncols, lo = (t / ncols) * GATHER_RUN;
+    gather_run<I>(srcs[c], widths[c], outs[c], idx, lo,
+                  std::min(lo + GATHER_RUN, n));
+  }
+}
+
+// idx_width: 4 or 8 bytes an ordinal; the ordinals are non-negative (the
+// caller's bounds check), so signed and unsigned read alike.
+extern "C" void gather_columns(const char* const* srcs, const int64_t* widths,
+                               char* const* outs, int64_t ncols,
+                               const void* idx, int32_t idx_width, int64_t n) {
+  if (idx_width == 4)
+    gather_columns_t<uint32_t>(srcs, widths, outs, ncols, (const uint32_t*)idx, n);
+  else
+    gather_columns_t<uint64_t>(srcs, widths, outs, ncols, (const uint64_t*)idx, n);
 }
 
 // ----------------------------------------------- point-in-polygon refine

@@ -674,6 +674,7 @@ class QueryPlanner:
                 candidates = self.store.gather(
                     plan.type_name, ordinals, chunks=chunks
                 )
+                sp.add("gather_native", int(candidates.gathered_native))
                 return self._refine_and_post(
                     plan, candidates, certain, hints, exp, deadline,
                     skip_visibility, span=sp,

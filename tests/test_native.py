@@ -377,6 +377,66 @@ class TestNativePointsInPolygon:
         np.testing.assert_array_equal(got, want)
 
 
+_GATHER_COLUMNS_SCRIPT = """
+import numpy as np
+from geomesa_tpu import native
+
+rng = np.random.default_rng(29)
+n = 1 << 16
+cols = [
+    rng.integers(0, 255, n).astype(np.uint8),                 # 1 byte an item
+    rng.integers(0, 1 << 15, n).astype(np.int16),             # 2
+    rng.integers(0, 99, n).astype("S3"),                      # 3: no word fits
+    rng.integers(-9, 9, n).astype(np.int32),                  # 4: word loads
+    rng.normal(size=n).astype(np.float32),
+    rng.normal(size=n),                                       # 8
+    rng.integers(0, 1 << 60, n),
+    rng.integers(0, 999, n).astype("<U3"),                    # 12
+    rng.integers(0, 10 ** 9, n).astype("<U24"),               # 96
+    rng.normal(size=(n, 5)),                                  # 40: a row of five
+    rng.integers(0, 9, (n, 2, 3)).astype(np.int16),           # 12: two trailing axes
+    np.zeros((n, 0)),                                         # 0
+]
+assert all(native.ColumnTable.fits(c) for c in cols)
+assert not native.ColumnTable.fits(cols[5][::2]) and not native.ColumnTable.fits(cols[5].astype(object))
+table = native.ColumnTable(cols)
+assert table.row_bytes == 1 + 2 + 3 + 4 + 4 + 8 + 8 + 12 + 96 + 40 + 12
+for m in (0, 1, 7, 2048, 2049, 50_000, 300_000):  # 300,000 rows: 57 MB, a full team
+    for dtype in (np.int64, np.uint32, np.int32, np.uint64, np.int16):
+        top = min(n, np.iinfo(dtype).max)
+        idx = rng.integers(0, top, m).astype(dtype)
+        out = native.gather_columns(table, idx)
+        assert out.widths is table.widths and len(out.arrays) == len(cols)
+        for got, col in zip(out.arrays, cols):
+            want = col[idx]
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert got.flags.c_contiguous and got.flags.owndata
+            assert got.tobytes() == want.tobytes()
+again = native.gather_columns(out, np.arange(len(idx))[::-1].copy())  # an answer's own table
+assert all(a.tobytes() == b[::-1].tobytes() for a, b in zip(again.arrays, out.arrays))
+print("ok")
+"""
+
+
+@pytest.mark.parametrize("threads", ["1", "3", None], ids=["omp1", "omp3", "default"])
+def test_gather_columns_matches_numpy_for_mixed_item_sizes(threads):
+    """One call for columns of 0 to 96 bytes an item, against NumPy's
+    indexing; OMP_NUM_THREADS is read when libgomp loads, so each setting
+    is a process of its own."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("OMP_NUM_THREADS", None)
+    if threads is not None:
+        env["OMP_NUM_THREADS"] = threads
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", _GATHER_COLUMNS_SCRIPT], env=env, cwd=root,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr[-2000:]
+
+
 class TestBuildArtefact:
     """PR 21: the library that loads is the one built from the committed
     source, and a failed build says so."""
