@@ -1400,6 +1400,11 @@ class DataStore:
         invalidated every publish."""
         return sum(len(c) for c in self._chunks.get(type_name, []))
 
+    def delta_rows(self, type_name: str) -> int:
+        """Rows past the compacted prefix: what the host delta tier of
+        every index of the type holds (storage/delta.py)."""
+        return self.row_count(type_name) - self._main_rows.get(type_name, 0)
+
     def pin_scan_state(self, type_name: str, index_name: str):
         """(scan table, chunk snapshot) captured consistently against the
         fold's renumbering publish: the two reads retry while

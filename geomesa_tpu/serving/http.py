@@ -494,7 +494,11 @@ class DataServer:
     # -- POST -------------------------------------------------------------
     def handle_post(self, path: str, headers, rfile):
         """Route one POST (ingest). Returns the same quadruple as
-        :meth:`handle_get`; reads at most Content-Length bytes."""
+        :meth:`handle_get`; reads at most Content-Length bytes. Under
+        the request's ``http`` root: ``ingest.read`` (the body off the
+        socket), ``ingest.parse`` (GeoJSON / Arrow to columns),
+        ``ingest.rows`` (columns to the hot tier's row dicts); the
+        ``write`` root that follows names this one (``http_trace``)."""
         self.metrics.counter("geomesa.serve.requests")
         if not path.startswith("/ingest/"):
             return self._client_error(404, f"unknown path {path!r}")
@@ -521,9 +525,12 @@ class DataServer:
                 413, f"body {length} over the "
                 f"{self.max_body_bytes}-byte bound"
             )
-        body = rfile.read(length)
+        with _ospan("ingest.read", bytes=length):
+            body = rfile.read(length)
         try:
-            fc = self._parse_ingest(type_name, body, headers)
+            with _ospan("ingest.parse") as sp:
+                fc = self._parse_ingest(type_name, body, headers)
+                sp.annotate(rows=len(fc))
         except KeyError:
             return self._client_error(404, f"unknown type {type_name!r}")
         except Exception as e:
@@ -533,8 +540,9 @@ class DataServer:
             return self._client_error(400, f"{type(e).__name__}: {e}")
         try:
             if self.lam is not None:
-                rows = fc.to_rows()
-                ids = [r.pop("__id__") for r in rows]
+                with _ospan("ingest.rows"):
+                    rows = fc.to_rows()
+                    ids = [r.pop("__id__") for r in rows]
                 n = self.lam.write(rows, ids=ids)
                 durable = self.lam.wal is not None
             else:

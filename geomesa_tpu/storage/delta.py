@@ -17,6 +17,8 @@ from __future__ import annotations
 import numpy as np
 
 from geomesa_tpu.index.api import ScanConfig, WriteKeys
+from geomesa_tpu.obs.trace import add as _oadd
+from geomesa_tpu.obs.trace import event as _oevent
 from geomesa_tpu.storage.table import RowSpans
 
 
@@ -175,14 +177,24 @@ class TieredTable:
         return self.main.n + len(self.delta.zs)
 
     def _delta_hits(self, config: ScanConfig) -> np.ndarray:
+        """Table ordinals of the delta rows the wide predicate keeps.
+        Under a caller's span (the planner's ``scan`` or ``agg``) the
+        host scan is the segment ``delta``, which runs on to the span's
+        end (the concatenation onto the device's rows, the density
+        scatter), and the span counts ``delta_rows`` and ``delta_hits``:
+        what tells the NumPy scan from the device's."""
         if config.disjoint or len(self.delta.zs) == 0:
             return np.zeros(0, np.int64)
-        return self.base + np.flatnonzero(
+        _oevent("delta")
+        hits = self.base + np.flatnonzero(
             delta_wide_mask(
                 config, self.delta,
                 packed_shift=getattr(self.keyspace, "packed_time", None),
             )
         )
+        _oadd("delta_rows", len(self.delta.zs))
+        _oadd("delta_hits", len(hits))
+        return hits
 
     def scan(self, config: ScanConfig, deadline=None):
         return self.scan_submit(config, deadline=deadline)()

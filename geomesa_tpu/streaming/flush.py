@@ -479,10 +479,21 @@ class StreamFlusher:
             if error is not None:
                 raise error
 
+            if trace is not None:
+                before = self.store.row_count(self.type_name)
             t0 = time.perf_counter()
             with _ospan("flush.commit", chunks=len(chunks)):
                 out = self._commit(chunks, incremental, pacer, on_slice)
             self._stage_time("commit", time.perf_counter() - t0)
+            if trace is not None:
+                # after the publish: rows new to the cold store, rows that
+                # replaced a persisted id, and what the host delta tier
+                # holds now (0 after a fold or a compaction)
+                grown = self.store.row_count(self.type_name) - before
+                trace.root.annotate(
+                    appended=grown, updated=out - grown,
+                    delta_rows=self.store.delta_rows(self.type_name),
+                )
             self.flushes += 1
             self.metrics.counter("geomesa.stream.flushes")
             self.metrics.counter("geomesa.stream.rows", out)

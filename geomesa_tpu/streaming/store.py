@@ -24,6 +24,7 @@ import numpy as np
 from geomesa_tpu import fault
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.predicates import INCLUDE
+from geomesa_tpu.obs.trace import span as _ospan
 from geomesa_tpu.streaming.cache import StreamingFeatureCache
 from geomesa_tpu.streaming.flush import StreamConfig, StreamFlusher
 from geomesa_tpu.streaming.wal import WalConfig, WriteAheadLog, unpack_upsert
@@ -251,8 +252,9 @@ class LambdaStore:
         the sync policy's guarantee BEFORE it applies — the return is
         the acknowledgment: under ``sync=always`` an acknowledged batch
         survives ``kill -9``. When tracing is armed the acknowledged
-        write is one trace (WAL append/fsync spans under it), sampled
-        like queries (docs/observability.md)."""
+        write is one trace (``wal.append``, ``wal.sync`` and
+        ``hot.upsert`` spans under it), sampled like queries
+        (docs/observability.md)."""
         from geomesa_tpu.obs.trace import tracer
 
         eng = self._standing
@@ -263,19 +265,20 @@ class LambdaStore:
                 # for its alerts, exactly as the WAL needs them for
                 # replay — one resolution, shared
                 ids, next_id = self.hot.assign_ids(rows, ids)
+            seq = None
             if self.wal is not None:
                 seq = self.wal.log_upsert(ids, rows, next_id)
-                try:
+            try:
+                with _ospan("hot.upsert"):
                     n = self.hot.upsert(rows, ids)
-                finally:
+            finally:
+                if seq is not None:
                     # logged -> applied: the checkpoint cover (applied
                     # horizon) may now pass this record — before this, a
                     # concurrent checkpoint's snapshot could miss the rows
                     # while its cover skipped the record at replay (the
                     # acknowledged-loss race the chaos harness caught)
                     self.wal.applied(seq)
-            else:
-                n = self.hot.upsert(rows, ids)
             self._gauge_hot()
             if eng is not None:
                 # AFTER the ack path: a matcher fault never
@@ -736,8 +739,20 @@ class LambdaStore:
 
         if isinstance(f, str):
             f = ecql.parse(f)
-        hot, live = self.hot.query_shadow(f)
+        with _ospan("hot") as sp:
+            hot, live = self.hot.query_shadow(f)
+            sp.annotate(hot_rows=len(live), hits=len(hot))
         cold = self._cold_query(f, hints=hints, tenant=tenant, block=block)
+        with _ospan("merge") as sp:
+            out, shadowed, deduped = self._merge(hot, live, cold)
+            sp.annotate(cold_rows=len(cold), shadowed=shadowed, deduped=deduped)
+        return out
+
+    @staticmethod
+    def _merge(hot, live, cold):
+        """(merged answer, cold rows a live hot id shadowed, rows the id
+        dedup dropped) of one query's two tiers."""
+        shadowed = 0
         # shadow cold rows by EVERY live hot id, not just the hot hits: a
         # hot update that moved a feature out of the query window must
         # hide the stale persisted row too (hot-wins-by-id). Set probes
@@ -750,11 +765,12 @@ class LambdaStore:
                 (str(i) not in live for i in ids), bool, count=len(ids)
             )
             if not keep.all():
+                shadowed = len(ids) - int(keep.sum())
                 cold = cold.mask(keep)
         if len(hot) == 0:
-            return cold
+            return cold, shadowed, 0
         if len(cold) == 0:
-            return hot
+            return hot, shadowed, 0
         out = FeatureCollection.concat([hot, cold])
         # belt + braces: dedup by feature id, first occurrence (= hot)
         # wins — exactness under every flush interleaving, including the
@@ -763,9 +779,10 @@ class LambdaStore:
         # queries (the overwhelming steady state) skip the string sort
         ids = np.asarray(out.ids).astype(str)
         _, first = np.unique(ids, return_index=True)
-        if len(first) != len(out):
+        deduped = len(out) - len(first)
+        if deduped:
             out = out.take(np.sort(first))
-        return out
+        return out, shadowed, deduped
 
     def count(self, f=INCLUDE) -> int:
         return len(self.query(f))

@@ -675,6 +675,7 @@ class WriteAheadLog:
                 upto = self._last_seq
 
         fsync_s: list = []  # wall of the LAST actual fsync (if any)
+        covered: list = []  # records that fsync made durable
 
         def attempt() -> None:
             with self._sync_lock:
@@ -692,13 +693,17 @@ class WriteAheadLog:
                     t0 = time.perf_counter()
                     os.fsync(fd)
                     fsync_s.append(time.perf_counter() - t0)
+                    covered.append(end - self._synced_seq)
                     self._durable_bytes = abytes
                 self._synced_seq = end
                 self._last_sync_t = time.monotonic()
                 self.metrics.counter("geomesa.stream.wal.syncs")
 
-        with _ospan("wal.sync"):
+        with _ospan("wal.sync") as sp:
             fault.with_retries(attempt, metrics=self.metrics)
+            # fsync=0: another producer's fsync had covered this record
+            # (group commit); covered: records this one made durable
+            sp.annotate(fsync=len(fsync_s), covered=sum(covered))
         if fsync_s:
             # the durability tail is a live histogram + SLO surface:
             # only REAL fsyncs record (group-committed fast returns
