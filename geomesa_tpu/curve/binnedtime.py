@@ -66,6 +66,9 @@ class BinnedTime:
 
     def __init__(self, period: "TimePeriod | str"):
         self.period = TimePeriod.parse(period)
+        # last true millisecond of bin MAX_BIN: MAX_OFFSET over-states short
+        # months/non-leap years, so derive the ceiling from the next bin start
+        self._max_millis = int(self.from_binned(MAX_BIN + 1, 0)) - 1
 
     @property
     def max_offset(self) -> int:
@@ -128,33 +131,44 @@ class BinnedTime:
         return self.from_binned(bin, 0)
 
     def bins_for_interval(self, lo_millis: int, hi_millis: int):
-        """All (bin, lo_offset, hi_offset) triples covering [lo, hi] millis.
+        """All (bin, lo_offset, hi_offset) triples covering [lo, hi] millis:
+        :meth:`bins_for_intervals` of one interval. Returns (bins int32[n],
+        lo int64[n], hi int64[n]) with inclusive offsets."""
+        return self.bins_for_intervals([lo_millis], [hi_millis])[:3]
+
+    def bins_for_intervals(self, lo_millis, hi_millis):
+        """The per-bin windows of several [lo, hi] millis intervals in one
+        pass: (bins int32[n], lo int64[n], hi int64[n], counts int64[k]),
+        interval j's rows after interval j-1's, ``counts[j]`` of them.
 
         The analogue of the reference's BinnedTime.timesByBin logic used by
         Z3IndexKeySpace (Z3IndexKeySpace.scala:132-158): a long interval is
-        tiled per time bin; interior bins cover the whole offset range.
-        Returns (bins int32[n], lo int64[n], hi int64[n]) with inclusive
-        offsets.
+        tiled per time bin; interior bins cover the whole offset range,
+        offsets inclusive.
 
         Query-side semantics: endpoints extending past the representable
         range are *clamped* into it (a query reaching before the epoch or
         past the max bin is still answerable over its in-range portion) —
         only ingest (`to_binned`) rejects out-of-range instants.
         """
-        if lo_millis > hi_millis:
-            raise ValueError(f"inverted interval: {lo_millis} > {hi_millis}")
-        # last true millisecond of bin MAX_BIN: MAX_OFFSET over-states short
-        # months/non-leap years, so derive the ceiling from the next bin start
-        max_millis = int(self.from_binned(MAX_BIN + 1, 0)) - 1
-        lo_millis = min(max(int(lo_millis), 0), max_millis)
-        hi_millis = min(max(int(hi_millis), 0), max_millis)
-        lo_b = self.to_binned(lo_millis)
-        hi_b = self.to_binned(hi_millis)
-        b0 = int(lo_b.bin)
-        b1 = int(hi_b.bin)
-        bins = np.arange(b0, b1 + 1, dtype=np.int32)
-        lo = np.zeros(len(bins), dtype=np.int64)
-        hi = np.full(len(bins), self.max_offset, dtype=np.int64)
-        lo[0] = int(lo_b.offset)
-        hi[-1] = int(hi_b.offset)
-        return bins, lo, hi
+        lo_ms = np.asarray(lo_millis, dtype=np.int64)
+        hi_ms = np.asarray(hi_millis, dtype=np.int64)
+        inverted = np.flatnonzero(lo_ms > hi_ms)
+        if len(inverted):
+            j = int(inverted[0])
+            raise ValueError(f"inverted interval: {int(lo_ms[j])} > {int(hi_ms[j])}")
+        lo_ms = np.clip(lo_ms, 0, self._max_millis)
+        hi_ms = np.clip(hi_ms, 0, self._max_millis)
+        k = len(lo_ms)
+        ends = self.to_binned(np.concatenate([lo_ms, hi_ms]))
+        b0, b1 = ends.bin[:k].astype(np.int64), ends.bin[k:].astype(np.int64)
+        counts = b1 - b0 + 1
+        stops = np.cumsum(counts)
+        starts = stops - counts
+        n = int(stops[-1]) if k else 0
+        bins = (np.arange(n, dtype=np.int64) + np.repeat(b0 - starts, counts)).astype(np.int32)
+        lo = np.zeros(n, dtype=np.int64)
+        hi = np.full(n, self.max_offset, dtype=np.int64)
+        lo[starts] = ends.offset[:k]
+        hi[stops - 1] = ends.offset[k:]
+        return bins, lo, hi, counts

@@ -14,7 +14,8 @@ import numpy as np
 from geomesa_tpu.curve.normalize import NormalizedLat, NormalizedLon
 from geomesa_tpu.curve.zorder import Z2
 from geomesa_tpu.curve.zranges import (
-    IndexRange, ranges_from_arrays, with_inner, zranges_arrays,
+    IndexRange, pad_corners, ranges_from_arrays, with_inner, zranges_arrays,
+    zranges_arrays_each,
 )
 
 
@@ -70,18 +71,36 @@ class Z2SFC:
         ``inner=True``: classify containment 2 cells inward so contained
         rows are certain f64 hits (see Z3SFC.ranges_arrays).
         """
-        los, his = [], []
-        for (xmin, ymin, xmax, ymax) in bounds:
-            if xmin > xmax or ymin > ymax:
-                raise ValueError(f"inverted bbox: {(xmin, ymin, xmax, ymax)}")
-            los.append((self.lon.normalize_one(xmin), self.lat.normalize_one(ymin)))
-            his.append((self.lon.normalize_one(xmax), self.lat.normalize_one(ymax)))
+        mins, maxes = self._corners([bounds])
         return zranges_arrays(
-            Z2,
-            *with_inner(
-                np.array(los, dtype=np.uint64).reshape(-1, 2),
-                np.array(his, dtype=np.uint64).reshape(-1, 2),
-                inner,
-            ),
+            Z2, *with_inner(mins[0], maxes[0], inner),
             max_ranges=max_ranges, max_recurse=max_recurse,
         )
+
+    def ranges_arrays_each(
+        self,
+        bounds: "Sequence[Sequence[tuple[float, float, float, float]]]",
+        inner: bool = False,
+    ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+        """``len(bounds)`` decompositions in one native call: query q is
+        the union of its boxes ``bounds[q]``. Returns ``(lower, upper,
+        contained, counts i64[nq])``, query q's ranges after query q-1's;
+        ``inner`` as :meth:`ranges_arrays`."""
+        return zranges_arrays_each(Z2, *with_inner(*self._corners(bounds), inner))
+
+    def _corners(self, bounds) -> tuple[np.ndarray, np.ndarray]:
+        """The min and max corner ordinals of every box of ``bounds[q]``,
+        u64 ``[nq, nbox, 2]`` each, ``nbox`` the most a query has: a query
+        with fewer repeats its last (the same union)."""
+        lon, lat = self.lon.normalize_one, self.lat.normalize_one
+        los, his = [], []
+        for boxes in bounds:
+            lo_q, hi_q = [], []
+            for (xmin, ymin, xmax, ymax) in boxes:
+                if xmin > xmax or ymin > ymax:
+                    raise ValueError(f"inverted bbox: {(xmin, ymin, xmax, ymax)}")
+                lo_q.append((lon(xmin), lat(ymin)))
+                hi_q.append((lon(xmax), lat(ymax)))
+            los.append(lo_q)
+            his.append(hi_q)
+        return pad_corners(los, 2), pad_corners(his, 2)

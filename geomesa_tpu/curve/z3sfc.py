@@ -17,7 +17,7 @@ from geomesa_tpu.curve.binnedtime import MAX_OFFSET, TimePeriod
 from geomesa_tpu.curve.normalize import NormalizedLat, NormalizedLon, NormalizedTime
 from geomesa_tpu.curve.zorder import Z3
 from geomesa_tpu.curve.zranges import (
-    IndexRange, ranges_from_arrays, with_inner, zranges_arrays,
+    IndexRange, pad_corners, ranges_from_arrays, with_inner, zranges_arrays,
     zranges_arrays_each,
 )
 
@@ -96,9 +96,9 @@ class Z3SFC:
         absorbs normalize() floor rounding on both the query bounds and the
         stored values.
         """
-        mins, maxes = self._corners(bounds, times)  # [nb, nt, 3]
+        mins, maxes = self._corners([list(bounds)], [list(times)])  # [1, nb * nt, 3]
         return zranges_arrays(
-            Z3, *with_inner(mins.reshape(-1, 3), maxes.reshape(-1, 3), inner),
+            Z3, *with_inner(mins[0], maxes[0], inner),
             max_ranges=max_ranges, max_recurse=max_recurse,
         )
 
@@ -109,41 +109,43 @@ class Z3SFC:
         inner: bool = False,
     ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
         """One :meth:`ranges_arrays` decomposition a time window (each
-        the union of ``bounds`` under that window alone), all in one native
-        call: ``(lower, upper, contained, counts i64[len(times)])``, window
-        w's ranges after window w-1's, ``counts[w]`` of them."""
-        mins, maxes = self._corners(bounds, times)
-        return zranges_arrays_each(
-            Z3,
-            *with_inner(
-                np.ascontiguousarray(mins.transpose(1, 0, 2)),
-                np.ascontiguousarray(maxes.transpose(1, 0, 2)),
-                inner,
-            ),
-        )
+        the union of ``bounds`` under that window alone):
+        :meth:`ranges_arrays_each` with the same boxes every window."""
+        return self.ranges_arrays_each([list(bounds)] * len(times), times, inner)
+
+    def ranges_arrays_each(
+        self,
+        bounds: "Sequence[Sequence[tuple[float, float, float, float]]]",
+        times: Sequence[tuple[float, float]],
+        inner: bool = False,
+    ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+        """``len(times)`` decompositions in one native call: query q is
+        the union of its boxes ``bounds[q]`` under its one offset window
+        ``times[q]``. Returns ``(lower, upper, contained, counts
+        i64[nq])``, query q's ranges after query q-1's, ``counts[q]`` of
+        them; ``inner`` as :meth:`ranges_arrays`."""
+        mins, maxes = self._corners(bounds, [[w] for w in times])
+        return zranges_arrays_each(Z3, *with_inner(mins, maxes, inner))
 
     def _corners(self, bounds, times) -> tuple[np.ndarray, np.ndarray]:
-        """The min and max corner ordinals of every bounds x times box,
-        u64 ``[len(bounds), len(times), 3]`` each."""
-        los, his = [], []
-        for (xmin, ymin, xmax, ymax) in bounds:
-            if xmin > xmax or ymin > ymax:
-                raise ValueError(f"inverted bbox: {(xmin, ymin, xmax, ymax)}")
-            for (tmin, tmax) in times:
-                if tmin > tmax:
-                    raise ValueError(f"inverted time window: {(tmin, tmax)}")
-                los.append((
-                    self.lon.normalize_one(xmin),
-                    self.lat.normalize_one(ymin),
-                    self.time.normalize_one(tmin),
-                ))
-                his.append((
-                    self.lon.normalize_one(xmax),
-                    self.lat.normalize_one(ymax),
-                    self.time.normalize_one(tmax),
-                ))
-        shape = (len(bounds), len(times), 3)
-        return (
-            np.array(los, dtype=np.uint64).reshape(shape),
-            np.array(his, dtype=np.uint64).reshape(shape),
+        """The min and max corner ordinals of every box of ``bounds[q]``
+        under every window of ``times[q]``, u64 ``[nq, nbox, 3]`` each,
+        ``nbox`` the most a query has: a query with fewer repeats its last
+        (the union it describes, and so its decomposition, is the same)."""
+        lon, lat, time = (
+            self.lon.normalize_one, self.lat.normalize_one, self.time.normalize_one
         )
+        los, his = [], []
+        for boxes, windows in zip(bounds, times):
+            lo_q, hi_q = [], []
+            for (xmin, ymin, xmax, ymax) in boxes:
+                if xmin > xmax or ymin > ymax:
+                    raise ValueError(f"inverted bbox: {(xmin, ymin, xmax, ymax)}")
+                for (tmin, tmax) in windows:
+                    if tmin > tmax:
+                        raise ValueError(f"inverted time window: {(tmin, tmax)}")
+                    lo_q.append((lon(xmin), lat(ymin), time(tmin)))
+                    hi_q.append((lon(xmax), lat(ymax), time(tmax)))
+            los.append(lo_q)
+            his.append(hi_q)
+        return pad_corners(los, 3), pad_corners(his, 3)

@@ -61,6 +61,17 @@ def with_inner(mins: np.ndarray, maxes: np.ndarray, inner: bool):
     return mins, maxes, mins + two, np.maximum(maxes, two) - two
 
 
+def pad_corners(corners: list, dims: int) -> np.ndarray:
+    """u64 ``[nq, nbox, dims]`` for :func:`zranges_arrays_each` from one
+    list of corner ordinals a query, ``nbox`` the longest: a shorter list
+    repeats its last corner (with mins and maxes padded alike the box
+    repeats: the same union, and so the same decomposition)."""
+    nbox = max((len(c) for c in corners), default=0)
+    if any(len(c) != nbox for c in corners):
+        corners = [c + c[-1:] * (nbox - len(c)) for c in corners]
+    return np.array(corners, dtype=np.uint64).reshape(len(corners), nbox, dims)
+
+
 def ranges_from_arrays(lower, upper, contained) -> list[IndexRange]:
     """The object view of :func:`zranges_arrays`' result, for callers that
     want one ``IndexRange`` a range (tests, ``explain``); the plan path

@@ -1606,13 +1606,12 @@ class DataStore:
         round-trip overlaps across the batch (throughput-oriented; the
         per-query results are identical to sequential ``query`` calls).
 
-        Traced as ONE root ``query_many``: a ``plan`` per member, the
-        ``dispatch`` that stages them all, then each member's ``scan``
-        and ``decode`` (``member`` = its position)."""
+        Traced as ONE root ``query_many``: one ``plan`` for the batch
+        (``QueryPlanner.plan_many``), the ``dispatch`` that stages the
+        members, then each member's ``scan`` and ``decode`` (``member`` =
+        its position)."""
         with _otracer().trace("query_many", type=type_name) as trace:
-            plans = [
-                self.planner.plan(type_name, f, limit=limit) for f in filters
-            ]
+            plans = self.planner.plan_many(type_name, filters, limit=limit)
             if trace is not None:
                 trace.root.annotate(members=len(plans))
                 trace.fingerprint = {"type": type_name, "members": len(plans)}

@@ -320,6 +320,37 @@ def _merge_intervals(ivs: Sequence[Interval]) -> list[Interval]:
 
 
 # ---------------------------------------------------------------------------
+# one filter's spatio-temporal extraction, shared by its readers
+# ---------------------------------------------------------------------------
+
+
+@dataclass
+class Extraction:
+    """What the point indexes and the row estimate read of one filter,
+    extracted once: ``geoms`` and ``intervals`` (None for a type without
+    a date field) as :func:`extract_geometries` / :func:`extract_intervals`
+    give them, ``bounds`` = :func:`geometry_bounds` of the geometries (an
+    empty list where there are none, or the filter is disjoint), and
+    ``boxes_exact``: every geometry precisely extracted and its own bbox,
+    so a box test answers the spatial constraint."""
+
+    geoms: FilterValues
+    intervals: "FilterValues | None"
+    bounds: list
+    boxes_exact: bool
+
+
+def extract_filter(f: Filter, geom_field: str, dtg_field: "str | None") -> Extraction:
+    geoms = extract_geometries(f, geom_field)
+    return Extraction(
+        geoms=geoms,
+        intervals=None if dtg_field is None else extract_intervals(f, dtg_field),
+        bounds=geometry_bounds(geoms),
+        boxes_exact=geoms.precise and all(_is_box(g) for g in geoms.values),
+    )
+
+
+# ---------------------------------------------------------------------------
 # id extraction
 # ---------------------------------------------------------------------------
 

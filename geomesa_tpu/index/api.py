@@ -156,6 +156,16 @@ def shrink_boxes(bounds) -> np.ndarray:
     return np.concatenate([lo, hi], axis=1).astype(np.float32)
 
 
+def expand_runs(first: np.ndarray, counts: np.ndarray) -> np.ndarray:
+    """Concatenated ``arange(first[k], first[k] + counts[k])`` over k."""
+    ends = np.cumsum(counts)
+    if not len(ends):
+        return np.zeros(0, np.int64)
+    return np.arange(int(ends[-1]), dtype=np.int64) + np.repeat(
+        first - (ends - counts), counts
+    )
+
+
 @runtime_checkable
 class IndexKeySpace(Protocol):
     """One logical index over a feature type."""
@@ -172,5 +182,9 @@ class IndexKeySpace(Protocol):
 
     def scan_config(self, f: Filter) -> Optional[ScanConfig]:
         """Scan configuration for a filter, or None when this index cannot
-        serve it (reference getIndexValues + getRanges)."""
+        serve it (reference getIndexValues + getRanges). An index may also
+        offer ``scan_configs(extractions)`` (the point indexes do): one
+        config or None an ``filter.extract.Extraction``, all of them
+        decomposed in one native call, which the planner's ``plan_many``
+        uses for a batch; ``scan_config`` is then its one-member case."""
         ...

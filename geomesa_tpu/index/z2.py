@@ -14,9 +14,9 @@ import numpy as np
 
 from geomesa_tpu.curve.z2sfc import Z2SFC
 from geomesa_tpu.features import FeatureCollection
-from geomesa_tpu.filter.extract import extract_geometries, geometry_bounds
+from geomesa_tpu.filter.extract import extract_filter
 from geomesa_tpu.filter.predicates import Filter, PointColumn
-from geomesa_tpu.index.api import ScanConfig, WriteKeys, widen_boxes
+from geomesa_tpu.index.api import ScanConfig, WriteKeys, shrink_boxes, widen_boxes
 from geomesa_tpu.sft import FeatureType
 
 
@@ -58,27 +58,37 @@ class Z2Index:
         )
 
     def scan_config(self, f: Filter) -> Optional[ScanConfig]:
-        geoms = extract_geometries(f, self.geom)
-        if geoms.disjoint:
-            return ScanConfig.empty(self.name)
-        if not geoms.values:
-            return None  # no spatial constraint: a z2 scan would be full-table
-        bounds = geometry_bounds(geoms)
-        from geomesa_tpu.index.api import shrink_boxes
-        from geomesa_tpu.index.z3 import _bounds_only, _poly_edges, _poly_raster
+        return self.scan_configs([extract_filter(f, self.geom, None)])[0]
 
-        bounds_exact = geoms.precise and _bounds_only(geoms.values)
-        poly = None if bounds_exact else _poly_edges(geoms)
-        rast, approx = (None, None) if bounds_exact else _poly_raster(geoms)
-        if rast is not None and poly is not None:
-            from geomesa_tpu.conf import RASTER_RESIDUE
+    def scan_configs(self, extractions: list) -> "list[Optional[ScanConfig]]":
+        """One scan config (None: no spatial constraint) an extraction
+        (``filter.extract.extract_filter`` of this type's geom field); the
+        boxes of all that take covering ranges from the curve decomposed
+        in ONE native call. ``scan_config`` is the one-member case."""
+        from geomesa_tpu.index.z3 import _poly_edges, _poly_raster
 
-            if str(RASTER_RESIDUE.get()).lower() != "device":
-                # host residue (default): the kernel runs the raster leg
-                # alone — partial-cell rows come back uncertain and the
-                # planner's exact refinement resolves them on host
-                poly = None
-        if approx is not None:
+        out: "list[Optional[ScanConfig]]" = [None] * len(extractions)
+        pending = []  # (member, poly): covering ranges from the curve
+        for m, ex in enumerate(extractions):
+            geoms = ex.geoms
+            if geoms.disjoint:
+                out[m] = ScanConfig.empty(self.name)
+                continue
+            if not geoms.values:
+                continue  # no spatial constraint: a z2 scan would be full-table
+            poly = None if ex.boxes_exact else _poly_edges(geoms)
+            rast, approx = (None, None) if ex.boxes_exact else _poly_raster(geoms)
+            if rast is not None and poly is not None:
+                from geomesa_tpu.conf import RASTER_RESIDUE
+
+                if str(RASTER_RESIDUE.get()).lower() != "device":
+                    # host residue (default): the kernel runs the raster leg
+                    # alone — partial-cell rows come back uncertain and the
+                    # planner's exact refinement resolves them on host
+                    poly = None
+            if approx is None:
+                pending.append((m, poly))
+                continue
             # raster-derived z-ranges (arXiv 2307.01716): FULL cells emit
             # contained ranges — certain hits even for polygons, because
             # full-cell containment implies membership (margin-safe at
@@ -91,39 +101,53 @@ class Z2Index:
                 max_ranges=SCAN_RANGES_TARGET.get()
             )
             if len(rlo) == 0:
-                return ScanConfig.empty(self.name)
-            return ScanConfig(
+                out[m] = ScanConfig.empty(self.name)
+                continue
+            out[m] = ScanConfig(
                 index=self.name,
                 range_bins=np.zeros(len(rlo), dtype=np.int32),
                 range_lo=rlo,
                 range_hi=rhi,
-                boxes=widen_boxes(bounds),
+                boxes=widen_boxes(ex.bounds),
                 windows=None,
                 geom_precise=True,
                 range_contained=rcont,
                 contained_exact=True,
-                boxes_inner=shrink_boxes(bounds),
+                boxes_inner=shrink_boxes(ex.bounds),
                 poly=poly,
                 rast=rast,
             )
-        range_lo, range_hi, range_contained = self.sfc.ranges_arrays(
+        if not pending:
+            return out
+        bounds = [extractions[m].bounds for m, _ in pending]
+        range_lo, range_hi, range_contained, counts = self.sfc.ranges_arrays_each(
             bounds, inner=True
         )
-        if len(range_lo) == 0:
-            return ScanConfig.empty(self.name)
-        return ScanConfig(
-            index=self.name,
-            range_bins=np.zeros(len(range_lo), dtype=np.int32),
-            range_lo=range_lo,
-            range_hi=range_hi,
-            boxes=widen_boxes(bounds),
-            windows=None,
-            # the device PIP tier answers polygon queries exactly (host
-            # refines only the uncertainty band), so the mask decides the
-            # filter; contained-range certainty stays bbox-only
-            geom_precise=bounds_exact or poly is not None,
-            range_contained=range_contained,
-            contained_exact=bool(bounds_exact),
-            boxes_inner=shrink_boxes(bounds),
-            poly=poly,
-        )
+        flat = [b for bs in bounds for b in bs]
+        wide, inner = widen_boxes(flat), shrink_boxes(flat)
+        zeros = np.zeros(len(range_lo), dtype=np.int32)
+        ra = ba = 0
+        for (m, poly), n, bs in zip(pending, counts.tolist(), bounds):
+            rz, bz = ra + n, ba + len(bs)
+            if n == 0:
+                out[m] = ScanConfig.empty(self.name)
+            else:
+                bounds_exact = extractions[m].boxes_exact
+                out[m] = ScanConfig(
+                    index=self.name,
+                    range_bins=zeros[ra:rz],
+                    range_lo=range_lo[ra:rz],
+                    range_hi=range_hi[ra:rz],
+                    boxes=wide[ba:bz],
+                    windows=None,
+                    # the device PIP tier answers polygon queries exactly (host
+                    # refines only the uncertainty band), so the mask decides the
+                    # filter; contained-range certainty stays bbox-only
+                    geom_precise=bounds_exact or poly is not None,
+                    range_contained=range_contained[ra:rz],
+                    contained_exact=bool(bounds_exact),
+                    boxes_inner=inner[ba:bz],
+                    poly=poly,
+                )
+            ra, ba = rz, bz
+        return out

@@ -19,10 +19,10 @@ import numpy as np
 from geomesa_tpu.curve.binnedtime import BinnedTime, MAX_BIN, MAX_OFFSET, TimePeriod
 from geomesa_tpu.curve.z3sfc import Z3SFC
 from geomesa_tpu.features import FeatureCollection
-from geomesa_tpu.filter.extract import extract_geometries, extract_intervals, geometry_bounds
+from geomesa_tpu.filter.extract import extract_filter
 from geomesa_tpu.filter.predicates import Filter, PointColumn
 from geomesa_tpu.index.api import (
-    IndexKeySpace, ScanConfig, WriteKeys, shrink_boxes, widen_boxes,
+    IndexKeySpace, ScanConfig, WriteKeys, expand_runs, shrink_boxes, widen_boxes,
 )
 from geomesa_tpu.sft import FeatureType
 
@@ -172,112 +172,149 @@ class Z3Index:
 
     # -- read side -------------------------------------------------------
     def scan_config(self, f: Filter) -> Optional[ScanConfig]:
+        return self.scan_configs([extract_filter(f, self.geom, self.dtg)])[0]
+
+    def scan_configs(self, extractions: list) -> "list[Optional[ScanConfig]]":
+        """One scan config (None: the index cannot serve the filter) an
+        extraction (``filter.extract.extract_filter`` of this type's geom
+        and date fields), the per-bin windows of all of them cut in one
+        pass and every (filter, distinct offset window) decomposed in ONE
+        native call. ``scan_config`` is the one-member case."""
+        out: "list[Optional[ScanConfig]]" = [None] * len(extractions)
         if self.dtg is None:
-            return None
-        geoms = extract_geometries(f, self.geom)
-        intervals = extract_intervals(f, self.dtg)
-        if geoms.disjoint or intervals.disjoint:
-            return ScanConfig.empty(self.name)
-        if not intervals.values:
-            return None  # unbounded time: z3 cannot serve (z2 should)
-        # no spatial constraint -> no box predicate: the scan variant then
-        # projects away the x/y columns entirely (ColumnGroups analogue)
-        no_geom = not geoms.values
-        bounds = geometry_bounds(geoms) if geoms.values else [WHOLE_WORLD]
+            return out
+        live, iv_lo, iv_hi, iv_member = [], [], [], []
+        for m, ex in enumerate(extractions):
+            if ex.geoms.disjoint or ex.intervals.disjoint:
+                out[m] = ScanConfig.empty(self.name)
+            elif ex.intervals.values:  # unbounded time: z3 cannot serve (z2 should)
+                for iv in ex.intervals.values:
+                    iv_lo.append(iv.lo)
+                    iv_hi.append(iv.hi)
+                    iv_member.append(len(live))
+                live.append(m)
+        if not live:
+            return out
 
         # per-bin time windows (reference timesByBin, Z3IndexKeySpace:132-158)
         # plus the *inner* windows: offsets certain to lie inside the query
         # at millisecond precision (offsets are unit-floored at ingest, so
         # an unaligned query endpoint leaves one boundary offset uncertain)
         unit = _OFFSET_UNIT_MS[self.period]
-        bins_list, lo_list, hi_list = [], [], []
-        ilo_list, ihi_list = [], []
-        for iv in intervals.values:
-            b, lo, hi = self.binner.bins_for_interval(iv.lo, iv.hi - 1)
-            ilo, ihi = lo.copy(), hi.copy()
-            if int(iv.lo) % unit != 0:
-                ilo[0] += 1
-            if int(iv.hi) % unit != 0:
-                ihi[-1] -= 1
-            b, (lo, hi, ilo, ihi) = clamp_bins(self.bin_range, b, lo, hi, ilo, ihi)
-            if len(b) == 0:
-                continue
-            bins_list.append(b)
-            lo_list.append(lo)
-            hi_list.append(hi)
-            ilo_list.append(ilo)
-            ihi_list.append(ihi)
-        if not bins_list:
-            return ScanConfig.empty(self.name)
-        bins = np.concatenate(bins_list)
-        los = np.concatenate(lo_list)
-        his = np.concatenate(hi_list)
-        ilos = np.concatenate(ilo_list)
-        ihis = np.concatenate(ihi_list)
+        iv_lo, iv_hi = np.array(iv_lo, np.int64), np.array(iv_hi, np.int64)
+        bins, los, his, per_iv = self.binner.bins_for_intervals(iv_lo, iv_hi - 1)
+        ilos, ihis = los.copy(), his.copy()
+        stops = np.cumsum(per_iv)
+        ilos[(stops - per_iv)[iv_lo % unit != 0]] += 1
+        ihis[stops[iv_hi % unit != 0] - 1] -= 1
+        row_member = np.repeat(np.array(iv_member, np.int64), per_iv)
+        bins, (los, his, ilos, ihis, row_member) = clamp_bins(
+            self.bin_range, bins, los, his, ilos, ihis, row_member
+        )
+        row_stops = np.cumsum(np.bincount(row_member, minlength=len(live))).tolist()
+        windows = np.empty((len(bins), 3), np.int32)
+        windows_inner = np.empty((len(bins), 3), np.int32)
+        windows[:, 0] = windows_inner[:, 0] = bins
+        windows[:, 1], windows[:, 2] = los, his
+        windows_inner[:, 1], windows_inner[:, 2] = ilos, ihis
 
         # z-ranges: one decomposition per distinct (lo, hi) offset window —
         # interior bins all share the full-offset window, so a long interval
         # costs one BFS, not one per bin (the reference recomputes per bin;
-        # sharing is the columnar win since ranges are bin-independent)
-        range_bins, range_lo, range_hi, range_cont = [], [], [], []
-        windows = np.stack([bins, los, his], axis=1).astype(np.int64)
-        windows_inner = np.stack([bins, ilos, ihis], axis=1).astype(np.int64)
-        offsets = list(set(zip(los.tolist(), his.tolist())))
-        wlo, whi, wcont, counts = self.sfc.ranges_arrays_by_window(
-            bounds, [(float(lo), float(hi)) for lo, hi in offsets], inner=True
-        )
+        # sharing is the columnar win since ranges are bin-independent).
+        # A query of the native call is one (member, window); ``emit`` lists
+        # the window rows in the order their ranges go out: a member's
+        # windows in its set's order, each once a row that has it
+        lo_l, hi_l = los.tolist(), his.tolist()
+        q_bounds, q_window, emit_row, emit_q, emit_stops = [], [], [], [], []
+        a = 0
+        for j, z in enumerate(row_stops):
+            ex = extractions[live[j]]
+            pairs = list(zip(lo_l[a:z], hi_l[a:z]))
+            rows_of: dict = {}
+            for k, w in enumerate(pairs, a):
+                rows_of.setdefault(w, []).append(k)
+            for w in set(pairs):
+                for k in rows_of[w]:
+                    emit_row.append(k)
+                    emit_q.append(len(q_window))
+                q_window.append(w)
+                q_bounds.append(ex.bounds if ex.geoms.values else [WHOLE_WORLD])
+            emit_stops.append(len(emit_row))
+            a = z
+        if not q_window:
+            for m in live:
+                out[m] = ScanConfig.empty(self.name)
+            return out
         # wcont: the 2-cell inner margin (Z3SFC.ranges_arrays inner=True)
         # exceeds one offset unit in every period, so contained cells'
         # offsets are strictly inside the query interval even when its
         # endpoints are not offset-aligned — contained rows are certain at
         # ms precision
-        end = 0
-        for (lo_off, hi_off), n in zip(offsets, counts.tolist()):
-            start, end = end, end + n
-            if n == 0:
-                continue
-            for k in np.flatnonzero((los == lo_off) & (his == hi_off)):
-                range_bins.append(np.full(n, bins[k], dtype=np.int32))
-                range_lo.append(wlo[start:end])
-                range_hi.append(whi[start:end])
-                range_cont.append(wcont[start:end])
-        if not range_bins:
-            return ScanConfig.empty(self.name)
-        bounds_exact = geoms.precise and _bounds_only(geoms.values)
-        poly = None if (no_geom or bounds_exact) else _poly_edges(geoms)
-        # kernel-side raster tier only: z3 ranges interleave time, so the
-        # 2-D raster cannot reshape them (z2 gets the full range rework),
-        # but the interval classification still replaces most per-row PIP
-        rast = None
-        if not (no_geom or bounds_exact):
-            rast, _ = _poly_raster(geoms)
-            if rast is not None and poly is not None:
-                from geomesa_tpu.conf import RASTER_RESIDUE
-
-                if str(RASTER_RESIDUE.get()).lower() != "device":
-                    poly = None  # host residue (see z2)
-        return ScanConfig(
-            index=self.name,
-            range_bins=np.concatenate(range_bins),
-            range_lo=np.concatenate(range_lo),
-            range_hi=np.concatenate(range_hi),
-            boxes=None if no_geom else widen_boxes(bounds),
-            windows=windows.astype(np.int32),
-            # the device PIP/raster tiers make single-polygon queries
-            # precise on device (see z2); contained certainty stays
-            # bbox-only here (z3 ranges are bbox-derived)
-            geom_precise=bounds_exact or poly is not None or rast is not None,
-            time_precise=intervals.precise,
-            range_contained=np.concatenate(range_cont),
-            # contained certainty additionally requires the *filter* to be
-            # decided by bbox+interval alone — the planner checks kinds; here
-            # we require the geometry values themselves to be plain boxes
-            contained_exact=bool(bounds_exact and intervals.precise),
-            boxes_inner=None if no_geom else shrink_boxes(bounds),
-            windows_inner=windows_inner.astype(np.int32),
-            poly=poly,
-            rast=rast,
+        wlo, whi, wcont, counts = self.sfc.ranges_arrays_each(
+            q_bounds, [(float(lo), float(hi)) for lo, hi in q_window], inner=True
         )
+        if len(emit_q) == len(q_window):  # no window in two rows: as decomposed
+            per_row, range_lo, range_hi, range_cont = counts, wlo, whi, wcont
+        else:
+            emit_q = np.array(emit_q, np.int64)
+            per_row = counts[emit_q]
+            src = expand_runs((np.cumsum(counts) - counts)[emit_q], per_row)
+            range_lo, range_hi, range_cont = wlo[src], whi[src], wcont[src]
+        range_bins = np.repeat(bins[np.array(emit_row, np.int64)], per_row)
+        range_stops = np.concatenate([[0], np.cumsum(per_row)])[emit_stops].tolist()
+
+        flat = [b for m in live for b in extractions[m].bounds]
+        wide, inner = widen_boxes(flat), shrink_boxes(flat)
+        box_stops = np.cumsum([len(extractions[m].bounds) for m in live]).tolist()
+        a = ra = 0
+        for m, z, rz, bz in zip(live, row_stops, range_stops, box_stops):
+            ex = extractions[m]
+            if rz == ra:
+                out[m] = ScanConfig.empty(self.name)
+                a, ra = z, rz
+                continue
+            # no spatial constraint -> no box predicate: the scan variant then
+            # projects away the x/y columns entirely (ColumnGroups analogue)
+            no_geom = not ex.geoms.values
+            geoms, bounds_exact = ex.geoms, ex.boxes_exact
+            poly = None if (no_geom or bounds_exact) else _poly_edges(geoms)
+            # kernel-side raster tier only: z3 ranges interleave time, so the
+            # 2-D raster cannot reshape them (z2 gets the full range rework),
+            # but the interval classification still replaces most per-row PIP
+            rast = None
+            if not (no_geom or bounds_exact):
+                rast, _ = _poly_raster(geoms)
+                if rast is not None and poly is not None:
+                    from geomesa_tpu.conf import RASTER_RESIDUE
+
+                    if str(RASTER_RESIDUE.get()).lower() != "device":
+                        poly = None  # host residue (see z2)
+            ba = bz - len(ex.bounds)
+            out[m] = ScanConfig(
+                index=self.name,
+                range_bins=range_bins[ra:rz],
+                range_lo=range_lo[ra:rz],
+                range_hi=range_hi[ra:rz],
+                boxes=None if no_geom else wide[ba:bz],
+                windows=windows[a:z],
+                # the device PIP/raster tiers make single-polygon queries
+                # precise on device (see z2); contained certainty stays
+                # bbox-only here (z3 ranges are bbox-derived)
+                geom_precise=bounds_exact or poly is not None or rast is not None,
+                time_precise=ex.intervals.precise,
+                range_contained=range_cont[ra:rz],
+                # contained certainty additionally requires the *filter* to be
+                # decided by bbox+interval alone — the planner checks kinds; here
+                # we require the geometry values themselves to be plain boxes
+                contained_exact=bool(bounds_exact and ex.intervals.precise),
+                boxes_inner=None if no_geom else inner[ba:bz],
+                windows_inner=windows_inner[a:z],
+                poly=poly,
+                rast=rast,
+            )
+            a, ra = z, rz
+        return out
 
 
 def clamp_bins(bin_range, b, *cols):

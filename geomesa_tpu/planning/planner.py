@@ -237,38 +237,60 @@ class QueryPlanner:
             self._config_memo.clear()
 
     def _scan_config(self, idx, f: Filter):
-        """``idx.scan_config(f)`` through the memo (planner half of the
-        cache tier's "probe before scan": a warm repeat query skips the
-        range decomposition entirely). Only valid between mutations —
-        see invalidate_config_memo. The decomposition itself runs outside
-        the lock: two racing planners may both compute (benign — the
-        result is pure), but never block each other on it."""
+        """``idx.scan_config(f)`` through the memo: :meth:`_scan_configs`
+        of one filter."""
         from geomesa_tpu.filter.predicates import canonical_key
 
-        key = (idx, canonical_key(f))
-        with _ospan("plan.probe", index=idx.name):
+        return self._scan_configs(
+            idx, [canonical_key(f)], lambda _: [idx.scan_config(f)]
+        )[0]
+
+    def _scan_configs(self, idx, keys: list, decompose) -> list:
+        """One scan config (or None) a canonical filter key, through the
+        memo (planner half of the cache tier's "probe before scan": a warm
+        repeat query skips the range decomposition entirely): the keys are
+        probed under ONE lock hold, and ``decompose(positions)`` computes
+        the configs of the misses (a key met twice, once) in ONE call.
+        Only valid between mutations — see invalidate_config_memo. The
+        decomposition itself runs outside the lock: two racing planners may
+        both compute (benign — the result is pure), but never block each
+        other on it."""
+        out: list = [None] * len(keys)
+        miss: dict = {}  # key -> its positions
+        with _ospan("plan.probe", index=idx.name, members=len(keys)):
             with self._memo_lock:
                 memo = self._config_memo
-                if key in memo:
-                    memo.move_to_end(key)
-                    return memo[key]
+                for k, key in enumerate(keys):
+                    if (idx, key) in memo:
+                        memo.move_to_end((idx, key))
+                        out[k] = memo[(idx, key)]
+                    else:
+                        miss.setdefault(key, []).append(k)
                 epoch = self._memo_epoch
-        with _ospan("plan.decompose", index=idx.name) as sp:
-            cfg = idx.scan_config(f)
-            if sp is not _NULL_SPAN and sp.trace.retain and cfg is not None:
+        if not miss:
+            return out
+        with _ospan("plan.decompose", index=idx.name, members=len(miss)) as sp:
+            cfgs = decompose([pos[0] for pos in miss.values()])
+            if sp is not _NULL_SPAN and sp.trace.retain:
                 # what it emitted: tells a cheap decomposition from a small one
-                sp.annotate(ranges=len(cfg.range_lo))
+                sp.annotate(ranges=sum(
+                    len(c.range_lo) for c in cfgs if c is not None
+                ))
+        for pos, cfg in zip(miss.values(), cfgs):
+            for k in pos:
+                out[k] = cfg
         with self._memo_lock:
             if self._memo_epoch != epoch:
-                # a mutation invalidated mid-compute: this decomposition
-                # reflects pre-write data — usable for THIS query (the
+                # a mutation invalidated mid-compute: these decompositions
+                # reflect pre-write data — usable for THIS call (the
                 # inherent plan/execute race) but never memoizable
-                return cfg
+                return out
             memo = self._config_memo
-            memo[key] = cfg
+            for key, cfg in zip(miss, cfgs):
+                memo[(idx, key)] = cfg
             while len(memo) > _CONFIG_MEMO_MAX:
                 memo.popitem(last=False)
-        return cfg
+        return out
 
     # -- planning --------------------------------------------------------
     def plan(
@@ -280,75 +302,260 @@ class QueryPlanner:
         intercept: bool = True,
         guard: "bool | None" = None,
     ) -> QueryPlan:
-        """``intercept=False`` skips the interceptor rewrite — for internal
+        """One filter's plan: :meth:`plan_many`'s stages for a batch of one.
+        ``intercept=False`` skips the interceptor rewrite — for internal
         maintenance scans (age-off sweeps, delete_features, which guards
         must not reject either: ``guard`` defaults to ``intercept``) and
         for callers that already applied the rewrite themselves (pass
         ``guard=True`` to keep guarding those)."""
         if guard is None:
             guard = intercept
+        return self._plan_members(
+            type_name, [f], limit, [explain or ExplainNull()], intercept, guard
+        )[0]
+
+    def plan_many(
+        self,
+        type_name: str,
+        filters: "list[Filter | str]",
+        limit: Optional[int] = None,
+    ) -> "list[QueryPlan]":
+        """:meth:`plan` of every filter, each stage run ONCE for the batch
+        (``DataStore.query_many``'s planning; ``plan`` is the batch of one):
+        every plan equals, field for field, what ``plan(type_name, f,
+        limit)`` returns for its filter on the same store; ``planning_s``
+        is the batch's wall over its members.
+
+        1. *parse, extract*: a member is parsed, normalized, intercepted
+           and checked for hidden attributes on its own; its geometries,
+           intervals and bounds are extracted once
+           (``filter.extract.extract_filter``) for every index and the row
+           estimate.
+        2. *decompose*, once an index: the memo is probed a member, the
+           misses go to ``idx.scan_configs`` (one native call), and enter
+           the memo under the epoch check of :meth:`_scan_configs`.
+        3. *spans*, once a table: ``table.candidate_rows_many`` searches
+           every member's ranges in one pass and leaves each config's
+           candidate spans in its slot, where the dispatch finds them.
+        4. *select, estimate, guard*: the cheapest index a member by
+           ``(rows + 1) x multiplier``; the row estimates in array calls;
+           guards and the health warning a plan.
+
+        The array stages serve what they can see in the input: a type
+        whose every index offers ``scan_configs`` and whose tables offer
+        ``candidate_rows_many`` (z3 and z2 over plain or delta-tiered
+        tables), and of its filters those some index serves. Any other
+        member (an id filter, a filter no index serves, which may plan a
+        union or a full scan; every member of any other type) goes, in
+        its position, through :meth:`_select`: an index at a time.
+
+        Traced: ONE ``plan`` span (``members``, ``batched`` = members
+        through stages 2-3 as arrays; segments ``parse``, ``extract``,
+        ``decompose``, ``spans``, ``estimate``), with a ``plan.probe`` and a
+        ``plan.decompose`` child an index (``members``, ``ranges``)."""
+        filters = list(filters)
+        if not filters:
+            return []
+        return self._plan_members(
+            type_name, filters, limit, [ExplainNull()] * len(filters), True, True
+        )
+
+    def _plan_members(
+        self, type_name: str, filters: list, limit, exps: list,
+        intercept: bool, guard: bool,
+    ) -> list:
+        """The plans of ``filters`` under ONE ``plan`` span; ``exps``: an
+        Explainer a member."""
         t0 = time.perf_counter()
-        exp = explain or ExplainNull()
-        with _ospan("plan", cpu=True, type=type_name):
-            if isinstance(f, str):
-                f = ecql.parse(f)
-            from geomesa_tpu.filter.predicates import normalize_antimeridian
+        with _ospan("plan", cpu=True, type=type_name, members=len(filters)) as sp:
+            sp.event("parse")
+            filters = [self._prepare(type_name, f, intercept) for f in filters]
+            for f, exp in zip(filters, exps):
+                exp(f"Planning query on '{type_name}': {type(f).__name__}")
+            plans: list = [None] * len(filters)
+            indexes = self.store.indexes(type_name)
+            tables = self._batch_tables(type_name, indexes)
+            batch = [] if tables is None else [
+                m for m, f in enumerate(filters) if extract_ids(f).empty
+            ]
+            batched = 0
+            if batch:
+                batched = self._plan_arrays(
+                    type_name, filters, batch, indexes, tables, limit, plans, exps, sp
+                )
+            sp.event("estimate")
+            for m, f in enumerate(filters):
+                if plans[m] is None:
+                    # not one for the array stages: an index at a time
+                    plans[m] = self._select(type_name, f, limit, exps[m])
+                    self._estimate_rows([plans[m]], [exps[m]])
+                self._finish(plans[m], guard, exps[m])
+            sp.annotate(batched=batched)
+        share = (time.perf_counter() - t0) / len(plans)
+        for plan in plans:
+            plan.planning_s = share
+        return plans
 
-            f = normalize_antimeridian(f)
-            if intercept:
-                f = self.store.apply_interceptors(type_name, f)
-                # attribute-level visibility closes at PLAN depth: a predicate
-                # over a hidden attribute would evaluate against the hidden
-                # values during scan/refinement, letting unauthorized auths
-                # reconstruct them by probing (the reference's cell-level
-                # visibility makes the cell unreadable to the scan itself)
-                self._check_attr_visibility(type_name, f)
-            exp(f"Planning query on '{type_name}': {type(f).__name__}")
+    def _plan_arrays(
+        self, type_name, filters, batch, indexes, tables, limit, plans, exps, sp
+    ) -> int:
+        """Stages 1 (extraction) to 4 of :meth:`plan_many` for the members
+        ``batch`` of ``filters``: fills their ``plans`` where some index
+        serves the member, and returns how many those are."""
+        from geomesa_tpu.filter.extract import extract_filter
+        from geomesa_tpu.filter.predicates import canonical_key
 
-            plan = self._select(type_name, f, limit, exp)
-            self._estimate_rows(plan, exp)
-            if guard:
-                self.store.apply_guards(plan)
-            # degraded mode: a store that quarantined damaged partitions at
-            # load answers from the survivors and WARNS instead of raising
-            health = getattr(self.store, "health", None)
-            if health is not None:
-                w = health.warning_for(type_name)
-                if w is not None:
-                    plan.warnings = [w]
-                    exp.warn(w)
-        plan.planning_s = time.perf_counter() - t0
-        return plan
+        sp.event("extract")
+        sft = self.store.get_schema(type_name)
+        extractions = {
+            m: extract_filter(filters[m], sft.geom_field, sft.dtg_field)
+            for m in batch
+        }
+        keys = {m: canonical_key(filters[m]) for m in batch}
 
-    def _estimate_rows(self, plan: QueryPlan, exp) -> None:
-        """Resolve the stats-sketch row estimate for a finished plan
-        (docs/observability.md "Estimate accountability"): the marginal-
-        histogram selectivity product first, the z-prefix sketch of the
-        chosen index as the fallback — the same two tiers
+        # as _select_single: the indexes in order, and a member an index
+        # finds disjoint is planned there (no later index decomposes it)
+        sp.event("decompose")
+        alive, served, decided = batch, [], 0
+        for idx in indexes:
+            cfgs = self._scan_configs(
+                idx, [keys[m] for m in alive],
+                lambda pos, at=alive: idx.scan_configs([extractions[at[k]] for k in pos]),
+            )
+            keep, kept = [], []
+            for m, cfg in zip(alive, cfgs):
+                if cfg is not None and cfg.disjoint:
+                    exps[m](f"Index {idx.name}: filter disjoint -> empty plan")
+                    plans[m] = QueryPlan(type_name, filters[m], idx.name, cfg, limit=limit)
+                    decided += 1
+                    continue
+                keep.append(m)
+                if cfg is not None:
+                    kept.append((m, cfg))
+            served.append((idx.name, kept))
+            alive = keep
+
+        sp.event("spans")
+        best: dict = {}  # member -> (cost, index name, config), the first cheapest
+        for name, kept in served:
+            table = tables[name]
+            if table is not None and kept:
+                uniq = list({id(cfg): cfg for _, cfg in kept}.values())
+                rows = dict(zip(map(id, uniq), table.candidate_rows_many(uniq).tolist()))
+            for m, cfg in kept:
+                cost = self._multiplier(type_name, name, exps[m])
+                if table is not None:
+                    cost *= rows[id(cfg)] + 1
+                exps[m](f"Index {name}: {cfg.n_ranges} ranges, cost {cost:.1f}")
+                if m not in best or cost < best[m][0]:
+                    best[m] = (cost, name, cfg)
+
+        sp.event("estimate")
+        for m, (cost, name, cfg) in best.items():
+            exps[m](f"Strategy: {name} (cost {cost:.1f})")
+            plans[m] = QueryPlan(type_name, filters[m], name, cfg, limit=limit)
+        self._estimate_rows(
+            [plans[m] for m in best], [exps[m] for m in best],
+            [extractions[m] for m in best],
+        )
+        return decided + len(best)
+
+    def _batch_tables(self, type_name: str, indexes) -> "dict | None":
+        """index name -> its table (None: no data written yet) where every
+        index and table of the type offers the batched entries
+        (``scan_configs``, ``candidate_rows_many``), else None."""
+        if not all(hasattr(idx, "scan_configs") for idx in indexes):
+            return None
+        tables: dict = {}
+        for idx in indexes:
+            try:
+                table = self.store.table(type_name, idx.name)
+            except KeyError:
+                table = None
+            if table is not None and not hasattr(table, "candidate_rows_many"):
+                return None
+            tables[idx.name] = table
+        return tables
+
+    def _prepare(self, type_name: str, f: "Filter | str", intercept: bool) -> Filter:
+        """A caller's filter as the stages plan it: parsed, normalized,
+        and (``intercept``) rewritten by the interceptors and checked for
+        attributes the auths may not see."""
+        from geomesa_tpu.filter.predicates import normalize_antimeridian
+
+        if isinstance(f, str):
+            f = ecql.parse(f)
+        f = normalize_antimeridian(f)
+        if intercept:
+            f = self.store.apply_interceptors(type_name, f)
+            # attribute-level visibility closes at PLAN depth: a predicate
+            # over a hidden attribute would evaluate against the hidden
+            # values during scan/refinement, letting unauthorized auths
+            # reconstruct them by probing (the reference's cell-level
+            # visibility makes the cell unreadable to the scan itself)
+            self._check_attr_visibility(type_name, f)
+        return f
+
+    def _finish(self, plan: QueryPlan, guard: bool, exp) -> None:
+        if guard:
+            self.store.apply_guards(plan)
+        # degraded mode: a store that quarantined damaged partitions at
+        # load answers from the survivors and WARNS instead of raising
+        health = getattr(self.store, "health", None)
+        if health is not None:
+            w = health.warning_for(plan.type_name)
+            if w is not None:
+                plan.warnings = [w]
+                exp.warn(w)
+
+    def _estimate_rows(
+        self, plans: list, exps: list, extractions: "list | None" = None
+    ) -> None:
+        """Resolve the stats-sketch row estimate of finished plans of ONE
+        type (docs/observability.md "Estimate accountability"): the
+        marginal-histogram selectivity product first (every plan's boxes
+        and intervals in one histogram pass each; ``exps`` and, where the
+        caller has them, ``extractions`` aligned with ``plans``), the
+        z-prefix sketch of
+        the chosen index as the fallback — the same two tiers
         ``estimate_count`` trusts. Skipped for id lookups (exact by
         construction — they would dilute the staleness signal with
         perfect scores) and disjoint plans (nothing scans)."""
         from geomesa_tpu import conf
+        from geomesa_tpu.filter.extract import extract_filter
 
-        if not conf.PLAN_ESTIMATE.get():
+        if not plans or not conf.PLAN_ESTIMATE.get():
             return
-        if plan.ids is not None or (
-            plan.config is not None and plan.config.disjoint
-        ):
-            return
-        stats = self.store.stats_for(plan.type_name)
+        type_name = plans[0].type_name
+        stats = self.store.stats_for(type_name)
         if stats is None:
             return
-        if isinstance(plan.filter, Include):
-            est = float(stats.total_count())
-        else:
-            sft = self.store.get_schema(plan.type_name)
-            est = stats.estimate_filter(sft, plan.filter)
+        sft = self.store.get_schema(type_name)
+        todo, asked = [], []
+        for k, plan in enumerate(plans):
+            if plan.ids is not None or (
+                plan.config is not None and plan.config.disjoint
+            ):
+                continue
+            if isinstance(plan.filter, Include):
+                self._note_estimate(plan, float(stats.total_count()), exps[k])
+                continue
+            todo.append(k)
+            asked.append(
+                extractions[k] if extractions is not None
+                else extract_filter(plan.filter, sft.geom_field, sft.dtg_field)
+            )
+        for k, est in zip(todo, stats.estimate_extractions(sft, asked)):
+            plan = plans[k]
             if est is None and plan.index is not None and plan.config is not None:
                 est = stats.estimate_scan(plan.index, plan.config)
-        if est is not None:
-            plan.estimated_rows = float(est)
-            exp(f"Estimated rows: ~{est:.0f} (stats sketch)")
+            if est is not None:
+                self._note_estimate(plan, est, exps[k])
+
+    @staticmethod
+    def _note_estimate(plan: QueryPlan, est: float, exp) -> None:
+        plan.estimated_rows = float(est)
+        exp(f"Estimated rows: ~{est:.0f} (stats sketch)")
 
     def _check_attr_visibility(self, type_name: str, f: Filter) -> None:
         auths = getattr(self.store, "auths", None)
@@ -461,6 +668,17 @@ class QueryPlanner:
         chronically miss (docs/tuning.md leg a) — bounded, hysteretic,
         and explain-traced; factor 1.0 (or no reweighter) leaves the
         cost bit-identical to the static decision."""
+        mult = self._multiplier(type_name, index_name, exp)
+        try:
+            table = self.store.table(type_name, index_name)
+        except KeyError:
+            return mult  # no data written yet
+        rows = table.candidate_spans(cfg).n_rows()
+        return (rows + 1) * mult
+
+    def _multiplier(self, type_name: str, index_name: str, exp) -> float:
+        """An index's static cost multiplier, times the armed tuning
+        tier's estimate-accuracy factor."""
         mult = index_priority(index_name)
         rw = self.reweighter
         if rw is not None:
@@ -471,12 +689,7 @@ class QueryPlanner:
                     f"Index {index_name}: estimate-accuracy reweight "
                     f"x{fac:.2f} (docs/tuning.md)"
                 )
-        try:
-            table = self.store.table(type_name, index_name)
-        except KeyError:
-            return mult  # no data written yet
-        rows = table.candidate_spans(cfg).n_rows()
-        return (rows + 1) * mult
+        return mult
 
     # -- execution -------------------------------------------------------
     def execute(

@@ -123,15 +123,23 @@ class Histogram:
         return self
 
     def estimate_range(self, lo: float, hi: float) -> float:
-        """Estimated count within [lo, hi] assuming uniform intra-bin mass.
-        Vectorized: hot-path callers (estimate_bbox, the kNN radius
-        refinement) probe this several times per query."""
+        """Estimated count within [lo, hi] assuming uniform intra-bin mass:
+        :meth:`estimate_ranges` of one range."""
+        return float(self.estimate_ranges([lo], [hi])[0])
+
+    def estimate_ranges(self, lo, hi) -> np.ndarray:
+        """:meth:`estimate_range` of several [lo[k], hi[k]] in one pass
+        (f64 a range, each row summed on its own: a range's estimate does
+        not depend on its neighbours). Hot-path callers (estimate_bbox,
+        the kNN radius refinement, a batch's row estimates) probe this
+        several times per query."""
+        lo = np.asarray(lo, dtype=np.float64)[:, None]
+        hi = np.asarray(hi, dtype=np.float64)[:, None]
         w = (self.hi - self.lo) / self.n_bins
         edges = self.lo + np.arange(self.n_bins + 1) * w
-        overlap = np.clip(
-            np.minimum(hi, edges[1:]) - np.maximum(lo, edges[:-1]), 0.0, w
-        )
-        return float((self.counts * (overlap / w)).sum())
+        overlap = np.minimum(hi, edges[1:]) - np.maximum(lo, edges[:-1])
+        overlap = np.minimum(np.maximum(overlap, 0.0), w)  # clip's values
+        return np.add.reduce(self.counts * (overlap / w), axis=1)
 
     def to_json(self):
         return {

@@ -149,10 +149,17 @@ class StatsStore:
         return h.estimate_range(lo, hi) if h is not None else None
 
     def estimate_bbox(self, geom: str, x0, y0, x1, y1) -> float | None:
-        """Estimated rows intersecting a bbox from the marginal coordinate
-        histograms under independence (reference StatsBasedEstimator's
-        attribute-selectivity composition). Correlated multi-cluster data
-        can overestimate; callers treat this as a selectivity hint."""
+        """Estimated rows intersecting a bbox: :meth:`estimate_bboxes` of
+        one box."""
+        est = self.estimate_bboxes(geom, [(x0, y0, x1, y1)])
+        return None if est is None else float(est[0])
+
+    def estimate_bboxes(self, geom: str, boxes) -> "np.ndarray | None":
+        """Estimated rows intersecting each (x0, y0, x1, y1) box from the
+        marginal coordinate histograms under independence (reference
+        StatsBasedEstimator's attribute-selectivity composition), f64 a
+        box. Correlated multi-cluster data can overestimate; callers treat
+        this as a selectivity hint. None without the sketches."""
         hx = self.histograms.get(geom + ".x")
         hy = self.histograms.get(geom + ".y")
         n = self.total_count()
@@ -162,47 +169,60 @@ class StatsStore:
         ty = float(hy.counts.sum())
         if tx <= 0 or ty <= 0:
             return None
-        fx = hx.estimate_range(float(x0), float(x1)) / tx
-        fy = hy.estimate_range(float(y0), float(y1)) / ty
+        b = np.asarray(boxes, dtype=np.float64).reshape(-1, 4)
+        fx = hx.estimate_ranges(b[:, 0], b[:, 2]) / tx
+        fy = hy.estimate_ranges(b[:, 1], b[:, 3]) / ty
         return n * fx * fy
 
     def estimate_filter(self, sft, f) -> float | None:
         """Selectivity-product estimate for a filter's spatial and temporal
-        parts: bbox marginals x date-histogram fraction. None when neither
-        axis is constrained or sketches are missing."""
-        from geomesa_tpu.filter.extract import (
-            extract_geometries, extract_intervals, geometry_bounds,
-        )
+        parts: :meth:`estimate_extractions` of one filter."""
+        from geomesa_tpu.filter.extract import extract_filter
 
+        return self.estimate_extractions(
+            sft, [extract_filter(f, sft.geom_field, sft.dtg_field)]
+        )[0]
+
+    def estimate_extractions(self, sft, extractions: list) -> "list[float | None]":
+        """Selectivity-product estimate of each filter's spatial and
+        temporal parts (``filter.extract.extract_filter`` of the type's
+        geom and date fields): bbox marginals x date-histogram fraction,
+        every filter's boxes and intervals in one histogram pass each.
+        None when neither axis is constrained or sketches are missing."""
         n = self.total_count()
+        out: "list[float | None]" = [None] * len(extractions)
         if not n or sft.geom_field is None:
-            return None
-        geoms = extract_geometries(f, sft.geom_field)
-        if geoms.disjoint:
-            return 0.0
-        est = None
-        if geoms.values:
-            parts = [
-                self.estimate_bbox(sft.geom_field, *b)
-                for b in geometry_bounds(geoms)
-            ]
-            if any(p is None for p in parts):
-                return None
-            est = min(float(np.sum(parts)), float(n))
-        if sft.dtg_field is not None:
-            intervals = extract_intervals(f, sft.dtg_field)
-            if intervals.disjoint:
-                return 0.0
-            if intervals.values:
-                h = self.histograms.get(sft.dtg_field)
-                if h is not None and h.counts.sum() > 0:
-                    frac = sum(
-                        h.estimate_range(float(iv.lo), float(iv.hi))
-                        for iv in intervals.values
-                    ) / float(h.counts.sum())
-                    frac = min(frac, 1.0)
-                    est = n * frac if est is None else est * frac
-        return est
+            return out
+        boxes = [b for ex in extractions if not ex.geoms.disjoint for b in ex.bounds]
+        parts = self.estimate_bboxes(sft.geom_field, boxes) if boxes else None
+        h = None if sft.dtg_field is None else self.histograms.get(sft.dtg_field)
+        total = float(h.counts.sum()) if h is not None else 0.0
+        spans = [
+            (float(iv.lo), float(iv.hi))
+            for ex in extractions if not ex.geoms.disjoint
+            and ex.intervals is not None and not ex.intervals.disjoint
+            for iv in ex.intervals.values
+        ] if total > 0 else []
+        in_span = h.estimate_ranges(*zip(*spans)).tolist() if spans else []
+        b = s = 0
+        for k, ex in enumerate(extractions):
+            if ex.geoms.disjoint:
+                out[k] = 0.0
+                continue
+            ivs = ex.intervals
+            nb = len(ex.bounds)
+            ni = len(ivs.values) if spans and ivs is not None and not ivs.disjoint else 0
+            b, s, b0, s0 = b + nb, s + ni, b, s
+            if nb and parts is None:
+                continue  # boxes without the coordinate sketches: no estimate
+            est = min(float(np.sum(parts[b0:b])), float(n)) if nb else None
+            if ivs is not None and ivs.disjoint:
+                est = 0.0
+            elif ni:
+                frac = min(sum(in_span[s0:s]) / total, 1.0)
+                est = n * frac if est is None else est * frac
+            out[k] = est
+        return out
 
     def attribute_bounds(self, attr: str):
         mm = self.minmax.get(attr)

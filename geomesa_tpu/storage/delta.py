@@ -171,6 +171,12 @@ class TieredTable:
         self.delta = delta_keys
         self.base = base_ordinal
         self.keyspace = main.keyspace
+        many = getattr(main, "candidate_rows_many", None)
+        if many is not None:
+            # :meth:`candidate_spans`' row counts of several configs, where
+            # the main table searches them in one pass: its own, plus the
+            # whole delta each
+            self.candidate_rows_many = lambda configs: many(configs) + len(delta_keys.zs)
 
     @property
     def n(self) -> int:

@@ -434,6 +434,19 @@ def test_every_operation_is_one_root_with_covering_phases(op):
                          and "wait" in s.attrs["segments"]]
                 # one pull serves the fused group: one member carries it
                 assert len(pulls) == 1 and pulls[0].attrs["group"] == len(MANY)
+                # ONE plan for the batch (QueryPlanner.plan_many), a direct
+                # child of the root, with an index's probe and decompose
+                # as its children
+                (plan,) = [s for s in tr.spans if s.name == "plan"]
+                assert plan.parent_id == tr.root.span_id
+                pa = plan.attrs
+                assert pa["members"] == len(MANY)
+                assert 0 <= pa["batched"] <= pa["members"]
+                assert {"parse", "estimate"} <= set(pa["segments"]) <= {
+                    "parse", "extract", "decompose", "spans", "estimate"}
+                kids = [s for s in tr.spans if s.name.startswith("plan.")]
+                assert kids and all(s.parent_id == plan.span_id for s in kids)
+                assert all(s.attrs["members"] <= len(MANY) for s in kids)
             if op in ("query", "count"):
                 assert set(by_name["scan"].attrs["segments"]) == {
                     "wait", "pull", "bits"}

@@ -272,6 +272,24 @@ def test_z3_scan_config_equals_list_api_reference(shape, seed):
     assert 1 in bins_met and max(bins_met) >= 2
 
 
+@pytest.mark.parametrize("n_intervals", [2, 4, 7])
+def test_z3_scan_config_of_several_intervals_equals_list_api_reference(n_intervals):
+    """Intervals OR'ed under one box: up to three distinct offset windows
+    an interval, emitted in the order of the reference's set of them."""
+    idx = Z3Index(_sft())
+    idx.bin_range = (2817, 2823)
+    rng = np.random.default_rng(n_intervals)
+    wins = []
+    for k in range(n_intervals):  # disjoint, each across a week's boundary or two
+        start = T0 + k * 6 * DAY + int(rng.integers(0, DAY)) // 1000 * 1000
+        wins.append((start, start + int(rng.integers(4 * DAY, 5 * DAY)) // 1000 * 1000))
+    during = " OR ".join(f"dtg DURING {_iso(a)}/{_iso(z)}" for a, z in wins)
+    f = ecql.parse("bbox(geom, 10.0, 10.0, 30.0, 20.0) AND (" + during + ")")
+    cfg = idx.scan_config(f)
+    _assert_fields(cfg, _z3_reference(idx, f))
+    assert len({(lo, hi) for _, lo, hi in cfg.windows.tolist()}) >= min(2 * n_intervals, 5)
+
+
 @pytest.mark.parametrize("seed", [2_600_000_011, 3_100_000_007])
 def test_z2_scan_config_equals_list_api_reference(seed):
     idx = Z2Index(_sft())
