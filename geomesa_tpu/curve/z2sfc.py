@@ -81,12 +81,19 @@ class Z2SFC:
         self,
         bounds: "Sequence[Sequence[tuple[float, float, float, float]]]",
         inner: bool = False,
+        cover: "Sequence[Sequence[tuple[float, float, float, float]]] | None" = None,
     ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
         """``len(bounds)`` decompositions in one native call: query q is
         the union of its boxes ``bounds[q]``. Returns ``(lower, upper,
         contained, counts i64[nq])``, query q's ranges after query q-1's;
-        ``inner`` as :meth:`ranges_arrays`."""
-        return zranges_arrays_each(Z2, *with_inner(*self._corners(bounds), inner))
+        ``inner`` as :meth:`ranges_arrays`. ``cover`` (aligned with
+        ``bounds``): the boxes the ranges have to cover where those are
+        wider than the boxes that decide containment, as the f32 mask's
+        are (``index.api.widen_boxes``)."""
+        mins, maxes, imins, imaxes = with_inner(*self._corners(bounds), inner)
+        if cover is not None:
+            mins, maxes = self._corners(cover)
+        return zranges_arrays_each(Z2, mins, maxes, imins, imaxes)
 
     def _corners(self, bounds) -> tuple[np.ndarray, np.ndarray]:
         """The min and max corner ordinals of every box of ``bounds[q]``,

@@ -1912,6 +1912,10 @@ class DataStore:
         Otherwise rows gather to host and the grid is a NumPy scatter over
         refined results (LocalQueryRunner semantics). Extent geometries
         weight their bbox centroid pixel.
+
+        The grid is f32 on every path: exact while a pixel holds at most
+        2^24 = 16,777,216 rows (or that much weight); past it an added row
+        can round away. A caller with denser pixels asks for more of them.
         """
         return self.density_many(
             type_name, [(f, envelope)], width=width, height=height,

@@ -140,6 +140,17 @@ def widen_boxes(bounds) -> np.ndarray:
     return np.concatenate([lo, hi], axis=1).astype(np.float32)
 
 
+def cover_boxes(wide: np.ndarray, counts) -> list:
+    """The boxes a member's key ranges have to cover: ``widen_boxes``' rows
+    (what the device mask keeps) as float tuples, ``counts[q]`` of them to
+    member q. A box whose edge is a cell boundary of the curve (a WMS
+    tile's) has rows an f32 step beyond it in cells the f64 box's ranges
+    do not reach, and an aggregation counts whatever the mask keeps."""
+    rows = [tuple(r) for r in wide.astype(np.float64).tolist()]
+    stops = np.cumsum(counts).tolist()
+    return [rows[z - n:z] for n, z in zip(counts, stops)]
+
+
 def shrink_boxes(bounds) -> np.ndarray:
     """f64 boxes -> f32 boxes shrunk two ulps inward (subset semantics).
 

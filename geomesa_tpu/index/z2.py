@@ -16,7 +16,9 @@ from geomesa_tpu.curve.z2sfc import Z2SFC
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.extract import extract_filter
 from geomesa_tpu.filter.predicates import Filter, PointColumn
-from geomesa_tpu.index.api import ScanConfig, WriteKeys, shrink_boxes, widen_boxes
+from geomesa_tpu.index.api import (
+    ScanConfig, WriteKeys, cover_boxes, shrink_boxes, widen_boxes,
+)
 from geomesa_tpu.sft import FeatureType
 
 
@@ -120,11 +122,13 @@ class Z2Index:
         if not pending:
             return out
         bounds = [extractions[m].bounds for m, _ in pending]
-        range_lo, range_hi, range_contained, counts = self.sfc.ranges_arrays_each(
-            bounds, inner=True
-        )
         flat = [b for bs in bounds for b in bs]
         wide, inner = widen_boxes(flat), shrink_boxes(flat)
+        # covering ranges of the boxes the mask keeps, containment by the
+        # f64 boxes: a contained row is a certain f64 hit as before
+        range_lo, range_hi, range_contained, counts = self.sfc.ranges_arrays_each(
+            bounds, inner=True, cover=cover_boxes(wide, [len(bs) for bs in bounds])
+        )
         zeros = np.zeros(len(range_lo), dtype=np.int32)
         ra = ba = 0
         for (m, poly), n, bs in zip(pending, counts.tolist(), bounds):

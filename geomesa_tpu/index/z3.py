@@ -22,7 +22,8 @@ from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.extract import extract_filter
 from geomesa_tpu.filter.predicates import Filter, PointColumn
 from geomesa_tpu.index.api import (
-    IndexKeySpace, ScanConfig, WriteKeys, expand_runs, shrink_boxes, widen_boxes,
+    IndexKeySpace, ScanConfig, WriteKeys, cover_boxes, expand_runs, shrink_boxes,
+    widen_boxes,
 )
 from geomesa_tpu.sft import FeatureType
 
@@ -226,7 +227,12 @@ class Z3Index:
         # the window rows in the order their ranges go out: a member's
         # windows in its set's order, each once a row that has it
         lo_l, hi_l = los.tolist(), his.tolist()
-        q_bounds, q_window, emit_row, emit_q, emit_stops = [], [], [], [], []
+        flat = [b for m in live for b in extractions[m].bounds]
+        wide, inner = widen_boxes(flat), shrink_boxes(flat)
+        n_boxes = [len(extractions[m].bounds) for m in live]
+        box_stops = np.cumsum(n_boxes).tolist()
+        covers = cover_boxes(wide, n_boxes)
+        q_bounds, q_cover, q_window, emit_row, emit_q, emit_stops = [], [], [], [], [], []
         a = 0
         for j, z in enumerate(row_stops):
             ex = extractions[live[j]]
@@ -240,6 +246,7 @@ class Z3Index:
                     emit_q.append(len(q_window))
                 q_window.append(w)
                 q_bounds.append(ex.bounds if ex.geoms.values else [WHOLE_WORLD])
+                q_cover.append(covers[j] if ex.geoms.values else [WHOLE_WORLD])
             emit_stops.append(len(emit_row))
             a = z
         if not q_window:
@@ -250,9 +257,10 @@ class Z3Index:
         # exceeds one offset unit in every period, so contained cells'
         # offsets are strictly inside the query interval even when its
         # endpoints are not offset-aligned — contained rows are certain at
-        # ms precision
+        # ms precision. The ranges cover the boxes the mask keeps (see z2)
         wlo, whi, wcont, counts = self.sfc.ranges_arrays_each(
-            q_bounds, [(float(lo), float(hi)) for lo, hi in q_window], inner=True
+            q_bounds, [(float(lo), float(hi)) for lo, hi in q_window], inner=True,
+            cover=q_cover,
         )
         if len(emit_q) == len(q_window):  # no window in two rows: as decomposed
             per_row, range_lo, range_hi, range_cont = counts, wlo, whi, wcont
@@ -264,9 +272,6 @@ class Z3Index:
         range_bins = np.repeat(bins[np.array(emit_row, np.int64)], per_row)
         range_stops = np.concatenate([[0], np.cumsum(per_row)])[emit_stops].tolist()
 
-        flat = [b for m in live for b in extractions[m].bounds]
-        wide, inner = widen_boxes(flat), shrink_boxes(flat)
-        box_stops = np.cumsum([len(extractions[m].bounds) for m in live]).tolist()
         a = ra = 0
         for m, z, rz, bz in zip(live, row_stops, range_stops, box_stops):
             ex = extractions[m]

@@ -246,14 +246,17 @@ class DistributedIndexTable(IndexTable):
     def _split_blocks(self, blocks: np.ndarray, pad: int = 0):
         """Global candidate blocks -> ([D, M] i32 local block ids padded to
         one mesh-wide static bucket, per-device real counts [D]). Past the
-        largest bucket every device scans all its local blocks. The
+        largest bucket every device scans all its local blocks (``full``
+        on the caller's span, as ``IndexTable._full_or`` counts it). The
         caller's span gets the segment ``deal`` and the deal's counters;
         what follows is ``prune`` again, as on one chip."""
         _oevent("deal")
         D = self.n_devices
         per = [blocks[blocks % D == d] // D for d in range(D)]
         mx = max(len(p) for p in per)
-        if mx > bk.M_BUCKETS[-1]:
+        full = mx > bk.M_BUCKETS[-1]
+        _oadd("full", int(full))
+        if full:
             per = [np.arange(self.blocks_local, dtype=np.int64)] * D
             mx = self.blocks_local
         m = bk.bucket_of(mx)

@@ -1201,8 +1201,11 @@ class IndexTable(SortedKeys):
 
     def _full_or(self, blocks: np.ndarray) -> np.ndarray:
         """Past the largest static M bucket, scan every block — one static
-        shape per table instead of an unbounded bucket ladder."""
-        if len(blocks) > bk.M_BUCKETS[-1]:
+        shape per table instead of an unbounded bucket ladder. The caller's
+        span counts ``full``: how often that shape was taken."""
+        full = len(blocks) > bk.M_BUCKETS[-1]
+        _oadd("full", int(full))
+        if full:
             return np.arange(self.n_blocks, dtype=np.int64)
         return blocks
 

@@ -186,6 +186,23 @@ def test_density(one_chip, size):
     _assert_mosaic(compiled)
 
 
+@pytest.mark.parametrize("m", [bk.M_BUCKETS[0], M_LARGE, N_BLOCKS])
+def test_heat_map_tile_over_a_z2_table(one_chip, m):
+    """A WMS heat-map tile over a type with a z2 index alone: x and y, no
+    window, 256 x 256, from the ladder's first bucket to the whole-table
+    shape past it (every one of 8,192 blocks, 2^27 rows)."""
+    ch = agg._density_chunk(256, 256, SUB, len(Z2))
+    assert ch is not None
+    compiled = agg._pallas_density.lower(
+        _cols(Z2, one_chip), _s((m,), jnp.int32, one_chip),
+        *_params(one_chip), _s((4,), jnp.float32, one_chip),
+        width=256, height=256, interpret=False, chunk=ch,
+        **_flags(Z2, False),
+    ).compile()
+    _assert_mosaic(compiled)
+    assert bk.bucket_of(bk.M_BUCKETS[-1] + 1) == N_BLOCKS  # what pad_bids hands the kernel
+
+
 def test_density_1024_takes_the_xla_path():
     assert agg._density_chunk(1024, 1024, SUB, len(Z3)) is None
 

@@ -6,7 +6,8 @@ SFCs' ``ranges_arrays``): the list of ``IndexRange`` is a view of them.
   for dtype, native tier and plain-Python reference;
 - ``Z3Index`` / ``Z2Index.scan_config`` against a reference built here
   from the LIST api the way the indexes built their configs before the
-  arrays went straight through: every array field equal, dtype included;
+  arrays went straight through (the ranges cover the f32 mask's boxes,
+  containment is the f64 boxes'): every array field equal, dtype included;
 - planning a bbox + DURING filter constructs no ``IndexRange``.
 """
 
@@ -174,6 +175,31 @@ def _sft():
     return sft
 
 
+def _mask_ranges(sfc, curve, bounds, window=None):
+    """The list api over the boxes the device mask keeps (``widen_boxes``:
+    an aggregation counts whatever the mask keeps, so the ranges have to
+    reach it), containment two cells inside the f64 boxes as ``inner=True``
+    has it."""
+    def corners(boxes):
+        lo, hi = [], []
+        for xmin, ymin, xmax, ymax in boxes:
+            a = [sfc.lon.normalize_one(xmin), sfc.lat.normalize_one(ymin)]
+            z = [sfc.lon.normalize_one(xmax), sfc.lat.normalize_one(ymax)]
+            if window is not None:
+                a.append(sfc.time.normalize_one(window[0]))
+                z.append(sfc.time.normalize_one(window[1]))
+            lo.append(a)
+            hi.append(z)
+        return lo, hi
+
+    lo, hi = corners(widen_boxes(bounds).astype(np.float64).tolist())
+    ilo, ihi = corners(bounds)
+    return zr.zranges(
+        curve, [zr.ZBox(tuple(a), tuple(z)) for a, z in zip(lo, hi)],
+        inner_boxes=[zr.ZBox(tuple(v + 2 for v in a), tuple(max(v, 2) - 2 for v in z))
+                     for a, z in zip(ilo, ihi)])
+
+
 def _z3_reference(idx: Z3Index, f):
     """``Z3Index.scan_config``'s array fields, from ``Z3SFC.ranges`` (the
     list of objects) with one call a distinct offset window, as the index
@@ -196,7 +222,7 @@ def _z3_reference(idx: Z3Index, f):
     bins, los, his, ilos, ihis = (np.concatenate(c) for c in cols)
     range_bins, range_lo, range_hi, range_cont = [], [], [], []
     for lo_off, hi_off in set(zip(los.tolist(), his.tolist())):
-        ranges = idx.sfc.ranges(bounds, [(float(lo_off), float(hi_off))], inner=True)
+        ranges = _mask_ranges(idx.sfc, Z3, bounds, (float(lo_off), float(hi_off)))
         if not ranges:
             continue
         rlo = np.array([r.lower for r in ranges], dtype=np.uint64)
@@ -222,7 +248,7 @@ def _z3_reference(idx: Z3Index, f):
 def _z2_reference(idx: Z2Index, f):
     geoms = extract_geometries(f, idx.geom)
     bounds = geometry_bounds(geoms)
-    ranges = idx.sfc.ranges(bounds, inner=True)
+    ranges = _mask_ranges(idx.sfc, Z2, bounds)
     return {
         "range_bins": np.zeros(len(ranges), dtype=np.int32),
         "range_lo": np.array([r.lower for r in ranges], dtype=np.uint64),
