@@ -20,10 +20,15 @@ Two backends per kernel:
 - Pallas (TPU): scalar-prefetched block DMA; density accumulates the grid
   in VMEM via an MXU one-hot matmul histogram (no scatter — TPU has no
   fast vector scatter, but ``A^T @ B`` over one-hot pixel-coordinate
-  planes IS the histogram), bounds reduce per-block on the VPU.
+  planes IS the histogram), contracted per block over the window of the
+  grid that the block's rows touch (the table is sorted by its curve, so
+  they are neighbours on the map; a block spread wider takes the whole
+  grid, one with no row in the tile takes nothing), bounds reduce
+  per-block on the VPU.
 
 Pad slots are -1 (``pad_bids(..., pad=-1)``): the XLA path masks them out,
-the Pallas index map clamps them to block 0 and the kernel masks them.
+the Pallas index map clamps them to block 0 and the kernels mask them (the
+density kernel skips them before any vector work).
 Sharded tables run these same kernels per shard under ``shard_map`` and
 merge with ``psum`` (geomesa_tpu.parallel.dtable), the analogue of the
 client-side reducer merging coprocessor partials.
@@ -114,7 +119,7 @@ def _pops_xla(cols3, bids, boxes, wins, *, col_names, has_boxes, has_windows, ex
 
 def block_density(
     cols3, bids, boxes, wins, grid_bounds, *,
-    col_names, has_boxes, has_windows, extent, width, height,
+    col_names, has_boxes, has_windows, extent, width, height, counts=False,
 ):
     """[height, width] f32 density grid over ``grid_bounds`` (x0,y0,x1,y1).
 
@@ -123,18 +128,24 @@ def block_density(
     are dropped, not clamped — DensityScan only renders within bounds).
     bids: i32 [M], -1 = pad slot. grid_bounds: f32 [4] (rides the jit
     dispatch — the envelope is dynamic, only width/height are compiled in).
-    """
+
+    ``counts=True`` returns ``(grid, counts)``: the Pallas kernel's i32[3]
+    device array of slots by path (skipped, windowed, whole; see
+    ``_make_density_kernel``), None from the XLA twin, which has one path.
+    The same program runs either way."""
     kw = dict(
         col_names=col_names, has_boxes=has_boxes, has_windows=has_windows,
         extent=extent, width=width, height=height,
     )
     ch = _density_chunk(width, height, cols3[0].shape[1], len(col_names))
     if ch is not None and bk.use_pallas():
-        return _pallas_density(
+        grid, paths = _pallas_density(
             cols3, bids, boxes, wins, grid_bounds,
             interpret=jax.default_backend() != "tpu", chunk=ch, **kw,
         )
-    return _xla_density(cols3, bids, boxes, wins, grid_bounds, **kw)
+    else:
+        grid, paths = _xla_density(cols3, bids, boxes, wins, grid_bounds, **kw), None
+    return (grid, paths) if counts else grid
 
 
 @partial(
@@ -173,6 +184,12 @@ def _xla_density(
 # busy (one dot per chunk instead of one per sublane).
 _DENSITY_CHUNK = 32
 
+# density window: the grid rows one contraction covers when a block's rows
+# fall inside them and inside one 128-lane tile (see _make_density_kernel).
+# Chosen on a v5e over 2^27 GPS points: 32 and 64 rows cost the same slot
+# (the MXU loads 128 weight tiles a block whatever the height) and catch
+# the same blocks to a point; 128 rows cost half as much again in planes.
+_DENSITY_WINDOW_ROWS = 32
 
 
 def _density_chunk(width, height, sub, n_cols) -> int | None:
@@ -198,47 +215,108 @@ def _make_density_kernel(col_names, has_boxes, has_windows, extent, width, heigh
     one-hot planes: for each row r with pixel (py, px), grid = Ay^T-style
     contraction of Ay[h, r] = (py_r == h) against Ax[w, r] = (px_r == w)
     masked — both built with broadcasted_iota compares in VMEM, contracted
-    on the MXU. The grid accumulates in VMEM
-    across grid steps (init at step 0), padded to
-    (8, 128)-aligned (hp, wp); the host slices to (height, width)."""
+    on the MXU. The grid accumulates in VMEM across grid steps (init at
+    step 0), padded to (8, 128)-aligned (hp, wp); the host slices to
+    (height, width).
+
+    The table is sorted by its curve, so a block's rows are neighbours on
+    the map and most of those planes would be zeros. Per slot the kernel
+    therefore takes the pixel extent of the block's masked rows and
+    contracts over what they touch, no more:
+    - nothing (a pad slot, known from the prefetched id; a block with no
+      row inside box and envelope): no planes, no contraction;
+    - a window of ``_DENSITY_WINDOW_ROWS`` grid rows starting at a multiple
+      of 8, inside one 128-lane tile: planes relative to the window's
+      origin, one [rows, 128] contraction, added to that part of the grid;
+    - else the whole (hp, wp) grid.
+    The pixels, the mask and the f32 sums are the same in every path, so
+    the grid is the same array bit for bit; only multiplications by zero
+    are left out. ``cnt_ref`` (SMEM, i32[3]) counts the slots by path:
+    skipped, windowed, whole."""
     import jax.experimental.pallas as pl
 
     n = len(col_names)
+    hb = min(_DENSITY_WINDOW_ROWS, hp)
+    can_window = hb < hp or wp > bk.LANES  # else the window IS the grid
 
-    def kernel(bids_ref, boxes_ref, wins_ref, gb_ref, *refs):
-        cols = {name: refs[k][0] for k, name in enumerate(col_names)}
-        out_ref = refs[n]
-        i = pl.program_id(0)
-
-        @pl.when(i == 0)
-        def _():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        w, _ = bk._masks(cols, boxes_ref, wins_ref, has_boxes, has_windows, extent)
-        x, y = _rep_xy(cols, extent)
-        x0, y0 = gb_ref[0, 0], gb_ref[0, 1]
-        x1, y1 = gb_ref[0, 2], gb_ref[0, 3]
-        m = (
-            w & (bids_ref[i] >= 0)
-            & (x >= x0) & (x <= x1) & (y >= y0) & (y <= y1)
-        )
-        px = jnp.clip(((x - x0) / (x1 - x0) * width).astype(jnp.int32), 0, width - 1)
-        py = jnp.clip(((y - y0) / (y1 - y0) * height).astype(jnp.int32), 0, height - 1)
-        pix_y = jnp.where(m, py, -1)  # -1 matches no iota row: mask rides Ay
-        acc = jnp.zeros((hp, wp), jnp.float32)
+    def histogram(pix_y, pix_x, rows, lanes):
+        """[rows, lanes] f32 counts of one block: pix_y -1 matches no iota
+        row, so the mask rides Ay."""
+        acc = jnp.zeros((rows, lanes), jnp.float32)
         for c in range(sub // ch):
             yy = pix_y[c * ch : (c + 1) * ch, :].reshape(1, ch * bk.LANES)
-            xx = px[c * ch : (c + 1) * ch, :].reshape(1, ch * bk.LANES)
-            ay = (lax.broadcasted_iota(jnp.int32, (hp, ch * bk.LANES), 0) == yy).astype(
+            xx = pix_x[c * ch : (c + 1) * ch, :].reshape(1, ch * bk.LANES)
+            ay = (lax.broadcasted_iota(jnp.int32, (rows, ch * bk.LANES), 0) == yy).astype(
                 jnp.bfloat16
             )
-            ax = (lax.broadcasted_iota(jnp.int32, (wp, ch * bk.LANES), 0) == xx).astype(
+            ax = (lax.broadcasted_iota(jnp.int32, (lanes, ch * bk.LANES), 0) == xx).astype(
                 jnp.bfloat16
             )
             acc += lax.dot_general(
                 ay, ax, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             )
-        out_ref[...] += acc
+        return acc
+
+    def kernel(bids_ref, boxes_ref, wins_ref, gb_ref, *refs):
+        out_ref, cnt_ref = refs[n], refs[n + 1]
+        i = pl.program_id(0)
+
+        @pl.when(i == 0)
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)
+            for k in range(3):
+                cnt_ref[k] = 0
+
+        @pl.when(bids_ref[i] < 0)
+        def _():
+            cnt_ref[0] += 1
+
+        @pl.when(bids_ref[i] >= 0)
+        def _():
+            cols = {name: refs[k][0] for k, name in enumerate(col_names)}
+            w, _ = bk._masks(cols, boxes_ref, wins_ref, has_boxes, has_windows, extent)
+            x, y = _rep_xy(cols, extent)
+            x0, y0 = gb_ref[0, 0], gb_ref[0, 1]
+            x1, y1 = gb_ref[0, 2], gb_ref[0, 3]
+            m = w & (x >= x0) & (x <= x1) & (y >= y0) & (y <= y1)
+            px = jnp.clip(((x - x0) / (x1 - x0) * width).astype(jnp.int32), 0, width - 1)
+            py = jnp.clip(((y - y0) / (y1 - y0) * height).astype(jnp.int32), 0, height - 1)
+            # the extent of the masked rows' pixels, as scalars
+            ylo = jnp.min(jnp.where(m, py, hp))
+            yhi = jnp.max(jnp.where(m, py, -1))
+            hit = yhi >= 0
+            whole = hit
+            if can_window:
+                xlo = jnp.min(jnp.where(m, px, wp))
+                xhi = jnp.max(jnp.where(m, px, -1))
+                # window origin: a sublane tile (>> 3: 8 rows; past the grid's
+                # last rows it slides up) and a lane tile (>> 7: bk.LANES)
+                wy = jnp.minimum((ylo >> 3) << 3, hp - hb)
+                tx = xlo >> 7
+                fits = hit & (yhi < wy + hb) & ((xhi >> 7) == tx)
+                whole = hit & jnp.logical_not(fits)
+
+                @pl.when(fits)
+                def _():
+                    acc = histogram(
+                        jnp.where(m, py - wy, -1), px - (tx << 7), hb, bk.LANES
+                    )
+                    rows = pl.ds(pl.multiple_of(wy, 8), hb)
+                    for t in range(wp // bk.LANES):
+                        @pl.when(tx == t)
+                        def _():
+                            out_ref[rows, t * bk.LANES : (t + 1) * bk.LANES] += acc
+
+                    cnt_ref[1] += 1
+
+            @pl.when(whole)
+            def _():
+                out_ref[...] += histogram(jnp.where(m, py, -1), px, hp, wp)
+                cnt_ref[2] += 1
+
+            @pl.when(jnp.logical_not(hit))
+            def _():
+                cnt_ref[0] += 1
 
     return kernel
 
@@ -254,6 +332,7 @@ def _pallas_density(
     cols3, bids, boxes, wins, grid_bounds, *,
     col_names, has_boxes, has_windows, extent, width, height, interpret, chunk,
 ):
+    """-> ([height, width] f32 grid, i32[3] slots skipped, windowed, whole)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -277,16 +356,22 @@ def _pallas_density(
             pl.BlockSpec((1, SUB, bk.LANES), lambda i, bids: (jnp.maximum(bids[i], 0), 0, 0))
             for _ in col_names
         ],
-        out_specs=pl.BlockSpec((hp, wp), lambda i, bids: (0, 0)),
+        out_specs=[
+            pl.BlockSpec((hp, wp), lambda i, bids: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+        ],
     )
-    grid = pl.pallas_call(
+    grid, counts = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hp, wp), jnp.float32),
+        out_shape=[
+            jax.ShapeDtypeStruct((hp, wp), jnp.float32),
+            jax.ShapeDtypeStruct((3,), jnp.int32),
+        ],
         interpret=interpret,
         name=DENSITY_NAME,
     )(bids, boxes, wins, gb, *cols3)
-    return grid[:height, :width]
+    return grid[:height, :width], counts
 
 
 # ---------------------------------------------------------------- bounds

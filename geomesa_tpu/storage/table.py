@@ -1343,16 +1343,22 @@ class IndexTable(SortedKeys):
         _oadd("blocks", n_real)
         _oadd("slots", len(bids))
         _oevent("enqueue")
-        grid = aggregations.block_density(
+        grid, paths = aggregations.block_density(
             self._cols_args(names), bids, boxes, wins, grid_bounds,
-            width=width, height=height, **self._kernel_kwargs(config, names),
+            width=width, height=height, counts=True,
+            **self._kernel_kwargs(config, names),
         )
         if hasattr(grid, "copy_to_host_async"):
             grid.copy_to_host_async()
 
         def finish():
-            _await_device(grid)
-            return np.asarray(jax.device_get(grid))
+            # the kernel's slot counts are the span's: no span, no pull
+            if not _await_device(grid) or paths is None:
+                return np.asarray(jax.device_get(grid))
+            out, by_path = jax.device_get((grid, paths))
+            for name, n in zip(("skipped", "windowed", "whole"), by_path):
+                _oadd(name, int(n))
+            return np.asarray(out)
 
         return finish
 

@@ -7,8 +7,9 @@ cell ``osm-gpx.heatmap``) at a small size on the CPU:
     for the test: ``_full_or`` taken, the grid equal to the laddered one,
     ``full`` = 1 on the span;
 (c) the ``density`` root's spans (docs/observability.md; ``full`` is new)
-    on a one-chip and on a four-device store; an untraced density is the
-    traced one;
+    on a one-chip and on a four-device store; the ``agg`` span's counts of
+    the kernel's slots by path (PR 34); an untraced density is the traced
+    one and pulls the grid alone;
 (d) ``datagen/osm_gpx.py``: a seed gives the same columns twice, times
     ascend, no zoom-0 square of the tile pyramid holds 6.25% of the rows;
     and PERF.md section 7 (u), repaired in PR 33: a dense city ON a tile's
@@ -239,12 +240,101 @@ def test_density_spans_count_blocks_slots_full_and_time_the_grid(
     assert set(agg.attrs["segments"]) == {"wait", "pull"}
 
 
-def test_an_untraced_density_is_the_traced_one(bench, one, traced):
-    _, with_spans = _tile(bench, one, 2, 4, 2)
-    conf.OBS_TRACE_SAMPLE.clear()
+@pytest.fixture()
+def pallas():
+    """The Pallas density kernel in interpret mode: the XLA twin a CPU takes
+    by default has one path and counts none."""
+    conf.PALLAS_MODE.set("1")
+    yield
+    conf.PALLAS_MODE.clear()
+
+
+def _paths(span):
+    return [span.attrs[k] for k in ("skipped", "windowed", "whole")]
+
+
+def test_the_agg_span_counts_the_kernels_slots_by_path(bench, one, traced, pallas):
+    """PR 34: the kernel contracts a block over the window of the grid its
+    rows touch, and says how many slots took which path. The ``agg`` span
+    carries the three counts; they sum to the ``dispatch`` span's slots,
+    and ``skipped`` holds at least the bucket's padding."""
+    _, grid = _tile(bench, one, 1, 2, 1)
+    (tr,) = traced()
+    (d,) = [s for s in _spans(tr, "dispatch") if "slots" in (s.attrs or {})]
+    (agg,) = _spans(tr, "agg")
+    skipped, windowed, whole = _paths(agg)
+    assert skipped + windowed + whole == d.attrs["slots"]
+    assert skipped >= d.attrs["slots"] - d.attrs["blocks"]
+    assert windowed + whole > 0 and grid.sum() > 0
+    assert set(agg.attrs["segments"]) == {"wait", "pull"}
+    conf.PALLAS_MODE.set("0")  # the XLA twin: the same grid, nothing to count
     obs.install(obs.Tracer())
-    _, without = _tile(bench, one, 2, 4, 2)
+    _, twin = _tile(bench, one, 1, 2, 1)
+    (tr,) = traced()
+    (agg,) = _spans(tr, "agg")
+    assert np.array_equal(twin, grid) and "windowed" not in agg.attrs
+
+
+def test_past_the_ladder_skipped_counts_the_blocks_outside_the_tile(
+        bench, one, traced, pallas, monkeypatch):
+    """The whole-table shape hands the kernel every block; those with no
+    row in the tile cost it a mask and no contraction."""
+    table = one.ds.table(one.type_name, "z2")
+    _, laddered = _tile(bench, one, 0, 1, 0)
+    (tr,) = traced()
+    (d,) = [s for s in _spans(tr, "dispatch") if "slots" in (s.attrs or {})]
+    asked = d.attrs["blocks"]
+    inside = sum(_paths(_spans(tr, "agg")[0])[1:])  # candidates that hold a row of it
+    assert 0 < inside <= asked < table.n_blocks
+    monkeypatch.setattr(bk, "M_BUCKETS", (4, 8))
+    obs.install(obs.Tracer())
+    _, whole_table = _tile(bench, one, 0, 1, 0)
+    (tr,) = traced()
+    (d,) = [s for s in _spans(tr, "dispatch") if "slots" in (s.attrs or {})]
+    assert d.attrs["full"] == 1 and d.attrs["slots"] == table.n_blocks
+    skipped, windowed, whole = _paths(_spans(tr, "agg")[0])
+    assert skipped == table.n_blocks - inside and windowed + whole == inside
+    assert np.array_equal(whole_table, laddered)
+
+
+def test_density_windowed_pct_reads_the_agg_spans_of_density_roots(bench):
+    """``benchmark/layer_metrics/density_windowed_pct.py``: windowed over
+    windowed + whole, summed over the window; spans that count neither (the
+    program before PR 34, the XLA twin, a mesh store) are nothing to read."""
+    from layer_metrics import density_windowed_pct as reader
+
+    def span(i, root, **attrs):
+        return {"id": i, "name": "agg", "root": root, "parent": 0, "attrs": attrs, "dur_s": 0.001}
+
+    counted = [span(1, "density", skipped=600, windowed=3400, whole=96, segments={}),
+               span(2, "density", skipped=20, windowed=0, whole=12),
+               span(3, "density", skipped=32, windowed=0, whole=0)]
+    other = [span(4, "density", segments={"wait": 0.001}), span(5, "count", windowed=9, whole=1)]
+    assert reader.read({"spans": counted + other + counted}) == 100.0 * 3400 / 3508
+    assert reader.read({"spans": other}) is None and reader.read({"spans": counted[2:]}) is None
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_an_untraced_density_is_the_traced_one(kernel, bench, one, traced, monkeypatch):
+    """... and pays no pull beyond the grid's: the kernel's slot counts are
+    fetched with the grid, in one ``device_get``, only under a span."""
+    import jax
+
+    if kernel == "pallas":
+        conf.PALLAS_MODE.set("1")
+    pulled = []
+    real = jax.device_get
+    monkeypatch.setattr(jax, "device_get", lambda x: pulled.append(x) or real(x))
+    try:
+        _, with_spans = _tile(bench, one, 2, 4, 2)
+        assert len(pulled) == 1 and isinstance(pulled[0], tuple) == (kernel == "pallas")
+        conf.OBS_TRACE_SAMPLE.clear()
+        obs.install(obs.Tracer())
+        _, without = _tile(bench, one, 2, 4, 2)
+    finally:
+        conf.PALLAS_MODE.clear()
     assert not obs.tracer().traces() and np.array_equal(with_spans, without)
+    assert len(pulled) == 2 and not isinstance(pulled[1], tuple)
 
 
 # ---------------------------------------------------------------- (d) the data
