@@ -155,6 +155,9 @@ class DataStore:
         config; a QueryCache instance is used directly (e.g. shared
         across a reload via ``persist.load(root, cache=...)``). Default
         None = no caching."""
+        from geomesa_tpu.obs.trace import hook_compiler
+
+        hook_compiler()  # compiles onto the stall record (docs/observability.md)
         self._schemas: dict[str, FeatureType] = {}
         # features live as a list of write-batch chunks (LSM memtable
         # pattern): writes append O(batch); the concatenated view is built

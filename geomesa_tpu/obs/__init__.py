@@ -46,6 +46,8 @@ from geomesa_tpu.obs.trace import (
     install,
     phase_breakdown,
     span,
+    stall_totals,
+    stalls,
     tracer,
 )
 
@@ -67,6 +69,8 @@ __all__ = [
     "ops_report",
     "phase_breakdown",
     "span",
+    "stall_totals",
+    "stalls",
     "stats_payload",
     "tracer",
 ]

@@ -12,6 +12,7 @@ already computes in-process (docs/observability.md "The ops plane"):
 | ``/stats`` | per-type StatsStore sketches as JSON |
 | ``/debug/slow?type=&n=`` | the slow-query ring (filterable) |
 | ``/debug/trace`` | Chrome trace-event export of retained traces |
+| ``/debug/stalls?n=`` | the runtime-stall ring (collections, compiles) |
 | ``/debug/vars?window=`` | TelemetryRecorder time-series rings |
 | ``/debug/audit?n=`` | the audit ring (trace-id cross-referenced) |
 
@@ -71,7 +72,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qs, urlparse
 
 from geomesa_tpu import conf
-from geomesa_tpu.metrics import resolve
+from geomesa_tpu.metrics import _prom, resolve
 
 #: pending hot-tier rows over this multiple of the fold threshold flag
 #: ``hot.occupancy`` — the overlay outgrew what one fold was sized to
@@ -396,7 +397,7 @@ class OpsRoutes:
     #: paths this table answers (the data server's dispatch check)
     PATHS = (
         "/metrics", "/health", "/stats", "/debug/slow", "/debug/trace",
-        "/debug/vars", "/debug/audit", "/debug/tuning",
+        "/debug/vars", "/debug/audit", "/debug/tuning", "/debug/stalls",
     )
 
     def __init__(self, store, lam=None, audit=None):
@@ -415,7 +416,9 @@ class OpsRoutes:
         if path == "/metrics":
             # Render the same registry the serving path counts into: a store
             # without its own registry instruments the process-global one.
-            return 200, "text/plain; version=0.0.4", metrics.render_prometheus()
+            return 200, "text/plain; version=0.0.4", (
+                metrics.render_prometheus() + runtime_families()
+            )
         if path == "/health":
             report = self.monitor.evaluate()
             code = 503 if report["status"] == "unhealthy" else 200
@@ -437,6 +440,12 @@ class OpsRoutes:
             return 200, "application/json", _json_dump(
                 tracer().chrome_payload()
             )
+        if path == "/debug/stalls":
+            from geomesa_tpu.obs.trace import stalls
+
+            ring = stalls()
+            n = int(_first(query, "n") or 0)
+            return 200, "application/json", _json_dump(ring[-n:] if n > 0 else ring)
         if path == "/debug/vars":
             window = _first(query, "window")
             return 200, "application/json", _json_dump(
@@ -464,6 +473,43 @@ class OpsRoutes:
         return 404, "application/json", _json_dump(
             {"error": f"unknown path {path!r}"}
         )
+
+
+def runtime_families() -> str:
+    """The process's runtime stalls as Prometheus families, read from
+    ``obs.trace``'s totals as the scrape renders (the hooks push
+    nothing: the collector's may not touch a registry). They are the
+    process's, so every store's ``/metrics`` shows the same."""
+    from geomesa_tpu.obs.trace import stall_totals
+
+    tot = stall_totals()
+    lines: list = []
+
+    def family(name: str, kind: str, samples) -> None:
+        lines.append(f"# TYPE {_prom(name)} {kind}")
+        lines.extend(f"{_prom(name)}{labels} {value}" for labels, value in samples)
+
+    # ``counter`` and ``gauge`` by name: the static analysis takes a name
+    # passed to a call so named for a metric instrument (docs cite these)
+    def counter(name: str, samples) -> None:
+        family(name, "counter", samples)
+
+    def gauge(name: str, value: float) -> None:
+        family(name, "gauge", [("", value)])
+
+    gens = sorted(tot["gc"].items())
+    counter("geomesa.runtime.gc.collections",
+            [(f'{{generation="{g[3:]}"}}', t["n"]) for g, t in gens])
+    counter("geomesa.runtime.gc.seconds",
+            [(f'{{generation="{g[3:]}"}}', t["s"]) for g, t in gens])
+    gauge("geomesa.runtime.gc.max_seconds", max(t["max_s"] for _, t in gens))
+    programs = tot["compile"].values()
+    counter("geomesa.runtime.compile.seconds",
+            [(f'{{phase="{ph}"}}', sum(p[ph] for p in programs))
+             for ph in ("trace", "lower", "backend")])
+    counter("geomesa.runtime.compile.programs", [("", sum(p["calls"] for p in programs))])
+    counter("geomesa.query.compiled", [("", tot["compiled"])])
+    return "\n".join(lines) + "\n"
 
 
 class OpsServer:

@@ -38,21 +38,41 @@ thread's own line beside the device ops (no session active: a no-op;
 ``jax`` is imported by the first retained span, never before). All of it
 rides ``attrs``, the one carrier every reader and exporter passes on.
 
+**Runtime stalls** (docs/observability.md "Runtime stalls"): two things
+stop this process that belong to no span, CPython's collector and JAX's
+compiler. Both announce themselves through a hook (``gc.callbacks``;
+``jax.monitoring``'s listeners), and both hooks write into ONE
+process-wide record (:func:`stalls`, :func:`stall_totals`: a bounded
+ring and totals) and onto the span that was open on the thread
+(``gc_s``, ``gc_n``; ``compile_s``, ``compile_n``). The record is the
+process's, not a :class:`Tracer`'s: ``install`` and ``reset`` leave it,
+:func:`clear_stalls` empties it. The collector's hook goes in when a root
+first finds tracing armed, the compiler's when the first ``DataStore``
+is built. A collection stops every thread, so ``Tracer.end`` stamps
+``gc_wait_s`` on each root it finishes: the seconds of the ring's pauses
+that overlap it.
+
 Locking: ``Tracer._lock`` (LOCKS rank 76, hot) guards only the
 retention rings and the sampling counter — it is taken once per root
 begin/end, never per child span (children append to their trace's own
 span list, a GIL-atomic ``list.append``; see :class:`Span`), and
 nothing blocking runs under it. Span finish never acquires it, so
-spans are safe to close while arbitrary store locks are held.
+spans are safe to close while arbitrary store locks are held. The
+stall record has NO lock: the collector's hook runs wherever this thread
+happens to be, under any lock it holds (``MetricsRegistry``'s and
+``Tracer._lock`` included), so it appends to structures that need none
+and calls no registry and no logger.
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
 import threading
 import time
+from collections import deque
 from typing import Optional
 
 from geomesa_tpu import conf
@@ -302,26 +322,332 @@ class _Activation:
             _tls.span = self._prev
 
 
-class TraceBuffer:
-    """Bounded ring of finished traces (plain list + cap: the buffer is
-    only touched under ``Tracer._lock``)."""
-
-    __slots__ = ("cap", "_items")
+class TraceBuffer(deque):
+    """A bounded ring, newest last: a ``deque`` with a ``maxlen``, so an
+    append past the cap drops the oldest in the same atomic step. Holds
+    the finished traces (touched only under ``Tracer._lock``) and the
+    process's stall record (appended to with no lock at all)."""
 
     def __init__(self, cap: int):
-        self.cap = max(int(cap), 1)
-        self._items: list[Trace] = []
+        super().__init__(maxlen=max(int(cap), 1))
 
-    def append(self, trace: Trace) -> None:
-        self._items.append(trace)
-        if len(self._items) > self.cap:
-            del self._items[: len(self._items) - self.cap]
+    @property
+    def cap(self) -> int:
+        return self.maxlen
 
-    def items(self) -> list[Trace]:
-        return list(self._items)
+    def items(self) -> list:
+        while True:
+            try:
+                return list(self)
+            except RuntimeError:  # appended to meanwhile (the stall ring has no lock)
+                continue
 
-    def __len__(self) -> int:
-        return len(self._items)
+
+# -- runtime stalls ---------------------------------------------------------
+
+#: records the stall ring keeps (a set-up of the largest benchmark cell
+#: leaves a few hundred: three a compiled program, one a collection of a
+#: millisecond or more)
+STALL_RING = 8192
+#: a collection shorter than this goes to the totals only: generation 0
+#: runs hundreds of times a second
+GC_RING_S = 1e-3
+#: the short collections are summed in slices of this many seconds, the
+#: newest ``_SLICES`` of them, so that they too can be cut to a window
+_SLICE_S = 0.25
+_SLICES = 4096
+#: a compile under one of these roots stalled a request
+REQUEST_ROOTS = frozenset(("query", "query_many", "count", "density", "write"))
+
+_GC_NAMES = ("gen0", "gen1", "gen2")
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+# the persistent cache is asked (a miss until it answers), then hits
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+
+
+class _StallRecord:
+    """The state both hooks write. No lock: the ring's append is one
+    atomic step; CPython runs one collection at a time, callbacks
+    included, so the collector's totals have one writer; the compiler's
+    totals are kept a thread (each thread adds to a dict of its own,
+    registered by one atomic ``list.append``) and summed by the reader.
+    :func:`clear_stalls` swaps in a fresh instance."""
+
+    __slots__ = ("ring", "seq", "gc", "gc_short", "gc_last_end", "gc_t0",
+                 "gc_ann", "parts")
+
+    def __init__(self):
+        self.ring = TraceBuffer(STALL_RING)
+        self.seq = itertools.count()
+        # a generation: [collections, seconds, longest]
+        self.gc = [[0, 0.0, 0.0] for _ in _GC_NAMES]
+        # {slice number: [n0, s0, n1, s1, n2, s2]}, pauses under GC_RING_S
+        self.gc_short: dict = {}
+        self.gc_last_end = 0.0  # when the ring's newest pause ended
+        self.gc_t0 = None       # the running collection's start
+        self.gc_ann = None      # ... and its profiler annotation
+        # a thread that compiled: {"compiled": n, "programs": {fun_name:
+        # a _program()}}
+        self.parts: list = []
+
+
+_stalls = _StallRecord()
+_gc_hooked = False
+_compiler_hooked = False
+
+
+def _program() -> dict:
+    """A program name's totals: seconds by phase, backend phases, and
+    those of them the persistent cache answered."""
+    return {"trace": 0.0, "lower": 0.0, "backend": 0.0, "calls": 0, "hits": 0}
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks``: the pause between ``start`` and ``stop`` into
+    the totals, into the ring from ``GC_RING_S`` up, and onto this
+    thread's active span. Runs under whatever lock this thread holds:
+    takes none, calls no registry and no logger."""
+    rec = _stalls
+    gen = info["generation"]
+    if phase == "start":
+        rec.gc_t0 = time.perf_counter()
+        if _profiler is not None and gen > 0:
+            ann = _profiler.TraceAnnotation("geomesa:gc." + _GC_NAMES[gen])
+            ann.__enter__()
+            rec.gc_ann = ann
+        return
+    ann, rec.gc_ann = rec.gc_ann, None
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    now = time.perf_counter()
+    t0, rec.gc_t0 = rec.gc_t0, None
+    if t0 is None:  # hooked, or cleared, while this collection ran
+        return
+    dur = now - t0
+    tot = rec.gc[gen]
+    tot[0] += 1
+    tot[1] += dur
+    if dur > tot[2]:
+        tot[2] = dur
+    cur = getattr(_tls, "span", None)
+    if cur is not None:
+        cur.add("gc_s", dur)
+        cur.add("gc_n", 1)
+    if dur < GC_RING_S:
+        short = rec.gc_short
+        k = int(now / _SLICE_S)
+        row = short.get(k)
+        if row is None:
+            row = short[k] = [0, 0.0, 0, 0.0, 0, 0.0]
+            if len(short) > _SLICES:
+                del short[next(iter(short))]
+        row[2 * gen] += 1
+        row[2 * gen + 1] += dur
+        return
+    rec.ring.append({
+        "seq": next(rec.seq), "kind": "gc", "name": _GC_NAMES[gen], "t0": t0,
+        "dur_s": dur, "tid": threading.get_ident(),
+        "trace_id": None if cur is None else cur.trace.trace_id,
+        "collected": info.get("collected", 0),
+    })
+    rec.gc_last_end = now
+
+
+def _hook_collector() -> None:
+    global _gc_hooked
+    if not _gc_hooked:
+        _gc_hooked = True
+        gc.callbacks.append(_on_gc)
+
+
+def _on_cache(event: str, **kw) -> None:
+    kind = _CACHE_EVENTS.get(event)
+    if kind is not None:
+        _tls.cache_seen = (kind, time.perf_counter())
+
+
+def _on_compile_start(event: str, value, **kw) -> None:
+    """A phase began on this thread (JAX records its start time as a
+    scalar): one level deeper."""
+    if event in _PHASES:
+        _tls.phases = getattr(_tls, "phases", 0) + 1
+
+
+def _on_compile(event: str, secs: float, **kw) -> None:
+    """``jax.monitoring``: one of a program's three phases ended on this
+    thread (tracing to a jaxpr, lowering it to MLIR, which for a Pallas
+    kernel is Mosaic's, and the backend's compile or its fetch from the
+    persistent cache). Silent on a jit cache hit: nothing compiles.
+    Every ``jnp`` call made while a function is traced, and some made
+    while one is lowered, is a trace of its own, thousands a kernel: a
+    trace that ends inside another phase is that phase's time and is
+    left out, so a program is three records whatever it calls inside."""
+    phase = _PHASES.get(event)
+    if phase is None:
+        return
+    depth = _tls.phases = max(getattr(_tls, "phases", 1) - 1, 0)
+    if depth and phase == "trace":
+        return
+    now = time.perf_counter()
+    rec = _stalls
+    name = str(kw.get("fun_name", "?"))
+    if name.startswith("jit(") and name.endswith(")"):
+        name = name[4:-1]  # lowering and the backend name the module, tracing the function
+    held = getattr(_tls, "compiles", None)
+    if held is None or held[0] is not rec:
+        held = _tls.compiles = (rec, {"compiled": 0, "programs": {}})
+        rec.parts.append(held[1])
+    part = held[1]
+    row = part["programs"].get(name)
+    if row is None:
+        row = part["programs"][name] = _program()
+    row[phase] += secs
+    cur = getattr(_tls, "span", None)
+    out = {"seq": next(rec.seq), "kind": "compile", "name": name, "phase": phase,
+           "t0": now - secs, "dur_s": secs, "tid": threading.get_ident(),
+           "trace_id": None if cur is None else cur.trace.trace_id}
+    if cur is not None:
+        cur.add("compile_s", secs)
+    if phase == "backend":
+        row["calls"] += 1
+        seen = getattr(_tls, "cache_seen", None)
+        if seen is not None and seen[1] >= now - secs:
+            out["cache"] = seen[0]
+            row["hits"] += seen[0] == "hit"
+        if cur is not None:
+            cur.add("compile_n", 1)
+            if cur.trace.name in REQUEST_ROOTS:
+                out["root"] = cur.trace.name
+                part["compiled"] += 1
+    rec.ring.append(out)
+
+
+def hook_compiler() -> None:
+    """Put the compiler's listeners on ``jax.monitoring``, once a
+    process. The first ``DataStore`` calls it; importing ``obs`` never
+    does."""
+    global _compiler_hooked
+    if _compiler_hooked:
+        return
+    _compiler_hooked = True
+    import jax.monitoring as mon
+
+    mon.register_scalar_listener(_on_compile_start)
+    mon.register_event_duration_secs_listener(_on_compile)
+    mon.register_event_listener(_on_cache)
+
+
+def stalls() -> list:
+    """The stall ring, newest last: ``{seq, kind: "gc" | "compile", name
+    (``gen0/1/2`` or JAX's ``fun_name``), t0 (``time.perf_counter``, the
+    spans' clock), dur_s, tid, trace_id or None}``; a collection adds
+    ``collected``; a compile ``phase`` (``trace``, ``lower``,
+    ``backend``) and, on the backend phase, ``cache`` (``hit`` /
+    ``miss``, where the persistent cache was asked) and ``root`` (the
+    request it stalled)."""
+    return [dict(r) for r in _stalls.ring.items()]
+
+
+def stall_totals(t_lo: Optional[float] = None, t_hi: Optional[float] = None) -> dict:
+    """``{"gc": {gen: {"n", "s", "max_s"}}, "compile": {fun_name:
+    {"trace", "lower", "backend" (seconds), "calls", "hits"}},
+    "compiled", "dropped"}``. With no bound: the process's totals since
+    the hooks went in. With one: what ENDED inside ``[t_lo, t_hi)`` on
+    ``time.perf_counter``, summed from the ring and, for the collections
+    too short for it, from their slices (``max_s`` is then the ring's; a
+    window is right to a slice's width and while ``dropped``, the records
+    the ring has forgotten, is 0)."""
+    rec = _stalls
+    out_gc = {g: {"n": 0, "s": 0.0, "max_s": 0.0} for g in _GC_NAMES}
+    programs: dict = {}
+    compiled = 0
+    if t_lo is None and t_hi is None:
+        for g, (n, s, mx) in zip(_GC_NAMES, rec.gc):
+            out_gc[g] = {"n": n, "s": s, "max_s": mx}
+        for part in list(rec.parts):
+            compiled += part["compiled"]
+            for name, row in list(part["programs"].items()):
+                p = programs.setdefault(name, _program())
+                for key, v in row.items():
+                    p[key] += v
+    else:
+        lo = float("-inf") if t_lo is None else t_lo
+        hi = float("inf") if t_hi is None else t_hi
+        for r in rec.ring.items():
+            if not lo <= r["t0"] + r["dur_s"] < hi:
+                continue
+            if r["kind"] == "gc":
+                g = out_gc[r["name"]]
+                g["n"] += 1
+                g["s"] += r["dur_s"]
+                g["max_s"] = max(g["max_s"], r["dur_s"])
+            else:
+                p = programs.setdefault(r["name"], _program())
+                p[r["phase"]] += r["dur_s"]
+                if r["phase"] == "backend":
+                    p["calls"] += 1
+                    p["hits"] += r.get("cache") == "hit"
+                    compiled += "root" in r
+        for k, row in list(rec.gc_short.items()):
+            if lo <= k * _SLICE_S < hi:
+                for i, g in enumerate(_GC_NAMES):
+                    out_gc[g]["n"] += row[2 * i]
+                    out_gc[g]["s"] += row[2 * i + 1]
+    try:
+        dropped = rec.ring[0]["seq"]  # records are numbered from 0
+    except IndexError:
+        dropped = 0
+    return {"gc": out_gc, "compile": programs, "compiled": compiled, "dropped": dropped}
+
+
+def clear_stalls() -> None:
+    """Forget every stall (tests; ``Tracer.reset`` does not)."""
+    global _stalls
+    _stalls = _StallRecord()
+
+
+def _gc_overlap(t_lo: float, t_hi: float) -> float:
+    """Seconds of the ring's collections, of any thread, that overlap
+    ``[t_lo, t_hi]``: a walk from the newest record that stops at the
+    first one that ended before ``t_lo`` (records enter the ring as they
+    end). One comparison where no pause ended since ``t_lo``."""
+    rec = _stalls
+    if rec.gc_last_end < t_lo:
+        return 0.0
+    while True:
+        total = 0.0
+        try:
+            for r in reversed(rec.ring):
+                end = r["t0"] + r["dur_s"]
+                if end < t_lo:
+                    break
+                if r["kind"] == "gc":
+                    total += max(min(end, t_hi) - max(r["t0"], t_lo), 0.0)
+            return total
+        except RuntimeError:  # appended to meanwhile: walk again
+            continue
+
+
+def _stall_events(pid: int) -> list[dict]:
+    """The ring as Chrome trace events, each on its thread's lane."""
+    to_wall = time.time() - time.perf_counter()
+    out = []
+    for r in _stalls.ring.items():
+        name = r["name"] if r["kind"] == "gc" else f"{r['phase']} {r['name']}"
+        out.append({
+            "name": f"{r['kind']}:{name}", "ph": "X", "pid": pid, "tid": r["tid"],
+            "ts": round((r["t0"] + to_wall) * 1e6, 1),
+            "dur": round(r["dur_s"] * 1e6, 1),
+            "args": {k: v for k, v in r.items() if k not in ("t0", "dur_s", "tid")},
+        })
+    return out
 
 
 class Tracer:
@@ -371,6 +697,8 @@ class Tracer:
         slow_ms = conf.OBS_SLOW_MS.get()
         if sample <= 0 and slow_ms <= 0:
             return None
+        if not _gc_hooked:
+            _hook_collector()
         retain = False
         if sample > 0:
             with self._lock:
@@ -397,7 +725,13 @@ class Tracer:
         (retention counters) record after the lock is released."""
         if trace is None:
             return
-        trace.root.finish()
+        root = trace.root
+        root.finish()
+        # a collection stops every thread: what this root waited for the
+        # collector, whichever thread it ran on
+        waited = _gc_overlap(root.t0, root.t0 + root.dur_s)
+        if waited > 0.0:
+            root.add("gc_wait_s", waited)
         slow_ms = conf.OBS_SLOW_MS.get()
         is_slow = (
             trace.capture and slow_ms > 0 and trace.wall_s * 1e3 >= slow_ms
@@ -497,12 +831,13 @@ class Tracer:
 
     def chrome_payload(self) -> dict:
         """Every retained trace (buffer + slow ring, deduped by trace
-        id) as a Chrome trace-event payload — the ``/debug/trace``
-        body, and what :meth:`dump` writes."""
+        id) and every stall of the process's ring as a Chrome
+        trace-event payload — the ``/debug/trace`` body, and what
+        :meth:`dump` writes."""
         with self._lock:
             traces = self.buffer.items()
             slow = [e["trace"] for e in self.slow]
-        events = []
+        events = _stall_events(os.getpid())
         for tr in traces:
             events.extend(_chrome_events(tr.to_dict()))
         seen = {tr.trace_id for tr in traces}
@@ -571,7 +906,10 @@ def _chrome_events(td: dict) -> list[dict]:
 
 def phase_breakdown(trace: Optional[Trace]) -> list[str]:
     """Human-readable top-level phase lines for explain trails:
-    ``trace: <phase> <dur>ms`` per phase plus the covered fraction."""
+    ``trace: <phase> <dur>ms`` per phase plus the covered fraction, and
+    one line more where the runtime stalled the operation: what the root
+    waited for the collector (``gc_wait_s``) and what its spans spent
+    compiling (``compile_s``)."""
     if trace is None or trace.wall_s <= 0:
         return []
     lines = []
@@ -583,6 +921,13 @@ def phase_breakdown(trace: Optional[Trace]) -> list[str]:
         f"trace: wall {trace.wall_s * 1e3:.3f}ms, phases cover "
         f"{100.0 * covered / trace.wall_s:.1f}%"
     )
+    waited = (trace.root.attrs or {}).get("gc_wait_s", 0.0)
+    compiling = sum((s.attrs or {}).get("compile_s", 0.0) for s in trace.spans)
+    if waited or compiling:
+        lines.append(
+            f"trace: stalled gc {waited * 1e3:.3f}ms, "
+            f"compile {compiling * 1e3:.3f}ms"
+        )
     return lines
 
 

@@ -528,9 +528,8 @@ class DataServer:
         with _ospan("ingest.read", bytes=length):
             body = rfile.read(length)
         try:
-            with _ospan("ingest.parse") as sp:
+            with _ospan("ingest.parse"):
                 fc = self._parse_ingest(type_name, body, headers)
-                sp.annotate(rows=len(fc))
         except KeyError:
             return self._client_error(404, f"unknown type {type_name!r}")
         except Exception as e:

@@ -449,13 +449,14 @@ def test_the_served_paths_carry_the_new_spans(bench, cols, served, traced):
     for tr in posts:  # the request's root: body, parse, rows; the write names it
         (read,), (parse,), (rows,) = (_spans(tr, n) for n in (
             "ingest.read", "ingest.parse", "ingest.rows"))
-        assert read.attrs["bytes"] > 30_000 and parse.attrs["rows"] == BATCH
+        # ``ingest.parse`` carries no ``rows`` since PR 35: the ``write`` root below does
+        assert read.attrs["bytes"] > 30_000 and "rows" not in (parse.attrs or {})
         assert tr.root.attrs["status"] == 200
         write = next(w for w in by["write"] if w.trace_id == tr.root.attrs["write_trace"])
         assert write.root.attrs["http_trace"] == tr.trace_id and write.root.attrs["rows"] == BATCH
         (append,), (sync,), (upsert,) = (_spans(write, n) for n in (
             "wal.append", "wal.sync", "hot.upsert"))
-        assert sync.parent_id == append.span_id and sync.attrs == {"fsync": 1, "covered": 1}
+        assert sync.parent_id == append.span_id and (sync.attrs["fsync"], sync.attrs["covered"]) == (1, 1)
         assert upsert.parent_id == write.root.span_id and upsert.t0 >= sync.t0 + sync.dur_s
         covered = sum(s.dur_s for n in ("ingest.read", "ingest.parse", "ingest.rows")
                       for s in _spans(tr, n)) + write.root.dur_s
@@ -463,7 +464,8 @@ def test_the_served_paths_carry_the_new_spans(bench, cols, served, traced):
     # the read: hot and merge under the request's root, the delta scan in the cold scan
     (wait,), (hot,), (merge,) = (_spans(get, n) for n in ("http.wait", "hot", "merge"))
     assert hot.parent_id == merge.parent_id == wait.span_id
-    assert hot.attrs == {"hot_rows": BATCH, "hits": BATCH}
+    # (named keys, not the whole dict: a collection under a span stamps ``gc_s`` on it)
+    assert (hot.attrs["hot_rows"], hot.attrs["hits"]) == (BATCH, BATCH)
     assert merge.attrs["cold_rows"] == len(answer["ids"]) - BATCH
     assert merge.attrs["shadowed"] == 0 and merge.attrs["deduped"] == 0
     query = next(q for q in by["query"] if q.trace_id == get.root.attrs["query_trace"])

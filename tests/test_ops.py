@@ -43,8 +43,10 @@ Q = "BBOX(geom, -20, -20, 20, 20)"
 
 @pytest.fixture(autouse=True)
 def _fresh_obs():
-    """Fresh tracer + restored knobs around every test."""
+    """Fresh tracer, an empty stall record (it is the process's, and
+    ``/debug/trace`` exports it) + restored knobs around every test."""
     obs.install(obs.Tracer())
+    obs.trace.clear_stalls()
     yield
     for knob in (conf.OBS_TRACE_SAMPLE, conf.OBS_SLOW_MS,
                  conf.OBS_SLOW_MAX, conf.PLAN_ESTIMATE,
