@@ -10,7 +10,9 @@ in every image).
 
 from __future__ import annotations
 
+import functools
 import io
+import itertools
 import json
 from typing import IO
 
@@ -19,6 +21,10 @@ import numpy as np
 from geomesa_tpu import geometry as geo
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.predicates import PointColumn
+
+#: rows a page of the GeoJSON serializer where the caller names none (the
+#: served default is conf.SERVE_PAGE_ROWS, the same number)
+PAGE_ROWS = 4096
 
 FORMATS = (
     "csv", "tsv", "geojson", "wkt", "json", "gml", "arrow", "avro",
@@ -129,10 +135,9 @@ def _quote(v: str, sep: str) -> str:
 
 
 def geojson_features(fc: FeatureCollection):
-    """Per-feature GeoJSON dicts, in result order — the shared core of
-    :func:`_geojson` and the served data plane's streamed writer
-    (serving/http.py), so a paged network response is bit-identical to
-    the one-shot export by construction."""
+    """Per-feature GeoJSON dicts, in result order: the per-feature route
+    of :class:`GeoJSONChunks`, for the collections whose columns only the
+    interpreter can read."""
     geom_field = fc.sft.geom_field
     date_fields = {a.name for a in fc.sft.attributes if a.type == "Date"}
     for row in fc.to_rows():
@@ -150,6 +155,91 @@ def geojson_features(fc: FeatureCollection):
         }
 
 
+@functools.lru_cache(maxsize=4096)
+def _member(name: str) -> bytes:
+    return (json.dumps(name) + ": ").encode()
+
+
+def _native_columns(fc: FeatureCollection):
+    """The collection as native.GeoJSONColumns where every column is one
+    the native serializer reads without the interpreter (``<U``, bool,
+    int and float arrays, a PointColumn as the geometry, an int64 Date,
+    int64 or ``<U`` ids), else None: object columns (``None`` cells,
+    lists, bytes), packed geometries, no native tier."""
+    from geomesa_tpu import native
+
+    geom_field = fc.sft.geom_field
+    xy = None
+    if geom_field is not None:
+        col = fc.columns.get(geom_field)
+        if not isinstance(col, PointColumn):
+            return None
+        xy = (col.x, col.y)
+    dates = {a.name for a in fc.sft.attributes if a.type == "Date"}
+    return native.GeoJSONColumns.of(fc.ids, xy, [
+        (_member(k), col, k in dates)
+        for k, col in fc.columns.items() if k != geom_field
+    ])
+
+
+class GeoJSONChunks:
+    """The GeoJSON document of a collection as ASCII byte chunks, a page
+    of ``page_rows`` features each: exactly ``json.dumps`` of the
+    FeatureCollection dict (member order, ``", "`` and ``": "``,
+    ``ensure_ascii``), whatever the page size. The ONE serializer behind
+    :func:`_geojson` and the served data plane (serving/http.py), with
+    two routes picked from what the columns are: a page is one call of
+    ``native.geojson_features`` (no interpreter lock held) where
+    :func:`_native_columns` can describe the collection, and
+    ``json.dumps`` over :func:`geojson_features`' dicts where it cannot,
+    or from the first page that holds a value whose text the native code
+    leaves undecided (NaN, an infinity, a year outside 0001-9999).
+    ``native``, once the chunks are drained: True if the native route
+    wrote every page."""
+
+    def __init__(self, fc: FeatureCollection, page_rows: int = PAGE_ROWS):
+        self.native = None
+        self._gen = self._chunks(fc, max(int(page_rows), 1))
+
+    def __iter__(self):
+        return self
+
+    def __next__(self) -> bytes:
+        return next(self._gen)
+
+    def _pages(self, fc, step):
+        from geomesa_tpu import native
+
+        table = _native_columns(fc)
+        self.native = table is not None
+        lo = 0
+        while self.native and lo < len(fc):
+            page = native.geojson_features(table, lo, lo + step)
+            if page is None:
+                self.native = False
+            else:
+                yield page
+                lo += step
+        if lo < len(fc):
+            feats = itertools.islice(geojson_features(fc), lo, None)
+            for batch in itertools.batched(feats, step):
+                yield ", ".join(map(json.dumps, batch)).encode()
+
+    def _chunks(self, fc, step):
+        held = [b'{"type": "FeatureCollection", "features": [']
+        for k, page in enumerate(self._pages(fc, step)):
+            if k:
+                yield b"".join(held)
+                held = [b", "]
+            held.append(page)
+        crs = geojson_crs(fc)
+        if crs is not None:
+            held.append(b'], "crs": ' + json.dumps(crs).encode() + b"}")
+        else:
+            held.append(b"]}")
+        yield b"".join(held)  # an answer of one page is one chunk, copied once
+
+
 def geojson_crs(fc: FeatureCollection) -> "dict | None":
     """The legacy named-CRS member for non-WGS84 collections (None for
     EPSG:4326). RFC 7946 mandates WGS84; reprojected collections carry
@@ -165,11 +255,7 @@ def geojson_crs(fc: FeatureCollection) -> "dict | None":
 
 
 def _geojson(fc: FeatureCollection) -> str:
-    out = {"type": "FeatureCollection", "features": list(geojson_features(fc))}
-    crs = geojson_crs(fc)
-    if crs is not None:
-        out["crs"] = crs
-    return json.dumps(out)
+    return b"".join(GeoJSONChunks(fc)).decode("ascii")
 
 
 def _geojson_geom(g: geo.Geometry) -> dict:

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import itertools
 import logging
 import math
 import os
@@ -131,6 +132,11 @@ def _load():
             ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64,
         ]
         lib.gather_columns.restype = None
+        lib.geojson_features.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_void_p,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
+        ]
+        lib.geojson_features.restype = ctypes.c_int64
         lib.points_in_polygon_cpp.argtypes = [
             f64p, f64p, ctypes.c_int64, f64p, i64p, ctypes.c_int64, i32p, u8p
         ]
@@ -380,6 +386,90 @@ def gather_columns(table: ColumnTable, idx: np.ndarray) -> "ColumnTable | None":
         idx.ctypes.data, idx.dtype.itemsize, len(idx),
     )
     return out
+
+
+class GeoJSONColumns:
+    """What :func:`geojson_features` needs of a collection, made once an
+    answer: the arrays (kept alive here for as long as their addresses
+    are), four int64 a column (kind, address, stride, bytes an item) for
+    the ids, a point column's x and y and every property, and the
+    properties' ``"name": `` texts end to end. ``of`` gives None where
+    the library is not there or a column is one the native code cannot
+    read: the caller's per-feature route then serves the collection."""
+
+    __slots__ = ("arrays", "rows", "cols", "keys", "key_off")
+
+    #: geomesa_native.cpp's GJ_* kinds, by ``dtype.char``
+    NONE, STR, BOOL, INT, UINT, F32, F64, DATE = -1, 0, 1, 2, 3, 4, 5, 6
+    _KINDS = {
+        "U": STR, "?": BOOL, "f": F32, "d": F64,
+        **dict.fromkeys("bhilq", INT), **dict.fromkeys("BHILQ", UINT),
+    }
+
+    @classmethod
+    def of(cls, ids, xy, props) -> "GeoJSONColumns | None":
+        """``ids``: a ``<U`` or int64 array; ``xy``: a point column's two
+        float64 arrays, or None (every feature's geometry is null);
+        ``props``: (``"name": `` as json.dumps writes it, array, True for
+        a Date of int64 epoch milliseconds) in member order."""
+        if _load() is None:
+            return None
+        n = len(ids)
+        arrays = [ids, *(xy or ()), *[p[1] for p in props]]
+        first = 1 if xy is None else 3
+        flat = []
+        for j, a in enumerate(arrays):
+            if type(a) is not np.ndarray or a.ndim != 1 or len(a) != n:
+                return None
+            dt = a.dtype
+            kind, width = cls._KINDS.get(dt.char), dt.itemsize
+            if kind is None or not dt.isnative:
+                return None
+            if j == 0:
+                if kind != cls.STR and (kind, width) != (cls.INT, 8):
+                    return None
+            elif j < first:
+                if kind != cls.F64:
+                    return None
+            elif props[j - first][2]:
+                if (kind, width) != (cls.INT, 8):
+                    return None
+                kind = cls.DATE
+            try:  # the cheaper way to an address, where the buffer allows it
+                address = _ADDRESS(_BYTE.from_buffer(a))
+            except (TypeError, ValueError, BufferError):  # read-only, strided, empty
+                address = a.ctypes.data
+            flat += (kind, address, a.strides[0], width)
+        if xy is None:
+            flat[4:4] = (cls.NONE, 0, 0, 0) * 2
+        self = cls()
+        self.arrays, self.rows = arrays, n
+        self.cols = (ctypes.c_int64 * len(flat))(*flat)
+        self.keys = b"".join([p[0] for p in props])
+        self.key_off = (ctypes.c_int64 * (len(props) + 1))(
+            0, *itertools.accumulate([len(p[0]) for p in props])
+        )
+        return self
+
+
+def geojson_features(table: GeoJSONColumns, lo: int, hi: int) -> "bytes | None":
+    """The GeoJSON text of the features ``[lo, hi)``, ``", "``-joined:
+    byte for byte what ``json.dumps`` gives for the dicts
+    ``io.exporters.geojson_features`` builds, in one native call that
+    holds no interpreter lock (geomesa_native.cpp). None where a value's
+    text is not the native code's to decide (NaN, an infinity, a year
+    outside 0001-9999): the caller's per-feature route then serves the
+    collection."""
+    lo, hi = max(int(lo), 0), min(int(hi), table.rows)
+    if hi <= lo:
+        return b""
+    out = ctypes.c_void_p()
+    n = _load().geojson_features(
+        table.cols, len(table.cols) // 4, table.keys, table.key_off, lo, hi,
+        ctypes.byref(out),
+    )
+    # the bytes are the calling thread's until its next call: copied here
+    return None if n < 0 else ctypes.string_at(out, n)
 
 
 _ROW_GATHERS = {

@@ -15,8 +15,10 @@
 // Build: g++ -O3 -shared -fPIC [-fopenmp] geomesa_native.cpp -o libgeomesa_native.so
 
 #include <algorithm>
+#include <charconv>
 #include <cmath>
 #include <cstdint>
+#include <cstdlib>
 #include <cstring>
 #include <utility>
 #include <vector>
@@ -346,6 +348,267 @@ extern "C" void gather_columns(const char* const* srcs, const int64_t* widths,
     gather_columns_t<uint32_t>(srcs, widths, outs, ncols, (const uint32_t*)idx, n);
   else
     gather_columns_t<uint64_t>(srcs, widths, outs, ncols, (const uint64_t*)idx, n);
+}
+
+// ------------------------------------------- GeoJSON text of an answer
+// io/exporters.py's GeoJSON serializer for collections whose columns can
+// be read without the interpreter: the features [lo, hi) of a collection
+// as the bytes json.dumps gives for the dicts geojson_features() builds,
+// ", "-joined, in ONE call with the interpreter lock released (a served
+// store has sixteen handler threads; the per-feature route holds the lock
+// for 50-60 us a row of 27 attributes). Columns come as rows of four
+// int64: kind, address, stride in bytes, bytes an item. Row 0 is the ids
+// (GJ_STR or GJ_INT), rows 1 and 2 the point column's x and y (GJ_F64, or
+// GJ_NONE for "geometry": null), the rest the properties in member order,
+// property c's `"name": ` being keys[key_off[c] : key_off[c + 1]] as
+// json.dumps wrote it. The text is json.dumps' own: ensure_ascii escapes
+// (surrogate pairs past U+FFFF), a `<U` cell cut at its trailing NULs,
+// doubles as float.__repr__ writes them, a Date as
+// numpy.datetime64(v, "ms") with a Z. A value whose text this code does
+// not decide (NaN, an infinity, a year outside 0001-9999, a code point
+// past U+10FFFF) ends the call with -1 and the caller takes the
+// per-feature route. The bytes land in a buffer of the calling thread's
+// that lives until its next call: the caller copies them out.
+
+enum { GJ_NONE = -1, GJ_STR = 0, GJ_BOOL = 1, GJ_INT = 2, GJ_UINT = 3,
+       GJ_F32 = 4, GJ_F64 = 5, GJ_DATE = 6 };
+
+// a buffer grown past this is given back to the allocator at the thread's
+// next call (a page of 4,096 rows of the widest type is under it)
+static const size_t GJ_KEEP_BYTES = 8 << 20;
+
+struct GjBuf {  // realloc, not a vector: growing neither zero-fills nor copies twice
+  char* p = nullptr;
+  size_t cap = 0, n = 0;
+  ~GjBuf() { std::free(p); }
+  inline char* need(size_t k) {
+    if (n + k > cap) {
+      cap = std::max(std::max(cap * 2, n + k), (size_t)1 << 16);
+      p = (char*)std::realloc(p, cap);
+    }
+    return p + n;
+  }
+  inline void put(const char* s, size_t k) {
+    std::memcpy(need(k), s, k);
+    n += k;
+  }
+  template <size_t K>
+  inline void lit(const char (&s)[K]) { put(s, K - 1); }
+};
+
+static inline void gj_hex4(char* p, uint32_t c) {
+  static const char* H = "0123456789abcdef";
+  p[0] = '\\'; p[1] = 'u';
+  p[2] = H[(c >> 12) & 15]; p[3] = H[(c >> 8) & 15];
+  p[4] = H[(c >> 4) & 15]; p[5] = H[c & 15];
+}
+
+// json's py_encode_basestring_ascii over UCS4 code points
+static bool gj_string(GjBuf& b, const char* cell, int64_t width) {
+  int64_t len = width / 4;
+  uint32_t c;
+  while (len > 0) {  // numpy cuts a `<U` item at its trailing NULs
+    std::memcpy(&c, cell + (len - 1) * 4, 4);
+    if (c) break;
+    --len;
+  }
+  char* p = b.need((size_t)len * 12 + 2);
+  char* const p0 = p;
+  *p++ = '"';
+  for (int64_t i = 0; i < len; ++i) {
+    std::memcpy(&c, cell + i * 4, 4);
+    if (c >= ' ' && c <= '~' && c != '\\' && c != '"') { *p++ = (char)c; continue; }
+    char e = 0;
+    switch (c) {
+      case '\\': e = '\\'; break;
+      case '"': e = '"'; break;
+      case '\b': e = 'b'; break;
+      case '\f': e = 'f'; break;
+      case '\n': e = 'n'; break;
+      case '\r': e = 'r'; break;
+      case '\t': e = 't'; break;
+    }
+    if (e) { *p++ = '\\'; *p++ = e; continue; }
+    if (c > 0x10FFFF) return false;
+    if (c >= 0x10000) {
+      const uint32_t v = c - 0x10000;
+      gj_hex4(p, 0xd800 | ((v >> 10) & 0x3ff));
+      p += 6;
+      c = 0xdc00 | (v & 0x3ff);
+    }
+    gj_hex4(p, c);
+    p += 6;
+  }
+  *p++ = '"';
+  b.n += (size_t)(p - p0);
+  return true;
+}
+
+static inline void gj_uint(GjBuf& b, uint64_t v, bool neg) {
+  char t[24];
+  char* e = t + sizeof(t);
+  char* p = e;
+  do { *--p = (char)('0' + v % 10); v /= 10; } while (v);
+  if (neg) *--p = '-';
+  b.put(p, (size_t)(e - p));
+}
+
+static inline void gj_int(GjBuf& b, int64_t v) {
+  gj_uint(b, v < 0 ? (uint64_t)0 - (uint64_t)v : (uint64_t)v, v < 0);
+}
+
+// float.__repr__: the shortest digits that read back as v (to_chars'
+// and Python's dtoa mode 0 agree on them), laid out as format_float_short
+// lays out 'r': fixed while -4 < decimal point <= 16, else d[.ddd]e+XX
+static bool gj_double(GjBuf& b, double v) {
+  if (!std::isfinite(v)) return false;
+  char t[40];
+  const auto r = std::to_chars(t, t + sizeof(t), v, std::chars_format::scientific);
+  const char* s = t;
+  const char* const end = r.ptr;
+  char* p = b.need(48);
+  char* const p0 = p;
+  if (*s == '-') *p++ = *s++;
+  const char* e = s;
+  while (*e != 'e') ++e;
+  int x = 0;
+  for (const char* q = e + 2; q < end; ++q) x = x * 10 + (*q - '0');
+  const int decpt = (e[1] == '-' ? -x : x) + 1;
+  if (decpt <= -4 || decpt > 16) {  // to_chars wrote Python's exponent form
+    std::memcpy(p, s, (size_t)(end - s));
+    p += end - s;
+  } else {
+    char d[20];
+    int nd = 0;
+    for (const char* q = s; q < e; ++q)
+      if (*q != '.') d[nd++] = *q;
+    if (decpt <= 0) {
+      *p++ = '0'; *p++ = '.';
+      for (int i = decpt; i < 0; ++i) *p++ = '0';
+      std::memcpy(p, d, (size_t)nd);
+      p += nd;
+    } else if (decpt >= nd) {
+      std::memcpy(p, d, (size_t)nd);
+      p += nd;
+      for (int i = nd; i < decpt; ++i) *p++ = '0';
+      *p++ = '.'; *p++ = '0';
+    } else {
+      std::memcpy(p, d, (size_t)decpt);
+      p += decpt;
+      *p++ = '.';
+      std::memcpy(p, d + decpt, (size_t)(nd - decpt));
+      p += nd - decpt;
+    }
+  }
+  b.n += (size_t)(p - p0);
+  return true;
+}
+
+static inline void gj_pad(char* p, int v, int digits) {
+  for (int i = digits - 1; i >= 0; --i) { p[i] = (char)('0' + v % 10); v /= 10; }
+}
+
+// "YYYY-MM-DDTHH:MM:SS.mmmZ" of epoch milliseconds (proleptic Gregorian,
+// days from the civil epoch as in H. Hinnant's civil_from_days)
+static bool gj_date(GjBuf& b, int64_t ms) {
+  int64_t days = ms / 86400000, rem = ms % 86400000;
+  if (rem < 0) { rem += 86400000; --days; }
+  if (days < -719162 || days > 2932896) return false;  // 0001-01-01 .. 9999-12-31
+  const int64_t z = days + 719468;
+  const int64_t era = z / 146097;  // z > 0 in the range kept
+  const int64_t doe = z - era * 146097;
+  const int64_t yoe = (doe - doe / 1460 + doe / 36524 - doe / 146096) / 365;
+  const int64_t doy = doe - (365 * yoe + yoe / 4 - yoe / 100);
+  const int64_t mp = (5 * doy + 2) / 153;
+  const int day = (int)(doy - (153 * mp + 2) / 5 + 1);
+  const int month = (int)(mp < 10 ? mp + 3 : mp - 9);
+  const int year = (int)(yoe + era * 400 + (month <= 2));
+  char* p = b.need(26);
+  p[0] = '"';
+  gj_pad(p + 1, year, 4); p[5] = '-';
+  gj_pad(p + 6, month, 2); p[8] = '-';
+  gj_pad(p + 9, day, 2); p[11] = 'T';
+  gj_pad(p + 12, (int)(rem / 3600000), 2); p[14] = ':';
+  gj_pad(p + 15, (int)(rem / 60000 % 60), 2); p[17] = ':';
+  gj_pad(p + 18, (int)(rem / 1000 % 60), 2); p[20] = '.';
+  gj_pad(p + 21, (int)(rem % 1000), 3);
+  p[24] = 'Z'; p[25] = '"';
+  b.n += 26;
+  return true;
+}
+
+template <typename T>
+static inline T gj_load(const char* p) {
+  T v;
+  std::memcpy(&v, p, sizeof(T));
+  return v;
+}
+
+static bool gj_value(GjBuf& b, int64_t kind, const char* p, int64_t w) {
+  switch (kind) {
+    case GJ_STR: return gj_string(b, p, w);
+    case GJ_BOOL:
+      if (*p) b.lit("true"); else b.lit("false");
+      return true;
+    case GJ_INT:
+      gj_int(b, w == 8 ? gj_load<int64_t>(p) : w == 4 ? gj_load<int32_t>(p)
+                : w == 2 ? gj_load<int16_t>(p) : gj_load<int8_t>(p));
+      return true;
+    case GJ_UINT:
+      gj_uint(b, w == 8 ? gj_load<uint64_t>(p) : w == 4 ? gj_load<uint32_t>(p)
+                 : w == 2 ? gj_load<uint16_t>(p) : gj_load<uint8_t>(p), false);
+      return true;
+    case GJ_F32: return gj_double(b, (double)gj_load<float>(p));
+    case GJ_F64: return gj_double(b, gj_load<double>(p));
+    case GJ_DATE: return gj_date(b, gj_load<int64_t>(p));
+  }
+  return false;
+}
+
+extern "C" int64_t geojson_features(const int64_t* cols, int64_t ncols,
+                                    const char* keys, const int64_t* key_off,
+                                    int64_t lo, int64_t hi, const char** out) {
+  static thread_local GjBuf b;
+  if (b.cap > GJ_KEEP_BYTES) {
+    std::free(b.p);
+    b.p = nullptr;
+    b.cap = 0;
+  }
+  b.n = 0;
+  const int64_t* ids = cols;
+  const int64_t* xs = cols + 4;
+  const int64_t* ys = cols + 8;
+  const int64_t nprops = ncols - 3;
+  for (int64_t i = lo; i < hi; ++i) {
+    if (i > lo) b.lit(", ");
+    b.lit("{\"type\": \"Feature\", \"id\": ");
+    const char* id = (const char*)ids[1] + i * ids[2];
+    if (ids[0] == GJ_STR) {
+      if (!gj_string(b, id, ids[3])) return -1;
+    } else {  // str(int) as a JSON string
+      b.lit("\"");
+      gj_int(b, gj_load<int64_t>(id));
+      b.lit("\"");
+    }
+    if (xs[0] == GJ_NONE) {
+      b.lit(", \"geometry\": null, \"properties\": {");
+    } else {
+      b.lit(", \"geometry\": {\"type\": \"Point\", \"coordinates\": [");
+      if (!gj_double(b, gj_load<double>((const char*)xs[1] + i * xs[2]))) return -1;
+      b.lit(", ");
+      if (!gj_double(b, gj_load<double>((const char*)ys[1] + i * ys[2]))) return -1;
+      b.lit("]}, \"properties\": {");
+    }
+    for (int64_t c = 0; c < nprops; ++c) {
+      const int64_t* col = cols + 4 * (c + 3);
+      if (c) b.lit(", ");
+      b.put(keys + key_off[c], (size_t)(key_off[c + 1] - key_off[c]));
+      if (!gj_value(b, col[0], (const char*)col[1] + i * col[2], col[3])) return -1;
+    }
+    b.lit("}}");
+  }
+  *out = b.p;
+  return (int64_t)b.n;
 }
 
 // ----------------------------------------------- point-in-polygon refine

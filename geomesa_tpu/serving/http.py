@@ -11,8 +11,9 @@ callers do, plus the things only a network boundary needs
   streamed Arrow IPC stream, delivered in paged chunks
   (``geomesa.serve.page.rows`` rows per chunk) so one big result never
   head-of-line-blocks the socket — and bit-identical to the in-process
-  exporters by construction (the server composes the SAME per-feature /
-  per-batch serializers ``io/exporters.py`` and ``io/arrow.py`` use);
+  exporters by construction (a GeoJSON answer IS ``io/exporters.py``'s
+  ``GeoJSONChunks``; Arrow composes the per-batch writer ``io/arrow.py``
+  uses);
 - **a streaming ingest endpoint** (``POST /ingest/<type>``) whose 200
   acknowledgment rides :meth:`LambdaStore.write
   <geomesa_tpu.streaming.store.LambdaStore.write>`'s WAL path: when the
@@ -429,7 +430,11 @@ class DataServer:
                 return 200, ARROW_CTYPE, _arrow_chunks(fc, page_rows), extra
             except RuntimeError as e:  # pyarrow not installed
                 return self._client_error(501, str(e))
-        return 200, GEOJSON_CTYPE, _geojson_chunks(fc, page_rows), extra
+        # the exporter's own chunks: the bytes of the in-process export by
+        # construction; lazy, so the encoding runs where they are drained
+        from geomesa_tpu.io.exporters import GeoJSONChunks
+
+        return 200, GEOJSON_CTYPE, GeoJSONChunks(fc, page_rows), extra
 
     def _schema(self, type_name: str):
         if self.lam is not None:
@@ -489,6 +494,9 @@ class DataServer:
             wfile.write(b"0\r\n\r\n")
             if timed:
                 sp.annotate(bytes=sent, chunks=chunks, write_s=write_s)
+                native = getattr(payload, "native", None)
+                if native is not None:  # a GeoJSON answer: which route
+                    sp.annotate(native=int(native))
         return sent
 
     # -- POST -------------------------------------------------------------
@@ -577,30 +585,7 @@ class DataServer:
         return fc
 
 
-# -- streamed serializers (bit-identical to the one-shot exporters) -------
-
-def _geojson_chunks(fc, page_rows: int):
-    """Byte chunks whose concatenation equals the in-process GeoJSON
-    export exactly: same per-feature serializer, same separators, same
-    optional trailing crs member (io/exporters.py)."""
-    from geomesa_tpu.io.exporters import geojson_crs, geojson_features
-
-    def gen():
-        yield b'{"type": "FeatureCollection", "features": ['
-        buf: list = []
-        for i, feat in enumerate(geojson_features(fc)):
-            buf.append(("" if i == 0 else ", ") + json.dumps(feat))
-            if len(buf) >= max(int(page_rows), 1):
-                yield "".join(buf).encode()
-                buf = []
-        tail = "".join(buf) + "]"
-        crs = geojson_crs(fc)
-        if crs is not None:
-            tail += ', "crs": ' + json.dumps(crs)
-        yield (tail + "}").encode()
-
-    return gen()
-
+# -- the streamed Arrow serializer (bit-identical to the one-shot exporter) --
 
 class _ArrowSink:
     """A write-only file shim collecting the IPC writer's output so the
