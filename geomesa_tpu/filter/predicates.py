@@ -18,12 +18,14 @@ x/y — the point fast path) or a ``PackedGeometryColumn`` (extents).
 from __future__ import annotations
 
 import re
+import time
 from dataclasses import dataclass
 from typing import Mapping, Sequence
 
 import numpy as np
 
 from geomesa_tpu import geometry as geo
+from geomesa_tpu.obs.trace import tracer as _otracer
 
 
 @dataclass(frozen=True)
@@ -122,6 +124,22 @@ def _eval_spatial(col, fn_points, fn_geom, candidates=None) -> np.ndarray:
     raise TypeError(f"not a geometry column: {type(col)}")
 
 
+def _exact_tier(col, g, rows, out, span) -> None:
+    """The per-geometry exact tier of an intersects over a packed column:
+    ``out[i] = geo.intersects(geometry i, g)`` for ``rows``. ``span`` is
+    the thread's active span or None (the planner's ``decode`` when the
+    refinement runs under a trace): it gets ``refine_exact`` (geometries
+    tested here) and, where there are any, ``refine_exact_s`` (wall
+    seconds in the loop)."""
+    t0 = time.perf_counter() if span is not None else 0.0
+    for i in rows:
+        out[i] = geo.intersects(col.geometry(int(i)), g)
+    if span is not None:
+        span.add("refine_exact", len(rows))
+        if len(rows):
+            span.add("refine_exact_s", time.perf_counter() - t0)
+
+
 def _per_geom_vertex_counts(col: "geo.PackedGeometryColumn", vertex_mask):
     """How many of each geometry's pool vertices satisfy ``vertex_mask``
     ([total_verts] bool) — the cumsum reduction over the contiguous
@@ -183,12 +201,17 @@ def _packed_box_intersects(
     )
     hard = rough & ~bmask
     n_hard = int(hard.sum())
+    span = _otracer().current()
+    if span is not None:
+        # every row is decided by exactly one tier: rectangle algebra (a
+        # rectangle feature, or a bbox that misses the query's), the
+        # vertex accept tier, or the exact tier; refine_hits = rows kept
+        span.add("refine_rect", len(col) - n_hard)
     if 0 < n_hard <= 64:
         # a handful of non-rect candidates (e.g. a few odd polygons in a
         # mostly-rectangle column): the per-geometry loop beats scanning
         # the whole coords pool
-        for i in np.nonzero(hard)[0]:
-            out[i] = geo.intersects(col.geometry(int(i)), g)
+        _exact_tier(col, g, np.nonzero(hard)[0], out, span)
     elif n_hard:
         # vectorized accept tier for arbitrary (non-rectangle) geometries:
         # the query here is ALWAYS an axis-aligned rect (both call sites
@@ -204,8 +227,12 @@ def _packed_box_intersects(
         )
         any_vertex = _per_geom_vertex_counts(col, inb) > 0
         out |= hard & any_vertex
-        for i in np.nonzero(hard & ~any_vertex)[0]:
-            out[i] = geo.intersects(col.geometry(int(i)), g)
+        rest = np.nonzero(hard & ~any_vertex)[0]
+        if span is not None:
+            span.add("refine_accept", n_hard - len(rest))
+        _exact_tier(col, g, rest, out, span)
+    if span is not None:
+        span.add("refine_hits", int(out.sum()))
     return out
 
 
@@ -248,6 +275,11 @@ class Intersects(Filter):
             rough = geo.bbox_intersects(col.bboxes.astype(np.float64), q)
             out = np.zeros(len(col), dtype=bool)
             n_rough = int(rough.sum())
+            span = _otracer().current()
+            if span is not None:
+                # the tiers as _packed_box_intersects counts them; here
+                # rectangle algebra decides only the bboxes that miss
+                span.add("refine_rect", len(col) - n_rough)
             if n_rough > 64 and isinstance(g, (geo.Polygon, geo.MultiPolygon)):
                 # accept tier for a POLYGON query over arbitrary features:
                 # any feature vertex inside the query polygon proves
@@ -257,8 +289,11 @@ class Intersects(Filter):
                 n_in = _per_geom_vertex_counts(col, inside)
                 out |= rough & (n_in > 0)
                 rough &= ~out
-            for i in np.nonzero(rough)[0]:
-                out[i] = geo.intersects(col.geometry(int(i)), g)
+                if span is not None:
+                    span.add("refine_accept", n_rough - int(rough.sum()))
+            _exact_tier(col, g, np.nonzero(rough)[0], out, span)
+            if span is not None:
+                span.add("refine_hits", int(out.sum()))
             return out
         raise TypeError(f"not a geometry column: {type(col)}")
 

@@ -207,6 +207,24 @@ def test_density_1024_takes_the_xla_path():
     assert agg._density_chunk(1024, 1024, SUB, len(Z3)) is None
 
 
+FOOTPRINT_BLOCKS = 1 << 10  # osm-buildings-1chip: 2^24 footprints, four f32 bbox columns
+
+
+@pytest.mark.parametrize("m", [m for m in bk.M_BUCKETS if m <= FOOTPRINT_BLOCKS])
+def test_extent_scan_over_the_footprints_table(one_chip, m):
+    """The XZ2 table of the ``osm-buildings.intersects`` cell (1,024
+    blocks of 16,384 bboxes): the extent scan, boxes and no window, at
+    every bucket ``IndexTable.warmup`` compiles there. The cell's mix stays
+    in the first (at most 10 candidate blocks a request), its warm pass
+    reaches the second; the rest are a wider viewport's."""
+    cols = tuple(_s((FOOTPRINT_BLOCKS, SUB, bk.LANES), jnp.float32, one_chip) for _ in XZ2)
+    compiled = bk._pallas_block_scan.lower(
+        cols, _s((m,), jnp.int32, one_chip), *_params(one_chip), None, None,
+        interpret=False, n_edges=0, n_rints=0, **_flags(XZ2, False),
+    ).compile()
+    _assert_mosaic(compiled)
+
+
 # ---- the mesh forms: jit(shard_map) over the described four chips
 
 
