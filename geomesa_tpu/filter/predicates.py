@@ -125,17 +125,20 @@ def _eval_spatial(col, fn_points, fn_geom, candidates=None) -> np.ndarray:
 
 
 def _exact_tier(col, g, rows, out, span) -> None:
-    """The per-geometry exact tier of an intersects over a packed column:
-    ``out[i] = geo.intersects(geometry i, g)`` for ``rows``. ``span`` is
-    the thread's active span or None (the planner's ``decode`` when the
-    refinement runs under a trace): it gets ``refine_exact`` (geometries
-    tested here) and, where there are any, ``refine_exact_s`` (wall
-    seconds in the loop)."""
+    """The exact tier of an intersects over a packed column: ``out[rows]``
+    = whether each of those geometries intersects ``g``, decided by
+    ``geo.intersects_rows`` in one batched pass over the column's arrays
+    (points, multipoints and a query without rings: a geometry at a time).
+    ``span`` is the thread's active span or None (the planner's ``decode``
+    when the refinement runs under a trace): it gets ``refine_exact``
+    (geometries decided here), ``refine_batched`` (those of them the
+    batched pass decided) and, where there are any, ``refine_exact_s``
+    (wall seconds in the tier)."""
     t0 = time.perf_counter() if span is not None else 0.0
-    for i in rows:
-        out[i] = geo.intersects(col.geometry(int(i)), g)
+    out[rows] = geo.intersects_rows(col, rows, g)
     if span is not None:
         span.add("refine_exact", len(rows))
+        span.add("refine_batched", int(geo.flat_form_rows(col, rows, g).sum()))
         if len(rows):
             span.add("refine_exact_s", time.perf_counter() - t0)
 
@@ -190,8 +193,8 @@ def _packed_box_intersects(
     """Geometry-intersects-axis-aligned-box over a packed column.
 
     Rectangle features (geometry == bbox: footprints, tiles, extents)
-    resolve exactly with vectorized f64 bbox algebra; only non-rectangle
-    candidates fall to per-geometry exact tests."""
+    resolve exactly with vectorized f64 bbox algebra; of the others a
+    vertex inside the box accepts, and the rest go to the exact tier."""
     rough = geo.bbox_intersects(col.bboxes.astype(np.float64), q)
     bmask, bb = col.box_info()
     out = (
@@ -207,19 +210,14 @@ def _packed_box_intersects(
         # rectangle feature, or a bbox that misses the query's), the
         # vertex accept tier, or the exact tier; refine_hits = rows kept
         span.add("refine_rect", len(col) - n_hard)
-    if 0 < n_hard <= 64:
-        # a handful of non-rect candidates (e.g. a few odd polygons in a
-        # mostly-rectangle column): the per-geometry loop beats scanning
-        # the whole coords pool
-        _exact_tier(col, g, np.nonzero(hard)[0], out, span)
-    elif n_hard:
+    if n_hard:
         # vectorized accept tier for arbitrary (non-rectangle) geometries:
         # the query here is ALWAYS an axis-aligned rect (both call sites
         # gate on is_rectangle), so any geometry VERTEX inside it proves
         # intersection. Each geometry's coords are one contiguous pool
         # slice; a cumsum turns the per-vertex test into per-geometry
         # counts. Only vertex-free overlaps (rect fully inside the
-        # geometry, or pure edge crossings) fall to the per-geometry loop.
+        # geometry, or pure edge crossings) fall to the exact tier.
         c = col.coords
         inb = (
             (c[:, 0] >= q[0]) & (c[:, 0] <= q[2])
@@ -280,7 +278,7 @@ class Intersects(Filter):
                 # the tiers as _packed_box_intersects counts them; here
                 # rectangle algebra decides only the bboxes that miss
                 span.add("refine_rect", len(col) - n_rough)
-            if n_rough > 64 and isinstance(g, (geo.Polygon, geo.MultiPolygon)):
+            if n_rough and isinstance(g, (geo.Polygon, geo.MultiPolygon)):
                 # accept tier for a POLYGON query over arbitrary features:
                 # any feature vertex inside the query polygon proves
                 # intersection (one native ray cast over the coords pool)

@@ -469,6 +469,17 @@ def is_rectangle(g: "Geometry") -> bool:
 # ---------------------------------------------------------------------------
 
 
+def _expand_ranges(starts: np.ndarray, ends: np.ndarray):
+    """Concatenate aranges [starts[i], ends[i]) -> (flat int64 index list,
+    int32 offsets of each range in it)."""
+    lens = ends - starts
+    if len(lens) == 0 or lens.sum() == 0:
+        return np.zeros(0, dtype=np.int64), np.zeros(len(lens) + 1, dtype=np.int32)
+    flat = np.repeat(starts - np.concatenate([[0], np.cumsum(lens)[:-1]]), lens) + np.arange(lens.sum())
+    offsets = np.concatenate([[0], np.cumsum(lens)]).astype(np.int32)
+    return flat, offsets
+
+
 def _gather_rows(src: np.ndarray, flat: np.ndarray) -> np.ndarray:
     """out[i] = src[flat[i]] through the threaded native row gather when
     the pull is big enough to matter and the indices fit u32; the
@@ -706,24 +717,15 @@ class PackedGeometryColumn:
         if getattr(self, "_uniform_rect", False):
             return self._take_uniform_rect(idx)
 
-        def expand(starts, ends):
-            """Concatenate aranges [starts[i], ends[i]) -> flat index list."""
-            lens = ends - starts
-            if len(lens) == 0 or lens.sum() == 0:
-                return np.zeros(0, dtype=np.int64), np.zeros(1, dtype=np.int32)
-            flat = np.repeat(starts - np.concatenate([[0], np.cumsum(lens)[:-1]]), lens) + np.arange(lens.sum())
-            offsets = np.concatenate([[0], np.cumsum(lens)]).astype(np.int32)
-            return flat, offsets
-
-        p_flat, gpo = expand(
+        p_flat, gpo = _expand_ranges(
             self.geom_part_offsets[idx].astype(np.int64),
             self.geom_part_offsets[idx + 1].astype(np.int64),
         )
-        r_flat, pro = expand(
+        r_flat, pro = _expand_ranges(
             self.part_ring_offsets[p_flat].astype(np.int64),
             self.part_ring_offsets[p_flat + 1].astype(np.int64),
         )
-        c_flat, ro = expand(
+        c_flat, ro = _expand_ranges(
             self.ring_offsets[r_flat].astype(np.int64),
             self.ring_offsets[r_flat + 1].astype(np.int64),
         )
@@ -989,32 +991,35 @@ def segments_intersect(a1, a2, b1, b2) -> np.ndarray:
     """Proper-or-touching segment intersection test, vectorized.
 
     a1/a2/b1/b2: [..., 2] arrays. Standard orientation construction
-    including the collinear-overlap cases.
+    including the collinear-overlap cases. A proper crossing also needs
+    the two segments' closed bounds to overlap: true of every crossing,
+    and it keeps the rounding of four points on one line (orientation
+    signs that are noise) from reading one into segments that lie apart,
+    so a caller may leave out the pairs whose bounds miss.
     """
     a1 = np.asarray(a1, dtype=np.float64)
     a2 = np.asarray(a2, dtype=np.float64)
     b1 = np.asarray(b1, dtype=np.float64)
     b2 = np.asarray(b2, dtype=np.float64)
-    d1 = _orient(b1[..., 0], b1[..., 1], b2[..., 0], b2[..., 1], a1[..., 0], a1[..., 1])
-    d2 = _orient(b1[..., 0], b1[..., 1], b2[..., 0], b2[..., 1], a2[..., 0], a2[..., 1])
-    d3 = _orient(a1[..., 0], a1[..., 1], a2[..., 0], a2[..., 1], b1[..., 0], b1[..., 1])
-    d4 = _orient(a1[..., 0], a1[..., 1], a2[..., 0], a2[..., 1], b2[..., 0], b2[..., 1])
-    proper = (d1 * d2 < 0) & (d3 * d4 < 0)
-
-    def on_seg(px, py, qx, qy, rx, ry):
-        """r collinear with p-q and within its bbox."""
-        return (
-            (np.minimum(px, qx) <= rx)
-            & (rx <= np.maximum(px, qx))
-            & (np.minimum(py, qy) <= ry)
-            & (ry <= np.maximum(py, qy))
-        )
-
+    a1x, a1y, a2x, a2y = a1[..., 0], a1[..., 1], a2[..., 0], a2[..., 1]
+    b1x, b1y, b2x, b2y = b1[..., 0], b1[..., 1], b2[..., 0], b2[..., 1]
+    d1 = _orient(b1x, b1y, b2x, b2y, a1x, a1y)
+    d2 = _orient(b1x, b1y, b2x, b2y, a2x, a2y)
+    d3 = _orient(a1x, a1y, a2x, a2y, b1x, b1y)
+    d4 = _orient(a1x, a1y, a2x, a2y, b2x, b2y)
+    # each segment's closed bounds, taken before the operands broadcast
+    ax0, ax1, ay0, ay1 = np.minimum(a1x, a2x), np.maximum(a1x, a2x), np.minimum(a1y, a2y), np.maximum(a1y, a2y)
+    bx0, bx1, by0, by1 = np.minimum(b1x, b2x), np.maximum(b1x, b2x), np.minimum(b1y, b2y), np.maximum(b1y, b2y)
+    proper = (
+        (d1 * d2 < 0) & (d3 * d4 < 0)
+        & (ax0 <= bx1) & (ax1 >= bx0) & (ay0 <= by1) & (ay1 >= by0)
+    )
+    # an endpoint collinear with the other segment and within its bounds
     touch = (
-        ((d1 == 0) & on_seg(b1[..., 0], b1[..., 1], b2[..., 0], b2[..., 1], a1[..., 0], a1[..., 1]))
-        | ((d2 == 0) & on_seg(b1[..., 0], b1[..., 1], b2[..., 0], b2[..., 1], a2[..., 0], a2[..., 1]))
-        | ((d3 == 0) & on_seg(a1[..., 0], a1[..., 1], a2[..., 0], a2[..., 1], b1[..., 0], b1[..., 1]))
-        | ((d4 == 0) & on_seg(a1[..., 0], a1[..., 1], a2[..., 0], a2[..., 1], b2[..., 0], b2[..., 1]))
+        ((d1 == 0) & (bx0 <= a1x) & (a1x <= bx1) & (by0 <= a1y) & (a1y <= by1))
+        | ((d2 == 0) & (bx0 <= a2x) & (a2x <= bx1) & (by0 <= a2y) & (a2y <= by1))
+        | ((d3 == 0) & (ax0 <= b1x) & (b1x <= ax1) & (ay0 <= b1y) & (b1y <= ay1))
+        | ((d4 == 0) & (ax0 <= b2x) & (b2x <= ax1) & (ay0 <= b2y) & (b2y <= ay1))
     )
     return proper | touch
 
@@ -1061,7 +1066,10 @@ def _first_point(g: Geometry) -> tuple[float, float]:
 
 
 def intersects(a: Geometry, b: Geometry) -> bool:
-    """Exact geometry intersection (the host twin of the device refine).
+    """Exact geometry intersection of two host geometries: the definition
+    that :func:`intersects_rows` answers for a whole packed column at once
+    (the filter stack's exact tier calls that, and this only for the rows
+    it leaves out).
 
     Construction: bbox reject, then point-containment either way, then any
     edge-pair intersection. Matches JTS `intersects` semantics (boundaries
@@ -1082,6 +1090,123 @@ def intersects(a: Geometry, b: Geometry) -> bool:
     if isinstance(a, (Polygon, MultiPolygon)) and bool(points_in_polygon(bx, by, a)):
         return True
     return _any_edge_intersection(a, b)
+
+
+#: by type code: the types whose vertices lie in rings of consecutive edges
+#: (the rows, and the query, that the flat form of ``intersects_rows``
+#: takes), and those of them with an inside
+_RINGED = np.isin(np.arange(7), (LINESTRING, POLYGON, MULTILINESTRING, MULTIPOLYGON))
+_POLYGONAL = np.isin(np.arange(7), (POLYGON, MULTIPOLYGON))
+
+#: cells of one chunk of the (row edge, query edge) pair mask: a few MB of
+#: booleans whatever the candidates and the query's ring
+_PAIR_CELLS = 1 << 22
+
+
+def flat_form_rows(col: PackedGeometryColumn, rows: np.ndarray, g: Geometry) -> np.ndarray:
+    """bool [len(rows)]: the rows that ``intersects_rows`` decides in its
+    batched pass: linestrings, polygons and their multis, under a query
+    that has rings itself. Points and multipoints (``col.types``), and
+    every row under a point or multipoint query, are asked whether the
+    other side COVERS a point, another construction: they keep
+    :func:`intersects`."""
+    if not _RINGED[g.type_code]:
+        return np.zeros(len(rows), dtype=bool)
+    return _RINGED[col.types[rows]]
+
+
+def intersects_rows(col: PackedGeometryColumn, rows: np.ndarray, g: Geometry) -> np.ndarray:
+    """bool [len(rows)]: ``intersects(col.geometry(i), g)`` for i in
+    ``rows``, decided in one pass over the column's arrays for the rows
+    that ``flat_form_rows`` names and a geometry at a time for the rest."""
+    rows = np.asarray(rows, dtype=np.int64)
+    flat = flat_form_rows(col, rows, g)
+    out = np.zeros(len(rows), dtype=bool)
+    for k in np.flatnonzero(~flat):
+        out[k] = intersects(col.geometry(int(rows[k])), g)
+    if flat.any():
+        out[flat] = _intersects_flat(col, rows[flat], g)
+    return out
+
+
+def _intersects_flat(col: PackedGeometryColumn, rows: np.ndarray, g: Geometry) -> np.ndarray:
+    """``intersects`` of ringed rows against a ringed ``g``, asked in its
+    order and in its f64 arithmetic, over the rows' vertices laid flat
+    with an owner index where it takes a geometry at a time: the bounds
+    reject, the row's first point in ``g``, ``g``'s first point in the row
+    (``points_in_ring``'s crossings, parity by part, any part by row), and
+    ``segments_intersect`` over the (row edge, query edge) pairs whose
+    closed bounds overlap. A pair whose bounds miss shares no point, and
+    neither a touch nor a collinear overlap can be read into one (both
+    are tests against those bounds), so the pairs left out change no
+    answer. Rings are read as the pool holds them: closed, as every
+    constructor of the column lays them."""
+    n = len(rows)
+    gpo, pro, ro = col.geom_part_offsets, col.part_ring_offsets, col.ring_offsets
+    parts, part_off = _expand_ranges(gpo[rows].astype(np.int64), gpo[rows + 1].astype(np.int64))
+    part_row = np.repeat(np.arange(n), np.diff(part_off))
+    first_ring = pro[parts].astype(np.int64)
+    rings, ring_off = _expand_ranges(first_ring, pro[parts + 1].astype(np.int64))
+    ring_part = np.repeat(np.arange(len(parts)), np.diff(ring_off))
+    verts, vert_off = _expand_ranges(ro[rings].astype(np.int64), ro[rings + 1].astype(np.int64))
+    xy = col.coords[verts]
+
+    # bounds reject: a geometry's bounds() are its shells' (a part's first
+    # ring; a line's only one), f64, closed
+    shell = (rings == first_ring[ring_part])[:, None]
+    row_rings = ring_off[part_off[:-1]]
+    lo = np.minimum.reduceat(
+        np.where(shell, np.minimum.reduceat(xy, vert_off[:-1], axis=0), np.inf), row_rings, axis=0)
+    hi = np.maximum.reduceat(
+        np.where(shell, np.maximum.reduceat(xy, vert_off[:-1], axis=0), -np.inf), row_rings, axis=0)
+    todo = bbox_intersects(np.hstack([lo, hi]), g.bounds())
+    out = np.zeros(n, dtype=bool)
+
+    # the row's first point inside g
+    if _POLYGONAL[g.type_code]:
+        first = xy[vert_off[row_rings]]
+        out |= todo & points_in_polygon(first[:, 0], first[:, 1], g)
+
+    # edges: every vertex but a ring's last starts one
+    starts = np.ones(len(verts), dtype=bool)
+    starts[vert_off[1:] - 1] = False
+    e = np.flatnonzero(starts)
+    p1, p2 = xy[e], xy[e + 1]
+    edge_part = ring_part[np.repeat(np.arange(len(rings)), np.diff(vert_off) - 1)]
+    edge_row = part_row[edge_part]
+
+    # g's first point inside the row: a polygon's rings XOR (a hole counts
+    # against its shell), a multipolygon's parts OR
+    px, py = _first_point(g)
+    x1e, y1e, x2e, y2e = p1[:, 0], p1[:, 1], p2[:, 0], p2[:, 1]
+    spans = (y1e <= py) != (y2e <= py)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        t = (py - y1e) / np.where(y2e == y1e, np.inf, y2e - y1e)
+        xi = x1e + t * (x2e - x1e)
+    crossings = np.bincount(edge_part[spans & (xi > px)], minlength=len(parts))
+    holds = np.bincount(part_row[crossings % 2 == 1], minlength=n) > 0
+    out |= todo & holds & _POLYGONAL[col.types[rows]]
+
+    # any edge of the row against any edge of g, for the rows still open
+    q_rings = _rings_of(g)
+    q1 = np.concatenate([r[:-1] for r in q_rings], axis=0)
+    q2 = np.concatenate([r[1:] for r in q_rings], axis=0)
+    qlo, qhi = np.minimum(q1, q2), np.maximum(q1, q2)
+    elo, ehi = np.minimum(p1, p2), np.maximum(p1, p2)
+    # an edge that misses the bounds of all of g's edges is in no pair
+    near = bbox_intersects(np.hstack([elo, ehi]), np.concatenate([qlo.min(axis=0), qhi.max(axis=0)]))
+    k = np.flatnonzero((todo & ~out)[edge_row] & near)
+    step = max(1, _PAIR_CELLS // len(q1))
+    for at in range(0, len(k), step):
+        c = k[at : at + step]
+        i, j = np.nonzero(
+            (elo[c, None, 0] <= qhi[None, :, 0]) & (ehi[c, None, 0] >= qlo[None, :, 0])
+            & (elo[c, None, 1] <= qhi[None, :, 1]) & (ehi[c, None, 1] >= qlo[None, :, 1])
+        )
+        i = c[i]
+        hit = segments_intersect(p1[i], p2[i], q1[j], q2[j])
+        out[edge_row[i[hit]]] = True
+    return out
 
 
 def _geom_covers_point(g: Geometry, x: float, y: float) -> bool:
