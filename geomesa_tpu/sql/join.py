@@ -42,6 +42,9 @@ from geomesa_tpu import geometry as geo
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.predicates import PointColumn
 from geomesa_tpu.metrics import resolve as _resolve_metrics
+from geomesa_tpu.obs.trace import NULL_SPAN as _NULL_SPAN
+from geomesa_tpu.obs.trace import span as _ospan
+from geomesa_tpu.obs.trace import tracer as _otracer
 from geomesa_tpu.tuning.primitives import CostEwma
 
 
@@ -85,6 +88,13 @@ class _AdaptiveGate:
 
 
 _GATE = _AdaptiveGate()
+
+#: how ``spatial_join_indexed`` decides a member, as its ``join.plan`` span
+#: counts them: the device's point-in-polygon tier, its raster-interval
+#: tier, its box mask alone (a rectangle; a polygon with neither stack,
+#: whose every row the host refines), the host's whole-table raster route,
+#: or nothing to scan
+_TIERS = ("pip", "rast", "bbox_only", "host_raster", "empty")
 
 
 def _bboxes(fc: FeatureCollection) -> np.ndarray:
@@ -261,7 +271,8 @@ def _polygon_inside(xs, ys, ga, predicate, approx, metrics, cls=None):
     the plain pairing: full cells are strictly interior (margin), out
     cells strictly exterior, so only partial-cell points can differ
     from — and they run — the exact code. ``cls``: optionally reuse an
-    already-computed classification of exactly these points."""
+    already-computed classification of exactly these points. Returns
+    (inside, how many points the raster left to the exact code)."""
     if cls is None:
         t0 = time.perf_counter()
         cls = approx.classify_points(xs, ys)
@@ -281,7 +292,7 @@ def _polygon_inside(xs, ys, ga, predicate, approx, metrics, cls=None):
         _GATE.update(
             "pip_s", time.perf_counter() - t0, len(bidx) * _edge_count(ga)
         )
-    return inside
+    return inside, len(bidx)
 
 
 def _plain_inside(xs, ys, ga, predicate):
@@ -385,7 +396,7 @@ def _join_points_right(left, right, lb, pred, predicate, x0, y0, inv_cx,
             chosen, pre_cls = _pick_strategy(xs, ys, ga, approx, strategy)
             if chosen == "raster" and approx is not None:
                 metrics.counter("geomesa.join.strategy.raster")
-                inside = _polygon_inside(
+                inside, _ = _polygon_inside(
                     xs, ys, ga, predicate, approx, metrics, cls=pre_cls
                 )
             else:
@@ -456,19 +467,24 @@ def spatial_join_indexed(
     the point side IS the GeoMesa-indexed relation); use
     :func:`spatial_join` for two bare collections.
 
-    ``predicate``: "contains" (left polygon strictly contains the point)
-    or "intersects" (boundary points count).
+    ``predicate``: "contains" (the point is inside the left polygon by
+    the f64 even-odd rule of ``geo.points_in_polygon``, ``geo.contains``'s
+    own test: a point EXACTLY on an edge falls to one side of it, where
+    JTS holds it in no interior; docs/joins.md) or "intersects"
+    (boundary points count).
+
+    Traced as ONE root ``join`` (``members``, ``predicate``, ``pairs``;
+    docs/observability.md): ``join.plan`` (a filter, a ``scan_config``
+    and the broad test a polygon), ``join.host`` (the broad route, where
+    a member takes it), the table's ``dispatch`` and a ``scan`` a live
+    member as ``query_many`` has them, ``join.refine`` a member that
+    answered rows, ``join.assemble``.
     """
     if predicate not in ("contains", "intersects"):
         raise ValueError(f"indexed join supports contains/intersects, got {predicate!r}")
     n_left = len(left)
     if n_left == 0 or len(ds.features(type_name)) == 0:
         return np.zeros(0, np.int64), np.zeros(0, np.int64)
-
-    from geomesa_tpu.filter.predicates import BBox, Intersects
-
-    sft = ds.get_schema(type_name)
-    gf = sft.geom_field
     idx = next((i for i in ds.indexes(type_name) if i.name == index), None)
     if idx is None:
         have = [i.name for i in ds.indexes(type_name)]
@@ -476,15 +492,28 @@ def spatial_join_indexed(
             f"indexed join needs the {index!r} index on {type_name!r}; "
             f"store has {have}"
         )
-    table = ds.table(type_name, index)
     pts = ds.features(type_name).geom_column
     if not isinstance(pts, PointColumn):
         raise TypeError("indexed join requires a point store")
+    with _otracer().trace(
+        "join", type=type_name, predicate=predicate, members=n_left
+    ) as trace:
+        lo, ro = _join_indexed(
+            ds, type_name, left, predicate, idx, pts, _resolve_metrics(metrics)
+        )
+        if trace is not None:
+            trace.root.annotate(pairs=len(lo))
+        return lo, ro
 
+
+def _join_indexed(ds, type_name, left, predicate, idx, pts, metrics):
+    """:func:`spatial_join_indexed` under its root."""
     from geomesa_tpu.conf import JOIN_ADAPTIVE, JOIN_BROAD_FRACTION
     from geomesa_tpu.filter import raster as fr
+    from geomesa_tpu.filter.predicates import BBox, Intersects
 
-    metrics = _resolve_metrics(metrics)
+    gf = ds.get_schema(type_name).geom_field
+    table = ds.table(type_name, idx.name)
     broad_frac = float(JOIN_BROAD_FRACTION.get())
     adaptive = bool(JOIN_ADAPTIVE.get())
 
@@ -500,88 +529,120 @@ def spatial_join_indexed(
     # instead (measured selectivity = candidate rows / table rows).
     cfgs: list = []
     exacts: list[bool] = []
-    host_results: dict[int, np.ndarray] = {}
-    for k, g in enumerate(lgeoms):
-        rect = geo.is_rectangle(g)
-        f = BBox(gf, *g.bounds()) if rect else Intersects(gf, g)
-        cfg = idx.scan_config(f)
-        if cfg is None or cfg.disjoint:
-            cfgs.append(None)
-            exacts.append(False)
-            continue
-        if adaptive and not rect and not cfg.disjoint:
-            cand_rows = table.candidate_spans(cfg).n_rows()
-            if cand_rows > broad_frac * max(table.n, 1):
-                approx = fr.raster_for(g)
-                if approx is not None:
-                    metrics.counter("geomesa.join.strategy.host_raster")
-                    inside = _polygon_inside(
-                        np.asarray(pts.x, np.float64),
-                        np.asarray(pts.y, np.float64),
-                        g, predicate, approx, metrics,
-                    )
-                    host_results[k] = np.flatnonzero(inside).astype(np.int64)
-                    cfgs.append(None)
-                    exacts.append(False)
-                    continue
-        metrics.counter("geomesa.join.strategy.probe")
-        # certainty is only trustworthy when the device evaluated the
-        # TRUE predicate: the shrunk box for rectangles, the PIP or
-        # raster tiers for polygons. A polygon past the edge-bucket
-        # ladder with no raster (cfg.poly and cfg.rast both None) gets
-        # bbox certainty only — every row must host-refine or
-        # bbox-inside-but-outside-polygon points would join as false
-        # pairs
-        cfgs.append(cfg)
-        exacts.append(rect or cfg.poly is not None or cfg.rast is not None)
-    live_idx = [k for k, c in enumerate(cfgs) if c is not None]
-    fins = table.scan_submit_many([cfgs[k] for k in live_idx])
+    broad: list = []  # (k, polygon, raster): members for the host route
+    tiers = dict.fromkeys(_TIERS, 0)
+    with _ospan("join.plan", cpu=True) as sp:
+        cand_total = 0
+        for k, g in enumerate(lgeoms):
+            rect = geo.is_rectangle(g)
+            f = BBox(gf, *g.bounds()) if rect else Intersects(gf, g)
+            cfg = idx.scan_config(f)
+            if cfg is None or cfg.disjoint:
+                cfgs.append(None)
+                exacts.append(False)
+                tiers["empty"] += 1
+                continue
+            if adaptive and not rect:
+                cand_rows = table.candidate_spans(cfg).n_rows()
+                cand_total += cand_rows
+                if cand_rows > broad_frac * max(table.n, 1):
+                    approx = fr.raster_for(g)
+                    if approx is not None:
+                        metrics.counter("geomesa.join.strategy.host_raster")
+                        broad.append((k, g, approx))
+                        cfgs.append(None)
+                        exacts.append(False)
+                        tiers["host_raster"] += 1
+                        continue
+            metrics.counter("geomesa.join.strategy.probe")
+            # certainty is only trustworthy when the device evaluated the
+            # TRUE predicate: the shrunk box for rectangles, the PIP or
+            # raster tiers for polygons. A polygon past the edge-bucket
+            # ladder with no raster (cfg.poly and cfg.rast both None) gets
+            # bbox certainty only — every row must host-refine or
+            # bbox-inside-but-outside-polygon points would join as false
+            # pairs
+            cfgs.append(cfg)
+            exacts.append(rect or cfg.poly is not None or cfg.rast is not None)
+            tiers[
+                "pip" if cfg.poly is not None
+                else "rast" if cfg.rast is not None else "bbox_only"
+            ] += 1
+        if sp is not _NULL_SPAN:  # the members' edges and ranges are summed for it alone
+            sp.annotate(
+                edges=sum(_edge_count(g) for g in lgeoms),
+                ranges=sum(c.n_ranges for c in cfgs if c is not None),
+                candidate_rows=int(cand_total), **tiers,
+            )
 
     # per-left ordinal results keyed by k, emitted in k order at the end
     # so the documented (left, right) sort holds across strategies
-    per_left: dict[int, np.ndarray] = {
-        k: ords for k, ords in host_results.items() if len(ords)
-    }
+    per_left: dict[int, np.ndarray] = {}
+    if broad:
+        with _ospan("join.host", members=len(broad)) as sp:
+            px = np.asarray(pts.x, np.float64)
+            py = np.asarray(pts.y, np.float64)
+            residue = 0
+            for k, g, approx in broad:
+                inside, left_over = _polygon_inside(px, py, g, predicate, approx, metrics)
+                residue += left_over
+                ords = np.flatnonzero(inside).astype(np.int64)
+                if len(ords):
+                    per_left[k] = ords
+            points = len(px) * len(broad)
+            sp.annotate(points=points, decided=points - residue, residue=residue)
+
+    ascending = set(per_left)  # flatnonzero's order; a scan's rows come in table order
+    live_idx = [k for k, c in enumerate(cfgs) if c is not None]
+    with _ospan("dispatch", index=idx.name, members=len(live_idx)):
+        fins = table.scan_submit_many([cfgs[k] for k in live_idx])
+
     for k, fin in zip(live_idx, fins):
-        ordinals, certain = fin()
-        exact_on_device = exacts[k]
-        if not exact_on_device:
+        with _ospan("scan", index=idx.name, member=k):
+            ordinals, certain = fin()
+        if not exacts[k]:
             certain = np.zeros(len(ordinals), dtype=bool)
         if len(ordinals) == 0:
             continue
         g = lgeoms[k]
-        unc = np.flatnonzero(~certain)
-        if len(unc):
-            # exact host check over the uncertainty band only (f32 box
-            # rounding / PIP near band): vectorized rect compare or the
-            # native threaded ray cast
-            ux, uy = pts.x[ordinals[unc]], pts.y[ordinals[unc]]
-            if geo.is_rectangle(g):
-                x0, y0, x1, y1 = g.bounds()
-                if predicate == "contains":
-                    ok = (ux > x0) & (ux < x1) & (uy > y0) & (uy < y1)
+        with _ospan("join.refine", cpu=True, member=k, rows=len(ordinals)) as sp:
+            unc = np.flatnonzero(~certain)
+            sp.add("certain", len(ordinals) - len(unc))
+            sp.add("uncertain", len(unc))
+            if len(unc):
+                # exact host check over the uncertainty band only (f32 box
+                # rounding / PIP near band): vectorized rect compare or the
+                # native threaded ray cast
+                ux, uy = pts.x[ordinals[unc]], pts.y[ordinals[unc]]
+                if geo.is_rectangle(g):
+                    x0, y0, x1, y1 = g.bounds()
+                    if predicate == "contains":
+                        ok = (ux > x0) & (ux < x1) & (uy > y0) & (uy < y1)
+                    else:
+                        ok = (ux >= x0) & (ux <= x1) & (uy >= y0) & (uy <= y1)
                 else:
-                    ok = (ux >= x0) & (ux <= x1) & (uy >= y0) & (uy <= y1)
-            else:
-                ok = geo.points_in_polygon(ux, uy, g)
-                if predicate == "intersects":
-                    nb = np.flatnonzero(~ok)
-                    if len(nb):
-                        ok[nb] = geo.points_on_boundary(ux[nb], uy[nb], g)
-            keep = certain.copy()
-            keep[unc] = ok
-            ordinals = ordinals[keep]
+                    ok = geo.points_in_polygon(ux, uy, g)
+                    if predicate == "intersects":
+                        nb = np.flatnonzero(~ok)
+                        if len(nb):
+                            ok[nb] = geo.points_on_boundary(ux[nb], uy[nb], g)
+                keep = certain.copy()
+                keep[unc] = ok
+                ordinals = ordinals[keep]
         if len(ordinals):
-            # decode yields TABLE-row order; perm makes that
-            # non-monotonic in feature ordinals — sort so the documented
-            # (left, right) pair order actually holds
-            per_left[k] = np.sort(ordinals)
+            per_left[k] = ordinals
     if not per_left:
         return np.zeros(0, np.int64), np.zeros(0, np.int64)
-    lo_parts = []
-    ro_parts = []
-    for k in sorted(per_left):
-        ords = per_left[k]
-        lo_parts.append(np.full(len(ords), k, dtype=np.int64))
-        ro_parts.append(ords)
-    return np.concatenate(lo_parts), np.concatenate(ro_parts)
+    with _ospan("join.assemble", members=len(per_left)):
+        lo_parts = []
+        ro_parts = []
+        for k in sorted(per_left):
+            ords = per_left[k]
+            if k not in ascending:
+                # decode yields TABLE-row order; perm makes that
+                # non-monotonic in feature ordinals — sort so the
+                # documented (left, right) pair order actually holds
+                ords = np.sort(ords)
+            lo_parts.append(np.full(len(ords), k, dtype=np.int64))
+            ro_parts.append(ords)
+        return np.concatenate(lo_parts), np.concatenate(ro_parts)

@@ -161,6 +161,53 @@ def test_fused_multi_query_scan(one_chip, case):
     _assert_mosaic(compiled)
 
 
+#: (edge bucket, raster bucket) of the fused chunks a join of the cell
+#: ``nyc-taxi.zone-join`` packs (PR 41; sql/join.py through
+#: ``IndexTable.scan_submit_many``): census blocks ride the point-in-polygon
+#: tier with no raster, neighborhoods the raster tier with no edges, a
+#: block of over 16 edges the next fused edge bucket; under the device's
+#: residue mode one chunk carries both stacks
+JOIN_CHUNKS = {"blocks-e16": (16, 0), "blocks-e64": (64, 0), "nbhd-r16": (0, 16),
+               "both-e16-r16": (16, 16)}
+
+
+@pytest.mark.parametrize("case", sorted(JOIN_CHUNKS))
+def test_fused_polygon_chunk_of_the_join_cell(one_chip, case):
+    """The fused polygon chunk over the join cell's own z2 table: 2^24 rows
+    are 1,024 blocks, so the chunk has 1,024 slots (``fused_slots`` clamps
+    FUSED_CHUNK_SLOTS to the table's block bucket), FUSED_CHUNK_Q members,
+    a per-slot polygon selector and the members' edge and raster stacks."""
+    n_edges, n_rints = JOIN_CHUNKS[case]
+    m = min(FUSED_CHUNK_SLOTS, bk.bucket_of((1 << 24) // bk.BLOCK))
+    assert m == 1024
+    slot = _s((m,), jnp.int32, one_chip)
+    cols = tuple(_s((m, SUB, bk.LANES), jnp.float32, one_chip) for _ in Z2)
+    compiled = bk._pallas_block_scan_multi.lower(
+        cols, slot, slot, *_params(one_chip, lead=(FUSED_CHUNK_Q,)),
+        _s((FUSED_CHUNK_Q, n_edges, bk.LANES), jnp.float32, one_chip) if n_edges else None,
+        slot,
+        _s((FUSED_CHUNK_Q, 1 + n_rints, bk.LANES), jnp.float32, one_chip) if n_rints else None,
+        interpret=False, n_edges=n_edges, n_rints=n_rints, **_flags(Z2, False),
+    ).compile()
+    _assert_mosaic(compiled)
+
+
+@pytest.mark.parametrize("n_edges,n_rints", [(16, 0), (32, 0), (0, 16)])
+def test_single_member_polygon_scan_of_the_join_cell(one_chip, n_edges, n_rints):
+    """A join's small groups dispatch member by member on the single-query
+    ladder: a census block at the 32-block floor with its 16- or 32-edge
+    stack, a neighborhood or a borough with its raster."""
+    m = bk.M_BUCKETS[0]
+    compiled = bk._pallas_block_scan.lower(
+        tuple(_s((1024, SUB, bk.LANES), jnp.float32, one_chip) for _ in Z2),
+        _s((m,), jnp.int32, one_chip), *_params(one_chip),
+        _s((n_edges, bk.LANES), jnp.float32, one_chip) if n_edges else None,
+        _s((1 + n_rints, bk.LANES), jnp.float32, one_chip) if n_rints else None,
+        interpret=False, n_edges=n_edges, n_rints=n_rints, **_flags(Z2, False),
+    ).compile()
+    _assert_mosaic(compiled)
+
+
 @pytest.mark.parametrize("names,has_w", [(Z3, True), (Z2, False)])
 def test_pops_and_bounds(one_chip, names, has_w):
     args = (
