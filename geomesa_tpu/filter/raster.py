@@ -28,7 +28,10 @@ Two products feed the scan engine:
 
 The host-side :meth:`classify_points` powers the adaptive spatial join
 (sql/join.py): definite-in/definite-out points skip the exact predicate,
-only boundary-cell points pay it.
+only boundary-cell points pay it. However many points it is handed, its
+temporaries are a chunk's (``CLASSIFY_CHUNK`` points): it walks a longer
+input a chunk at a time, and the join's broad route, which classifies a
+whole table, walks it in the same chunks (sql/join.py ``_broad_inside``).
 """
 
 from __future__ import annotations
@@ -51,6 +54,15 @@ from geomesa_tpu.curve.zorder import Z2
 RASTER_MARGIN = 3e-4
 
 Z2_BITS = 31  # ordinal bits per dimension (curve.z2sfc.Z2SFC precision)
+
+# points a host classification works at a time. One pass over 2^24 points
+# makes a dozen 128 MiB temporaries that no cache holds and that malloc
+# maps and unmaps one by one (1.7 s on a v5e's host); at 2^18 points an f64
+# temporary is 2 MiB, inside L2 and on the heap: 0.24-0.25 s. The join's
+# whole pass reads alike at 2^16 and 2^18 (0.63 s) but at 2^20, 8 MiB a
+# temporary, 0.65 s in one process and 1.33 s in another, by the allocator's
+# state (PERF.md section 6, PR 42); 2^18 makes a quarter of 2^16's calls.
+CLASSIFY_CHUNK = 1 << 18
 
 
 @dataclass
@@ -120,9 +132,24 @@ class RasterApprox:
     def classify_points(self, x, y) -> np.ndarray:
         """int8 [n] cell class per point (RASTER_OUT for points outside
         the grid window — the window covers the polygon bbox, so such
-        points are guaranteed misses)."""
+        points are guaranteed misses). Up to ``CLASSIFY_CHUNK`` points
+        take one pass; more are walked a chunk at a time into the one
+        result array, so the temporaries (eight arrays of 8 B a point)
+        are a chunk's whatever ``n`` is. Element for element the single
+        pass's result: the arithmetic is per point."""
         x = np.asarray(x, dtype=np.float64)
         y = np.asarray(y, dtype=np.float64)
+        n = len(x)
+        if n <= CLASSIFY_CHUNK:
+            return self._classify(x, y)
+        out = np.empty(n, dtype=np.int8)
+        for lo in range(0, n, CLASSIFY_CHUNK):
+            hi = lo + CLASSIFY_CHUNK
+            out[lo:hi] = self._classify(x[lo:hi], y[lo:hi])
+        return out
+
+    def _classify(self, x: np.ndarray, y: np.ndarray) -> np.ndarray:
+        """One pass of :meth:`classify_points` over f64 arrays."""
         i = np.floor((x - self.x0) / self.cell_w).astype(np.int64)
         j = np.floor((y - self.y0) / self.cell_h).astype(np.int64)
         ok = (i >= 0) & (i < self.nx) & (j >= 0) & (j < self.ny)

@@ -22,9 +22,10 @@ the join picks PER PARTITION from measured selectivity —
 - ``spatial_join_indexed``: polygons whose candidate spans cover more
   than ``geomesa.join.broad.fraction`` of the table skip the fused-scan
   probe and classify the whole point set against their raster on host
-  (one vectorized pass beats scanning ~the entire store through the
-  kernel); everything else keeps the fused-scan probe, which itself now
-  rides the raster tier via ScanConfig.rast.
+  (one vectorized pass, walked in chunks whose temporaries stay in cache,
+  beats scanning ~the entire store through the kernel); everything else
+  keeps the fused-scan probe, which itself now rides the raster tier via
+  ScanConfig.rast.
 
 Either strategy returns bit-identical pairs — the adaptive layer moves
 work, never answers.
@@ -279,6 +280,13 @@ def _polygon_inside(xs, ys, ga, predicate, approx, metrics, cls=None):
         _GATE.update("cls_s", time.perf_counter() - t0, len(xs))
     inside = cls == geo.RASTER_FULL
     bidx = np.flatnonzero(cls == geo.RASTER_PARTIAL)
+    _settle_residue(xs, ys, bidx, inside, ga, predicate, metrics)
+    return inside, len(bidx)
+
+
+def _settle_residue(xs, ys, bidx, inside, ga, predicate, metrics):
+    """The exact code over the points ``bidx`` that the raster left
+    undecided, written into ``inside``; counts decided and residue."""
     metrics.counter("geomesa.join.raster.decided", len(xs) - len(bidx))
     metrics.counter("geomesa.join.raster.residue", len(bidx))
     if len(bidx):
@@ -292,7 +300,40 @@ def _polygon_inside(xs, ys, ga, predicate, approx, metrics, cls=None):
         _GATE.update(
             "pip_s", time.perf_counter() - t0, len(bidx) * _edge_count(ga)
         )
-    return inside, len(bidx)
+
+
+def _broad_inside(px, py, ga, predicate, approx, metrics):
+    """:func:`_polygon_inside` over a WHOLE table's points, the broad
+    route's pass: the table is walked ``filter.raster.CLASSIFY_CHUNK``
+    points at a time, a chunk classified, its full cells' verdicts
+    written into the one mask and its residue's ordinals kept, so the
+    f64 temporaries are a chunk's however many rows the table holds.
+    The residue is settled ONCE after the walk: the native ray cast
+    threads over points, and a chunk's share of a thin boundary would
+    fall under the size where it does (PERF.md section 6, PR 42: alike
+    within 4% on the cell's borough, and no cliff). The same code on the
+    same points as one call over all of them: the mask, the counters and
+    the gate's units are that call's. Returns (inside [n] bool, points
+    left to the exact code, chunks walked)."""
+    from geomesa_tpu.filter import raster as fr
+
+    n = len(px)
+    inside = np.empty(n, dtype=bool)
+    parts = []
+    cls_s = 0.0
+    for lo in range(0, n, fr.CLASSIFY_CHUNK):
+        hi = lo + fr.CLASSIFY_CHUNK
+        t0 = time.perf_counter()
+        cls = approx.classify_points(px[lo:hi], py[lo:hi])
+        cls_s += time.perf_counter() - t0
+        np.equal(cls, geo.RASTER_FULL, out=inside[lo:hi])
+        part = np.flatnonzero(cls == geo.RASTER_PARTIAL)
+        part += lo
+        parts.append(part)
+    _GATE.update("cls_s", cls_s, n)
+    bidx = np.concatenate(parts)
+    _settle_residue(px, py, bidx, inside, ga, predicate, metrics)
+    return inside, len(bidx), len(parts)
 
 
 def _plain_inside(xs, ys, ga, predicate):
@@ -582,15 +623,23 @@ def _join_indexed(ds, type_name, left, predicate, idx, pts, metrics):
         with _ospan("join.host", members=len(broad)) as sp:
             px = np.asarray(pts.x, np.float64)
             py = np.asarray(pts.y, np.float64)
-            residue = 0
+            residue = chunks = 0
             for k, g, approx in broad:
-                inside, left_over = _polygon_inside(px, py, g, predicate, approx, metrics)
+                inside, left_over, passes = _broad_inside(
+                    px, py, g, predicate, approx, metrics
+                )
                 residue += left_over
-                ords = np.flatnonzero(inside).astype(np.int64)
+                chunks += passes
+                # the mask's ordinals ARE the answer's array (intp is int64
+                # wherever the native tier builds): no copy after this one
+                ords = np.flatnonzero(inside).astype(np.int64, copy=False)
                 if len(ords):
                     per_left[k] = ords
             points = len(px) * len(broad)
-            sp.annotate(points=points, decided=points - residue, residue=residue)
+            sp.annotate(
+                points=points, decided=points - residue, residue=residue,
+                chunks=chunks, chunked=points,
+            )
 
     ascending = set(per_left)  # flatnonzero's order; a scan's rows come in table order
     live_idx = [k for k, c in enumerate(cfgs) if c is not None]

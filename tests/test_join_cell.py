@@ -18,7 +18,11 @@ cell ``nyc-taxi.zone-join``) at a small size on the CPU:
 (d) the join's spans (PR 41; docs/observability.md): root ``join`` with
     ``join.plan`` (its tiers sum to ``members``), ``join.host``,
     ``dispatch`` / ``scan``, ``join.refine`` (``certain`` + ``uncertain`` =
-    ``rows``), ``join.assemble``; a traced answer is the untraced one;
+    ``rows``), ``join.assemble``; a traced answer is the untraced one; PR 42:
+    the Manhattan-like borough's whole-table pass answers alike, pair for
+    pair and count for count, whether it walks the table 1,024 points at a
+    time or ``filter.raster.CLASSIFY_CHUNK``, and ``join.host`` counts its
+    ``chunks`` and the points that went through them (``chunked``);
 (e) ``generators/zone_joins.py``: every seed's round is the same multiset;
     ``join_ladder`` asks alone every borough and every neighborhood a mix
     of 8,000 requests reaches, under every seed;
@@ -63,7 +67,8 @@ def bench():
         from generators import join_ladder, zone_joins
         from harness import check, reference_join
         from harness import requests as rq
-        from layer_metrics import join_device_pct, join_polygon_us, join_residue_pct
+        from layer_metrics import (join_device_pct, join_host_chunked_pct, join_polygon_us,
+                                   join_residue_pct)
         from ops import join
         from stores import datastore_join
 
@@ -71,7 +76,8 @@ def bench():
             nyc_taxi=nyc_taxi, join_ladder=join_ladder, zone_joins=zone_joins, check=check,
             ref=reference_join, rq=rq, op=join, stores=datastore_join,
             readers={"join_device_pct": join_device_pct, "join_polygon_us": join_polygon_us,
-                     "join_residue_pct": join_residue_pct})
+                     "join_residue_pct": join_residue_pct,
+                     "join_host_chunked_pct": join_host_chunked_pct})
     finally:
         sys.path.remove(BENCH)
         for k in [k for k in sys.modules if k.split(".")[0] in BENCH_PACKAGES and k not in held]:
@@ -368,6 +374,8 @@ def test_the_joins_spans_count_its_members_and_rows(klass, bench, mix, cols, sto
             (host,) = _spans(traced, "join.host")
             assert host.attrs["points"] == N * plan.attrs["host_raster"]
             assert host.attrs["decided"] + host.attrs["residue"] == host.attrs["points"]
+            assert host.attrs["chunked"] == host.attrs["points"]  # PR 42: every one in chunks
+            assert host.attrs["chunks"] == plan.attrs["host_raster"]  # 2^16 rows: one a member
         elif live:
             assert 0 < dispatch.attrs["blocks"] <= dispatch.attrs["slots"]
             assert any("wait" in (s.attrs or {}).get("segments", {})
@@ -385,6 +393,38 @@ def test_the_manhattan_borough_takes_the_hosts_route_and_the_blocks_the_devices(
     bench.op.embedded(store, bench.zone_joins.join_request("blocks-16", "blocks", spot, "contains"))
     plan = _spans(traced, "join.plan")[0].attrs
     assert plan["pip"] + plan["rast"] == 16 and plan["candidate_rows"] > 0
+
+
+@pytest.mark.parametrize("predicate", ["contains", "intersects"])
+def test_the_manhattan_pass_answers_alike_whatever_the_chunk(
+        predicate, bench, cols, store, traced, monkeypatch):
+    from geomesa_tpu.filter import raster as fr
+    from geomesa_tpu.metrics import MetricsRegistry
+    from geomesa_tpu.sql import spatial_join_indexed
+
+    left = store.layers["boroughs"].take(np.array([bench.nyc_taxi.MANHATTAN]))
+
+    def ask():
+        m = MetricsRegistry()
+        k, ids = spatial_join_indexed(store.ds, store.type_name, left, predicate, metrics=m)
+        (host,) = _spans(traced, "join.host")
+        return k, ids, dict(host.attrs), [
+            m.counter_value(f"geomesa.join.raster.{c}") for c in ("decided", "residue")]
+
+    k, ids, host, counted = ask()
+    assert host["chunks"] == 1 and N <= fr.CLASSIFY_CHUNK
+    monkeypatch.setattr(fr, "CLASSIFY_CHUNK", 1024)
+    k_s, ids_s, host_s, counted_s = ask()
+    assert host_s["chunks"] == N // 1024 and host["chunks"] == 1
+    for name in ("members", "points", "decided", "residue", "chunked"):
+        assert host_s[name] == host[name], name
+    assert counted_s == counted == [host["decided"], host["residue"]]
+    assert host["chunked"] == host["points"] == N and 0 < host["residue"] < N
+    assert ids_s.dtype == np.int64 and np.array_equal(k, k_s) and np.array_equal(ids, ids_s)
+    assert (np.diff(ids) > 0).all() and 0.84 * N < len(ids) < 0.93 * N
+    if predicate == "contains":  # the cell's own request, held to the plain reference
+        _, want = bench.ref.join_pairs(cols, "boroughs", [bench.nyc_taxi.MANHATTAN])
+        assert np.array_equal(ids, want)
 
 
 @pytest.mark.parametrize("klass", CLASSES)
@@ -416,11 +456,17 @@ def test_the_readers_read_the_joins_spans(bench):
     spans = [span(1, "join", parent=None, members=20),
              span(2, "join.plan", pip=12, rast=4, bbox_only=2, host_raster=1, empty=1),
              span(3, "join.refine", rows=100, certain=75, uncertain=25),
-             span(4, "join.refine", rows=300, certain=300, uncertain=0)]
+             span(4, "join.refine", rows=300, certain=300, uncertain=0),
+             span(5, "join.host", members=1, points=65536, decided=60000, residue=5536,
+                  chunks=64, chunked=65536)]
     view = {"spans": spans}
     assert bench.readers["join_device_pct"].read(view) == pytest.approx(80.0)
     assert bench.readers["join_polygon_us"].read(view) == pytest.approx(500.0)
     assert bench.readers["join_residue_pct"].read(view) == pytest.approx(6.25)
+    assert bench.readers["join_host_chunked_pct"].read(view) == 100.0
+    for gone in ("chunks", "chunked"):  # PR 42's parent: one sweep, and it counts neither
+        del spans[-1]["attrs"][gone]
+    assert bench.readers["join_host_chunked_pct"].read(view) is None
     parent = [dict(s, root="query", name=s["name"].replace("join", "query")) for s in spans[:1]]
     for reader in bench.readers.values():  # the parent's spans: nothing to read
         assert reader.read({"spans": parent}) is None
@@ -510,8 +556,9 @@ def test_the_cell_rehearses_on_the_cpu():
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 32
     read = line["rehearsal_metrics"]
     assert {"join_plan_ms", "join_polygon_us", "join_scan_ms", "join_refine_ms", "join_host_ms",
-            "join_device_pct", "join_residue_pct", "join_coverage_pct",
+            "join_device_pct", "join_residue_pct", "join_coverage_pct", "join_host_chunked_pct",
             "query_p50_ms"} <= set(read)
+    assert read["join_host_chunked_pct"]["value"] == 100.0
     assert 80 < read["join_device_pct"]["value"] < 100  # all but the Manhattan-like borough
     assert 90 < read["join_coverage_pct"]["value"] <= 100
     window = next(json.loads(s) for s in out.stdout.splitlines() if '"phase": "window"' in s)
