@@ -15,6 +15,14 @@ reference's delta protocol, which exists only because region servers
 cannot see each other's batches), points become FixedSizeList<2 x f64>
 vectors (the geomesa-arrow-jts point vector layout), and Dates become
 timestamp[ms]. Python row objects are never materialized.
+
+ONE table build with two routes, picked from what the columns are: where
+the native tier can read every column without the interpreter it makes
+the whole record batch in one call that holds no interpreter lock
+(:func:`_native_batch`), else pyarrow builds it an array a call
+(:func:`_pyarrow_table`); both give the same bytes
+(tests/test_arrow_native.py). :class:`ArrowChunks` is the stream a page
+at a time, for the served data plane.
 """
 
 from __future__ import annotations
@@ -93,20 +101,112 @@ _SFT_KEY = b"geomesa.sft.spec"
 _NAME_KEY = b"geomesa.sft.name"
 
 
+#: the attribute types whose ``<U`` columns are dictionary-encoded
+_TEXT = ("String", "UUID")
+
+
+def _metadata(sft) -> dict:
+    """The SFT spec rides in the schema metadata so IPC payloads are
+    self-describing (read_arrow)."""
+    return {_SFT_KEY: sft.to_spec().encode(), _NAME_KEY: sft.name.encode()}
+
+
+def _native_batch(pa, fc: FeatureCollection, dictionary: bool):
+    """The collection as ONE record batch holding the arrays
+    :func:`_id_array` and :func:`_attr_array` give, buffer for buffer,
+    where every column is one the native tier reads without the
+    interpreter (``<U``, bool, int and float arrays in native byte order,
+    a PointColumn as the geometry, an int64 Date, int64 or ``<U`` ids).
+    ``native.arrow_batch`` makes every buffer (a ``<U`` column's
+    dictionary and codes, the ids' UTF-8, a bool column's bits, x and y
+    interleaved, the fixed-width columns' copies) in ONE call that holds
+    no interpreter lock, and Arrow imports them in one more
+    (``RecordBatch._import_from_c``: the C data interface). pyarrow's own
+    constructors give the lock away once an array or more, and
+    ``pa.array`` takes it back a ``<U`` cell: among a served store's
+    handler threads each hand-off is a wait. None where a column is not
+    such a one (object columns, ``Bytes``, packed geometries, a String
+    attribute held as something else, a byte-swapped column, NaT, no
+    native library, a pyarrow without the import): the caller's pyarrow
+    route then builds the table."""
+    from geomesa_tpu import native
+
+    importer = getattr(pa.RecordBatch, "_import_from_c", None)
+    if importer is None:
+        return None
+    geom = fc.sft.geom_field
+    xy, props = None, []  # as GeoJSONColumns.of takes them
+    for a in fc.sft.attributes:
+        col = fc.columns[a.name]
+        if a.name == "id":  # the pyarrow route's dict keeps one column of the name
+            return None
+        if a.name == geom:
+            if not isinstance(col, PointColumn):
+                return None
+            xy = (col.x, col.y)
+        elif a.type == "Bytes" or (
+            a.type in _TEXT and getattr(col, "dtype", np.dtype("O")).kind != "U"
+        ):
+            return None
+        else:
+            props.append((b"", col, a.type == "Date"))
+    table = native.GeoJSONColumns.of(fc.ids, xy, props)
+    if table is None:
+        return None
+
+    def made(col, type_=None):
+        """What the native call makes of one column, and its Arrow type."""
+        if type_ == "Date":
+            return native.AR_DATE, pa.timestamp("ms")
+        if col.dtype.kind == "U":
+            if dictionary and type_ in _TEXT:
+                return native.AR_DICT, pa.dictionary(pa.int32(), pa.string())
+            return native.AR_STRING, pa.string()
+        if col.dtype.kind == "b":
+            return native.AR_BITS, pa.bool_()
+        return native.AR_COPY, pa.from_numpy_dtype(col.dtype)
+
+    # ``table.cols``' rows: the ids, x, y (none without a point column),
+    # then ``props``; the batch's columns are the ids and the attributes
+    ops, order, fields = [0] * (3 + len(props)), [], []
+
+    def column(name, row, op, type_):
+        ops[row] = op
+        order.append(row)
+        fields.append(pa.field(name, type_))
+
+    column("id", 0, *made(fc.ids))
+    rows = iter(range(3, len(ops)))
+    for a in fc.sft.attributes:
+        if a.name == geom:
+            column(a.name, 1, native.AR_XY, pa.list_(pa.float64(), 2))
+        else:
+            column(a.name, next(rows), *made(fc.columns[a.name], a.type))
+    schema = pa.schema(fields, _metadata(fc.sft))
+    return native.arrow_batch(table, ops, order, lambda at: importer(at, schema))
+
+
+def _pyarrow_table(pa, fc: FeatureCollection, dictionary: bool):
+    """The table built an array a pyarrow call: whatever the columns are."""
+    names = ["id"] + [a.name for a in fc.sft.attributes]
+    arrays = [_id_array(pa, fc)] + [
+        _attr_array(pa, fc, a, dictionary) for a in fc.sft.attributes
+    ]
+    return pa.table(dict(zip(names, arrays))).replace_schema_metadata(
+        _metadata(fc.sft)
+    )
+
+
 def to_arrow_table(fc: FeatureCollection, dictionary: bool = True):
     """The collection as a pyarrow Table (store columns, no Python rows).
-    The SFT spec rides in the schema metadata so IPC payloads are
-    self-describing (read_arrow)."""
+    ONE build with two routes, picked from what the columns are
+    (:func:`_native_batch`, else an array a pyarrow call); both give the
+    same table to the byte."""
     pa = _pa()
-    names = ["id"]
-    arrays = [_id_array(pa, fc)]
-    for a in fc.sft.attributes:
-        names.append(a.name)
-        arrays.append(_attr_array(pa, fc, a, dictionary))
-    table = pa.table(dict(zip(names, arrays)))
-    return table.replace_schema_metadata(
-        {_SFT_KEY: fc.sft.to_spec().encode(), _NAME_KEY: fc.sft.name.encode()}
-    )
+    batch = _native_batch(pa, fc, dictionary)
+    if batch is None:
+        return _pyarrow_table(pa, fc, dictionary)
+    return pa.Table.from_batches([batch])
 
 
 def read_arrow(source, sft=None) -> FeatureCollection:
@@ -168,6 +268,65 @@ def arrow_stream(
     if fh is not None:
         fh.write(payload)
     return payload
+
+
+#: an IPC stream's end: the continuation marker and a length of 0
+_END_OF_STREAM = b"\xff\xff\xff\xff\x00\x00\x00\x00"
+
+
+class ArrowChunks:
+    """ONE Arrow IPC stream of a collection as byte chunks, a record batch
+    of ``page_rows`` rows each: concatenated, bit-identical to
+    :func:`arrow_stream` with the same batch rows, whatever the page size
+    (the served data plane's Arrow answers, serving/http.py). The writer
+    writes into Arrow's own buffer, never into a Python object (which it
+    would take the interpreter lock for a write, 180 times an answer), so
+    an answer of one page is ONE chunk: schema, dictionaries, batch, end
+    of stream. A longer answer holds one page at a time beside the table:
+    the first page through the writer less the end-of-stream marker, each
+    later page as its batch's own IPC message, the marker last. Lazy:
+    nothing is built before the first pull. ``arrow_native``, once the
+    first chunk is out: True if the native build made every column
+    (:func:`_native_batch`); ``py_writes``: the writes that went
+    through a Python object."""
+
+    py_writes = 0
+
+    def __init__(self, fc: FeatureCollection, page_rows: int = BATCH_ROWS):
+        self._pa = _pa()  # raises here, before any chunk, where pyarrow is missing
+        self.arrow_native = None
+        self._gen = self._chunks(fc, max(int(page_rows), 1))
+
+    def __iter__(self):
+        return self
+
+    def __next__(self) -> bytes:
+        return next(self._gen)
+
+    def _chunks(self, fc, step):
+        pa = self._pa
+        import pyarrow.ipc as ipc
+
+        batch = _native_batch(pa, fc, True)
+        self.arrow_native = batch is not None
+        if batch is not None:  # to_batches' slices, without the table
+            schema = batch.schema
+            batches = [batch.slice(lo, step) for lo in range(0, len(fc), step)]
+        else:
+            table = _pyarrow_table(pa, fc, True)
+            schema, batches = table.schema, table.to_batches(max_chunksize=step)
+        sink = pa.BufferOutputStream()
+        with ipc.new_stream(sink, schema) as w:
+            if batches:
+                w.write_batch(batches[0])
+        head = sink.getvalue()
+        if len(batches) < 2:
+            yield head.to_pybytes()
+            return
+        yield head.slice(0, head.size - len(_END_OF_STREAM)).to_pybytes()
+        for batch in batches[1:]:
+            yield batch.serialize().to_pybytes()
+        yield _END_OF_STREAM
 
 
 def read_arrow_table(data: bytes):

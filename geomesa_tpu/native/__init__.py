@@ -137,6 +137,13 @@ def _load():
             ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
         ]
         lib.geojson_features.restype = ctypes.c_int64
+        lib.arrow_batch.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_void_p,
+        ]
+        lib.arrow_batch.restype = ctypes.c_int64
+        lib.arrow_batch_release.argtypes = [ctypes.c_void_p]
+        lib.arrow_batch_release.restype = None
         lib.points_in_polygon_cpp.argtypes = [
             f64p, f64p, ctypes.c_int64, f64p, i64p, ctypes.c_int64, i32p, u8p
         ]
@@ -470,6 +477,35 @@ def geojson_features(table: GeoJSONColumns, lo: int, hi: int) -> "bytes | None":
     )
     # the bytes are the calling thread's until its next call: copied here
     return None if n < 0 else ctypes.string_at(out, n)
+
+
+#: geomesa_native.cpp's AR_* ops: what ``arrow_batch`` makes of a column
+AR_STRING, AR_DICT, AR_BITS, AR_COPY, AR_DATE, AR_XY = range(1, 7)
+
+
+def arrow_batch(table: GeoJSONColumns, ops, order, importer):
+    """The record batch of the columns ``table`` describes, made whole in
+    one native call that holds no interpreter lock (geomesa_native.cpp
+    says how: ``ops`` is one ``AR_*`` a row of ``table.cols``, ``order``
+    the row of each of the batch's columns) and handed over through
+    Arrow's C data interface: ``importer(address)`` takes the ArrowArray
+    at ``address`` and owns its buffers from then on, as
+    ``pyarrow.RecordBatch._import_from_c`` does; what it returns is
+    returned. None where the bytes are pyarrow's to decide (NaT, a
+    surrogate, a code point past U+10FFFF): the caller's pyarrow route
+    then builds the table."""
+    lib = _load()
+    out = (ctypes.c_int64 * 10)()  # arrow/c/abi.h's ArrowArray: ten words
+    if lib.arrow_batch(
+        table.cols, (ctypes.c_int64 * len(ops))(*ops),
+        (ctypes.c_int64 * len(order))(*order), len(order), table.rows, out,
+    ) < 0:
+        return None
+    try:
+        return importer(ctypes.addressof(out))
+    finally:
+        if out[8]:  # ``release`` still set: nothing took the buffers
+            lib.arrow_batch_release(out)
 
 
 _ROW_GATHERS = {

@@ -611,6 +611,262 @@ extern "C" int64_t geojson_features(const int64_t* cols, int64_t ncols,
   return (int64_t)b.n;
 }
 
+// --------------------------------------------- Arrow batch of an answer
+// io/arrow.py's table build for collections whose columns can be read
+// without the interpreter: the whole record batch, every buffer of every
+// column, in ONE call with the interpreter lock released, handed to Arrow
+// through its C data interface (arrow/c/abi.h: the ArrowArray below),
+// which imports it in one more. pyarrow's own constructors give the lock
+// away once an array or more, and pa.array takes it back a `<U` cell:
+// about 130 hand-offs for the 27 attributes of GDELT's type, each a wait
+// among a served store's handler threads. Columns come as
+// geojson_features' rows of four int64 (ids, the point column's x and y,
+// the attributes in schema order) beside one op a row, and `order` names
+// the row of each of the batch's columns:
+//   AR_STRING  a `<U` column as a string array: int32 offsets, UTF-8 data
+//   AR_DICT    a `<U` column as dictionary_encode() gives it: int32 codes
+//              and a string dictionary, values in order of first appearance
+//   AR_BITS    a bool column as a bitmap, least significant bit first
+//   AR_COPY    a fixed-width column (a Date's int64 is timestamp[ms])
+//   AR_DATE    the same, where NaT (pyarrow's null) ends the call
+//   AR_XY      x's row, y's being the next: FixedSizeList<2 x f64>
+// A `<U` cell ends at its first NUL, where pyarrow's NumPy converter cuts
+// it. -1 where the bytes are not this code's to decide (a surrogate, a
+// code point past U+10FFFF, NaT, 2 GiB of text in one column): the
+// caller's pyarrow route then builds the table. The buffers are one
+// allocation that the batch's release callback frees, whichever thread
+// drops the last reference: Arrow reads them where they lie.
+
+struct ArrowArray {
+  int64_t length, null_count, offset, n_buffers, n_children;
+  const void** buffers;
+  ArrowArray** children;
+  ArrowArray* dictionary;
+  void (*release)(ArrowArray*);
+  void* private_data;
+};
+
+enum { AR_STRING = 1, AR_DICT = 2, AR_BITS = 3, AR_COPY = 4, AR_DATE = 5, AR_XY = 6 };
+
+// an array before its buffers have their last address: where up to three
+// lie in `bytes` (-1: none, as every validity bitmap), the node of its
+// one child or of its dictionary
+struct ArNode { int64_t length; int n_buffers; int64_t at[3]; int child, dictionary; };
+
+struct ArBatch {
+  GjBuf bytes;
+  std::vector<ArNode> nodes;
+  std::vector<ArrowArray> arrays;
+  std::vector<const void*> buffers;
+  std::vector<ArrowArray*> children;
+  int add(int64_t length, int n_buffers, int64_t a = -1, int64_t b = -1) {
+    nodes.push_back({length, n_buffers, {-1, a, b}, -1, -1});
+    return (int)nodes.size() - 1;
+  }
+};
+
+static inline int64_t ar_begin(GjBuf& b, size_t bytes) {  // buffers start in 64-byte steps
+  const size_t at = (b.n + 63) & ~(size_t)63;
+  b.need(at - b.n + bytes);
+  std::memset(b.p + b.n, 0, at - b.n);
+  b.n = at + bytes;
+  return (int64_t)at;
+}
+
+static inline int64_t ar_cell_len(const char* cell, int64_t width) {
+  int64_t len = 0;
+  while (len < width / 4 && gj_load<uint32_t>(cell + len * 4)) ++len;
+  return len;
+}
+
+static bool ar_utf8(GjBuf& b, const char* cell, int64_t len) {
+  char* p = b.need((size_t)len * 4);
+  char* const p0 = p;
+  for (int64_t i = 0; i < len; ++i) {
+    const uint32_t c = gj_load<uint32_t>(cell + i * 4);
+    if (c < 0x80) {
+      *p++ = (char)c;
+    } else if (c < 0x800) {
+      *p++ = (char)(0xc0 | (c >> 6));
+      *p++ = (char)(0x80 | (c & 0x3f));
+    } else if (c < 0x10000) {
+      if (c >= 0xd800 && c < 0xe000) return false;
+      *p++ = (char)(0xe0 | (c >> 12));
+      *p++ = (char)(0x80 | ((c >> 6) & 0x3f));
+      *p++ = (char)(0x80 | (c & 0x3f));
+    } else {
+      if (c > 0x10ffff) return false;
+      *p++ = (char)(0xf0 | (c >> 18));
+      *p++ = (char)(0x80 | ((c >> 12) & 0x3f));
+      *p++ = (char)(0x80 | ((c >> 6) & 0x3f));
+      *p++ = (char)(0x80 | (c & 0x3f));
+    }
+  }
+  b.n += (size_t)(p - p0);
+  return true;
+}
+
+static inline void ar_put32(GjBuf& b, int64_t at, int64_t i, int32_t v) {
+  std::memcpy(b.p + at + i * 4, &v, 4);
+}
+
+static int ar_string(ArBatch& t, const int64_t* col, int64_t rows) {
+  GjBuf& b = t.bytes;
+  const int64_t offsets = ar_begin(b, (size_t)(rows + 1) * 4);
+  const int64_t data = ar_begin(b, 0);
+  for (int64_t i = 0; i < rows; ++i) {
+    const char* cell = (const char*)col[1] + i * col[2];
+    ar_put32(b, offsets, i, (int32_t)(b.n - data));
+    if (!ar_utf8(b, cell, ar_cell_len(cell, col[3])) || b.n - data > (size_t)INT32_MAX)
+      return -1;
+  }
+  ar_put32(b, offsets, rows, (int32_t)(b.n - data));
+  return t.add(rows, 3, offsets, data);
+}
+
+struct ArSeen { const char* cell; int64_t len; uint64_t hash; };
+
+static inline uint64_t ar_hash(const char* cell, int64_t len) {
+  uint64_t h = 0x9e3779b97f4a7c15ull ^ (uint64_t)len;
+  for (int64_t i = 0; i < len; ++i) {
+    h = (h ^ gj_load<uint32_t>(cell + i * 4)) * 0xff51afd7ed558ccdull;
+    h ^= h >> 32;
+  }
+  return h;
+}
+
+static int ar_dictionary(ArBatch& t, const int64_t* col, int64_t rows) {
+  GjBuf& b = t.bytes;
+  const int64_t codes = ar_begin(b, (size_t)rows * 4);
+  const int64_t data = ar_begin(b, 0);
+  std::vector<ArSeen> seen;
+  std::vector<int32_t> ends(1, 0), slots(64, -1);  // open addressing, at most half full
+  for (int64_t i = 0; i < rows; ++i) {
+    const char* cell = (const char*)col[1] + i * col[2];
+    const int64_t len = ar_cell_len(cell, col[3]);
+    const uint64_t h = ar_hash(cell, len);
+    size_t s = (size_t)h & (slots.size() - 1);
+    int32_t code;
+    while ((code = slots[s]) >= 0) {
+      const ArSeen& e = seen[(size_t)code];
+      if (e.hash == h && e.len == len && !std::memcmp(e.cell, cell, (size_t)len * 4)) break;
+      s = (s + 1) & (slots.size() - 1);
+    }
+    if (code < 0) {
+      if (seen.size() >= (size_t)INT32_MAX) return -1;
+      code = slots[s] = (int32_t)seen.size();
+      seen.push_back({cell, len, h});
+      if (!ar_utf8(b, cell, len) || b.n - data > (size_t)INT32_MAX) return -1;
+      ends.push_back((int32_t)(b.n - data));
+      if (seen.size() * 2 > slots.size()) {
+        slots.assign(slots.size() * 2, -1);
+        for (size_t k = 0; k < seen.size(); ++k) {
+          size_t at = (size_t)seen[k].hash & (slots.size() - 1);
+          while (slots[at] >= 0) at = (at + 1) & (slots.size() - 1);
+          slots[at] = (int32_t)k;
+        }
+      }
+    }
+    ar_put32(b, codes, i, code);
+  }
+  const int64_t offsets = ar_begin(b, ends.size() * 4);
+  std::memcpy(b.p + offsets, ends.data(), ends.size() * 4);
+  const int node = t.add(rows, 2, codes);
+  const int values = t.add((int64_t)seen.size(), 3, offsets, data);
+  t.nodes[(size_t)node].dictionary = values;
+  return node;
+}
+
+static int ar_column(ArBatch& t, const int64_t* col, int64_t op, int64_t rows) {
+  GjBuf& b = t.bytes;
+  const char* src = (const char*)col[1];
+  const int64_t stride = col[2], w = col[3];
+  switch (op) {
+    case AR_STRING: return ar_string(t, col, rows);
+    case AR_DICT: return ar_dictionary(t, col, rows);
+    case AR_BITS: {
+      const size_t bytes = (size_t)((rows + 7) / 8);
+      const int64_t at = ar_begin(b, bytes);
+      std::memset(b.p + at, 0, bytes);
+      for (int64_t i = 0; i < rows; ++i)
+        if (src[i * stride]) b.p[at + (i >> 3)] |= (char)(1 << (i & 7));
+      return t.add(rows, 2, at);
+    }
+    case AR_COPY:
+    case AR_DATE: {
+      const int64_t at = ar_begin(b, (size_t)(rows * w));
+      if (stride == w) std::memcpy(b.p + at, src, (size_t)(rows * w));
+      else for (int64_t i = 0; i < rows; ++i) std::memcpy(b.p + at + i * w, src + i * stride, (size_t)w);
+      if (op == AR_DATE)
+        for (int64_t i = 0; i < rows; ++i)
+          if (gj_load<int64_t>(b.p + at + i * 8) == INT64_MIN) return -1;
+      return t.add(rows, 2, at);
+    }
+    case AR_XY: {
+      const int64_t* ys = col + 4;
+      const int64_t at = ar_begin(b, (size_t)rows * 16);
+      for (int64_t i = 0; i < rows; ++i) {
+        std::memcpy(b.p + at + i * 16, src + i * stride, 8);
+        std::memcpy(b.p + at + i * 16 + 8, (const char*)ys[1] + i * ys[2], 8);
+      }
+      const int node = t.add(rows, 1);
+      const int values = t.add(rows * 2, 2, at);
+      t.nodes[(size_t)node].child = values;
+      return node;
+    }
+  }
+  return -1;
+}
+
+static void ar_release_part(ArrowArray* a) { a->release = nullptr; }
+
+static void ar_release(ArrowArray* a) {
+  delete (ArBatch*)a->private_data;
+  a->release = nullptr;
+}
+
+extern "C" int64_t arrow_batch(const int64_t* cols, const int64_t* ops,
+                               const int64_t* order, int64_t nout, int64_t rows,
+                               ArrowArray* out) {
+  ArBatch* t = new ArBatch;
+  t->bytes.need(64);
+  std::vector<int> columns;
+  for (int64_t k = 0; k < nout; ++k) {
+    columns.push_back(ar_column(*t, cols + 4 * order[k], ops[order[k]], rows));
+    if (columns.back() < 0) {
+      delete t;
+      return -1;
+    }
+  }
+  GjBuf& b = t->bytes;
+  b.p = (char*)std::realloc(b.p, b.cap = std::max(b.n, (size_t)64));  // the doubling's slack
+  const size_t n = t->nodes.size();
+  t->arrays.resize(n);
+  t->buffers.assign(3 * n + 1, nullptr);  // the last: the batch's own, no validity
+  t->children.assign((size_t)nout + n, nullptr);  // the batch's, then slot i for node i's child
+  for (size_t i = 0; i < n; ++i) {
+    const ArNode& nd = t->nodes[i];
+    for (int k = 0; k < 3; ++k)
+      if (nd.at[k] >= 0) t->buffers[3 * i + k] = b.p + nd.at[k];
+    ArrowArray** child = &t->children[(size_t)nout + i];
+    if (nd.child >= 0) *child = &t->arrays[(size_t)nd.child];
+    t->arrays[i] = {nd.length, 0, 0, nd.n_buffers, nd.child >= 0 ? 1 : 0,
+                    &t->buffers[3 * i], child,
+                    nd.dictionary >= 0 ? &t->arrays[(size_t)nd.dictionary] : nullptr,
+                    ar_release_part, nullptr};
+  }
+  for (int64_t k = 0; k < nout; ++k)
+    t->children[(size_t)k] = &t->arrays[(size_t)columns[(size_t)k]];
+  *out = {rows, 0, 0, 1, nout, &t->buffers[3 * n], t->children.data(), nullptr,
+          ar_release, t};
+  return 0;
+}
+
+// an ArrowArray that nothing imported: what arrow_batch made is given back
+extern "C" void arrow_batch_release(ArrowArray* a) {
+  if (a->release) a->release(a);
+}
+
 // ----------------------------------------------- point-in-polygon refine
 // Host refinement hot loop for polygon queries over point stores: the
 // numpy even-odd ray cast materializes an [n_points, n_edges] matrix

@@ -12,8 +12,7 @@ callers do, plus the things only a network boundary needs
   (``geomesa.serve.page.rows`` rows per chunk) so one big result never
   head-of-line-blocks the socket — and bit-identical to the in-process
   exporters by construction (a GeoJSON answer IS ``io/exporters.py``'s
-  ``GeoJSONChunks``; Arrow composes the per-batch writer ``io/arrow.py``
-  uses);
+  ``GeoJSONChunks``, an Arrow one ``io/arrow.py``'s ``ArrowChunks``);
 - **a streaming ingest endpoint** (``POST /ingest/<type>``) whose 200
   acknowledgment rides :meth:`LambdaStore.write
   <geomesa_tpu.streaming.store.LambdaStore.write>`'s WAL path: when the
@@ -425,13 +424,15 @@ class DataServer:
         cur = _otracer().current()
         if cur is not None:  # the request's ``http`` root
             cur.trace.root.annotate(fmt=fmt, rows=len(fc))
+        # the exporters' own chunks: the bytes of the in-process export by
+        # construction; lazy, so the encoding runs where they are drained
         if fmt == "arrow":
+            from geomesa_tpu.io.arrow import ArrowChunks
+
             try:
-                return 200, ARROW_CTYPE, _arrow_chunks(fc, page_rows), extra
+                return 200, ARROW_CTYPE, ArrowChunks(fc, page_rows), extra
             except RuntimeError as e:  # pyarrow not installed
                 return self._client_error(501, str(e))
-        # the exporter's own chunks: the bytes of the in-process export by
-        # construction; lazy, so the encoding runs where they are drained
         from geomesa_tpu.io.exporters import GeoJSONChunks
 
         return 200, GEOJSON_CTYPE, GeoJSONChunks(fc, page_rows), extra
@@ -497,6 +498,12 @@ class DataServer:
                 native = getattr(payload, "native", None)
                 if native is not None:  # a GeoJSON answer: which route
                     sp.annotate(native=int(native))
+                arrow_native = getattr(payload, "arrow_native", None)
+                if arrow_native is not None:  # an Arrow answer: which route
+                    sp.annotate(
+                        arrow_native=int(arrow_native),
+                        py_writes=payload.py_writes,
+                    )
         return sent
 
     # -- POST -------------------------------------------------------------
@@ -583,58 +590,6 @@ class DataServer:
             }:
                 security.validate(str(label))
         return fc
-
-
-# -- the streamed Arrow serializer (bit-identical to the one-shot exporter) --
-
-class _ArrowSink:
-    """A write-only file shim collecting the IPC writer's output so the
-    generator can yield it batch-by-batch."""
-
-    closed = False
-
-    def __init__(self):
-        self.chunks: list = []
-
-    def write(self, b) -> int:
-        self.chunks.append(bytes(b))
-        return len(b)
-
-    def flush(self) -> None:
-        pass
-
-    def drain(self) -> bytes:
-        out, self.chunks = b"".join(self.chunks), []
-        return out
-
-
-def _arrow_chunks(fc, page_rows: int):
-    """Byte chunks forming ONE Arrow IPC stream, one record batch per
-    ``page_rows`` rows — concatenated, bit-identical to
-    :func:`geomesa_tpu.io.arrow.arrow_stream` with the same batch rows
-    (same table construction, same writer)."""
-    from geomesa_tpu.io.arrow import _pa, to_arrow_table
-
-    _pa()
-    import pyarrow.ipc as ipc
-
-    def gen():
-        # the table is built on the first pull, as the GeoJSON features
-        # are: all of a response's encoding runs where it is written
-        table = to_arrow_table(fc)
-        sink = _ArrowSink()
-        with ipc.new_stream(sink, table.schema) as writer:
-            if table.num_rows:
-                for batch in table.to_batches(
-                    max_chunksize=max(int(page_rows), 1)
-                ):
-                    writer.write_batch(batch)
-                    yield sink.drain()
-        tail = sink.drain()
-        if tail:
-            yield tail
-
-    return gen()
 
 
 def _grid_arrow(grid) -> bytes:

@@ -140,6 +140,20 @@ class TestWireFormats:
             map(str, direct.ids.tolist())
         )
 
+    @pytest.mark.parametrize("rows", [1, 64, "all", 4096])
+    def test_arrow_identity_across_page_sizes(self, served, rows):
+        """One record batch a page, whatever the page: the chunks of one
+        page (schema, dictionaries, batch, end of stream) and of many
+        (later pages as their own IPC messages) are the one-shot
+        stream's bytes with the same batch rows."""
+        from geomesa_tpu.io.arrow import arrow_stream
+
+        ds, srv, client = served
+        direct = ds.query("t", Q)
+        rows = len(direct) if rows == "all" else rows
+        raw = client.query("t", cql=Q, fmt="arrow", page_rows=rows)
+        assert len(direct) > 64 and raw == arrow_stream(direct, batch_rows=rows)
+
     def test_keep_alive_connection_reused(self, served):
         ds, srv, client = served
         with DataClient(srv.url, keep_alive=True) as ka:
