@@ -235,13 +235,6 @@ LOCKS: dict[str, LockDecl] = {d.name: d for d in [
        doc="trace retention rings + sampling counter: taken once per "
            "root begin/end, never per child span; nothing blocking "
            "runs under it and it acquires no other lock"),
-    _d("TuningManager._lock", "geomesa_tpu/tuning/manager.py", 77,
-       fields=("_queries", "_pulses", "_pulsing", "_decisions"),
-       doc="tuning pacing counters + the decision ring + the pulse "
-           "claim flag: a LEAF by design — every sense/adjust step "
-           "(metrics reads, accuracy report, SLO burn, conf writes) "
-           "runs OUTSIDE it between claim and release; only arithmetic "
-           "and the deque extend ever hold it"),
     _d("TelemetryRecorder._lock", "geomesa_tpu/obs/ops.py", 79,
        fields=("_rings",),
        doc="telemetry history rings: the 1 Hz sampler appends points "

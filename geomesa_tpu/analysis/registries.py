@@ -117,35 +117,6 @@ FAULT_POINTS: dict[str, str] = {
     "pod.wal.replay": "before a killed host's WAL replay on rejoin",
 }
 
-# -- controllers ----------------------------------------------------------
-# The FIFTH dotted-name namespace (PR 19; docs/tuning.md): the store's
-# AUTO-TUNED knob surface. A knob a controller writes online is a
-# bigger contract than a knob an operator sets — it must declare hard
-# bounds (the controller may never leave them) and an objective metric
-# that actually exists (a controller optimizing a metric nobody
-# records would hill-climb noise). Like USER_DATA_KEYS and
-# FAULT_POINTS, this registry IS the declaration; the
-# ``controller-registry`` rule machine-checks both directions (every
-# ``ControllerSpec`` literal registered here, every name here backed
-# by a spec) plus the per-spec contract: knob resolves in the knob
-# registry, ``lo < hi`` present, objective resolves in the metrics
-# registry.
-CONTROLLERS: dict[str, str] = {
-    "cache_min_cost": (
-        "result-cache admission cost threshold, tuned against the "
-        "cache-hit rate (cache/result.py admission gate)"
-    ),
-    "fold_slice_rows": (
-        "incremental fold slice size, tuned against the slice-pause "
-        "p99 (datastore.fold_upsert)"
-    ),
-    "flush_chunk_rows": (
-        "stream flush batch rows, tuned against flushed-row "
-        "throughput (streaming/flush.py)"
-    ),
-}
-
-
 # metric instrument methods on MetricsRegistry, by instrument kind
 INSTRUMENT_METHODS = {
     "counter": "counter",
@@ -429,62 +400,6 @@ def test_string_tokens(project: Project) -> set[str]:
                 tokens.add(tok)
     project._lint_test_tokens = tokens  # type: ignore[attr-defined]
     return tokens
-
-
-# -- controller-spec occurrences ------------------------------------------
-
-
-@dataclass(frozen=True)
-class ControllerSpecUse:
-    """One ``ControllerSpec(...)`` literal call site, with the fields
-    the controller-registry rule checks. Non-literal field values come
-    through as None and are reported as missing — a spec whose bounds
-    are computed cannot be machine-checked, so it does not pass."""
-
-    name: "str | None"
-    knob: "str | None"
-    lo: "float | None"
-    hi: "float | None"
-    objective: "str | None"
-    path: str
-    line: int
-
-
-def _const_num(node) -> "float | None":
-    if isinstance(node, ast.Constant) and isinstance(node.value, (int, float)) \
-            and not isinstance(node.value, bool):
-        return float(node.value)
-    return None
-
-
-def controller_spec_uses(project: Project) -> list[ControllerSpecUse]:
-    """Every ``ControllerSpec(...)`` call in the production tree with
-    its literal name/knob/bounds/objective fields (keyword or
-    positional, matching the dataclass field order)."""
-    fields = ("name", "knob", "lo", "hi", "objective")
-    out: list[ControllerSpecUse] = []
-    for sf in project.python_files():
-        if sf.tree is None:
-            continue
-        for node in ast.walk(sf.tree):
-            if not isinstance(node, ast.Call):
-                continue
-            if call_name(node) != "ControllerSpec":
-                continue
-            got: dict = {f: None for f in fields}
-            for i, arg in enumerate(node.args[:5]):
-                f = fields[i]
-                got[f] = _const_num(arg) if f in ("lo", "hi") else const_str(arg)
-            for kw in node.keywords:
-                if kw.arg in ("lo", "hi"):
-                    got[kw.arg] = _const_num(kw.value)
-                elif kw.arg in fields:
-                    got[kw.arg] = const_str(kw.value)
-            out.append(ControllerSpecUse(
-                got["name"], got["knob"], got["lo"], got["hi"],
-                got["objective"], sf.relpath, node.lineno,
-            ))
-    return out
 
 
 # -- doc occurrences ------------------------------------------------------

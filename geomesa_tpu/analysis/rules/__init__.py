@@ -14,7 +14,6 @@ from geomesa_tpu.analysis.rules.concurrency import (
     GuardedEscapeRule,
     LockOrderRule,
 )
-from geomesa_tpu.analysis.rules.controllers import ControllerRegistryRule
 from geomesa_tpu.analysis.rules.faults import FaultPointRule
 from geomesa_tpu.analysis.rules.fused import FusedVariantKeyRule
 from geomesa_tpu.analysis.rules.kernels import (
@@ -45,7 +44,6 @@ ALL_RULES = [
     MetricConventionRule(),
     MetricTypeConflictRule(),
     FaultPointRule(),
-    ControllerRegistryRule(),
     FusedVariantKeyRule(),
     LockDisciplineRule(),
     LockOrderRule(),

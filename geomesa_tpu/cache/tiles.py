@@ -37,7 +37,7 @@ from typing import Optional
 import numpy as np
 
 from geomesa_tpu.cache.generations import GenerationTracker, KeyRange
-from geomesa_tpu.tuning.primitives import ProbeGate, ewma_step
+from geomesa_tpu.utils.costgate import ProbeGate, ewma_step
 
 
 @dataclass
@@ -77,7 +77,7 @@ class TileCacheConf:
 # for fragmented edge-strip scans. The cache measures BOTH costs per type
 # (EWMAs) and gates composition off when it is losing, re-probing
 # periodically in case the balance shifts (store grew, tiles warmed).
-# The blend/explore/re-probe mechanics live in tuning/primitives.py —
+# The blend/explore/re-probe mechanics live in utils/costgate.py —
 # this gate, the join gate and standing's match gate share them.
 _EXPLORE_MIN = 6     # composes observed before the gate may trip
 _REPROBE_EVERY = 8   # gated attempts between re-explorations

@@ -477,61 +477,6 @@ def cmd_ops(args) -> int:
     return 0
 
 
-def cmd_tune(args) -> int:
-    """One-shot self-tuning report (docs/tuning.md): every controller's
-    current value/bounds/objective reading, the plan-feedback factor
-    table, the burn gate state and the recorded adaptation decisions —
-    human text, or `--json` for scripts. Attaches a manager over the
-    loaded catalog (rehydrating persisted state when a state file
-    exists) without arming it; a live serving process exposes the same
-    payload at `GET /debug/tuning`."""
-    import os as _os
-
-    ds = _load(args)
-    state = args.state
-    if state is None:
-        default_state = _os.path.join(args.catalog, "_tuning.json")
-        if _os.path.exists(default_state):
-            state = default_state
-    if ds.tuning is None:
-        ds.attach_tuning(state_path=state)
-    report = ds.tuning_report()
-    if args.json:
-        print(json.dumps(report, default=str))
-        return 0
-    print(f"tuning: {'armed' if report['enabled'] else 'disarmed'}")
-    print("controllers:")
-    for row in report["controllers"]:
-        reading = row["reading"]
-        print(
-            f"  {row['name']}: {row['knob']} = {row['value']} "
-            f"in [{row['lo']:g}, {row['hi']:g}] "
-            f"({row['policy']} on {row['objective']}, "
-            f"reading {'-' if reading is None else f'{reading:.6g}'})"
-        )
-    factors = report["plan_factors"]
-    print("plan factors (estimate-accuracy reweighting, 1.0 = neutral):")
-    if not factors:
-        print("  none engaged")
-    for key, fac in factors.items():
-        print(f"  {key}: x{fac}")
-    burn = report.get("burn")
-    if burn:
-        state_s = "ENGAGED" if burn["engaged"] else "clear"
-        print(
-            f"burn gate: {state_s} ({burn['objective']} burn "
-            f"{burn['burn']}x / threshold {burn['threshold']}x)"
-        )
-    print(f"decisions (last {len(report['decisions'])}):")
-    if not report["decisions"]:
-        print("  none recorded")
-    for d in report["decisions"]:
-        what = d.get("knob") or d.get("key") or d["controller"]
-        print(f"  {d['controller']} {what}: {d['from']} -> {d['to']}")
-        print(f"    {d['reason']}")
-    return 0
-
-
 def cmd_serve(args, hold: bool = True):
     """Serve a catalog over HTTP (docs/serving.md "The data plane"):
     `/query/<type>`, `/ingest/<type>` and `/tenants` plus the ops
@@ -740,14 +685,6 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument(
         "--slow", type=int, default=10,
         help="slow-query captures to include (default 10)",
-    )
-
-    sp = add("tune", cmd_tune)
-    sp.add_argument("--json", action="store_true", help="machine-readable output")
-    sp.add_argument(
-        "--state", default=None, metavar="PATH",
-        help="tuning state file to report from (default "
-        "<catalog>/_tuning.json when present)",
     )
 
     sp = add("serve", cmd_serve)

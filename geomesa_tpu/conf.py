@@ -676,58 +676,6 @@ TENANT_SLO_P99_MS = SystemProperty(
     "tenant's own SloTracker window (0 disables per-tenant objectives)",
 )
 
-# -- self-tuning controller tier (docs/tuning.md) -------------------------
-TUNING_ENABLED = SystemProperty(
-    "geomesa.tuning.enabled", False, _parse_bool,
-    "arm the self-tuning controller tier (plan-feedback index "
-    "reweighting, knob auto-tuning, SLO-burn admission shedding); off "
-    "is bit-identical to a store without the tier",
-)
-TUNING_INTERVAL = SystemProperty(
-    "geomesa.tuning.interval", 64, int,
-    "queries between adaptation pulses: the tuning loop piggybacks on "
-    "the query path, so a busier store adapts faster and an idle one "
-    "not at all",
-)
-TUNING_DECISIONS = SystemProperty(
-    "geomesa.tuning.decisions", 128, int,
-    "bounded length of the adaptation decision ring served by "
-    "/debug/tuning and `geomesa tune` — the audit trail of a store "
-    "that changes its own configuration",
-)
-TUNING_PLAN_MAX_ADJUST = SystemProperty(
-    "geomesa.tuning.plan.max.adjust", 4.0, float,
-    "hard cap on the plan-feedback priority inflation for a "
-    "chronically misestimating index: it can lose plans but never be "
-    "exiled",
-)
-TUNING_PLAN_DEADBAND = SystemProperty(
-    "geomesa.tuning.plan.deadband", 2.0, float,
-    "p90 estimate-error factor at which plan reweighting engages; "
-    "release happens at the midpoint back toward 1.0, and the band "
-    "between holds (hysteresis: no plan flapping)",
-)
-TUNING_PLAN_MIN_COUNT = SystemProperty(
-    "geomesa.tuning.plan.min.count", 8, int,
-    "accuracy-window samples required per (type, index) before plan "
-    "reweighting may act on its error factor",
-)
-TUNING_BURN_OBJECTIVE = SystemProperty(
-    "geomesa.tuning.burn.objective", "query_p99", str,
-    "SLO objective name whose burn rate drives admission shedding "
-    "(must exist in the attached tracker's objective set)",
-)
-TUNING_BURN_THRESHOLD = SystemProperty(
-    "geomesa.tuning.burn.threshold", 2.0, float,
-    "burn rate above which the scheduler sheds below-max-weight "
-    "tenant work BEFORE the queue is physically full",
-)
-TUNING_BURN_RELEASE = SystemProperty(
-    "geomesa.tuning.burn.release", 1.0, float,
-    "burn rate at or below which engaged burn shedding releases "
-    "(hysteresis gap against admission flapping)",
-)
-
 
 def describe() -> str:
     """One line per registered property with its current value (CLI env)."""

@@ -248,10 +248,6 @@ class DataStore:
         self.ops = None
         # data plane (docs/serving.md): attached by serve(port=...)
         self.server = None
-        # self-tuning controller tier (docs/tuning.md): attached by
-        # attach_tuning(); None (and a disarmed manager) keep every
-        # hook path bit-identical to a store without the tier
-        self.tuning = None
 
     def serve(self, config=None, port: "int | None" = None,
               host: "str | None" = None, **server_kwargs):
@@ -292,11 +288,6 @@ class DataStore:
             if config is None or config is True:
                 config = ServingConfig.from_properties()
             self.scheduler = QueryScheduler(self, config).start()
-            # an armed tuning tier attached before serve(): wire its
-            # burn gate onto the fresh scheduler (docs/tuning.md leg c)
-            tuning = self.tuning
-            if tuning is not None and tuning.enabled:
-                self.scheduler.burn_gate = tuning.burnshed
             return self.scheduler
 
     def attach_cache(self, cache) -> None:
@@ -1691,13 +1682,6 @@ class DataStore:
                 self.metrics.timer_update(
                     "geomesa.query.cache_probe", plan.cache_probe_s
                 )
-        # self-tuning pacing (docs/tuning.md): an ARMED tuning tier
-        # counts every recorded query and runs one adaptation pulse per
-        # interval in this caller's thread — no locks are held here, and
-        # a disarmed/absent manager costs one attribute read
-        tuning = self.tuning
-        if tuning is not None and tuning.enabled:
-            tuning.on_query()
         if self.audit is not None:
             from geomesa_tpu.audit import AuditedEvent
             from geomesa_tpu.obs.trace import tracer
@@ -2334,62 +2318,6 @@ class DataStore:
         ops = self.ops
         if ops is not None:
             ops.close()
-        tuning = self.tuning
-        if tuning is not None:
-            # learned state outlives the store handle (docs/tuning.md
-            # "Persistence"): factors, controller baselines, tuned knobs
-            tuning.save()
-
-    def attach_tuning(self, enabled=None, state_path=None, interval=None):
-        """Attach the self-tuning controller tier (docs/tuning.md): one
-        :class:`~geomesa_tpu.tuning.manager.TuningManager` closing the
-        loop from this store's telemetry (estimate-accuracy windows,
-        metric rings, SLO burn) to its knobs, plan weights and
-        admission. ``enabled`` defaults to the
-        ``geomesa.tuning.enabled`` knob; a DISARMED manager reports
-        state but never
-        pulses, never installs the planner/scheduler hooks, and leaves
-        behavior bit-identical. ``state_path`` names a JSON file the
-        learned state persists to on :meth:`close` and rehydrates from
-        here, so a reopened store does not re-learn from zero.
-        Idempotent-by-replacement: re-attaching builds a fresh manager
-        and re-wires the hooks. Returns the manager."""
-        from geomesa_tpu.metrics import MetricsRegistry
-        from geomesa_tpu.tuning import TuningManager
-
-        if self.metrics is None:
-            # the tier is telemetry-driven: without a registry there is
-            # nothing to sense, so attach one (mirrors attach_slo)
-            self.metrics = MetricsRegistry()
-        manager = TuningManager(
-            self, enabled=enabled, state_path=state_path, interval=interval
-        )
-        self.tuning = manager
-        if manager.enabled:
-            self.planner.reweighter = manager.reweighter
-            sched = self.scheduler
-            if sched is not None:
-                sched.burn_gate = manager.burnshed
-        else:
-            # disarm must restore today's exact behavior, including
-            # after a previously-armed manager is replaced
-            self.planner.reweighter = None
-            sched = self.scheduler
-            if sched is not None:
-                sched.burn_gate = None
-        return manager
-
-    def tuning_report(self) -> dict:
-        """The attached tuning manager's report — the ``/debug/tuning``
-        payload (controller values/bounds/readings, plan factors, burn
-        gate state, decision ring). An unattached store reports a
-        disarmed empty tier."""
-        if self.tuning is None:
-            return {
-                "enabled": False, "controllers": [], "plan_factors": {},
-                "burn": None, "decisions": [],
-            }
-        return self.tuning.report()
 
     def attach_slo(self, objectives=None):
         """Attach an SLO tracker (docs/observability.md): declarative

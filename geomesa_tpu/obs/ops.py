@@ -397,7 +397,7 @@ class OpsRoutes:
     #: paths this table answers (the data server's dispatch check)
     PATHS = (
         "/metrics", "/health", "/stats", "/debug/slow", "/debug/trace",
-        "/debug/vars", "/debug/audit", "/debug/tuning", "/debug/stalls",
+        "/debug/vars", "/debug/audit", "/debug/stalls",
     )
 
     def __init__(self, store, lam=None, audit=None):
@@ -461,15 +461,6 @@ class OpsRoutes:
             if n > 0:
                 events = events[-n:]
             return 200, "application/json", _json_dump(events)
-        if path == "/debug/tuning":
-            # the self-tuning tier's audit surface (docs/tuning.md):
-            # controller values/bounds/objective readings, plan factor
-            # table, burn gate state, and the decision ring with reasons
-            return 200, "application/json", _json_dump(
-                self.store.tuning_report()
-                if hasattr(self.store, "tuning_report")
-                else {"enabled": False, "controllers": [], "decisions": []}
-            )
         return 404, "application/json", _json_dump(
             {"error": f"unknown path {path!r}"}
         )

@@ -211,11 +211,6 @@ class QueryPlanner:
         self._config_memo: "OrderedDict" = OrderedDict()
         self._memo_lock = threading.Lock()
         self._memo_epoch = 0  # bumped by every invalidation (see below)
-        # plan-feedback hook (docs/tuning.md): an armed tuning tier
-        # installs its IndexReweighter here; None (the default, and the
-        # disarmed state) keeps cost() bit-identical to the static
-        # multipliers. Reads are lock-free (immutable table swap).
-        self.reweighter = None
 
     @property
     def mutation_epoch(self) -> int:
@@ -439,11 +434,12 @@ class QueryPlanner:
         best: dict = {}  # member -> (cost, index name, config), the first cheapest
         for name, kept in served:
             table = tables[name]
+            mult = index_priority(name)
             if table is not None and kept:
                 uniq = list({id(cfg): cfg for _, cfg in kept}.values())
                 rows = dict(zip(map(id, uniq), table.candidate_rows_many(uniq).tolist()))
             for m, cfg in kept:
-                cost = self._multiplier(type_name, name, exps[m])
+                cost = mult
                 if table is not None:
                     cost *= rows[id(cfg)] + 1
                 exps[m](f"Index {name}: {cfg.n_ranges} ranges, cost {cost:.1f}")
@@ -644,7 +640,7 @@ class QueryPlanner:
             if cfg.disjoint:
                 exp(f"Index {idx.name}: filter disjoint -> empty plan")
                 return QueryPlan(type_name, f, idx.name, cfg, limit=limit)
-            cost = self.cost(type_name, idx.name, cfg, exp)
+            cost = self.cost(type_name, idx.name, cfg)
             options.append((cost, idx.name, cfg))
             exp(
                 f"Index {idx.name}: {cfg.n_ranges} ranges, cost {cost:.1f}"
@@ -657,39 +653,20 @@ class QueryPlanner:
         exp(f"Strategy: {name} (cost {cost:.1f})")
         return QueryPlan(type_name, f, name, cfg, limit=limit)
 
-    def cost(self, type_name: str, index_name: str, cfg: ScanConfig, exp) -> float:
+    def cost(self, type_name: str, index_name: str, cfg: ScanConfig) -> float:
         """Cost = estimated rows scanned x index multiplier (reference
         CostBasedStrategyDecider: stats.getCount x costMultiplier,
         StrategyDecider.scala:143-180). The primary estimator is exact —
         the sum of the searchsorted row spans the ranges cover, since the
         sorted keys are host-resident; the sketch estimate (Z3Histogram)
-        and the bare priority constant are fallbacks. An armed tuning
-        tier inflates the multiplier of an index whose row estimates
-        chronically miss (docs/tuning.md leg a) — bounded, hysteretic,
-        and explain-traced; factor 1.0 (or no reweighter) leaves the
-        cost bit-identical to the static decision."""
-        mult = self._multiplier(type_name, index_name, exp)
+        and the bare priority constant are fallbacks."""
+        mult = index_priority(index_name)
         try:
             table = self.store.table(type_name, index_name)
         except KeyError:
             return mult  # no data written yet
         rows = table.candidate_spans(cfg).n_rows()
         return (rows + 1) * mult
-
-    def _multiplier(self, type_name: str, index_name: str, exp) -> float:
-        """An index's static cost multiplier, times the armed tuning
-        tier's estimate-accuracy factor."""
-        mult = index_priority(index_name)
-        rw = self.reweighter
-        if rw is not None:
-            fac = rw.factor(type_name, index_name)
-            if fac != 1.0:
-                mult *= fac
-                exp(
-                    f"Index {index_name}: estimate-accuracy reweight "
-                    f"x{fac:.2f} (docs/tuning.md)"
-                )
-        return mult
 
     # -- execution -------------------------------------------------------
     def execute(

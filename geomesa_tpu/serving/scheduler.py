@@ -168,13 +168,6 @@ class QueryScheduler:
         # read of a slightly stale value only mistimes one shed decision
         self._window_s = 0.0
         self._thread: Optional[threading.Thread] = None  # guarded-by: _cond
-        # SLO-burn admission gate (docs/tuning.md leg c): an armed
-        # tuning tier installs its BurnShed here; None (the default and
-        # disarmed state) keeps admission bit-identical to physical
-        # backpressure only. Consulted BEFORE _cond is taken — the
-        # gate's own reads (SLO tracker, tenant weights) never nest
-        # under the scheduler condition.
-        self.burn_gate = None
 
     # -- lifecycle -------------------------------------------------------
     @property
@@ -345,19 +338,6 @@ class QueryScheduler:
                 f"{self._window_s * 1e3:.1f}ms batch window"
             ))
             return fut
-
-        # SLO-burn shed (docs/tuning.md): while the tracked p99
-        # objective burns its error budget past threshold, below-max-
-        # weight tenant work sheds HERE — before the queue is physically
-        # full — so the remaining capacity serves the top-weight tier.
-        # No lock is held; the gate reads an atomically-swapped snapshot.
-        gate = self.burn_gate
-        if gate is not None:
-            burn_why = gate.should_shed(tenant)
-            if burn_why is not None:
-                self.metrics.counter("geomesa.tuning.shed")
-                self._shed(it, burn_why, ServingRejected(burn_why))
-                return fut
 
         # backpressure: the shared bound AND (when tenancy is on) the
         # caller's per-tenant quota — a flooding tenant hits its own

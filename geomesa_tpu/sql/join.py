@@ -46,12 +46,12 @@ from geomesa_tpu.metrics import resolve as _resolve_metrics
 from geomesa_tpu.obs.trace import NULL_SPAN as _NULL_SPAN
 from geomesa_tpu.obs.trace import span as _ospan
 from geomesa_tpu.obs.trace import tracer as _otracer
-from geomesa_tpu.tuning.primitives import CostEwma
+from geomesa_tpu.utils.costgate import CostEwma
 
 
 class _AdaptiveGate:
     """Measured-cost strategy picker (the tile cache's adaptive-gate
-    pattern, shared mechanics in tuning/primitives.py): EWMAs of the
+    pattern, shared mechanics in utils/costgate.py): EWMAs of the
     exact predicate's per-(point x edge) cost and the raster
     classification's per-point cost, updated from every partition
     actually executed. Predictions are per partition:

@@ -341,7 +341,7 @@ class _MatchGate:
 
     def __init__(self):
         from geomesa_tpu.lockwitness import witness
-        from geomesa_tpu.tuning.primitives import CostEwma
+        from geomesa_tpu.utils.costgate import CostEwma
 
         self._host = CostEwma(self._ALPHA)   # guarded-by: _lock
         self._fused = CostEwma(self._ALPHA)  # guarded-by: _lock

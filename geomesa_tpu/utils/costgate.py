@@ -1,6 +1,6 @@
-"""Shared measured-cost controller primitives (docs/tuning.md).
+"""Shared measured-cost controller primitives.
 
-Three independent feedback gates grew hand-rolled before this tier
+Three independent feedback gates grew hand-rolled before this module
 existed: the tile compose cost gate (cache/tiles.py), the adaptive
 join gate from arXiv 1802.09488 (sql/join.py) and standing's
 host-vs-fused match gate (streaming/standing.py). They all reduce
@@ -8,7 +8,7 @@ to two moves — blend a measured per-unit cost into an EWMA, and back
 off with periodic re-probes after losing. This module IS those moves,
 extracted once; the gates import from here and their decisions stay
 bit-identical on their test matrices (pinned by the differential
-tests in tests/test_tuning.py).
+tests in tests/test_costgate.py).
 
 Everything here is lock-free plain arithmetic: callers own the
 synchronization (each gate keeps its own lock and rank, see

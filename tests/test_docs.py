@@ -992,53 +992,6 @@ def test_tiles_doc_honest():
         assert hasattr(TilePyramid, name), f"pyramid.{name}"
 
 
-def test_tuning_doc_honest():
-    """docs/tuning.md stays honest the registry way: every tuning API
-    it names is real, every geomesa.tuning.* knob and metric is
-    declared at runtime and cited by the doc (and the knobs by
-    config.md), and the controller table matches the machine-checked
-    CONTROLLERS registry."""
-    from geomesa_tpu import tuning
-    from geomesa_tpu.analysis.registries import CONTROLLERS
-    from geomesa_tpu.datastore import DataStore
-    from geomesa_tpu.tuning.controllers import CONTROLLER_SPECS
-
-    for name in ("TuningManager", "IndexReweighter", "BurnShed",
-                 "KnobController", "ControllerSpec", "CONTROLLER_SPECS",
-                 "CostEwma", "ProbeGate", "ewma_step"):
-        assert hasattr(tuning, name), name
-    for m in ("attach_tuning", "tuning_report", "record_query"):
-        assert hasattr(DataStore, m), m
-    for m in ("on_query", "pulse", "report", "state", "save", "load"):
-        assert hasattr(tuning.TuningManager, m), m
-    # every geomesa.tuning.* knob/metric resolves at runtime and is
-    # cited by both the subsystem doc and the operator index
-    knobs, metrics = _area_names("geomesa.tuning.")
-    assert len(knobs) == 9 and len(metrics) >= 5, (knobs, metrics)
-    _assert_runtime_declared(knobs)
-    _assert_documented("tuning.md", knobs + metrics)
-    _assert_documented("config.md", knobs)
-    # the controller table is the registry, verbatim: every registered
-    # controller (and its steered knob) appears in the doc
-    doc = open(os.path.join(_ROOT, "docs", "tuning.md")).read()
-    for name in CONTROLLERS:
-        assert name in doc, name
-    for spec in CONTROLLER_SPECS:
-        assert spec.knob in doc, spec.knob
-    # ops surface: the endpoint + CLI command the doc promises are real
-    import inspect
-
-    import geomesa_tpu.obs.ops as ops_mod
-    from geomesa_tpu import cli
-
-    assert "/debug/tuning" in doc
-    assert "/debug/tuning" in inspect.getsource(ops_mod.OpsRoutes.handle)
-    assert hasattr(cli, "cmd_tune")
-    # every `ds.X` the guide mentions in backticks resolves
-    for name in re.findall(r"`ds\.(\w+)", doc):
-        assert hasattr(DataStore, name), f"ds.{name}"
-
-
 def test_distributed_doc_honest():
     """docs/distributed.md stays honest the registry way: every pod API
     it names is real, every geomesa.pod.* knob is declared at runtime

@@ -215,11 +215,6 @@ def _workload(tmp_path, metrics=None):
     # TileAggregateCache._lock would never be crossed
     ds.metrics = metrics if metrics is not None else MetricsRegistry()
     ds.attach_slo()  # SLO windows fed through the registry observer hook
-    # self-tuning tier (docs/tuning.md), armed at interval=1 so every
-    # recorded query runs an adaptation pulse in its caller's thread:
-    # TuningManager._lock is witnessed on the pacing/claim path while
-    # the pulse crosses the accuracy, SLO and metrics locks OUTSIDE it
-    ds.attach_tuning(enabled=True, interval=1)
     sft = FeatureType.from_spec("t", SPEC)
     ds.create_schema(sft)
     ds.write("t", _fc(sft, 200, seed=0))
@@ -355,11 +350,6 @@ def _workload(tmp_path, metrics=None):
         lam.close()
         sched.close()
         conf.OBS_TRACE_SAMPLE.clear()
-        # the armed controllers write through GLOBAL conf: reset the
-        # three steered knobs so later tests see stock defaults
-        for prop in (conf.CACHE_MIN_COST,
-                     conf.STREAM_FOLD_SLICE_ROWS, conf.STREAM_CHUNK_ROWS):
-            prop.clear()
         obs.install(obs.Tracer())  # drop the witness-wrapped tracer
 
 

@@ -609,6 +609,37 @@ def test_ops_report_and_cli(tmp_path, capsys):
     assert "estimate accuracy" in out
 
 
+@pytest.mark.parametrize("mount", ["ops", "data"])
+def test_no_tuning_endpoint_on_either_mount(mount):
+    """Both listeners answer from one route table, and it holds no
+    ``/debug/tuning``: 404 on the ops port and on the data port."""
+    from geomesa_tpu.obs.ops import OpsRoutes
+
+    assert "/debug/tuning" not in OpsRoutes.PATHS
+    ds = _store(n=0)
+    srv = ds.serve_ops() if mount == "ops" else ds.serve(port=0)
+    try:
+        code, body = _get(srv.url + "/debug/tuning")
+        assert code == 404 and "unknown path" in body
+        code, _ = _get(srv.url + "/debug/stalls")
+        assert code == 200  # the table itself is mounted
+    finally:
+        ds.close()
+
+
+def test_no_tune_command_and_no_tuning_knob(tmp_path, capsys):
+    """The parser refuses ``geomesa tune``, and the property tier
+    declares no ``geomesa.tuning.*`` name: 90 knobs."""
+    from geomesa_tpu import cli
+
+    with pytest.raises(SystemExit) as e:
+        cli.main(["tune", "-c", str(tmp_path)])
+    assert e.value.code == 2
+    assert "invalid choice: 'tune'" in capsys.readouterr().err
+    assert not [n for n in conf.REGISTRY if n.startswith("geomesa.tuning.")]
+    assert len(conf.REGISTRY) == 90
+
+
 # -- layer 5: lifecycle (the bugfix regression) ----------------------------
 
 

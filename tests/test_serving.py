@@ -122,6 +122,35 @@ def test_queue_full_backpressure_and_shed():
     sched.close()
 
 
+def test_tenant_quota_sheds_while_the_shared_queue_is_open():
+    """Admission's third reason, at the scheduler: a tenant at its own
+    quota sheds (counted once) and the next tenant is still admitted.
+    The other two are test_shed_at_admission_when_timeout_inside_window
+    and test_queue_full_backpressure_and_shed."""
+    from geomesa_tpu.serving.tenancy import TenantRegistry
+
+    reg = MetricsRegistry()
+    store = _store(metrics=reg)
+    tenants = TenantRegistry(metrics=reg)
+    tenants.configure("flood", queue_max=1)
+    sched = QueryScheduler(
+        store, ServingConfig(queue_max=8), metrics=reg, tenants=tenants
+    )  # not started: what is admitted stays queued
+    f1 = sched.submit("ev", Q, tenant="flood", block=False)
+    f2 = sched.submit("ev", "kind = 'b'", tenant="flood", block=False)
+    with pytest.raises(ServingRejected, match="tenant 'flood' admission quota full"):
+        f2.result(1)
+    calm = sched.submit("ev", "kind = 'c'", tenant="calm", block=False)
+    assert reg.counters["geomesa.serving.shed"] == 1
+    assert reg.counters["geomesa.tenant.shed"] == 1
+    rows = {r["tenant"]: r for r in tenants.report()["tenants"]}
+    assert rows["flood"]["shed"] == 1 and rows["calm"]["shed"] == 0
+    sched.start()
+    assert len(f1.result(10)) == len(store.query("ev", Q))
+    assert len(calm.result(10)) == len(store.query("ev", "kind = 'c'"))
+    sched.close()
+
+
 def test_identical_fingerprints_coalesce_into_one_slot():
     reg = MetricsRegistry()
     store = _store(metrics=reg)

@@ -25,7 +25,6 @@ from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter import ecql
 from geomesa_tpu.index.api import ScanConfig, WriteKeys
 from geomesa_tpu.metrics import global_registry
-from geomesa_tpu.planning.explain import ExplainNull
 from geomesa_tpu.planning.planner import index_priority
 from geomesa_tpu.sft import FeatureType
 from geomesa_tpu.storage import table as tbl
@@ -395,7 +394,7 @@ def test_cost_and_chosen_index_equal_the_tuple_lists(store, shape, seed):
                 continue
             rows = sum(z - a for a, z in _oracle_union(ds.table(TYPE, idx.name), cfg))
             want = (rows + 1) * index_priority(idx.name)
-            got = ds.planner.cost(TYPE, idx.name, cfg, ExplainNull())
+            got = ds.planner.cost(TYPE, idx.name, cfg)
             assert type(got) is float and got == want
             options.append((want, idx.name))
         plan = ds.planner.plan(TYPE, f)
@@ -540,7 +539,7 @@ def test_a_delta_tier_keeps_its_mains_spans(traced):
     under = main.candidate_spans(plan.config)
     assert _pairs(spans) == _pairs(under) + [(main.n, main.n + 700)]
     assert _pairs(under) == _oracle_union(main, plan.config)
-    cost = ds.planner.cost(TYPE, plan.index, plan.config, ExplainNull())
+    cost = ds.planner.cost(TYPE, plan.index, plan.config)
     assert cost == (under.n_rows() + 700 + 1) * index_priority(plan.index)
 
 

@@ -361,35 +361,6 @@ def test_fault_point_registry_matches_kinds():
         assert doc, name
 
 
-def test_unregistered_controller_spec_is_caught(fixture_result):
-    """ISSUE 19 must-fail: one ControllerSpec trips every direction the
-    controller-registry rule checks — unregistered name, undeclared
-    knob, inverted bounds, unemitted objective — while the disciplined
-    twin (mirroring the shipped derive controller) stays silent."""
-    bad = _at(fixture_result, "controller_bad.py", "controller-registry")
-    symbols = {f.symbol for f in bad}
-    assert symbols == {
-        "bogus_controller", "knob:bogus_controller",
-        "bounds:bogus_controller", "objective:bogus_controller",
-    }, _render(bad)
-    for f in bad:
-        assert "bogus_controller" in f.message
-
-
-def test_controller_registry_matches_specs():
-    """Registry hygiene: CONTROLLERS names are snake_case with docs,
-    and the shipped spec tuple backs every entry exactly (the unbacked
-    direction of the rule at zero findings on the clean tree)."""
-    from geomesa_tpu.analysis.registries import CONTROLLERS
-    from geomesa_tpu.tuning.controllers import CONTROLLER_SPECS
-
-    assert len(CONTROLLERS) >= 3
-    for name, doc in CONTROLLERS.items():
-        assert name == name.lower() and " " not in name, name
-        assert doc, name
-    assert {s.name for s in CONTROLLER_SPECS} == set(CONTROLLERS)
-
-
 def test_fstring_family_reported_once(fixture_result):
     """An f-string fragment is scanned exactly once: the JoinedStr
     branch owns it, the plain-Constant walk must skip it (the
@@ -437,34 +408,45 @@ def test_baseline_and_inline_suppression(tmp_path):
     assert not r2.findings and r2.suppressed
 
 
-def test_scan_layer_imports_nothing_above_it():
-    """The kernels, the curves, the native tier and the indexes are the
-    bottom of the program: none of their modules imports the tuning,
-    serving, planning, pod or streaming tier, at module level or inside
-    a function."""
+@pytest.fixture(scope="module")
+def repo_project():
+    return Project.load(ROOT)
+
+
+@pytest.mark.parametrize(
+    "layer", ["scan", "curve", "native", "index", "filter", "utils", "stats", "obs"]
+)
+def test_scan_layer_imports_nothing_above_it(repo_project, layer):
+    """The kernels, the curves, the native tier, the indexes, the
+    filters, the utilities, the sketches and the instruments are the
+    bottom of the program: none of their modules imports an entry
+    point (datastore, cli) or the serving, planning, pod, streaming,
+    sql, process, tiles or cache tier, at module level or inside a
+    function."""
     import ast
 
-    above = {"tuning", "serving", "planning", "pod", "streaming"}
-    project = Project.load(ROOT)
+    above = {
+        "serving", "planning", "pod", "streaming", "datastore", "sql",
+        "process", "cli", "tiles", "cache",
+    }
     found = []
-    for layer in ("scan", "curve", "native", "index"):
-        for sf in project.python_files(under=f"geomesa_tpu/{layer}/"):
-            pkg = sf.relpath.split("/")[:-1]  # the module's package
-            for node in ast.walk(sf.tree):
-                if isinstance(node, ast.Import):
-                    mods = [a.name for a in node.names]
-                elif isinstance(node, ast.ImportFrom):
-                    stem = pkg[: len(pkg) - node.level + 1] if node.level else []
-                    mod = ".".join(stem + ([node.module] if node.module else []))
-                    # ``from geomesa_tpu import tuning`` names it too
-                    mods = [mod] + [f"{mod}.{a.name}" for a in node.names]
-                else:
-                    continue
-                found += [
-                    f"{sf.relpath}:{node.lineno}: {mod}" for mod in mods
-                    if mod.startswith("geomesa_tpu.")
-                    and mod.split(".")[1] in above
-                ]
+    for sf in repo_project.python_files(under=f"geomesa_tpu/{layer}/"):
+        pkg = sf.relpath.split("/")[:-1]  # the module's package
+        for node in ast.walk(sf.tree):
+            if isinstance(node, ast.Import):
+                mods = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                stem = pkg[: len(pkg) - node.level + 1] if node.level else []
+                mod = ".".join(stem + ([node.module] if node.module else []))
+                # ``from geomesa_tpu import serving`` names it too
+                mods = [mod] + [f"{mod}.{a.name}" for a in node.names]
+            else:
+                continue
+            found += [
+                f"{sf.relpath}:{node.lineno}: {mod}" for mod in mods
+                if mod.startswith("geomesa_tpu.")
+                and mod.split(".")[1] in above
+            ]
     assert not found, "\n".join(found)
 
 

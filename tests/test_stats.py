@@ -185,8 +185,8 @@ def test_cost_changes_with_distribution():
     dense = ecql.parse("bbox(geom, 10, -80, 170, 80)")
     empty = ecql.parse("bbox(geom, -170, -80, -10, 80)")
     idx = [i for i in ds.indexes("d") if i.name == "z2"][0]
-    c_dense = ds.planner.cost("d", "z2", idx.scan_config(dense), None)
-    c_empty = ds.planner.cost("d", "z2", idx.scan_config(empty), None)
+    c_dense = ds.planner.cost("d", "z2", idx.scan_config(dense))
+    c_empty = ds.planner.cost("d", "z2", idx.scan_config(empty))
     assert c_dense > 100 * c_empty
 
 
