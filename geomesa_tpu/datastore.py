@@ -1574,7 +1574,9 @@ class DataStore:
         one trace — plan/probe/scan/decode phases — retained per the
         sampling knob, captured into the slow-query ring when over
         ``geomesa.obs.slow.ms``, and appended to ``explain`` as a
-        per-phase breakdown."""
+        per-phase breakdown; the explainer then also holds the ``trace``
+        and the ``plan`` (a process that asks through here reads its
+        counters from them: ``process/tube.py``)."""
         from geomesa_tpu.obs.trace import phase_breakdown
 
         with _otracer().trace("query", type=type_name) as trace:
@@ -1586,6 +1588,7 @@ class DataStore:
             for line in phase_breakdown(trace):
                 explain(line)
             explain.trace = trace
+            explain.plan = plan
         return out
 
     def query_many(

@@ -15,6 +15,9 @@ import numpy as np
 
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.predicates import And, Filter, Include
+from geomesa_tpu.obs.trace import add as _oadd
+from geomesa_tpu.obs.trace import span as _ospan
+from geomesa_tpu.obs.trace import tracer as _otracer
 
 EARTH_RADIUS_M = 6_371_000.0
 
@@ -32,10 +35,19 @@ METERS_PER_DEGREE = 111_320.0  # one degree of latitude (~also longitude at equa
 
 
 def _meters_to_degrees(m: float, lat: float) -> float:
-    """Conservative (over-wide) degree radius for a meter distance."""
-    lat_deg = m / METERS_PER_DEGREE
-    lon_deg = lat_deg / max(0.01, np.cos(np.radians(min(abs(lat), 89.0))))
-    return float(max(lat_deg, lon_deg))
+    """Half-side, in degrees, of a box round a centre at latitude ``lat``
+    that holds every point within ``m`` metres of it as :func:`haversine_m`
+    measures: the angle m / R in latitude, and in longitude the circle's
+    farthest reach, asin(sin(m / R) / cos(lat)), which passes (m / R) /
+    cos(lat) because the circle's widest points lie poleward of its centre;
+    the larger of the two, a hair wider (a part in 10^9, and 1e-9 degrees
+    for the rounding of the box's own corners). (A degree is 111,195 m on this
+    sphere: sized by METERS_PER_DEGREE the box fell 0.11% short of the
+    circle and dropped rows at its east and west rims, PR 46.)"""
+    delta = m / EARTH_RADIUS_M
+    reach = np.sin(min(delta, np.pi / 2)) / max(0.01, np.cos(np.radians(min(abs(lat), 89.0))))
+    lon = np.pi if reach >= 1.0 else np.arcsin(reach)
+    return float(np.degrees(max(delta, lon)) * (1.0 + 1e-9) + 1e-9)
 
 
 def _degrees_to_meters(deg: float, lat: float) -> float:
@@ -97,19 +109,49 @@ def knn_many(
     device scans before pulling any result (planner.submit), then doubles
     the radius only for queries short of k — so a batch of Q queries pays
     ~max_rounds pipelined sweeps instead of Q x rounds sequential device
-    round-trips. Results are identical to per-point :func:`knn_search`."""
+    round-trips. Results are identical to per-point :func:`knn_search`.
+
+    Traced (docs/processes.md): ONE root ``knn`` a call (``members``,
+    ``k``, ``rounds``, ``windows`` = plans submitted over all rounds,
+    ``candidates`` = rows the windows returned, ``returned``, ``short`` =
+    members answered with fewer than ``k``); under it ``knn.estimate``
+    (``probes``: the sketch probes of the start radii) and a
+    ``knn.round`` a round (``pending``, ``radius_max_m``) that holds the
+    planner's ``plan`` a member, the one ``dispatch``, a ``scan`` and a
+    ``decode`` a member and a ``knn.rank`` a member (``rows``,
+    ``in_radius``)."""
     pts = np.asarray(points, dtype=np.float64).reshape(-1, 2)
+    # capture=False: built only where sampling retains it; the always-on slow
+    # log, which never saw a kNN call, still pays nothing for one
+    with _otracer().trace(
+        "knn", capture=False, type=type_name, members=len(pts), k=int(k)
+    ) as trace:
+        out, counts = _knn_rounds(
+            store, type_name, pts, k, estimated_distance_m, max_distance_m, filter
+        )
+        if trace is not None:
+            trace.root.annotate(
+                returned=sum(len(fc) for fc in out),
+                short=sum(len(fc) < k for fc in out), **counts,
+            )
+        return out
+
+
+def _knn_rounds(store, type_name, pts, k, estimated_distance_m, max_distance_m, filter):
+    """:func:`knn_many` under its root: (the answers, the root's
+    ``rounds`` / ``windows`` / ``candidates``)."""
     sft = store.get_schema(type_name)
     geom = sft.geom_field
     out: list = [None] * len(pts)
     radii = np.empty(len(pts))
-    for i, (x, y) in enumerate(pts):
-        r = (
-            _estimate_radius_m(store, type_name, k, float(x), float(y), max_distance_m)
-            if estimated_distance_m is None
-            else float(estimated_distance_m)
-        )
-        radii[i] = min(max(r, 1.0), float(max_distance_m))
+    with _ospan("knn.estimate", cpu=True, members=len(pts)):
+        for i, (x, y) in enumerate(pts):
+            r = (
+                _estimate_radius_m(store, type_name, k, float(x), float(y), max_distance_m)
+                if estimated_distance_m is None
+                else float(estimated_distance_m)
+            )
+            radii[i] = min(max(r, 1.0), float(max_distance_m))
     # speculative wide-window rounds: each pending query scans ONE window
     # at 4x its radius estimate per round — the estimate radius resolves
     # from the SAME result (the degree window is conservatively over-wide,
@@ -151,37 +193,46 @@ def knn_many(
         """First radius in ``radii_try`` (ascending) holding k-or-more
         hits -> its k nearest; else None (miss -> expand)."""
         x, y = pts[i]
-        if len(res):
-            cx, cy = res.representative_xy()
-            d = haversine_m(x, y, cx, cy)
-            for r in radii_try:
-                in_radius = d <= r
-                if in_radius.sum() >= k or r >= max_distance_m:
-                    return _top_k(res, d, in_radius)
-        elif radii_try[-1] >= max_distance_m:
-            return res
-        return None
+        with _ospan("knn.rank", cpu=True, member=i, rows=len(res)) as sp:
+            if len(res):
+                cx, cy = res.representative_xy()
+                d = haversine_m(x, y, cx, cy)
+                for r in radii_try:
+                    in_radius = d <= r
+                    n_in = int(in_radius.sum())
+                    if n_in >= k or r >= max_distance_m:
+                        sp.annotate(in_radius=n_in)
+                        return _top_k(res, d, in_radius)
+            elif radii_try[-1] >= max_distance_m:
+                return res
+            return None
 
+    rounds = windows = candidates = 0
     pending = list(range(len(pts)))
     while pending:
         # every pending query's window goes through ONE submit_many:
         # scans sharing the index fuse into a single kernel dispatch per
         # variant group (planner.submit_many -> table.scan_submit_many)
         wides = [min(float(radii[i]) * SPEC, max_distance_m) for i in pending]
-        fins = store.planner.submit_many(
-            [_plan(i, w) for i, w in zip(pending, wides)], hints=None
-        )
-        nxt = []
-        for i, w, fin in zip(pending, wides, fins):
-            r = float(radii[i])
-            got = _resolve(i, fin(), [r, w] if w > r else [r])
-            if got is not None:
-                out[i] = got
-                continue
-            radii[i] = min(float(radii[i]) * SPEC * SPEC, max_distance_m)
-            nxt.append(i)
+        rounds += 1
+        windows += len(pending)
+        with _ospan("knn.round", pending=len(pending), radius_max_m=max(wides)):
+            fins = store.planner.submit_many(
+                [_plan(i, w) for i, w in zip(pending, wides)], hints=None
+            )
+            nxt = []
+            for i, w, fin in zip(pending, wides, fins):
+                r = float(radii[i])
+                res = fin()
+                candidates += len(res)
+                got = _resolve(i, res, [r, w] if w > r else [r])
+                if got is not None:
+                    out[i] = got
+                    continue
+                radii[i] = min(float(radii[i]) * SPEC * SPEC, max_distance_m)
+                nxt.append(i)
         pending = nxt
-    return out
+    return out, {"rounds": rounds, "windows": windows, "candidates": candidates}
 
 
 def _estimate_radius_m(
@@ -239,12 +290,15 @@ def _refine_radius_local(
     hits in the window around (x, y). Sketch-only: no device work, no
     range decomposition — each probe is two histogram range sums."""
     target = max(4 * k, 64)
+    probes = 0
     while r < max_m:
         deg = _meters_to_degrees(r, y)
         est = stats.estimate_bbox(
             geom, x - deg, max(y - deg, -90.0), x + deg, min(y + deg, 90.0)
         )
+        probes += 1
         if est is None or est >= target:
             break
         r = min(r * 2.0, max_m)
+    _oadd("probes", probes)  # on the caller's ``knn.estimate``, where one is open
     return r

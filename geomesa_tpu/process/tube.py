@@ -16,6 +16,8 @@ import numpy as np
 
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.predicates import And, BBox, During, Filter, Include, Or
+from geomesa_tpu.obs.trace import span as _ospan
+from geomesa_tpu.obs.trace import tracer as _otracer
 from geomesa_tpu.process.knn import _meters_to_degrees, haversine_m
 
 
@@ -34,6 +36,13 @@ def tube_select(
     ``track_xy``: [n, 2] lon/lat waypoints; ``track_times_ms``: [n] epoch
     millis, ascending. ``bin_ms`` defaults to the track duration / number
     of waypoints (the reference's default binning).
+
+    Traced (docs/processes.md): ONE root ``tube`` a call (``waypoints``,
+    ``bins``, ``buffer_m``, ``rows`` the query returned, ``kept`` within
+    ``buffer_m``; ``boxes`` / ``windows`` / ``ranges`` / ``candidates`` from
+    the query's own plan and trace) with the children ``tube.bins`` and
+    ``tube.refine``; the query is ``store.query``'s own ``query`` root,
+    linked by ``tube_trace`` (and ``query_trace`` here).
     """
     xy = np.asarray(track_xy, dtype=np.float64).reshape(-1, 2)
     ts = np.asarray(track_times_ms, dtype=np.int64)
@@ -46,6 +55,65 @@ def tube_select(
         raise ValueError("tube select requires a time attribute")
     geom, dtg = sft.geom_field, sft.dtg_field
 
+    # capture=False: the slow log takes the ``query`` root inside, as it did
+    # before this root was here; unsampled, this one is never built
+    with _otracer().trace(
+        "tube", capture=False, type=type_name, waypoints=len(xy), buffer_m=float(buffer_m)
+    ) as trace:
+        with _ospan("tube.bins", cpu=True):
+            parts = _slices(geom, dtg, xy, ts, buffer_m, bin_ms, max_bins)
+            tube: Filter = parts[0] if len(parts) == 1 else Or(tuple(parts))
+            f = tube if isinstance(filter, Include) else And((tube, filter))
+        if trace is None:
+            out = store.query(type_name, f)
+        else:
+            out = _query_counted(store, type_name, f, trace.root.annotate(bins=len(parts)))
+        if len(out):
+            # refine: distance from each hit to the track position at the hit's time
+            with _ospan("tube.refine", cpu=True, rows=len(out)):
+                hx, hy = out.representative_xy()
+                ht = np.asarray(out.columns[dtg], dtype=np.int64)
+                px = np.interp(ht, ts, xy[:, 0])
+                py = np.interp(ht, ts, xy[:, 1])
+                d = haversine_m(hx, hy, px, py)
+                out = out.mask(d <= buffer_m)
+        if trace is not None:
+            trace.root.annotate(kept=len(out))
+        return out
+
+
+def _query_counted(store, type_name: str, f: Filter, root) -> FeatureCollection:
+    """The corridor's one query under a live ``tube`` root: ``store.query``
+    as ever, which opens its own ``query`` root (linked both ways by the
+    tracer: ``tube_trace`` there, ``query_trace`` here) and leaves its
+    trace and its plan on the explainer it is handed. From them the root's
+    ``rows``, and where that inner root was built too (sampled 1 in N by
+    its own name's count) ``boxes`` / ``windows`` / ``ranges`` (the chosen
+    plan's config) and ``candidates`` (its ``decode`` spans': the rows the
+    device's mask passed)."""
+    from geomesa_tpu.planning.explain import ExplainNull
+
+    exp = ExplainNull()
+    out = store.query(type_name, f, explain=exp)
+    root.annotate(rows=len(out))
+    inner, plan = getattr(exp, "trace", None), getattr(exp, "plan", None)
+    if inner is not None:
+        root.annotate(candidates=sum(
+            (s.attrs or {}).get("candidates", 0) for s in inner.spans if s.name == "decode"
+        ))
+    cfg = getattr(plan, "config", None)
+    if cfg is not None:
+        root.annotate(
+            boxes=0 if cfg.boxes is None else len(cfg.boxes),
+            windows=0 if cfg.windows is None else len(cfg.windows),
+            ranges=int(cfg.n_ranges),
+        )
+    return out
+
+
+def _slices(geom, dtg, xy, ts, buffer_m, bin_ms, max_bins) -> list:
+    """The track as ``And(BBox, During)`` slices: one a time bin, its box
+    the bin's part of the track widened by ``buffer_m``."""
     span = int(ts[-1] - ts[0])
     if bin_ms is None:
         bin_ms = max(1, span // max(1, len(xy)))
@@ -60,13 +128,19 @@ def tube_select(
     parts = []
     for i in range(n_bins):
         lo = int(ts[0] + i * bin_ms)
-        hi = int(min(ts[0] + (i + 1) * bin_ms, ts[-1] + 1))
-        deg = _meters_to_degrees(buffer_m, cy[i])
+        # DURING is [lo, hi): the last slice ends one past the track's last
+        # instant whatever the bins' width (where the span is a whole
+        # multiple of the bins, ts[0] + n_bins * bin_ms IS ts[-1], and a min
+        # with ts[-1] + 1 left the rows of that instant out)
+        hi = int(ts[-1] + 1 if i == n_bins - 1 else min(ts[0] + (i + 1) * bin_ms, ts[-1] + 1))
         # widen by the intra-bin track movement so interpolation error
         # cannot exclude a true hit
         j0, j1 = np.searchsorted(ts, [lo, hi])
         seg_x = np.concatenate([[cx[i]], xy[max(0, j0 - 1) : j1 + 1, 0]])
         seg_y = np.concatenate([[cy[i]], xy[max(0, j0 - 1) : j1 + 1, 1]])
+        # the buffer's reach in degrees at the slice's most poleward point,
+        # where a metre is the most longitude
+        deg = _meters_to_degrees(buffer_m, float(np.abs(seg_y).max()))
         parts.append(
             And(
                 (
@@ -81,19 +155,7 @@ def tube_select(
                 )
             )
         )
-    tube: Filter = parts[0] if len(parts) == 1 else Or(tuple(parts))
-    f = tube if isinstance(filter, Include) else And((tube, filter))
-    out = store.query(type_name, f)
-    if len(out) == 0:
-        return out
-
-    # refine: distance from each hit to the track position at the hit's time
-    hx, hy = out.representative_xy()
-    ht = np.asarray(out.columns[dtg], dtype=np.int64)
-    px = np.interp(ht, ts, xy[:, 0])
-    py = np.interp(ht, ts, xy[:, 1])
-    d = haversine_m(hx, hy, px, py)
-    return out.mask(d <= buffer_m)
+    return parts
 
 
 def standing_tube(

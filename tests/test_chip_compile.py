@@ -272,6 +272,51 @@ def test_extent_scan_over_the_footprints_table(one_chip, m):
     _assert_mosaic(compiled)
 
 
+AIS_BLOCKS = 1 << 9  # ais-reports-1chip: 2^23 position reports on z3 (and z2)
+
+
+def _ais_cols(sh):
+    return tuple(_s((AIS_BLOCKS, SUB, bk.LANES), jnp.int32 if n in _I32 else jnp.float32, sh)
+                 for n in Z3)
+
+
+@pytest.mark.parametrize("m", [m for m in bk.M_BUCKETS if m <= AIS_BLOCKS])
+def test_tube_scan_over_the_ais_table(one_chip, m):
+    """The z3 table of the ``ais.vessel-proximity`` cell (512 blocks of
+    16,384 reports) as a ``tube_select`` of 256 slices plans it (PR 46):
+    ONE single-query scan with boxes and a window, at every bucket of
+    candidate blocks. The tube's 256 boxes are no compile key:
+    ``pack_boxes`` always fills the kernel's eight slots (the last the
+    union of slices 8 to 256) and the one window covers the whole track,
+    so the parameter blocks are the shapes of any box-and-window query."""
+    boxes = np.tile(np.array([[-125.0, 32.0, -117.0, 48.0]], np.float32), (256, 1))
+    packed = bk.pack_boxes(boxes, None)
+    assert packed.shape == (8, bk.LANES) and np.isfinite(packed[:, 0]).all()  # all eight slots
+    compiled = bk._pallas_block_scan.lower(
+        _ais_cols(one_chip), _s((m,), jnp.int32, one_chip), *_params(one_chip), None, None,
+        interpret=False, n_edges=0, n_rints=0, **_flags(Z3, True),
+    ).compile()
+    _assert_mosaic(compiled)
+
+
+@pytest.mark.parametrize("members", [16, 32])
+def test_fused_knn_round_over_the_ais_table(one_chip, members):
+    """A ``knn_many`` round of 16 points (the cell's ``knn-many-16``) or of
+    32 (its warm ladder's largest) over that table: the windows of a round
+    go through ``submit_many`` into ONE fused chunk of the table's canonical
+    shape (512 slots: ``fused_slots`` clamps FUSED_CHUNK_SLOTS to the table's
+    block bucket; FUSED_CHUNK_Q queries) however many members fill it."""
+    assert members <= FUSED_CHUNK_Q
+    m, q = min(FUSED_CHUNK_SLOTS, bk.bucket_of(AIS_BLOCKS)), FUSED_CHUNK_Q
+    assert m == 512
+    slot = _s((m,), jnp.int32, one_chip)
+    compiled = bk._pallas_block_scan_multi.lower(
+        _ais_cols(one_chip), slot, slot, *_params(one_chip, lead=(q,)), None, None, None,
+        interpret=False, n_edges=0, n_rints=0, **_flags(Z3, True),
+    ).compile()
+    _assert_mosaic(compiled)
+
+
 # ---- the mesh forms: jit(shard_map) over the described four chips
 
 

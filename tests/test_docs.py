@@ -454,6 +454,42 @@ def test_fused_coverage_doc_honest():
         assert p in sig, p
 
 
+def test_processes_doc_honest():
+    """docs/processes.md stays honest: every API, parameter, constant and
+    span the kNN / tube doc names is real, and migration.md points to it."""
+    import inspect
+
+    from geomesa_tpu.filter import dnf
+    from geomesa_tpu.process import knn, tube
+    from geomesa_tpu.scan import block_kernels as bk
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    text = open(os.path.join(root, "docs", "processes.md")).read()
+    for p in ("k", "estimated_distance_m", "max_distance_m", "filter"):
+        assert p in inspect.signature(knn.knn_search).parameters and f"`{p}" in text, p
+    for p in ("track_xy", "track_times_ms", "buffer_m", "bin_ms", "max_bins"):
+        assert p in inspect.signature(tube.tube_select).parameters and p in text, p
+    assert inspect.signature(tube.tube_select).parameters["max_bins"].default == 256
+    assert knn.EARTH_RADIUS_M == 6_371_000.0 and "6,371,000" in text
+    assert dnf.MAX_DISJUNCTS == 16 and "`filter.dnf.MAX_DISJUNCTS` = 16" in text
+    assert hasattr(bk, "pack_boxes") and "pack_boxes" in text
+    for fn in ("_estimate_radius_m", "_refine_radius_local", "_meters_to_degrees"):
+        assert hasattr(knn, fn) and fn in text, fn
+    src = inspect.getsource(knn) + inspect.getsource(tube)
+    for span in ("knn", "knn.estimate", "knn.round", "knn.rank", "tube", "tube.bins",
+                 "tube.refine"):
+        assert f'"{span}"' in src and f"`{span}`" in text, span
+    for attr in ("members", "rounds", "windows", "candidates", "returned", "short", "probes",
+                 "pending", "radius_max_m", "in_radius", "waypoints", "bins", "boxes", "ranges",
+                 "rows", "kept"):
+        assert attr in src and f"`{attr}`" in text, attr
+    assert "capture=False" in src and "capture=False" in text
+    obs_text = open(os.path.join(root, "docs", "observability.md")).read()
+    assert "| `knn` |" in obs_text and "| `tube` |" in obs_text
+    mig = open(os.path.join(root, "docs", "migration.md")).read()
+    assert mig.count("(processes.md)") == 2
+
+
 def test_joins_doc_honest():
     """docs/joins.md stays honest: every API, knob, metric and constant
     the raster/adaptive-join doc names is real."""
