@@ -82,6 +82,7 @@ class Z2SFC:
         bounds: "Sequence[Sequence[tuple[float, float, float, float]]]",
         inner: bool = False,
         cover: "Sequence[Sequence[tuple[float, float, float, float]]] | None" = None,
+        max_ranges: int | None = None,
     ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
         """``len(bounds)`` decompositions in one native call: query q is
         the union of its boxes ``bounds[q]``. Returns ``(lower, upper,
@@ -89,11 +90,12 @@ class Z2SFC:
         ``inner`` as :meth:`ranges_arrays`. ``cover`` (aligned with
         ``bounds``): the boxes the ranges have to cover where those are
         wider than the boxes that decide containment, as the f32 mask's
-        are (``index.api.widen_boxes``)."""
+        are (``index.api.widen_boxes``). ``max_ranges`` bounds each query's
+        ranges (default: the target)."""
         mins, maxes, imins, imaxes = with_inner(*self._corners(bounds), inner)
         if cover is not None:
             mins, maxes = self._corners(cover)
-        return zranges_arrays_each(Z2, mins, maxes, imins, imaxes)
+        return zranges_arrays_each(Z2, mins, maxes, imins, imaxes, max_ranges)
 
     def _corners(self, bounds) -> tuple[np.ndarray, np.ndarray]:
         """The min and max corner ordinals of every box of ``bounds[q]``,

@@ -119,18 +119,20 @@ class Z3SFC:
         times: Sequence[tuple[float, float]],
         inner: bool = False,
         cover: "Sequence[Sequence[tuple[float, float, float, float]]] | None" = None,
+        max_ranges: int | None = None,
     ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
         """``len(times)`` decompositions in one native call: query q is
         the union of its boxes ``bounds[q]`` under its one offset window
         ``times[q]``. Returns ``(lower, upper, contained, counts
         i64[nq])``, query q's ranges after query q-1's, ``counts[q]`` of
         them; ``inner`` as :meth:`ranges_arrays`; ``cover`` as
-        :meth:`Z2SFC.ranges_arrays_each`'s (the windows stay ``times``)."""
+        :meth:`Z2SFC.ranges_arrays_each`'s (the windows stay ``times``);
+        ``max_ranges`` bounds each query's ranges (default: the target)."""
         windows = [[w] for w in times]
         mins, maxes, imins, imaxes = with_inner(*self._corners(bounds, windows), inner)
         if cover is not None:
             mins, maxes = self._corners(cover, windows)
-        return zranges_arrays_each(Z3, mins, maxes, imins, imaxes)
+        return zranges_arrays_each(Z3, mins, maxes, imins, imaxes, max_ranges)
 
     def _corners(self, bounds, times) -> tuple[np.ndarray, np.ndarray]:
         """The min and max corner ordinals of every box of ``bounds[q]``

@@ -11,10 +11,19 @@ The expansion is capped: distributing ANDs over ORs is exponential in the
 worst case, and past a handful of disjuncts a union plan loses to a single
 scan anyway (the reference caps at 32 options and falls back to a single
 full-filter strategy the same way).
+
+The cap has a second meaning (:func:`time_slices`): an ``Or`` of MORE
+than ``MAX_DISJUNCTS`` disjuncts that each carry a bounded interval of the
+type's date field (a tube's box-and-interval slices, a WFS client's own
+``OR`` of them) is not one scan either, since one scan would ask every
+disjunct's box for the union of all their intervals: it is cut, in time
+order, into consecutive groups of at most ``MAX_DISJUNCTS``, and the
+planner plans each group's ``Or`` as a scan of its own and unions them.
 """
 
 from __future__ import annotations
 
+from geomesa_tpu.filter.extract import MAX_MS, MIN_MS, extract_intervals
 from geomesa_tpu.filter.predicates import And, Filter, Not, Or
 
 MAX_DISJUNCTS = 16
@@ -29,6 +38,51 @@ def rewrite_dnf(f: Filter, limit: int = MAX_DISJUNCTS) -> list[Filter] | None:
     """
     out = _dnf(_push_not(f), limit)
     return out
+
+
+def time_slices(
+    f: Filter, dtg_field: "str | None", limit: int = MAX_DISJUNCTS
+) -> list[Filter] | None:
+    """``f`` as ``ceil(n / limit)`` filters whose union it is, where ``f``
+    is an ``Or`` (or an ``And`` holding exactly one ``Or`` beside other
+    conjuncts) of ``n`` > ``limit`` disjuncts that EACH constrain
+    ``dtg_field`` to bounded intervals; None for any other filter, which
+    keeps its single plan.
+
+    The disjuncts go in order of their interval's start into consecutive
+    groups of even size; a group is ``Or(group)``, under the ``And``'s other
+    conjuncts where there are any. Each group's scan then asks its own
+    boxes for its own stretch of time, not every box for the whole
+    duration."""
+    if isinstance(f, Or):
+        union, rest = f, ()
+    elif isinstance(f, And):
+        ors = [c for c in f.filters if isinstance(c, Or)]
+        if len(ors) != 1:
+            return None
+        union, rest = ors[0], tuple(c for c in f.filters if c is not ors[0])
+    else:
+        return None
+    n = len(union.filters)
+    if n <= limit or dtg_field is None:
+        return None
+    starts = []
+    for d in union.filters:
+        ivs = extract_intervals(d, dtg_field).values
+        if not ivs:
+            return None  # no time predicate (or none satisfiable): not a slice
+        lo, hi = min(iv.lo for iv in ivs), max(iv.hi for iv in ivs)
+        if lo <= MIN_MS or hi >= MAX_MS:
+            return None  # open-ended: it would span every group's stretch
+        starts.append(lo)
+    order = sorted(range(n), key=starts.__getitem__)
+    k = -(-n // limit)
+    cuts = [g * n // k for g in range(k + 1)]
+    groups = []
+    for a, b in zip(cuts, cuts[1:]):
+        part = Or(tuple(union.filters[i] for i in order[a:b]))
+        groups.append(And((part,) + rest) if rest else part)
+    return groups
 
 
 def _push_not(f: Filter) -> Filter:

@@ -194,8 +194,10 @@ class IndexKeySpace(Protocol):
     def scan_config(self, f: Filter) -> Optional[ScanConfig]:
         """Scan configuration for a filter, or None when this index cannot
         serve it (reference getIndexValues + getRanges). An index may also
-        offer ``scan_configs(extractions)`` (the point indexes do): one
-        config or None an ``filter.extract.Extraction``, all of them
-        decomposed in one native call, which the planner's ``plan_many``
-        uses for a batch; ``scan_config`` is then its one-member case."""
+        offer ``scan_configs(extractions, max_ranges=None)`` (the point
+        indexes do): one config or None an ``filter.extract.Extraction``,
+        all of them decomposed in one native call, which the planner's
+        ``plan_many`` uses for a batch (``max_ranges``: the most ranges a
+        decomposition may emit, for the branches of one query that share
+        its range target); ``scan_config`` is then its one-member case."""
         ...

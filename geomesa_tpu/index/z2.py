@@ -62,11 +62,14 @@ class Z2Index:
     def scan_config(self, f: Filter) -> Optional[ScanConfig]:
         return self.scan_configs([extract_filter(f, self.geom, None)])[0]
 
-    def scan_configs(self, extractions: list) -> "list[Optional[ScanConfig]]":
+    def scan_configs(
+        self, extractions: list, max_ranges: "int | None" = None
+    ) -> "list[Optional[ScanConfig]]":
         """One scan config (None: no spatial constraint) an extraction
         (``filter.extract.extract_filter`` of this type's geom field); the
         boxes of all that take covering ranges from the curve decomposed
-        in ONE native call. ``scan_config`` is the one-member case."""
+        in ONE native call. ``scan_config`` is the one-member case.
+        ``max_ranges`` as :meth:`Z3Index.scan_configs`'s."""
         from geomesa_tpu.index.z3 import _poly_edges, _poly_raster
 
         out: "list[Optional[ScanConfig]]" = [None] * len(extractions)
@@ -100,7 +103,7 @@ class Z2Index:
             from geomesa_tpu.conf import SCAN_RANGES_TARGET
 
             rlo, rhi, rcont = approx.zranges(
-                max_ranges=SCAN_RANGES_TARGET.get()
+                max_ranges=max_ranges or SCAN_RANGES_TARGET.get()
             )
             if len(rlo) == 0:
                 out[m] = ScanConfig.empty(self.name)
@@ -127,7 +130,8 @@ class Z2Index:
         # covering ranges of the boxes the mask keeps, containment by the
         # f64 boxes: a contained row is a certain f64 hit as before
         range_lo, range_hi, range_contained, counts = self.sfc.ranges_arrays_each(
-            bounds, inner=True, cover=cover_boxes(wide, [len(bs) for bs in bounds])
+            bounds, inner=True, cover=cover_boxes(wide, [len(bs) for bs in bounds]),
+            max_ranges=max_ranges,
         )
         zeros = np.zeros(len(range_lo), dtype=np.int32)
         ra = ba = 0

@@ -175,12 +175,17 @@ class Z3Index:
     def scan_config(self, f: Filter) -> Optional[ScanConfig]:
         return self.scan_configs([extract_filter(f, self.geom, self.dtg)])[0]
 
-    def scan_configs(self, extractions: list) -> "list[Optional[ScanConfig]]":
+    def scan_configs(
+        self, extractions: list, max_ranges: "int | None" = None
+    ) -> "list[Optional[ScanConfig]]":
         """One scan config (None: the index cannot serve the filter) an
         extraction (``filter.extract.extract_filter`` of this type's geom
         and date fields), the per-bin windows of all of them cut in one
         pass and every (filter, distinct offset window) decomposed in ONE
-        native call. ``scan_config`` is the one-member case."""
+        native call. ``scan_config`` is the one-member case.
+        ``max_ranges``: the most ranges a decomposition may emit, where
+        the extractions are the branches of one query that share its
+        ``geomesa.scan.ranges.target`` (default: the target, each)."""
         out: "list[Optional[ScanConfig]]" = [None] * len(extractions)
         if self.dtg is None:
             return out
@@ -260,7 +265,7 @@ class Z3Index:
         # ms precision. The ranges cover the boxes the mask keeps (see z2)
         wlo, whi, wcont, counts = self.sfc.ranges_arrays_each(
             q_bounds, [(float(lo), float(hi)) for lo, hi in q_window], inner=True,
-            cover=q_cover,
+            cover=q_cover, max_ranges=max_ranges,
         )
         if len(emit_q) == len(q_window):  # no window in two rows: as decomposed
             per_row, range_lo, range_hi, range_cont = counts, wlo, whi, wcont

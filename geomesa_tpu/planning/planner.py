@@ -360,43 +360,126 @@ class QueryPlanner:
         intercept: bool, guard: bool,
     ) -> list:
         """The plans of ``filters`` under ONE ``plan`` span; ``exps``: an
-        Explainer a member."""
+        Explainer a member.
+
+        A member that is an ``Or`` of more than ``MAX_DISJUNCTS``
+        box-and-interval slices (``filter.dnf.time_slices``) is planned as
+        the union of its time-ordered groups, not as one scan of every box
+        for the whole duration: the groups go through the stages as
+        members of the batch, after the callers' own, and the member's plan
+        is ``QueryPlan(union=groups)``; the single scan is not planned
+        beside it. The span's ``sliced`` counts the groups planned."""
+        from geomesa_tpu.conf import SCAN_RANGES_TARGET
+        from geomesa_tpu.filter.dnf import time_slices
+
         t0 = time.perf_counter()
-        with _ospan("plan", cpu=True, type=type_name, members=len(filters)) as sp:
+        n = len(filters)
+        with _ospan("plan", cpu=True, type=type_name, members=n) as sp:
             sp.event("parse")
             filters = [self._prepare(type_name, f, intercept) for f in filters]
             for f, exp in zip(filters, exps):
                 exp(f"Planning query on '{type_name}': {type(f).__name__}")
+            dtg_field = self.store.get_schema(type_name).dtg_field
+            sliced: dict = {}  # member -> the positions of its groups
+            exps = list(exps)
+            for m in range(n):
+                groups = time_slices(filters[m], dtg_field)
+                if groups is not None:
+                    sliced[m] = range(len(filters), len(filters) + len(groups))
+                    filters.extend(groups)
+                    exps.extend([exps[m]] * len(groups))
             plans: list = [None] * len(filters)
             indexes = self.store.indexes(type_name)
             tables = self._batch_tables(type_name, indexes)
-            batch = [] if tables is None else [
-                m for m, f in enumerate(filters) if extract_ids(f).empty
-            ]
-            batched = 0
-            if batch:
-                batched = self._plan_arrays(
-                    type_name, filters, batch, indexes, tables, limit, plans, exps, sp
-                )
+            arrays: set = set()  # the members the array stages planned
+            if tables is not None:
+                # the callers' own members at the range target each, then a
+                # sliced member's groups, which share their query's target
+                target = SCAN_RANGES_TARGET.get()
+                for members, max_ranges in [(range(n), None)] + [
+                    (groups, max(1, target // len(groups))) for groups in sliced.values()
+                ]:
+                    batch = [
+                        m for m in members
+                        if m not in sliced and extract_ids(filters[m]).empty
+                    ]
+                    if batch:
+                        self._plan_arrays(
+                            type_name, filters, batch, indexes, tables, limit, plans,
+                            exps, sp, max_ranges,
+                        )
+                        arrays.update(m for m in batch if plans[m] is not None)
             sp.event("estimate")
-            for m, f in enumerate(filters):
+            # the groups before the callers' members: a sliced one is their union
+            for m in [*range(n, len(filters)), *range(n)]:
+                if m in sliced:
+                    plans[m] = self._union_of(
+                        type_name, filters[m], limit, [plans[g] for g in sliced[m]],
+                        exps[m], "time-ordered groups",
+                    )
+                    if plans[m] is not None and arrays.issuperset(sliced[m]):
+                        arrays.add(m)
                 if plans[m] is None:
                     # not one for the array stages: an index at a time
-                    plans[m] = self._select(type_name, f, limit, exps[m])
+                    plans[m] = self._select(type_name, filters[m], limit, exps[m])
                     self._estimate_rows([plans[m]], [exps[m]])
+            for m in range(n):
                 self._finish(plans[m], guard, exps[m])
-            sp.annotate(batched=batched)
-        share = (time.perf_counter() - t0) / len(plans)
-        for plan in plans:
+            sp.annotate(
+                batched=sum(m < n for m in arrays),
+                sliced=sum(map(len, sliced.values())),
+            )
+        share = (time.perf_counter() - t0) / n
+        for plan in plans[:n]:
             plan.planning_s = share
-        return plans
+        return plans[:n]
+
+    @staticmethod
+    def _union_of(
+        type_name: str, f: Filter, limit, subs: list, exp, what: str
+    ) -> Optional[QueryPlan]:
+        """The union plan of ``f`` from the plans ``subs`` of the filters
+        whose union it is (a DNF's disjuncts, a time-sliced member's
+        groups; ``what`` names them in the explain trail): a branch its
+        index finds disjoint is dropped, every branch is unlimited (the
+        union's ``_post`` limits once), the row estimate is the branches'
+        summed where each has one. None where a branch needs a full scan:
+        one full scan of the whole filter beats a full scan a branch."""
+        live = [p for p in subs if not (p.config is not None and p.config.disjoint)]
+        if len(live) < len(subs):
+            exp(f"Union: {len(subs) - len(live)} unsatisfiable, dropped")
+        if any(p.strategy == "full-scan" for p in live):
+            exp("Union: a branch needs a full scan -> single-scan plan")
+            return None  # one full scan beats full scan + index scans
+        if not live:
+            return QueryPlan(type_name, f, None, ScanConfig.empty("union"), ids=[])
+        if len(live) == 1:
+            # every other branch was unsatisfiable: the live one IS the
+            # query (its filter is equivalent to the whole filter)
+            exp(f"Strategy: {live[0].strategy} (other branches unsatisfiable)")
+            live[0].limit = limit
+            return live[0]
+        for p in live:
+            p.limit = None
+        exp(
+            f"Strategy: union of {len(live)} {what} ("
+            + ", ".join(p.strategy for p in live) + ")"
+        )
+        plan = QueryPlan(type_name, f, None, None, limit=limit, union=live)
+        ests = [p.estimated_rows for p in live]
+        if None not in ests:
+            plan.estimated_rows = float(sum(ests))
+        return plan
 
     def _plan_arrays(
-        self, type_name, filters, batch, indexes, tables, limit, plans, exps, sp
-    ) -> int:
+        self, type_name, filters, batch, indexes, tables, limit, plans, exps, sp,
+        max_ranges=None,
+    ) -> None:
         """Stages 1 (extraction) to 4 of :meth:`plan_many` for the members
         ``batch`` of ``filters``: fills their ``plans`` where some index
-        serves the member, and returns how many those are."""
+        serves the member. ``max_ranges``: the most ranges a decomposition
+        may emit (the branches of one query share its target), part of the
+        memo's key where given; default the target, each."""
         from geomesa_tpu.filter.extract import extract_filter
         from geomesa_tpu.filter.predicates import canonical_key
 
@@ -407,22 +490,27 @@ class QueryPlanner:
             for m in batch
         }
         keys = {m: canonical_key(filters[m]) for m in batch}
+        budget = {}
+        if max_ranges is not None:
+            keys = {m: (key, max_ranges) for m, key in keys.items()}
+            budget = {"max_ranges": max_ranges}
 
         # as _select_single: the indexes in order, and a member an index
         # finds disjoint is planned there (no later index decomposes it)
         sp.event("decompose")
-        alive, served, decided = batch, [], 0
+        alive, served = batch, []
         for idx in indexes:
             cfgs = self._scan_configs(
                 idx, [keys[m] for m in alive],
-                lambda pos, at=alive: idx.scan_configs([extractions[at[k]] for k in pos]),
+                lambda pos, at=alive: idx.scan_configs(
+                    [extractions[at[k]] for k in pos], **budget
+                ),
             )
             keep, kept = [], []
             for m, cfg in zip(alive, cfgs):
                 if cfg is not None and cfg.disjoint:
                     exps[m](f"Index {idx.name}: filter disjoint -> empty plan")
                     plans[m] = QueryPlan(type_name, filters[m], idx.name, cfg, limit=limit)
-                    decided += 1
                     continue
                 keep.append(m)
                 if cfg is not None:
@@ -454,7 +542,6 @@ class QueryPlanner:
             [plans[m] for m in best], [exps[m] for m in best],
             [extractions[m] for m in best],
         )
-        return decided + len(best)
 
     def _batch_tables(self, type_name: str, indexes) -> "dict | None":
         """index name -> its table (None: no data written yet) where every
@@ -597,27 +684,10 @@ class QueryPlanner:
             return None
         subs: list[QueryPlan] = []
         for d in disjuncts:
-            sp = self._select_single(type_name, d, None, exp)
-            if sp.config is not None and sp.config.disjoint:
-                exp("Union: disjunct unsatisfiable, dropped")
-                continue  # contributes nothing to the union
-            if sp.index is None and sp.ids is None:
-                exp("Union: a disjunct needs a full scan -> single-scan plan")
-                return None  # one full scan beats full scan + index scans
-            subs.append(sp)
-        if not subs:
-            return QueryPlan(type_name, f, None, ScanConfig.empty("union"), ids=[])
-        if len(subs) == 1:
-            # every other disjunct was unsatisfiable: the live branch IS the
-            # query (its disjunct filter is equivalent to the whole filter)
-            exp(f"Strategy: {subs[0].strategy} (other disjuncts unsatisfiable)")
-            subs[0].limit = limit
-            return subs[0]
-        exp(
-            f"Strategy: union of {len(subs)} index scans ("
-            + ", ".join(s.strategy for s in subs) + ")"
-        )
-        return QueryPlan(type_name, f, None, None, limit=limit, union=subs)
+            subs.append(self._select_single(type_name, d, None, exp))
+            if subs[-1].strategy == "full-scan":
+                break  # no union: the others need no plan
+        return self._union_of(type_name, f, limit, subs, exp, "index scans")
 
     def _select_single(
         self, type_name: str, f: Filter, limit: Optional[int], exp
@@ -1028,9 +1098,12 @@ class QueryPlanner:
         with _ospan("dispatch", members=len(plans)):
             return self._stage_many(plans, per, exps, dls)
 
-    def _stage_many(self, plans, per, exps, dls) -> list:
+    def _stage_many(self, plans, per, exps, dls, branch: bool = False) -> list:
         """submit_many's staging, under its ``dispatch`` span (a member
-        that dispatches alone nests its own ``dispatch`` inside)."""
+        that dispatches alone nests its own ``dispatch`` inside).
+        ``branch``: the plans are the simple branches of ONE union
+        (:meth:`_execute_union`), which audits the query once and applies
+        visibility once over the merged rows: no branch does either."""
         finishes: list = [None] * len(plans)
         groups: dict[tuple, list[int]] = {}
         for j, plan in enumerate(plans):
@@ -1051,20 +1124,16 @@ class QueryPlanner:
             table, chunks = self.store.pin_scan_state(tname, iname)
             many = getattr(table, "scan_submit_many", None)
             if many is None or len(idxs) == 1:
-                for j in idxs:
-                    finishes[j] = self.submit(
-                        plans[j], explain=exps[j], hints=per[j],
-                        deadline=dls[j], member=j,
-                    )
-                continue
-            scan_fins = many([plans[j].config for j in idxs])
+                scan_fins, chunks = [None] * len(idxs), None  # each dispatches its own
+            else:
+                scan_fins = many([plans[j].config for j in idxs])
             for j, scan_fin in zip(idxs, scan_fins):
-                plan = plans[j]
-                finishes[j] = self._record_wrap(plan, self._submit_simple(
-                    plan, exps[j] or ExplainNull(), per[j],
+                finish = self._submit_simple(
+                    plans[j], exps[j] or ExplainNull(), per[j], branch,
                     finish_scan=scan_fin, deadline=dls[j], chunks=chunks,
                     member=j,
-                ))
+                )
+                finishes[j] = finish if branch else self._record_wrap(plans[j], finish)
         return finishes
 
     def execute_many(self, plans, hints=None) -> list:
@@ -1082,22 +1151,39 @@ class QueryPlanner:
         """Run every union branch on its own index and dedup-union by
         feature id (reference: per-option scans merged client-side with
         deduplication, FilterSplitter OR semantics). Each branch refines
-        with its own disjunct filter, so the union is exact. The query's
+        with its own disjunct filter, so the union is exact; the rows come
+        branch by branch. The branches that are simple index scans share
+        ONE ``dispatch`` (:meth:`_stage_many`: fused where they share a
+        table, as ``query_many``'s members are) with a ``scan`` and a
+        ``decode`` each; any other branch executes on its own. The query's
         ONE deadline bounds all branches: each gets the remaining budget,
         not a fresh one. Branches skip visibility — it runs once over the
         union in the final _post."""
         from geomesa_tpu.planning.hints import QueryHints
 
-        parts = []
-        for sp in plan.union:
+        def alone(sp):
             sub_hints = None
             if deadline is not None:
                 check_deadline(deadline, f"union branch [{sp.strategy}]")
                 sub_hints = QueryHints(timeout=max(deadline.remaining(), 1e-9))
+            return self._execute(sp, explain=exp, hints=sub_hints, skip_visibility=True)
+
+        # the branches that are simple index scans dispatch together,
+        # fused a table; their pulls and every other branch follow in order
+        simple = [sp for sp in plan.union if self._is_simple(sp)]
+        staged: dict = {}
+        if simple:
+            check_deadline(deadline, "union dispatch")
+            n = len(simple)
+            with _ospan("dispatch", members=n):
+                staged = dict(zip(map(id, simple), self._stage_many(
+                    simple, [None] * n, [exp] * n, [deadline] * n, branch=True
+                )))
+        parts = []
+        for sp in plan.union:
             with exp.span(f"Union branch [{sp.strategy}]"):
-                parts.append(
-                    self._execute(sp, explain=exp, hints=sub_hints, skip_visibility=True)
-                )
+                finish = staged.get(id(sp))
+                parts.append(alone(sp) if finish is None else finish())
         check_deadline(deadline, "union merge")
         nonempty = [p for p in parts if len(p)]
         if not nonempty:

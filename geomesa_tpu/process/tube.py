@@ -6,8 +6,10 @@ TubeSelectProcess.scala:36, TubeBuilder.scala) — bins an input track into
 time slices, buffers each slice's geometry, and queries features that fall
 inside the moving buffer both spatially and temporally. The TPU redesign
 bins the track the same way (``bin_ms`` slices, interpolating positions),
-issues one Or-of-(bbox And interval) indexed query, and refines with a
-vectorized distance test against each row's own time-matched tube center.
+issues one Or-of-(bbox And interval) indexed query (which the planner
+answers, past sixteen slices, as a union of time-ordered groups dispatched
+fused), and refines with a vectorized distance test against each row's own
+time-matched tube center.
 """
 
 from __future__ import annotations
@@ -39,10 +41,15 @@ def tube_select(
 
     Traced (docs/processes.md): ONE root ``tube`` a call (``waypoints``,
     ``bins``, ``buffer_m``, ``rows`` the query returned, ``kept`` within
-    ``buffer_m``; ``boxes`` / ``windows`` / ``ranges`` / ``candidates`` from
-    the query's own plan and trace) with the children ``tube.bins`` and
-    ``tube.refine``; the query is ``store.query``'s own ``query`` root,
-    linked by ``tube_trace`` (and ``query_trace`` here).
+    ``buffer_m``; ``groups`` / ``boxes`` / ``windows`` / ``ranges`` /
+    ``candidates`` from the query's own plan and trace) with the children
+    ``tube.bins`` and ``tube.refine``; the query is ``store.query``'s own
+    ``query`` root, linked by ``tube_trace`` (and ``query_trace`` here).
+
+    Past sixteen slices the planner answers the one query as a union of
+    time-ordered groups of slices (``filter.dnf.time_slices``), each group
+    a scan of its own boxes over its own stretch of the track: the rows
+    then come group by group, not in table order.
     """
     xy = np.asarray(track_xy, dtype=np.float64).reshape(-1, 2)
     ts = np.asarray(track_times_ms, dtype=np.int64)
@@ -88,9 +95,10 @@ def _query_counted(store, type_name: str, f: Filter, root) -> FeatureCollection:
     tracer: ``tube_trace`` there, ``query_trace`` here) and leaves its
     trace and its plan on the explainer it is handed. From them the root's
     ``rows``, and where that inner root was built too (sampled 1 in N by
-    its own name's count) ``boxes`` / ``windows`` / ``ranges`` (the chosen
-    plan's config) and ``candidates`` (its ``decode`` spans': the rows the
-    device's mask passed)."""
+    its own name's count) ``groups`` (the branches of a time-sliced union,
+    0 for one scan), ``boxes`` / ``windows`` / ``ranges`` (the chosen plan's
+    config, summed over a union's branches) and ``candidates`` (its
+    ``decode`` spans': the rows the device's mask passed)."""
     from geomesa_tpu.planning.explain import ExplainNull
 
     exp = ExplainNull()
@@ -101,12 +109,15 @@ def _query_counted(store, type_name: str, f: Filter, root) -> FeatureCollection:
         root.annotate(candidates=sum(
             (s.attrs or {}).get("candidates", 0) for s in inner.spans if s.name == "decode"
         ))
-    cfg = getattr(plan, "config", None)
-    if cfg is not None:
+    if plan is not None:
+        # one scan, or past sixteen slices the union's branches (a group each)
+        branches = plan.union if plan.union is not None else [plan]
+        cfgs = [p.config for p in branches if p.config is not None]
         root.annotate(
-            boxes=0 if cfg.boxes is None else len(cfg.boxes),
-            windows=0 if cfg.windows is None else len(cfg.windows),
-            ranges=int(cfg.n_ranges),
+            groups=len(branches) if plan.union is not None else 0,
+            boxes=sum(0 if c.boxes is None else len(c.boxes) for c in cfgs),
+            windows=sum(0 if c.windows is None else len(c.windows) for c in cfgs),
+            ranges=sum(int(c.n_ranges) for c in cfgs),
         )
     return out
 

@@ -22,7 +22,8 @@ on the CPU:
 (e) ``generators/vessel_proximity.py``: every seed's round is the same
     multiset with the issue's parameters letter for letter; the warm ladder's
     rungs;
-(f) the readers over hand-made spans, and None on a program without the roots;
+(f) the readers over hand-made spans (a tube's query as the union of groups
+    PR 47 plans, and as one scan), and None on a program without the roots;
 (g) the cell itself through ``benchmark/rehearse.py`` reads ``correct`` with
     every new metric; under ``--control swap-attr`` it does not.
 """
@@ -47,7 +48,7 @@ ROUND = {"knn-port": 6, "knn-sea": 2, "knn-many-16": 2, "tube-2k": 4, "tube-10k"
 CLASSES = tuple(ROUND)
 NEW_METRICS = ("knn_plan_ms", "tube_plan_ms", "knn_rounds", "knn_scan_ms", "knn_rank_ms",
                "knn_overfetch", "tube_scan_ms", "tube_refine_ms", "tube_keep_pct",
-               "process_coverage_pct")
+               "process_coverage_pct", "tube_groups")
 
 
 @pytest.fixture(scope="module")
@@ -539,11 +540,18 @@ def _span(i, trace, root, name, dur_ms, parent=None, **attrs):
 def test_the_readers_read_the_processes_spans(bench):
     knn = _span(1, 1, "knn", "knn", 20.0, members=2, k=8, rounds=2, windows=3, candidates=90,
                 returned=16, short=0)
-    tube = _span(20, 2, "tube", "tube", 100.0, waypoints=360, bins=256, buffer_m=2000.0,
-                 boxes=256, windows=1, ranges=900, candidates=4000, rows=1000, kept=400,
-                 query_trace=3)
+    # a tube as PR 47 plans it: a union of two groups under ONE query root, whose plan,
+    # one dispatch and a scan + decode a branch all lie directly under the root
+    tube = _span(20, 2, "tube", "tube", 100.0, waypoints=360, bins=32, buffer_m=2000.0,
+                 groups=2, boxes=32, windows=2, ranges=250, candidates=4000, rows=1000,
+                 kept=400, query_trace=3)
     query = _span(30, 3, "query", "query", 80.0, tube_trace=2)
     other = _span(40, 4, "query", "query", 5.0)  # a query no tube asked
+    # a tube of sixteen slices or fewer: one scan, ``groups`` 0
+    short = _span(50, 5, "tube", "tube", 20.0, waypoints=12, bins=8, buffer_m=500.0,
+                  groups=0, boxes=8, windows=1, ranges=40, candidates=100, rows=50, kept=40,
+                  query_trace=6)
+    asked = _span(60, 6, "query", "query", 15.0, tube_trace=5)
     spans = [
         knn, dict(knn),  # roots twice, as the harness lists them
         _span(2, 1, "knn", "knn.estimate", 0.5, parent=1, probes=4),
@@ -563,11 +571,14 @@ def test_the_readers_read_the_processes_spans(bench):
         _span(21, 2, "tube", "tube.bins", 4.0, parent=20),
         _span(22, 2, "tube", "tube.refine", 6.0, parent=20),
         query, dict(query),
-        _span(31, 3, "query", "plan", 30.0, parent=30),
+        _span(31, 3, "query", "plan", 30.0, parent=30, members=1, batched=1, sliced=2),
         _span(32, 3, "query", "plan.decompose", 20.0, parent=31),
-        _span(33, 3, "query", "dispatch", 2.0, parent=30),
-        _span(34, 3, "query", "scan", 3.0, parent=30),
-        _span(35, 3, "query", "decode", 40.0, parent=30, candidates=4000),
+        _span(33, 3, "query", "dispatch", 2.0, parent=30, members=2),
+        _span(37, 3, "query", "dispatch", 1.5, parent=33),  # a branch alone on its index, nested
+        _span(34, 3, "query", "scan", 2.0, parent=30, member=0),
+        _span(36, 3, "query", "scan", 1.0, parent=30, member=1),
+        _span(35, 3, "query", "decode", 25.0, parent=30, candidates=2500, member=0),
+        _span(38, 3, "query", "decode", 15.0, parent=30, candidates=1500, member=1),
         other, dict(other), _span(41, 4, "query", "plan", 99.0, parent=40),
     ]
     view = {"spans": spans, "client": {"query_ms": [21.0, 104.0, 25.0]}}
@@ -578,18 +589,34 @@ def test_the_readers_read_the_processes_spans(bench):
     assert r["knn_rounds"].read(view) == pytest.approx(1.5)
     assert r["knn_overfetch"].read(view) == pytest.approx(90 / 16)
     assert r["tube_plan_ms"].read(view) == pytest.approx(30.0)  # whole, its child inside it
-    assert r["tube_scan_ms"].read(view) == pytest.approx(5.0)
-    assert r["tube_refine_ms"].read(view) == pytest.approx(46.0)
+    assert r["tube_scan_ms"].read(view) == pytest.approx(5.0)  # the one dispatch + both scans
+    assert r["tube_refine_ms"].read(view) == pytest.approx(46.0)  # both decodes + tube.refine
     assert r["tube_keep_pct"].read(view) == pytest.approx(10.0)
+    assert r["tube_groups"].read(view) == pytest.approx(2.0)
     assert r["process_coverage_pct"].read(view) == pytest.approx(100 * 120.0 / 150.0)
+    # beside a tube that stayed one scan: medians over the two roots, groups pooled
+    both = {"spans": spans + [
+        short, dict(short), _span(51, 5, "tube", "tube.refine", 1.0, parent=50),
+        asked, dict(asked), _span(61, 6, "query", "plan", 4.0, parent=60, sliced=0),
+        _span(62, 6, "query", "dispatch", 1.0, parent=60),
+        _span(63, 6, "query", "scan", 2.0, parent=60),
+        _span(64, 6, "query", "decode", 3.0, parent=60, candidates=100)],
+        "client": view["client"]}
+    assert r["tube_groups"].read(both) == pytest.approx(1.0)
+    assert r["tube_plan_ms"].read(both) == pytest.approx((30.0 + 4.0) / 2)
+    assert r["tube_scan_ms"].read(both) == pytest.approx((5.0 + 3.0) / 2)
+    assert r["tube_refine_ms"].read(both) == pytest.approx((46.0 + 4.0) / 2)
+    assert r["tube_keep_pct"].read(both) == pytest.approx(100 * 440 / 4100)
     # a program without the two roots (PR 46's parent): the tube's query is a plain query root
     parent = {"spans": [dict(s, attrs={}) for s in spans if s["root"] == "query"],
               "client": view["client"]}
     for name, reader in r.items():
         assert reader.read(parent) is None, name
-    # a tube root whose query was sampled out carries no candidates: no share, no failure
+    # a tube root whose query was sampled out carries no candidates: no share, no failure;
+    # nor ``groups`` (the explainer held no plan): nothing to read, as on PR 47's parent
     bare = {"spans": [dict(tube, attrs={"rows": 5, "kept": 1})], "client": view["client"]}
     assert r["tube_keep_pct"].read(bare) is None
+    assert r["tube_groups"].read(bare) is None
     assert r["tube_plan_ms"].read(bare) == 0.0
 
 
@@ -616,6 +643,7 @@ def test_the_cell_rehearses_on_the_cpu():
     assert 1.0 <= read["knn_rounds"]["value"] < 3.0
     assert read["knn_overfetch"]["value"] >= 1.0
     assert 0 < read["tube_keep_pct"]["value"] <= 100
+    assert 2 <= read["tube_groups"]["value"] <= 16  # 256 slices a track, fewer for a short one
     assert 90 < read["process_coverage_pct"]["value"] <= 100
     window = next(json.loads(s) for s in out.stdout.splitlines() if '"phase": "window"' in s)
     assert window["compile_requests_in_window"] == 0

@@ -8,8 +8,10 @@ docs/observability.md): ``knn_search`` / ``knn_many`` under ONE root
     ``returned``, ``short`` counts the members answered under ``k``, every
     round holds one ``plan`` a pending member and one ``dispatch``; a
     ``tube`` root's ``kept`` <= ``rows`` <= ``candidates``, ``boxes`` /
-    ``windows`` / ``ranges`` are the chosen plan's config, ``bins`` the
-    slices;
+    ``windows`` / ``ranges`` are the chosen plan's config (summed over the
+    ``groups`` branches of a time-sliced union, PR 47), ``bins`` the slices;
+    its ``query`` root holds one ``plan``, one ``dispatch`` and a ``scan`` +
+    ``decode`` a branch;
 (b) answers are identical with tracing on and off, ids and every column;
 (c) with sampling off no span object is made;
 (d) the boundary repair of PR 46: a row at exactly the track's last instant
@@ -22,9 +24,10 @@ import pytest
 from geomesa_tpu import conf, obs
 from geomesa_tpu.datastore import DataStore
 from geomesa_tpu.features import FeatureCollection
-from geomesa_tpu.filter.predicates import During
+from geomesa_tpu.filter.predicates import During, Or
 from geomesa_tpu.obs import trace as otrace
 from geomesa_tpu.process import knn_many, knn_search, tube_select
+from geomesa_tpu.process.tube import _slices as _tube_slices
 from geomesa_tpu.sft import FeatureType
 
 N = 20_000
@@ -155,33 +158,55 @@ def test_a_tube_roots_counters_are_its_querys(case, store, traced):
     assert inner.root.attrs["tube_trace"] == tr.trace_id
     assert a["query_trace"] == inner.trace_id
     assert a["candidates"] == sum(s.attrs["candidates"] for s in _named(inner, "decode"))
-    # the chosen plan's config, as a fresh plan of the same filter reads it
-    from geomesa_tpu.process.tube import _slices
-    from geomesa_tpu.filter.predicates import Or
-
-    parts = _slices("geom", "dtg", xy, t, buffer_m, None, 256)
+    # the chosen plan's configs, as a fresh plan of the same filter reads them:
+    # one scan's, or past sixteen slices the union's branches' summed
+    parts = _tube_slices("geom", "dtg", xy, t, buffer_m, None, 256)
     assert a["bins"] == len(parts)
     plan = store.planner.plan("rep", parts[0] if len(parts) == 1 else Or(tuple(parts)))
-    cfg = plan.config
-    assert a["boxes"] == (0 if cfg.boxes is None else len(cfg.boxes))
-    assert a["windows"] == (0 if cfg.windows is None else len(cfg.windows))  # z2: no window
-    assert a["ranges"] == cfg.n_ranges
+    branches = [plan] if plan.union is None else plan.union
+    cfgs = [p.config for p in branches]
+    assert a["groups"] == (0 if plan.union is None else len(branches))
+    assert a["groups"] == {"slices-256": 16, "slices-40": 3, "one-slice": 0}[case]
+    assert a["boxes"] == sum(0 if c.boxes is None else len(c.boxes) for c in cfgs)
+    assert a["windows"] == sum(0 if c.windows is None else len(c.windows) for c in cfgs)  # z2: none
+    assert a["ranges"] == sum(c.n_ranges for c in cfgs)
     assert {s.name for s in _all(tr)} >= {"tube", "tube.bins"}
     if len(out) or a["rows"]:
         (refine,) = _named(tr, "tube.refine")
         assert refine.attrs["rows"] == a["rows"]
-    assert {"plan", "dispatch", "scan", "decode"} <= {s.name for s in _all(inner)}
+    # ONE query root: one plan, one dispatch and a scan + decode a branch directly under it
+    for name, count in (("plan", 1), ("dispatch", 1), ("scan", len(branches)),
+                        ("decode", len(branches))):
+        assert len(_children(inner, inner.root, name)) == count, name
+    (planned,) = _children(inner, inner.root, "plan")
+    assert planned.attrs["sliced"] == a["groups"]
 
 
-def test_a_tube_of_many_slices_is_one_scan_of_one_window(store, traced):
-    """What the cell was built to show (PERF.md section 7): past 16
-    disjuncts the planner scans every slice's box for the whole track's
-    duration: 256 boxes, ONE window."""
+def test_a_tube_of_many_slices_is_sixteen_scans_of_their_own_windows(store, traced):
+    """What the cell was built to show (PERF.md section 7), as PR 47 left
+    it: past 16 disjuncts the planner no longer scans every slice's box for
+    the whole track's duration (256 boxes, ONE window) but sixteen
+    time-ordered groups, each its own 16 boxes under its own window (two
+    where a group crosses a z3 bin's edge; none where z2 costs less)."""
     xy, t = _track(360, T0 + 600_000, T0 + 5 * 3_600_000)
-    tube_select(store, "rep", xy, t, 2_000.0)
+    out = tube_select(store, "rep", xy, t, 2_000.0)
     (tr,) = [x for x in traced.traces() if x.name == "tube"]
-    assert tr.root.attrs["bins"] == 256 == tr.root.attrs["boxes"]
-    assert tr.root.attrs["windows"] == 1
+    (inner,) = [x for x in traced.traces() if x.name == "query"]
+    a = tr.root.attrs
+    assert a["bins"] == 256 == a["boxes"] and a["groups"] == 16
+    plan = store.planner.plan("rep", Or(tuple(
+        _tube_slices("geom", "dtg", xy, t, 2_000.0, None, 256))))
+    assert len(plan.union) == 16 and all(len(p.config.boxes) == 16 for p in plan.union)
+    per_group = [0 if p.config.windows is None else len(p.config.windows) for p in plan.union]
+    assert all(w <= 2 for w in per_group) and 1 <= a["windows"] == sum(per_group) <= 32
+    spans_ms = [w[:, 2].max() - w[:, 1].min() for w in
+                (p.config.windows for p in plan.union if p.config.windows is not None)]
+    # a group's window is its sixteenth of the track (z3 offsets are seconds here)
+    assert max(spans_ms) <= (t[-1] - t[0]) / 1000 / 16 + 2
+    # the rows come group by group: each branch's decode in time order of the track
+    decodes = sorted(_children(inner, inner.root, "decode"), key=lambda s: s.attrs["member"])
+    assert len(decodes) == 16 and sum(s.attrs["candidates"] for s in decodes) == a["candidates"]
+    assert len(out) == a["kept"] <= a["rows"] <= a["candidates"]
 
 
 @pytest.mark.parametrize("what", ["knn", "knn-many", "tube"])

@@ -113,6 +113,51 @@ class TestUnionPlans:
         assert len(out) == 7
 
 
+UNIONS = {
+    "two-indexes": ("bbox(geom, -20, -15, 10, 10) OR name = 'n3'", 2),
+    "three-branches": (
+        "(bbox(geom, -20, -15, 10, 10) AND dtg DURING "
+        "2024-01-02T00:00:00Z/2024-01-12T00:00:00Z) OR name = 'n7' OR name = 'n11'", 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(UNIONS))
+def test_a_unions_simple_branches_share_one_dispatch_span(case, ds):
+    """PR 47: the branches that are simple index scans are staged together
+    (fused where they share a table; one alone on its index nests its own
+    ``dispatch`` inside), then pulled in order: ONE ``dispatch`` and a
+    ``scan`` + ``decode`` a branch directly under the root, the answer and
+    the audit (one query recorded, not one a branch) as before."""
+    from geomesa_tpu import conf, obs
+
+    q, branches = UNIONS[case]
+    plan = ds.planner.plan("u", q)
+    assert len(plan.union) == branches and all(ds.planner._is_simple(p) for p in plan.union)
+    audited = []
+    real = ds.record_query
+    ds.record_query = lambda plan, hits, scan_s: audited.append(plan) or real(plan, hits, scan_s)
+    obs.install(obs.Tracer())
+    conf.OBS_TRACE_SAMPLE.set(1)
+    try:
+        out = ds.query("u", q)
+        (tr,) = obs.tracer().traces()
+    finally:
+        conf.OBS_TRACE_SAMPLE.clear()
+        obs.install(obs.Tracer())
+        del ds.record_query
+    assert sorted(out.ids.tolist()) == brute(ds, q)
+    top = [s for s in tr.spans if s.parent_id == tr.root.span_id]
+    (dispatch,) = [s for s in top if s.name == "dispatch"]
+    assert dispatch.attrs["members"] == branches
+    assert [s.name for s in top].count("scan") == [s.name for s in top].count("decode") == branches
+    nested = [s for s in tr.spans if s.name == "dispatch" and s.parent_id == dispatch.span_id]
+    by_index = {}
+    for p in plan.union:
+        by_index[p.index] = by_index.get(p.index, 0) + 1
+    assert len(nested) == sum(1 for n in by_index.values() if n == 1)
+    assert len(audited) == 1 and audited[0].union is not None
+
+
 def test_union_branches_under_seam_crossing_and():
     """Mixed-kind OR (time/attribute) ANDed with a seam-crossing bbox:
     union plans + antimeridian normalization must compose (caught
