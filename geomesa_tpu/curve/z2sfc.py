@@ -14,8 +14,8 @@ import numpy as np
 from geomesa_tpu.curve.normalize import NormalizedLat, NormalizedLon
 from geomesa_tpu.curve.zorder import Z2
 from geomesa_tpu.curve.zranges import (
-    IndexRange, pad_corners, ranges_from_arrays, with_inner, zranges_arrays,
-    zranges_arrays_each,
+    SCALAR_CORNERS, IndexRange, box_list, box_rows, pad_corners, pad_rows, ranges_from_arrays,
+    with_inner, zranges_arrays, zranges_arrays_each,
 )
 
 
@@ -100,12 +100,22 @@ class Z2SFC:
     def _corners(self, bounds) -> tuple[np.ndarray, np.ndarray]:
         """The min and max corner ordinals of every box of ``bounds[q]``,
         u64 ``[nq, nbox, 2]`` each, ``nbox`` the most a query has: a query
-        with fewer repeats its last (the same union)."""
+        with fewer repeats its last (the same union). Past
+        ``SCALAR_CORNERS`` boxes in all the ordinals are one ``normalize``
+        a dimension over every query's boxes (the same floor, the same
+        clamp as ``normalize_one``'s)."""
+        if sum(map(len, bounds)) > SCALAR_CORNERS:
+            flat, counts = box_rows(bounds)
+            x, y = self.lon.normalize(flat[:, 0::2]), self.lat.normalize(flat[:, 1::2])
+            return (
+                pad_rows(np.stack([x[:, 0], y[:, 0]], axis=1), counts),
+                pad_rows(np.stack([x[:, 1], y[:, 1]], axis=1), counts),
+            )
         lon, lat = self.lon.normalize_one, self.lat.normalize_one
         los, his = [], []
         for boxes in bounds:
             lo_q, hi_q = [], []
-            for (xmin, ymin, xmax, ymax) in boxes:
+            for (xmin, ymin, xmax, ymax) in box_list(boxes):
                 if xmin > xmax or ymin > ymax:
                     raise ValueError(f"inverted bbox: {(xmin, ymin, xmax, ymax)}")
                 lo_q.append((lon(xmin), lat(ymin)))

@@ -17,8 +17,8 @@ from geomesa_tpu.curve.binnedtime import MAX_OFFSET, TimePeriod
 from geomesa_tpu.curve.normalize import NormalizedLat, NormalizedLon, NormalizedTime
 from geomesa_tpu.curve.zorder import Z3
 from geomesa_tpu.curve.zranges import (
-    IndexRange, pad_corners, ranges_from_arrays, with_inner, zranges_arrays,
-    zranges_arrays_each,
+    SCALAR_CORNERS, IndexRange, box_list, box_rows, pad_corners, pad_rows, ranges_from_arrays,
+    with_inner, zranges_arrays, zranges_arrays_each,
 )
 
 _INSTANCES: dict[TimePeriod, "Z3SFC"] = {}
@@ -128,11 +128,28 @@ class Z3SFC:
         them; ``inner`` as :meth:`ranges_arrays`; ``cover`` as
         :meth:`Z2SFC.ranges_arrays_each`'s (the windows stay ``times``);
         ``max_ranges`` bounds each query's ranges (default: the target)."""
-        windows = [[w] for w in times]
-        mins, maxes, imins, imaxes = with_inner(*self._corners(bounds, windows), inner)
+        mins, maxes, imins, imaxes = with_inner(*self._corners_each(bounds, times), inner)
         if cover is not None:
-            mins, maxes = self._corners(cover, windows)
+            mins, maxes = self._corners_each(cover, times)
         return zranges_arrays_each(Z3, mins, maxes, imins, imaxes, max_ranges)
+
+    def _corners_each(self, bounds, times) -> tuple[np.ndarray, np.ndarray]:
+        """:meth:`_corners` of ONE window a query (``times[q]``). Past
+        ``SCALAR_CORNERS`` boxes in all the ordinals are one ``normalize``
+        a dimension over every query's boxes and one over the windows (the
+        same floor, the same clamp as ``normalize_one``'s)."""
+        if sum(map(len, bounds)) <= SCALAR_CORNERS:
+            return self._corners(bounds, [[w] for w in times])
+        flat, counts = box_rows(bounds)
+        t = np.asarray(times, dtype=np.float64).reshape(-1, 2)
+        if (t[:, 0] > t[:, 1]).any():
+            raise ValueError(f"inverted time window: {tuple(t[np.argmax(t[:, 0] > t[:, 1])])}")
+        x, y = self.lon.normalize(flat[:, 0::2]), self.lat.normalize(flat[:, 1::2])
+        t = np.repeat(self.time.normalize(t), counts, axis=0)
+        return (
+            pad_rows(np.stack([x[:, 0], y[:, 0], t[:, 0]], axis=1), counts),
+            pad_rows(np.stack([x[:, 1], y[:, 1], t[:, 1]], axis=1), counts),
+        )
 
     def _corners(self, bounds, times) -> tuple[np.ndarray, np.ndarray]:
         """The min and max corner ordinals of every box of ``bounds[q]``
@@ -145,7 +162,7 @@ class Z3SFC:
         los, his = [], []
         for boxes, windows in zip(bounds, times):
             lo_q, hi_q = [], []
-            for (xmin, ymin, xmax, ymax) in boxes:
+            for (xmin, ymin, xmax, ymax) in box_list(boxes):
                 if xmin > xmax or ymin > ymax:
                     raise ValueError(f"inverted bbox: {(xmin, ymin, xmax, ymax)}")
                 for (tmin, tmax) in windows:

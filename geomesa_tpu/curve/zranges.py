@@ -72,6 +72,49 @@ def pad_corners(corners: list, dims: int) -> np.ndarray:
     return np.array(corners, dtype=np.uint64).reshape(len(corners), nbox, dims)
 
 
+# boxes in all (every query's summed) up to which the corner ordinals come
+# out of the scalar loop: under it NumPy's call overhead costs more than a
+# ``normalize_one`` a corner (a viewport's one box, a join's polygon); past
+# it (a tube's sixteen groups of sixteen boxes) one vector call a dimension
+SCALAR_CORNERS = 32
+
+
+def box_list(boxes) -> list:
+    """A query's boxes for the scalar loop: an array's rows as lists of
+    Python floats (what a list of tuples holds already)."""
+    return boxes.tolist() if isinstance(boxes, np.ndarray) else boxes
+
+
+def stack_boxes(bounds: list) -> np.ndarray:
+    """Every query's boxes as ONE f64 ``[B, 4]`` array, query q's rows
+    after query q-1's; a query's boxes are a sequence of (xmin, ymin,
+    xmax, ymax) or an array of such rows (what the extraction of a
+    ``filter.predicates.Slices`` carrier holds)."""
+    if not bounds:
+        return np.zeros((0, 4))
+    return np.concatenate([np.asarray(b, dtype=np.float64).reshape(-1, 4) for b in bounds])
+
+
+def box_rows(bounds: list) -> tuple[np.ndarray, np.ndarray]:
+    """:func:`stack_boxes` of ``bounds``, none inverted, and the boxes a
+    query i64 ``[nq]``."""
+    flat = stack_boxes(bounds)
+    bad = (flat[:, 0] > flat[:, 2]) | (flat[:, 1] > flat[:, 3])
+    if bad.any():
+        raise ValueError(f"inverted bbox: {tuple(flat[np.argmax(bad)].tolist())}")
+    return flat, np.array([len(b) for b in bounds], dtype=np.int64)
+
+
+def pad_rows(corners: np.ndarray, counts: np.ndarray) -> np.ndarray:
+    """:func:`pad_corners` of corner ordinals ``[B, dims]`` laid out as
+    :func:`box_rows` lays boxes out: u64 ``[nq, nbox, dims]``, a query
+    with fewer boxes than the most repeating its last."""
+    nbox = int(counts.max()) if len(counts) else 0
+    first = np.cumsum(counts) - counts
+    at = first[:, None] + np.minimum(np.arange(nbox)[None, :], counts[:, None] - 1)
+    return corners[at].astype(np.uint64)
+
+
 def ranges_from_arrays(lower, upper, contained) -> list[IndexRange]:
     """The object view of :func:`zranges_arrays`' result, for callers that
     want one ``IndexRange`` a range (tests, ``explain``); the plan path

@@ -2382,9 +2382,11 @@ def _observe_sketch(stats, idx, keys) -> None:
     )
 
 
-def _plan_fingerprint(plan) -> dict:
-    """The slow-query log's identity of a planned operation."""
-    return {
+def _plan_fingerprint(plan):
+    """The slow-query log's identity of a planned operation, as the
+    callable ``Trace.fingerprint`` may hold: the filter's text is rendered
+    only where the log takes the trace."""
+    return lambda: {
         "type": plan.type_name,
         "strategy": plan.strategy,
         "filter": str(plan.filter),

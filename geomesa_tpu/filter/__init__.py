@@ -27,6 +27,7 @@ from geomesa_tpu.filter.predicates import (
     Not,
     Or,
     PointColumn,
+    Slices,
     Within,
 )
 from geomesa_tpu.filter.ecql import parse, parse_dt_millis
@@ -45,7 +46,7 @@ __all__ = [
     "Filter", "Include", "Exclude", "INCLUDE", "EXCLUDE",
     "BBox", "Intersects", "Contains", "Within", "DWithin",
     "During", "Cmp", "Between", "In", "Like", "IsNull", "IdFilter",
-    "And", "Or", "Not", "PointColumn",
+    "And", "Or", "Not", "Slices", "PointColumn",
     "parse", "parse_dt_millis",
     "FilterValues", "Interval", "Bounds",
     "extract_geometries", "extract_intervals", "extract_ids",

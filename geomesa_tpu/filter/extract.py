@@ -34,6 +34,7 @@ from geomesa_tpu.filter.predicates import (
     Intersects,
     Not,
     Or,
+    Slices,
     Within,
 )
 
@@ -75,6 +76,8 @@ class FilterValues(Generic[T]):
 
 def _references_prop(f: Filter, prop: str) -> bool:
     """Does any predicate in the tree constrain ``prop``?"""
+    if isinstance(f, Slices):
+        return prop in (f.geom, f.dtg)
     if isinstance(f, (And, Or)):
         return any(_references_prop(c, prop) for c in f.filters)
     if isinstance(f, Not):
@@ -117,6 +120,10 @@ def extract_geometries(f: Filter, prop: str) -> FilterValues:
     (by bbox) across ANDs. Reference FilterHelper.extractGeometries."""
     if isinstance(f, (Include, Exclude, IdFilter)):
         return FilterValues.nothing()
+    if isinstance(f, Slices):  # as the Or of And(BBox, During) it means
+        if prop != f.geom:
+            return FilterValues.nothing()
+        return FilterValues(values=[geo.box(*b) for b in f.boxes.tolist()])
     single = _predicate_geometry(f, prop)
     if single is not None:
         g, precise = single
@@ -263,6 +270,10 @@ def extract_intervals(f: Filter, prop: str) -> FilterValues:
     """Time intervals constraining ``prop``. Reference extractIntervals."""
     if isinstance(f, (Include, Exclude, IdFilter)):
         return FilterValues.nothing()
+    if isinstance(f, Slices):  # as the Or of And(BBox, During) it means
+        if prop != f.dtg:
+            return FilterValues.nothing()
+        return FilterValues(values=_merge_windows(f.windows))
     single = _predicate_interval(f, prop)
     if single is not None:
         iv, precise = single
@@ -319,6 +330,17 @@ def _merge_intervals(ivs: Sequence[Interval]) -> list[Interval]:
     return out
 
 
+def _merge_windows(windows: np.ndarray) -> list[Interval]:
+    """:func:`_merge_intervals` of int64 ``[n, 2]`` half-open windows, as
+    array arithmetic: sorted by (lo, hi), a window that starts at or under
+    the running end joins the interval before it."""
+    order = np.lexsort((windows[:, 1], windows[:, 0]))
+    lo, top = windows[order, 0], np.maximum.accumulate(windows[order, 1])
+    first = np.flatnonzero(np.concatenate([[True], lo[1:] > top[:-1]]))
+    last = np.concatenate([first[1:], [len(lo)]]) - 1
+    return [Interval(a, b) for a, b in zip(lo[first].tolist(), top[last].tolist())]
+
+
 # ---------------------------------------------------------------------------
 # one filter's spatio-temporal extraction, shared by its readers
 # ---------------------------------------------------------------------------
@@ -332,15 +354,62 @@ class Extraction:
     give them, ``bounds`` = :func:`geometry_bounds` of the geometries (an
     empty list where there are none, or the filter is disjoint), and
     ``boxes_exact``: every geometry precisely extracted and its own bbox,
-    so a box test answers the spatial constraint."""
+    so a box test answers the spatial constraint.
+
+    Of a filter whose spatio-temporal part is ONE :class:`Slices` carrier
+    (alone, or under an ``And`` whose other conjuncts constrain neither
+    field) the arrays are the extraction: ``bounds`` is f64 ``[n, 4]``, the
+    carrier's boxes clipped to the world, ``geoms.values`` its rows (no
+    Polygon a slice: ``boxes_exact`` holds, so no reader asks one for its
+    shape), the intervals the windows merged."""
 
     geoms: FilterValues
     intervals: "FilterValues | None"
-    bounds: list
+    bounds: "list | np.ndarray"
     boxes_exact: bool
 
 
+def _sole_slices(f: Filter, geom_field: str, dtg_field: "str | None") -> "Slices | None":
+    """The carrier over the type's two fields that alone constrains them
+    in ``f``, else None."""
+    if isinstance(f, And):
+        found = [c for c in f.filters if isinstance(c, Slices)]
+        if len(found) != 1:
+            return None
+        s = found[0]
+        for c in f.filters:
+            if c is not s and (
+                _references_prop(c, geom_field)
+                or (dtg_field is not None and _references_prop(c, dtg_field))
+            ):
+                return None
+    elif isinstance(f, Slices):
+        s = f
+    else:
+        return None
+    if s.geom != geom_field or (dtg_field is not None and s.dtg != dtg_field):
+        return None
+    return s
+
+
+_WORLD_LO = np.array([-180.0, -90.0])
+_WORLD_HI = np.array([180.0, 90.0])
+
+
 def extract_filter(f: Filter, geom_field: str, dtg_field: "str | None") -> Extraction:
+    s = _sole_slices(f, geom_field, dtg_field)
+    if s is not None:
+        bounds = np.concatenate(
+            [np.maximum(s.boxes[:, :2], _WORLD_LO), np.minimum(s.boxes[:, 2:], _WORLD_HI)],
+            axis=1,
+        )
+        return Extraction(
+            geoms=FilterValues(values=list(bounds)),
+            intervals=None if dtg_field is None
+            else FilterValues(values=_merge_windows(s.windows)),
+            bounds=bounds,
+            boxes_exact=True,
+        )
     geoms = extract_geometries(f, geom_field)
     return Extraction(
         geoms=geoms,

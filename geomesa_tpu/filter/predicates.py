@@ -17,6 +17,7 @@ x/y — the point fast path) or a ``PackedGeometryColumn`` (extents).
 
 from __future__ import annotations
 
+import hashlib
 import re
 import time
 from dataclasses import dataclass
@@ -600,12 +601,184 @@ class Not(Filter):
         return ~self.filter.evaluate(batch)
 
 
+# the most slice x row cells one pass of Slices.evaluate compares at once
+_EVAL_CELLS = 1 << 20
+
+
+class Slices(Filter):
+    """Box-and-interval slices as two arrays: ``boxes`` f64 ``[n, 4]``
+    (xmin, ymin, xmax, ymax) over the geometry attribute ``geom``,
+    ``windows`` int64 ``[n, 2]`` half-open ``[lo, hi)`` epoch millis over
+    the date attribute ``dtg``. It MEANS exactly ``Or(And(BBox(geom,
+    *boxes[i]), During(dtg, *windows[i])) for i)`` (:meth:`expand`), and
+    answers what the planner asks of a filter off the arrays, without an
+    object a slice: a tube's up to 256 slices (``process/tube.py``) reach
+    the indexes this way (docs/processes.md).
+
+    Every box is finite and ordered (min <= max), every window non-empty
+    (lo < hi); the arrays are the filter's value and are not written to
+    after construction. ``repr`` / ``str`` is the expansion's ECQL text,
+    rendered when asked for (the audit writer, the slow-query log)."""
+
+    def __init__(self, geom: str, dtg: str, boxes, windows):
+        boxes = np.ascontiguousarray(boxes, dtype=np.float64).reshape(-1, 4)
+        windows = np.ascontiguousarray(windows, dtype=np.int64).reshape(-1, 2)
+        if len(boxes) < 1 or len(boxes) != len(windows):
+            raise ValueError(
+                f"Slices needs >= 1 boxes and a window each: {len(boxes)}, {len(windows)}"
+            )
+        if not np.isfinite(boxes).all() or (boxes[:, :2] > boxes[:, 2:]).any():
+            raise ValueError("Slices boxes must be finite with min <= max")
+        if (windows[:, 0] >= windows[:, 1]).any():
+            raise ValueError("Slices windows are half-open [lo, hi) with lo < hi")
+        self.geom, self.dtg, self.boxes, self.windows = geom, dtg, boxes, windows
+
+    def __len__(self) -> int:
+        return len(self.boxes)
+
+    def take(self, rows) -> "Slices":
+        """The slices ``rows`` (an index array or a slice) as a carrier of
+        their own: a subset of valid rows needs no second check."""
+        out = object.__new__(Slices)
+        out.geom, out.dtg = self.geom, self.dtg
+        out.boxes, out.windows = self.boxes[rows], self.windows[rows]
+        return out
+
+    @staticmethod
+    def of(f: Filter, dtg: str) -> "Slices | None":
+        """``f``, an ``Or`` whose EVERY disjunct is ``And`` of one ``BBox``
+        (all on one attribute) and one ``During`` on ``dtg``, as the
+        carrier that means the same, in one walk; None for anything it
+        cannot express exactly as (box, window) rows: a disjunct with a
+        polygon, a second predicate, two intervals, an empty window."""
+        boxes, windows, geom = [], [], None
+        for d in f.filters:
+            if not isinstance(d, And) or len(d.filters) != 2:
+                return None
+            a, b = d.filters
+            if isinstance(a, During):
+                a, b = b, a
+            if not (isinstance(a, BBox) and isinstance(b, During) and b.prop == dtg):
+                return None
+            if geom is None:
+                geom = a.prop
+            if a.prop != geom:
+                return None
+            boxes.append(a.bounds)
+            windows.append((b.lo_ms, b.hi_ms))
+        try:
+            return Slices(geom, dtg, boxes, windows)
+        except (ValueError, OverflowError, TypeError):
+            return None  # an inverted or non-finite box, an empty window
+
+    def expand(self) -> Filter:
+        """The ``Or`` of ``And(BBox, During)`` this carrier means (one
+        slice: the ``And`` itself)."""
+        parts = [
+            And((BBox(self.geom, *b), During(self.dtg, lo, hi)))
+            for b, (lo, hi) in zip(self.boxes.tolist(), self.windows.tolist())
+        ]
+        return parts[0] if len(parts) == 1 else Or(tuple(parts))
+
+    def evaluate(self, batch):
+        col = _column(batch, self.geom)
+        if not isinstance(col, PointColumn):
+            return self.expand().evaluate(batch)
+        t = np.asarray(_column(batch, self.dtg), dtype=np.int64)
+        b, w = self.boxes[:, :, None], self.windows[:, :, None]
+        out = np.empty(len(t), dtype=bool)
+        # a row passes if any slice's box and window hold it: [n, rows]
+        # comparisons, so many rows a pass that the temporaries stay small
+        step = max(1, _EVAL_CELLS // len(self))
+        for a in range(0, len(t), step):
+            x, y, ts = col.x[a:a + step], col.y[a:a + step], t[a:a + step]
+            out[a:a + step] = (
+                (x >= b[:, 0]) & (x <= b[:, 2]) & (y >= b[:, 1]) & (y <= b[:, 3])
+                & (ts >= w[:, 0]) & (ts < w[:, 1])
+            ).any(axis=0)
+        return out
+
+    def key(self) -> str:
+        """The canonical key: the attribute names and a digest of the
+        arrays' bytes with the rows in time order (then box order), so
+        equal sets of slices collide whatever order they were given in;
+        no string a slice."""
+        b, w = self.boxes, self.windows
+        order = np.lexsort((b[:, 3], b[:, 2], b[:, 1], b[:, 0], w[:, 1], w[:, 0]))
+        h = hashlib.blake2b(digest_size=16)
+        h.update(w[order].tobytes())
+        h.update(b[order].tobytes())
+        return f"Slices(geom={self.geom!r},dtg={self.dtg!r},n={len(self)},{h.hexdigest()})"
+
+    def wrapped(self) -> "Slices":
+        """:func:`wrap_box` of every box that leaves [-180, 180], as array
+        arithmetic: such a slice becomes one or two rows under its one
+        window (latitude clamped, as there). ``self`` where no box does."""
+        b = self.boxes
+        out = (b[:, 0] < -180.0) | (b[:, 2] > 180.0)
+        if not out.any():
+            return self
+        x0, x1 = b[:, 0].copy(), b[:, 2].copy()
+        y0 = np.where(out, np.maximum(b[:, 1], -90.0), b[:, 1])
+        y1 = np.where(out, np.minimum(b[:, 3], 90.0), b[:, 3])
+        whole = out & (x1 - x0 >= 360.0)
+        x0[whole], x1[whole] = -180.0, 180.0
+        # a box lying entirely beyond the seam shifts into range first
+        while (far := x0 > 180.0).any():
+            x0[far] -= 360.0
+            x1[far] -= 360.0
+        while (far := x1 < -180.0).any():
+            x0[far] += 360.0
+            x1[far] += 360.0
+        west, east = x0 < -180.0, x1 > 180.0  # never both: under 360 wide
+        first = np.stack(
+            [np.where(west, -180.0, x0), y0, np.where(east, 180.0, x1), y1], axis=1
+        )
+        second = np.stack(
+            [np.where(west, x0 + 360.0, -180.0), y0, np.where(west, 180.0, x1 - 360.0), y1],
+            axis=1,
+        )
+        two = west | east
+        row = np.repeat(np.arange(len(b)), 1 + two)
+        boxes = first[row]
+        boxes[np.cumsum(1 + two)[two] - 1] = second[two]
+        return Slices(self.geom, self.dtg, boxes, self.windows[row])
+
+    def ecql(self) -> str:
+        """The expansion as ECQL text, which ``filter.ecql.parse`` reads
+        back to ``expand()``: floats by ``repr`` (round-trip exact), the
+        windows' bounds as ISO-8601 instants to the millisecond."""
+        iso = np.datetime_as_string(self.windows.astype("datetime64[ms]")).tolist()
+        parts = [
+            f"BBOX({self.geom}, {x0!r}, {y0!r}, {x1!r}, {y1!r}) AND "
+            f"{self.dtg} DURING {lo}Z/{hi}Z"
+            for (x0, y0, x1, y1), (lo, hi) in zip(self.boxes.tolist(), iso)
+        ]
+        return parts[0] if len(parts) == 1 else " OR ".join(f"({p})" for p in parts)
+
+    __repr__ = ecql
+
+    def __eq__(self, other) -> bool:
+        return (
+            isinstance(other, Slices)
+            and (self.geom, self.dtg) == (other.geom, other.dtg)
+            and np.array_equal(self.boxes, other.boxes)
+            and np.array_equal(self.windows, other.windows)
+        )
+
+    def __hash__(self) -> int:
+        return hash((self.geom, self.dtg, self.boxes.tobytes(), self.windows.tobytes()))
+
+
 def canonical_key(f: Filter) -> str:
     """Deterministic canonical string of a filter tree. Logically-equal
     trees that differ only in And/Or child ORDER produce the SAME string
     (children sort by their own canonical keys), so cache fingerprints and
     plan comparisons treat ``a AND b`` and ``b AND a`` as one query.
-    Geometries render as WKT; floats as repr (round-trip exact)."""
+    Geometries render as WKT; floats as repr (round-trip exact); a
+    :class:`Slices` carrier as a digest of its arrays (``Slices.key``)."""
+    if isinstance(f, Slices):
+        return f.key()
     if isinstance(f, (And, Or)):
         kids = sorted(canonical_key(c) for c in f.filters)
         return f"{type(f).__name__}({','.join(kids)})"
@@ -677,6 +850,8 @@ def normalize_antimeridian(f: Filter) -> Filter:
     in the tree needed rewriting (the common case on every plan())."""
     if isinstance(f, BBox) and (f.xmin < -180.0 or f.xmax > 180.0):
         return wrap_box(f.prop, f.xmin, f.ymin, f.xmax, f.ymax)
+    if isinstance(f, Slices):
+        return f.wrapped()
     if isinstance(f, (And, Or)):
         kids = tuple(normalize_antimeridian(c) for c in f.filters)
         if all(k is c for k, c in zip(kids, f.filters)):

@@ -142,11 +142,12 @@ def widen_boxes(bounds) -> np.ndarray:
 
 def cover_boxes(wide: np.ndarray, counts) -> list:
     """The boxes a member's key ranges have to cover: ``widen_boxes``' rows
-    (what the device mask keeps) as float tuples, ``counts[q]`` of them to
-    member q. A box whose edge is a cell boundary of the curve (a WMS
-    tile's) has rows an f32 step beyond it in cells the f64 box's ranges
-    do not reach, and an aggregation counts whatever the mask keeps."""
-    rows = [tuple(r) for r in wide.astype(np.float64).tolist()]
+    (what the device mask keeps) as f64, ``counts[q]`` of them to member q
+    (a ``[counts[q], 4]`` array each). A box whose edge is a cell boundary
+    of the curve (a WMS tile's) has rows an f32 step beyond it in cells the
+    f64 box's ranges do not reach, and an aggregation counts whatever the
+    mask keeps."""
+    rows = wide.astype(np.float64)
     stops = np.cumsum(counts).tolist()
     return [rows[z - n:z] for n, z in zip(counts, stops)]
 

@@ -13,6 +13,7 @@ from typing import Optional
 import numpy as np
 
 from geomesa_tpu.curve.z2sfc import Z2SFC
+from geomesa_tpu.curve.zranges import stack_boxes
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.extract import extract_filter
 from geomesa_tpu.filter.predicates import Filter, PointColumn
@@ -125,7 +126,7 @@ class Z2Index:
         if not pending:
             return out
         bounds = [extractions[m].bounds for m, _ in pending]
-        flat = [b for bs in bounds for b in bs]
+        flat = stack_boxes(bounds)
         wide, inner = widen_boxes(flat), shrink_boxes(flat)
         # covering ranges of the boxes the mask keeps, containment by the
         # f64 boxes: a contained row is a certain f64 hit as before

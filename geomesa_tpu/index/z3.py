@@ -18,6 +18,7 @@ import numpy as np
 
 from geomesa_tpu.curve.binnedtime import BinnedTime, MAX_BIN, MAX_OFFSET, TimePeriod
 from geomesa_tpu.curve.z3sfc import Z3SFC
+from geomesa_tpu.curve.zranges import stack_boxes
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.extract import extract_filter
 from geomesa_tpu.filter.predicates import Filter, PointColumn
@@ -232,7 +233,7 @@ class Z3Index:
         # the window rows in the order their ranges go out: a member's
         # windows in its set's order, each once a row that has it
         lo_l, hi_l = los.tolist(), his.tolist()
-        flat = [b for m in live for b in extractions[m].bounds]
+        flat = stack_boxes([extractions[m].bounds for m in live])
         wide, inner = widen_boxes(flat), shrink_boxes(flat)
         n_boxes = [len(extractions[m].bounds) for m in live]
         box_stops = np.cumsum(n_boxes).tolist()

@@ -73,7 +73,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Optional
+from typing import Callable, Optional
 
 from geomesa_tpu import conf
 
@@ -219,8 +219,11 @@ class Trace:
         self.retain = retain
         self.capture = capture  # may the slow-query log take it
         # slow-log identity (set by the query path once planned): the
-        # plan fingerprint the capture carries
-        self.fingerprint: Optional[dict] = None
+        # plan fingerprint the capture carries, or a zero-argument callable
+        # that gives it: end() calls it if the slow-query log takes the
+        # trace and drops it otherwise (a filter's text is rendered only
+        # for the log: a tube's 256 slices are 40 KB of it)
+        self.fingerprint: "dict | Callable[[], dict] | None" = None
         self.root = Span(self, name, None)
 
     @property
@@ -737,6 +740,8 @@ class Tracer:
             trace.capture and slow_ms > 0 and trace.wall_s * 1e3 >= slow_ms
         )
         retained = trace.retain
+        if callable(trace.fingerprint):
+            trace.fingerprint = trace.fingerprint() if is_slow else None
         if not (retained or is_slow):
             return
         entry = None

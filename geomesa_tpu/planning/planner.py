@@ -142,11 +142,14 @@ def _filter_leaf_kinds(
 
 def _referenced_props(f: Filter) -> set:
     """Every attribute name a filter tree references (``prop`` fields of
-    leaf predicates, recursing into And/Or/Not)."""
-    from geomesa_tpu.filter.predicates import And, Not, Or
+    leaf predicates, a ``Slices`` carrier's two, recursing into
+    And/Or/Not)."""
+    from geomesa_tpu.filter.predicates import And, Not, Or, Slices
 
     out: set = set()
-    if isinstance(f, (And, Or)):
+    if isinstance(f, Slices):
+        out.update((f.geom, f.dtg))
+    elif isinstance(f, (And, Or)):
         for c in f.filters:
             out |= _referenced_props(c)
     elif isinstance(f, Not):
@@ -368,7 +371,11 @@ class QueryPlanner:
         for the whole duration: the groups go through the stages as
         members of the batch, after the callers' own, and the member's plan
         is ``QueryPlan(union=groups)``; the single scan is not planned
-        beside it. The span's ``sliced`` counts the groups planned."""
+        beside it. The span's ``sliced`` counts the groups planned, its
+        ``slice_rows`` the slices that reached the indexes as the rows of
+        two arrays (a ``Slices`` carrier's: a tube's, or the one
+        ``time_slices`` made of an ``Or`` of ``And(BBox, During)``), 0 for
+        every other filter."""
         from geomesa_tpu.conf import SCAN_RANGES_TARGET
         from geomesa_tpu.filter.dnf import time_slices
 
@@ -392,6 +399,7 @@ class QueryPlanner:
             indexes = self.store.indexes(type_name)
             tables = self._batch_tables(type_name, indexes)
             arrays: set = set()  # the members the array stages planned
+            slice_rows = 0
             if tables is not None:
                 # the callers' own members at the range target each, then a
                 # sliced member's groups, which share their query's target
@@ -404,7 +412,7 @@ class QueryPlanner:
                         if m not in sliced and extract_ids(filters[m]).empty
                     ]
                     if batch:
-                        self._plan_arrays(
+                        slice_rows += self._plan_arrays(
                             type_name, filters, batch, indexes, tables, limit, plans,
                             exps, sp, max_ranges,
                         )
@@ -428,6 +436,7 @@ class QueryPlanner:
             sp.annotate(
                 batched=sum(m < n for m in arrays),
                 sliced=sum(map(len, sliced.values())),
+                slice_rows=slice_rows,
             )
         share = (time.perf_counter() - t0) / n
         for plan in plans[:n]:
@@ -474,12 +483,13 @@ class QueryPlanner:
     def _plan_arrays(
         self, type_name, filters, batch, indexes, tables, limit, plans, exps, sp,
         max_ranges=None,
-    ) -> None:
+    ) -> int:
         """Stages 1 (extraction) to 4 of :meth:`plan_many` for the members
         ``batch`` of ``filters``: fills their ``plans`` where some index
         serves the member. ``max_ranges``: the most ranges a decomposition
         may emit (the branches of one query share its target), part of the
-        memo's key where given; default the target, each."""
+        memo's key where given; default the target, each. Returns the
+        slices whose extraction is a ``Slices`` carrier's arrays."""
         from geomesa_tpu.filter.extract import extract_filter
         from geomesa_tpu.filter.predicates import canonical_key
 
@@ -541,6 +551,9 @@ class QueryPlanner:
         self._estimate_rows(
             [plans[m] for m in best], [exps[m] for m in best],
             [extractions[m] for m in best],
+        )
+        return sum(
+            len(ex.bounds) for ex in extractions.values() if isinstance(ex.bounds, np.ndarray)
         )
 
     def _batch_tables(self, type_name: str, indexes) -> "dict | None":

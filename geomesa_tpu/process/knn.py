@@ -50,6 +50,19 @@ def _meters_to_degrees(m: float, lat: float) -> float:
     return float(np.degrees(max(delta, lon)) * (1.0 + 1e-9) + 1e-9)
 
 
+def _meters_to_degrees_each(m: float, lats: np.ndarray) -> np.ndarray:
+    """:func:`_meters_to_degrees` at every latitude of ``lats`` in one
+    pass, each element what the scalar call gives to the last bit (the
+    same ufuncs in the same order; tests/test_process.py holds it to
+    that)."""
+    delta = m / EARTH_RADIUS_M
+    reach = np.sin(min(delta, np.pi / 2)) / np.maximum(
+        0.01, np.cos(np.radians(np.minimum(np.abs(lats), 89.0)))
+    )
+    lon = np.where(reach >= 1.0, np.pi, np.arcsin(np.minimum(reach, 1.0)))
+    return np.degrees(np.maximum(delta, lon)) * (1.0 + 1e-9) + 1e-9
+
+
 def _degrees_to_meters(deg: float, lat: float) -> float:
     """Meters spanned by a longitude extent of ``deg`` at ``lat`` (the
     inverse direction of _meters_to_degrees, same constants)."""

@@ -270,7 +270,8 @@ class QueryScheduler:
             otr.end(trace)  # plan-time error: the trace still closes
             raise
         if trace is not None:
-            trace.fingerprint = {
+            # rendered only if the slow-query log takes the trace
+            trace.fingerprint = lambda: {
                 "type": type_name,
                 "strategy": plan.strategy,
                 "filter": str(plan.filter),

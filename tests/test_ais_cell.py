@@ -48,7 +48,7 @@ ROUND = {"knn-port": 6, "knn-sea": 2, "knn-many-16": 2, "tube-2k": 4, "tube-10k"
 CLASSES = tuple(ROUND)
 NEW_METRICS = ("knn_plan_ms", "tube_plan_ms", "knn_rounds", "knn_scan_ms", "knn_rank_ms",
                "knn_overfetch", "tube_scan_ms", "tube_refine_ms", "tube_keep_pct",
-               "process_coverage_pct", "tube_groups")
+               "process_coverage_pct", "tube_groups", "tube_bins_ms", "tube_arrays_pct")
 
 
 @pytest.fixture(scope="module")
@@ -544,13 +544,13 @@ def test_the_readers_read_the_processes_spans(bench):
     # one dispatch and a scan + decode a branch all lie directly under the root
     tube = _span(20, 2, "tube", "tube", 100.0, waypoints=360, bins=32, buffer_m=2000.0,
                  groups=2, boxes=32, windows=2, ranges=250, candidates=4000, rows=1000,
-                 kept=400, query_trace=3)
+                 kept=400, query_trace=3, arrays=1)
     query = _span(30, 3, "query", "query", 80.0, tube_trace=2)
     other = _span(40, 4, "query", "query", 5.0)  # a query no tube asked
     # a tube of sixteen slices or fewer: one scan, ``groups`` 0
     short = _span(50, 5, "tube", "tube", 20.0, waypoints=12, bins=8, buffer_m=500.0,
                   groups=0, boxes=8, windows=1, ranges=40, candidates=100, rows=50, kept=40,
-                  query_trace=6)
+                  query_trace=6, arrays=0)
     asked = _span(60, 6, "query", "query", 15.0, tube_trace=5)
     spans = [
         knn, dict(knn),  # roots twice, as the harness lists them
@@ -593,16 +593,22 @@ def test_the_readers_read_the_processes_spans(bench):
     assert r["tube_refine_ms"].read(view) == pytest.approx(46.0)  # both decodes + tube.refine
     assert r["tube_keep_pct"].read(view) == pytest.approx(10.0)
     assert r["tube_groups"].read(view) == pytest.approx(2.0)
+    # PR 48: the root's ``arrays`` (its plan counted ``slice_rows``) and its ``tube.bins``
+    assert r["tube_arrays_pct"].read(view) == pytest.approx(100.0)
+    assert r["tube_bins_ms"].read(view) == pytest.approx(4.0)
     assert r["process_coverage_pct"].read(view) == pytest.approx(100 * 120.0 / 150.0)
     # beside a tube that stayed one scan: medians over the two roots, groups pooled
     both = {"spans": spans + [
         short, dict(short), _span(51, 5, "tube", "tube.refine", 1.0, parent=50),
+        _span(52, 5, "tube", "tube.bins", 1.0, parent=50),
         asked, dict(asked), _span(61, 6, "query", "plan", 4.0, parent=60, sliced=0),
         _span(62, 6, "query", "dispatch", 1.0, parent=60),
         _span(63, 6, "query", "scan", 2.0, parent=60),
         _span(64, 6, "query", "decode", 3.0, parent=60, candidates=100)],
         "client": view["client"]}
     assert r["tube_groups"].read(both) == pytest.approx(1.0)
+    assert r["tube_arrays_pct"].read(both) == pytest.approx(50.0)  # one slice: And(BBox, During)
+    assert r["tube_bins_ms"].read(both) == pytest.approx((4.0 + 1.0) / 2)
     assert r["tube_plan_ms"].read(both) == pytest.approx((30.0 + 4.0) / 2)
     assert r["tube_scan_ms"].read(both) == pytest.approx((5.0 + 3.0) / 2)
     assert r["tube_refine_ms"].read(both) == pytest.approx((46.0 + 4.0) / 2)
@@ -617,7 +623,9 @@ def test_the_readers_read_the_processes_spans(bench):
     bare = {"spans": [dict(tube, attrs={"rows": 5, "kept": 1})], "client": view["client"]}
     assert r["tube_keep_pct"].read(bare) is None
     assert r["tube_groups"].read(bare) is None
+    assert r["tube_arrays_pct"].read(bare) is None  # as on PR 48's parent: no ``arrays``
     assert r["tube_plan_ms"].read(bare) == 0.0
+    assert r["tube_bins_ms"].read(bare) == 0.0
 
 
 # ---------------------------------------------------------------- (g) the cell
@@ -644,6 +652,7 @@ def test_the_cell_rehearses_on_the_cpu():
     assert read["knn_overfetch"]["value"] >= 1.0
     assert 0 < read["tube_keep_pct"]["value"] <= 100
     assert 2 <= read["tube_groups"]["value"] <= 16  # 256 slices a track, fewer for a short one
+    assert read["tube_arrays_pct"]["value"] == 100.0 and read["tube_bins_ms"]["value"] > 0
     assert 90 < read["process_coverage_pct"]["value"] <= 100
     window = next(json.loads(s) for s in out.stdout.splitlines() if '"phase": "window"' in s)
     assert window["compile_requests_in_window"] == 0

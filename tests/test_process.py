@@ -104,6 +104,119 @@ class TestTube:
             tube_select(store, "p", [(0, 0)], [0], buffer_m=100)
 
 
+def _slices_loop(xy, ts, buffer_m, bin_ms, max_bins):
+    """``process/tube.py`` ``_slices`` as it was before PR 48, a bin at a
+    time (the reference the vectorised one is held to, to the last bit):
+    ``(boxes [n, 4], windows [n, 2])`` of the slices that hold any time."""
+    from geomesa_tpu.process.knn import _meters_to_degrees
+
+    span = int(ts[-1] - ts[0])
+    if bin_ms is None:
+        bin_ms = max(1, span // max(1, len(xy)))
+    n_bins = min(max_bins, max(1, -(-span // bin_ms)))
+    bin_ms = -(-span // n_bins)
+    mids = ts[0] + bin_ms * np.arange(n_bins) + bin_ms // 2
+    cx = np.interp(mids, ts, xy[:, 0])
+    cy = np.interp(mids, ts, xy[:, 1])
+    boxes, windows = [], []
+    for i in range(n_bins):
+        lo = int(ts[0] + i * bin_ms)
+        hi = int(ts[-1] + 1 if i == n_bins - 1 else min(ts[0] + (i + 1) * bin_ms, ts[-1] + 1))
+        j0, j1 = np.searchsorted(ts, [lo, hi])
+        seg_x = np.concatenate([[cx[i]], xy[max(0, j0 - 1) : j1 + 1, 0]])
+        seg_y = np.concatenate([[cy[i]], xy[max(0, j0 - 1) : j1 + 1, 1]])
+        deg = _meters_to_degrees(buffer_m, float(np.abs(seg_y).max()))
+        if lo < hi:  # an empty DURING held no row: the carrier leaves it out
+            boxes.append((float(seg_x.min()) - deg, max(float(seg_y.min()) - deg, -90.0),
+                          float(seg_x.max()) + deg, min(float(seg_y.max()) + deg, 90.0)))
+            windows.append((lo, hi))
+    return np.array(boxes, np.float64), np.array(windows, np.int64)
+
+
+def _seeded_track(seed):
+    """One of 1,000 tracks: the AIS cell's two classes (360 waypoints over
+    6 h, 288 over 24 h), duplicate timestamps, a span that is a whole
+    multiple of the bins, a span of a few milliseconds, high latitudes, a
+    track across the antimeridian, explicit ``bin_ms`` and ``max_bins``."""
+    rng = np.random.default_rng(seed)
+    kind = seed % 10
+    n = {0: 360, 1: 288}.get(kind, int(rng.integers(2, 500)))
+    hours = {0: 6, 1: 24}.get(kind, float(rng.uniform(0.01, 48)))
+    t0 = 1_700_000_000_000 + int(rng.integers(0, 10**9))
+    ts = t0 + np.sort(rng.integers(0, int(hours * 3_600_000), n)) // 1000 * 1000
+    if kind == 2:  # dwell: many reports at one instant
+        ts[rng.integers(0, n, n // 3)] = ts[n // 2]
+        ts = np.sort(ts)
+    if kind == 3:  # a whole multiple of the bins: the last edge IS the last instant
+        ts = t0 + np.arange(n, dtype=np.int64) * 256_000
+    if kind == 4:  # shorter than its bins' steps: some bins hold no time
+        ts = t0 + np.sort(rng.integers(0, int(rng.integers(1, 40)), n))
+    lat0 = {5: 84.0, 6: -88.5}.get(kind, float(rng.uniform(-60, 60)))
+    lon0 = 179.2 if kind == 7 else float(rng.uniform(-170, 170))
+    xy = np.stack([lon0 + np.cumsum(rng.normal(0.004, 0.01, n)),
+                   np.clip(lat0 + np.cumsum(rng.normal(0.002, 0.01, n)), -90, 90)], 1)
+    bin_ms = None if kind != 8 else int(rng.integers(1, 3_600_000))
+    max_bins = 256 if kind != 9 else int(rng.integers(1, 300))
+    buffer_m = float(rng.choice([500.0, 2000.0, 10_000.0, 250_000.0]))
+    return xy, ts.astype(np.int64), buffer_m, bin_ms, max_bins
+
+
+class TestTubeSlices:
+    """PR 48: all bins at once, the same f64 boxes and [lo, hi) windows as
+    the loop, to the last bit."""
+
+    @pytest.mark.parametrize("block", range(10))
+    def test_the_arrays_are_the_loops_to_the_last_bit(self, block):
+        from geomesa_tpu.filter.predicates import And, Slices
+        from geomesa_tpu.process.tube import _slices
+
+        shapes = set()
+        for seed in range(block * 100, block * 100 + 100):
+            xy, ts, buffer_m, bin_ms, max_bins = _seeded_track(seed)
+            got = _slices("geom", "dtg", xy, ts, buffer_m, bin_ms, max_bins)
+            boxes, windows = _slices_loop(xy, ts, buffer_m, bin_ms, max_bins)
+            if len(boxes) == 1:  # one slice stays And(BBox, During)
+                assert isinstance(got, And)
+                bbox, during = got.filters
+                assert bbox.bounds == tuple(boxes[0]) and (during.lo_ms, during.hi_ms) == tuple(windows[0])
+                assert (bbox.prop, during.prop) == ("geom", "dtg")
+            else:
+                assert isinstance(got, Slices) and (got.geom, got.dtg) == ("geom", "dtg")
+                assert got.boxes.tobytes() == boxes.tobytes(), seed
+                assert got.windows.tobytes() == windows.tobytes(), seed
+                assert got.windows[-1, 1] == ts[-1] + 1  # the last instant is inside
+            shapes.add(len(boxes))
+        assert len(shapes) > 3 and max(shapes) <= 300
+
+    def test_the_cells_two_classes_are_256_slices(self):
+        from geomesa_tpu.process.tube import _slices
+
+        for seed in (0, 1, 10, 11):
+            xy, ts, buffer_m, _, _ = _seeded_track(seed)
+            assert len(_slices("geom", "dtg", xy, ts, buffer_m, None, 256)) == 256
+
+    def test_a_bin_that_holds_no_time_is_left_out(self):
+        from geomesa_tpu.filter.predicates import Slices
+        from geomesa_tpu.process.tube import _slices
+
+        # 10 ms over 8 bins of 2 ms: bins 5 to 7 start past the last instant
+        ts = 1_700_000_000_000 + np.arange(10, dtype=np.int64)
+        xy = np.stack([np.linspace(0, 1, 10), np.linspace(0, 1, 10)], 1)
+        got = _slices("geom", "dtg", xy, ts, 500.0, None, 8)
+        assert isinstance(got, Slices) and len(got) == 5
+        assert got.windows[0, 0] == ts[0] and got.windows[-1, 1] == ts[-1] + 1
+        assert (np.diff(got.windows, axis=1) > 0).all()
+
+    def test_degrees_each_is_the_scalars_at_every_latitude(self):
+        from geomesa_tpu.process.knn import _meters_to_degrees, _meters_to_degrees_each
+
+        rng = np.random.default_rng(48)
+        lats = np.concatenate([rng.uniform(-90, 90, 5000), [0.0, -0.0, 89.0, -89.0, 90.0, 89.999]])
+        for m in (1.0, 500.0, 2000.0, 10_000.0, 250_000.0, 5e6, 2.1e7):
+            want = np.array([_meters_to_degrees(m, float(v)) for v in lats])
+            assert _meters_to_degrees_each(m, lats).tobytes() == want.tobytes(), m
+
+
 class TestUnique:
     def test_counts(self, ds):
         store, fc, _ = ds

@@ -24,7 +24,7 @@ import pytest
 from geomesa_tpu import conf, obs
 from geomesa_tpu.datastore import DataStore
 from geomesa_tpu.features import FeatureCollection
-from geomesa_tpu.filter.predicates import During, Or
+from geomesa_tpu.filter.predicates import During, Slices
 from geomesa_tpu.obs import trace as otrace
 from geomesa_tpu.process import knn_many, knn_search, tube_select
 from geomesa_tpu.process.tube import _slices as _tube_slices
@@ -160,9 +160,10 @@ def test_a_tube_roots_counters_are_its_querys(case, store, traced):
     assert a["candidates"] == sum(s.attrs["candidates"] for s in _named(inner, "decode"))
     # the chosen plan's configs, as a fresh plan of the same filter reads them:
     # one scan's, or past sixteen slices the union's branches' summed
-    parts = _tube_slices("geom", "dtg", xy, t, buffer_m, None, 256)
-    assert a["bins"] == len(parts)
-    plan = store.planner.plan("rep", parts[0] if len(parts) == 1 else Or(tuple(parts)))
+    tube = _tube_slices("geom", "dtg", xy, t, buffer_m, None, 256)
+    carried = isinstance(tube, Slices)  # one slice stays And(BBox, During)
+    assert a["bins"] == (len(tube) if carried else 1)
+    plan = store.planner.plan("rep", tube)
     branches = [plan] if plan.union is None else plan.union
     cfgs = [p.config for p in branches]
     assert a["groups"] == (0 if plan.union is None else len(branches))
@@ -180,6 +181,9 @@ def test_a_tube_roots_counters_are_its_querys(case, store, traced):
         assert len(_children(inner, inner.root, name)) == count, name
     (planned,) = _children(inner, inner.root, "plan")
     assert planned.attrs["sliced"] == a["groups"]
+    # PR 48: the slices reach the indexes as the carrier's array rows, and the root says so
+    assert planned.attrs["slice_rows"] == (a["bins"] if carried else 0)
+    assert a["arrays"] == int(carried)
 
 
 def test_a_tube_of_many_slices_is_sixteen_scans_of_their_own_windows(store, traced):
@@ -194,8 +198,7 @@ def test_a_tube_of_many_slices_is_sixteen_scans_of_their_own_windows(store, trac
     (inner,) = [x for x in traced.traces() if x.name == "query"]
     a = tr.root.attrs
     assert a["bins"] == 256 == a["boxes"] and a["groups"] == 16
-    plan = store.planner.plan("rep", Or(tuple(
-        _tube_slices("geom", "dtg", xy, t, 2_000.0, None, 256))))
+    plan = store.planner.plan("rep", _tube_slices("geom", "dtg", xy, t, 2_000.0, None, 256))
     assert len(plan.union) == 16 and all(len(p.config.boxes) == 16 for p in plan.union)
     per_group = [0 if p.config.windows is None else len(p.config.windows) for p in plan.union]
     assert all(w <= 2 for w in per_group) and 1 <= a["windows"] == sum(per_group) <= 32
@@ -265,6 +268,42 @@ def test_no_span_is_made_with_sampling_off(slow_ms, store, monkeypatch):
             assert made == []
     finally:
         conf.OBS_SLOW_MS.clear()
+
+
+@pytest.mark.parametrize("bins", [256, 40, 1])
+def test_the_audit_event_and_the_slow_log_hold_the_tubes_ecql(bins, store, traced):
+    """PR 48: the carrier renders its text only when asked (the audit
+    writer, the slow-query log taking the trace), and the text is ECQL that
+    parses back to the ``Or`` of ``And(BBox, During)`` the tube means."""
+    from geomesa_tpu.audit import AuditWriter
+    from geomesa_tpu.filter import ecql
+
+    xy, t = _track(360, T0 + 600_000, T0 + 5 * 3_600_000)
+    tube = _tube_slices("geom", "dtg", xy, t, 2_000.0, None, bins)
+    want = tube.expand() if isinstance(tube, Slices) else tube
+    store.audit = AuditWriter()
+    conf.OBS_SLOW_MS.set(1e-6)  # every query is slow: the log takes its trace
+    rendered = []
+    real = Slices.ecql
+    try:
+        Slices.ecql = Slices.__repr__ = lambda self: rendered.append(1) or real(self)
+        out = tube_select(store, "rep", xy, t, 2_000.0, max_bins=bins)
+        (event,) = store.audit.events
+        (entry,) = [e for e in store.slow_queries() if e["fingerprint"].get("type") == "rep"]
+        n_slow = len(rendered)
+        conf.OBS_SLOW_MS.set(1e9)  # nothing is slow: the text is rendered for the audit alone
+        tube_select(store, "rep", xy, t, 2_000.0, max_bins=bins)
+    finally:
+        Slices.ecql = Slices.__repr__ = real
+        conf.OBS_SLOW_MS.clear()
+        store.audit = None
+    assert event.hits >= len(out)  # the query's rows, before the distance test
+    for text in (event.filter, entry["fingerprint"]["filter"]):
+        if bins > 1:
+            assert ecql.parse(text) == want and text.count("DURING") == bins
+    assert entry["fingerprint"]["strategy"] == event.strategy
+    if bins > 1:
+        assert n_slow == 2 and len(rendered) == 3  # the audit's and the log's; then the audit's
 
 
 @pytest.mark.parametrize("span_s", [256 * 40, 256 * 40 + 1, 32 * 673])

@@ -19,7 +19,7 @@ from geomesa_tpu.datastore import DataStore
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.dnf import MAX_DISJUNCTS, time_slices
 from geomesa_tpu.filter.extract import extract_intervals
-from geomesa_tpu.filter.predicates import And, BBox, Cmp, During, Or
+from geomesa_tpu.filter.predicates import And, BBox, Cmp, During, Or, Slices
 from geomesa_tpu.planning.errors import QueryTimeout
 from geomesa_tpu.planning.hints import QueryHints
 from geomesa_tpu.serving import QueryScheduler, ServingConfig
@@ -103,11 +103,24 @@ def test_time_slices_cuts_even_consecutive_groups(n):
     groups = time_slices(_filter([boxes[i] for i in order]), "dtg")
     k = -(-n // MAX_DISJUNCTS)
     assert len(groups) == k
-    sizes = [len(g.filters) for g in groups]
+    # an Or of And(BBox, During) is converted once: the groups are carriers over row slices
+    assert all(isinstance(g, Slices) for g in groups)
+    sizes = [len(g) for g in groups]
     assert sum(sizes) == n and max(sizes) <= MAX_DISJUNCTS and max(sizes) - min(sizes) <= 1
-    starts = [[extract_intervals(d, "dtg").values[0].lo for d in g.filters] for g in groups]
-    flat = [s for g in starts for s in g]
+    flat = [lo for g in groups for lo in g.windows[:, 0].tolist()]
     assert flat == sorted(flat) == [b[4] for b in boxes]  # every slice once, in time order
+
+
+@pytest.mark.parametrize("n", [17, 64, 256])
+def test_an_or_the_carrier_cannot_express_is_cut_as_objects(n):
+    """A disjunct with a second predicate keeps the object path: groups of ``Or``."""
+    boxes = _boxes(n)
+    parts = list(_filter(boxes).filters)
+    parts[3] = And((*parts[3].filters, Cmp("mmsi", "<", 50)))
+    groups = time_slices(Or(tuple(parts)), "dtg")
+    assert len(groups) == -(-n // MAX_DISJUNCTS) and all(isinstance(g, Or) for g in groups)
+    starts = [extract_intervals(d, "dtg").values[0].lo for g in groups for d in g.filters]
+    assert starts == [b[4] for b in boxes]
 
 
 NOT_SLICED = {
@@ -143,8 +156,8 @@ def test_the_other_conjuncts_go_into_every_group():
     assert len(groups) == 3
     for g in groups:
         assert isinstance(g, And) and rest in g.filters
-        (inner,) = [c for c in g.filters if isinstance(c, Or)]
-        assert len(inner.filters) in (13, 14)
+        (inner,) = [c for c in g.filters if isinstance(c, Slices)]
+        assert len(inner) in (13, 14)
 
 
 # ------------------------------------------------------------------ the plan
