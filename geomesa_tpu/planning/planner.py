@@ -26,6 +26,7 @@ from geomesa_tpu.filter.extract import extract_ids
 from geomesa_tpu.filter.predicates import Filter, Include
 from geomesa_tpu.index.api import ScanConfig
 from geomesa_tpu.obs.trace import NULL_SPAN as _NULL_SPAN
+from geomesa_tpu.obs.trace import add as _oadd
 from geomesa_tpu.obs.trace import span as _ospan
 from geomesa_tpu.obs.trace import tracer as _otracer
 from geomesa_tpu.planning.explain import Explainer, ExplainNull
@@ -438,6 +439,9 @@ class QueryPlanner:
                 sliced=sum(map(len, sliced.values())),
                 slice_rows=slice_rows,
             )
+            if sp is not _NULL_SPAN:
+                won = {plan.strategy for plan in plans[:n]}
+                sp.annotate(index=won.pop() if len(won) == 1 else "mixed")
         share = (time.perf_counter() - t0) / n
         for plan in plans[:n]:
             plan.planning_s = share
@@ -543,6 +547,7 @@ class QueryPlanner:
                 exps[m](f"Index {name}: {cfg.n_ranges} ranges, cost {cost:.1f}")
                 if m not in best or cost < best[m][0]:
                     best[m] = (cost, name, cfg)
+            sp.add("costed", len(kept))
 
         sp.event("estimate")
         for m, (cost, name, cfg) in best.items():
@@ -734,6 +739,12 @@ class QueryPlanner:
         options.sort(key=lambda o: o[0])
         cost, name, cfg = options[0]
         exp(f"Strategy: {name} (cost {cost:.1f})")
+        # on the caller's ``plan`` span: the indexes costed, and whether an
+        # attribute index offered a plan and won (the decider's record)
+        _oadd("costed", len(options))
+        if any(o[1].startswith("attr_") for o in options):
+            _oadd("attr_offered", 1)
+            _oadd("attr_won", int(name.startswith("attr_")))
         return QueryPlan(type_name, f, name, cfg, limit=limit)
 
     def cost(self, type_name: str, index_name: str, cfg: ScanConfig) -> float:
@@ -992,6 +1003,9 @@ class QueryPlanner:
                     candidates = candidates.mask(keep)
         elif not isinstance(plan.filter, Include):
             check_deadline(deadline, "residual refinement start")
+            # the whole filter over every candidate: an attribute
+            # predicate the chosen index did not bind is decided here
+            span.add("residual_rows", len(candidates))
             with exp.span("Residual filter refinement"):
                 mask = plan.filter.evaluate(candidates.batch)
             if not bool(np.all(mask)):  # see all-true note above
@@ -1250,18 +1264,25 @@ class QueryPlanner:
             if hints.sample is not None:
                 out = out.sample(hints.sample, hints.sample_by)
                 exp(f"Sampled: {len(out)}")
-            if hints.sort_by:
-                out = out.sort_values(hints.sort_by)
+        sort_by = hints.sort_by if hints is not None else None
         off = hints.offset if hints is not None and hints.offset else 0
-        if off or (plan.limit is not None and len(out) > plan.limit):
-            # one gather for the page: materializing the whole post-offset
-            # tail before the limit would copy every column of a large
-            # result just to keep a page of it
-            lo = min(off, len(out))
-            hi = len(out) if plan.limit is None else min(lo + plan.limit, len(out))
-            out = out.take(np.arange(lo, hi))
-            if off:
-                exp(f"Offset {off}: rows [{lo}, {hi})")
+        paged = off or (plan.limit is not None and len(out) > plan.limit)
+        if sort_by or paged:
+            # ``sort`` (traced): the ordering of every matched row and the
+            # page kept of it, ``rows`` in and ``kept`` out
+            with _ospan("sort", rows=len(out)) as sp:
+                if sort_by:
+                    out = out.sort_values(sort_by)
+                if paged:
+                    # one gather for the page: materializing the whole
+                    # post-offset tail before the limit would copy every
+                    # column of a large result just to keep a page of it
+                    lo = min(off, len(out))
+                    hi = len(out) if plan.limit is None else min(lo + plan.limit, len(out))
+                    out = out.take(np.arange(lo, hi))
+                    if off:
+                        exp(f"Offset {off}: rows [{lo}, {hi})")
+                sp.annotate(kept=len(out))
         if hints is not None and hints.reproject is not None:
             from geomesa_tpu.crs import reproject_collection
 

@@ -863,10 +863,15 @@ class IndexTable(SortedKeys):
         """Decoded kernel rows -> (feature ordinals, certain): span
         clipping, contained-span union (all certain; native two-pointer
         dedup when available), permutation to feature ordinals. Shared by
-        the per-query and fused scan paths."""
+        the per-query and fused scan paths. Under a span a clipped scan
+        counts ``clip_in`` and ``clip_kept``."""
         if config.clip_rows:
             keep = _rows_in_spans(rows, spans.union)
+            # on the caller's ``scan`` span: the rows the kernel's blocks
+            # hit, and those inside the value's row spans
+            _oadd("clip_in", len(rows))
             rows, certain = rows[keep], certain[keep]
+            _oadd("clip_kept", len(rows))
         contained = spans.contained
         if len(contained):
             from geomesa_tpu import native

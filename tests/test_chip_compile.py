@@ -317,6 +317,56 @@ def test_fused_knn_round_over_the_ais_table(one_chip, members):
     _assert_mosaic(compiled)
 
 
+TDRIVE_BLOCKS = 1 << 10  # tdrive-tracks-1chip: 2^24 taxi reports; attr_taxiId holds z3's columns
+#: the predicates a filter that names taxis leaves the device (PR 49): a window
+#: alone (``track-day``, ``fleet-32-day``, a ``tracks-many-32``'s members: the
+#: scan projects the two time columns) or a box and a window (``taxi-box-hour``)
+ATTR_SCANS = {"window": (("tbin", "toff"), False), "box-window": (Z3, True)}
+
+
+def _tdrive_cols(names, sh):
+    return tuple(_s((TDRIVE_BLOCKS, SUB, bk.LANES), jnp.int32 if n in _I32 else jnp.float32, sh)
+                 for n in names)
+
+
+@pytest.mark.parametrize("m", [bk.M_BUCKETS[0], 64, TDRIVE_BLOCKS])
+@pytest.mark.parametrize("case", sorted(ATTR_SCANS))
+def test_attribute_scan_over_the_tdrive_table(one_chip, case, m):
+    """The attribute table of the ``tdrive.track-history`` cell (1,024 blocks
+    of 16,384 reports sorted by the lexicode of ``taxiId``) as a filter that
+    names taxis plans it: ONE single-query scan over the blocks the values'
+    row spans touch: a taxi's one to three (the smallest bucket), a list of 32
+    (the next), a list of 1,024 (every block). The value itself is no kernel
+    argument: the host clips the block-granular hits to the row spans."""
+    names, has_boxes = ATTR_SCANS[case]
+    compiled = bk._pallas_block_scan.lower(
+        _tdrive_cols(names, one_chip), _s((m,), jnp.int32, one_chip), *_params(one_chip),
+        None, None, interpret=False, n_edges=0, n_rints=0,
+        col_names=names, has_boxes=has_boxes, has_windows=True, extent=False,
+    ).compile()
+    _assert_mosaic(compiled)
+
+
+@pytest.mark.parametrize("case", sorted(ATTR_SCANS))
+def test_fused_attribute_scan_over_the_tdrive_table(one_chip, case):
+    """A ``query_many`` of 32 one-taxi one-day filters (the cell's
+    ``tracks-many-32``) over that table: the members go through
+    ``submit_many`` into ONE fused chunk of the table's canonical shape
+    (1,024 slots, FUSED_CHUNK_Q queries), a window a member and no box; the
+    box-and-window chunk is what a batch of ``taxi-box-hour`` filters would
+    ride."""
+    names, has_boxes = ATTR_SCANS[case]
+    m, q = min(FUSED_CHUNK_SLOTS, bk.bucket_of(TDRIVE_BLOCKS)), FUSED_CHUNK_Q
+    assert m == 1024
+    slot = _s((m,), jnp.int32, one_chip)
+    compiled = bk._pallas_block_scan_multi.lower(
+        _tdrive_cols(names, one_chip), slot, slot, *_params(one_chip, lead=(q,)),
+        None, None, None, interpret=False, n_edges=0, n_rints=0,
+        col_names=names, has_boxes=has_boxes, has_windows=True, extent=False,
+    ).compile()
+    _assert_mosaic(compiled)
+
+
 # ---- the mesh forms: jit(shard_map) over the described four chips
 
 

@@ -490,6 +490,54 @@ def test_processes_doc_honest():
     assert mig.count("(processes.md)") == 2
 
 
+def test_attribute_index_doc_honest():
+    """docs/attribute-index.md stays honest: the names it gives are the
+    code's, the multipliers it quotes are the planner's, and the README and
+    the observability page point to it."""
+    import inspect
+
+    from geomesa_tpu.filter import extract
+    from geomesa_tpu.index import api, attribute
+    from geomesa_tpu.planning import planner
+    from geomesa_tpu.storage import table
+    from geomesa_tpu.utils import lexicode
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    text = open(os.path.join(root, "docs", "attribute-index.md")).read()
+    for mod, names in ((lexicode, ("bounds_to_range", "lex_string_words", "MAX_SUB_WORDS")),
+                       (extract, ("extract_attribute_bounds",)),
+                       (table, ("_rows_in_spans",)),
+                       (planner, ("mask_decides_filter", "INDEX_PRIORITY"))):
+        for name in names:
+            assert hasattr(mod, name) and name in text, name
+    for field in ("clip_rows", "range_lo2", "range_hi2"):
+        assert field in api.ScanConfig.__dataclass_fields__ and field in text, field
+    assert "sub" in api.WriteKeys.__dataclass_fields__ and "WriteKeys.sub" in text
+    for fn in ("_select_single", "cost", "_post", "plan_many"):
+        assert hasattr(planner.QueryPlanner, fn) and fn in text, fn
+    assert hasattr(table.IndexTable, "_post_decode") and "_post_decode" in text
+    pri = planner.INDEX_PRIORITY
+    assert (pri["z3"], pri["z2"], pri["attr"]) == (1.1, 2.0, 2.5)
+    assert "z3 1.1, z2 2.0, an attribute index 2.5" in text
+    assert not hasattr(attribute.AttributeIndex, "scan_configs")  # "takes no part in the array stages"
+    src = inspect.getsource(planner) + inspect.getsource(table)
+    for attr in ("clip_in", "clip_kept", "residual_rows", "costed", "attr_offered", "attr_won"):
+        assert f'"{attr}"' in src, attr
+    for attr in ("clip_in", "clip_kept", "residual_rows", "costed"):
+        assert f"`{attr}`" in text, attr
+    assert '_ospan("sort"' in src and "`sort` span" in text
+    obs_text = open(os.path.join(root, "docs", "observability.md")).read()
+    for attr in ("index", "costed", "attr_offered", "attr_won", "clip_in", "clip_kept",
+                 "residual_rows"):
+        assert f"`{attr}`" in obs_text, attr
+    assert "(attribute-index.md)" in obs_text
+    for reader in ("attr_plan_ms", "plan_lost_ms", "attr_chosen_pct", "attr_scan_ms",
+                   "attr_clip_keep_pct", "sort_ms"):
+        assert f"`{reader}`" in obs_text, reader
+        assert os.path.exists(os.path.join(root, "benchmark", "layer_metrics", reader + ".py"))
+    assert "(attribute-index.md)" in open(os.path.join(root, "docs", "README.md")).read()
+
+
 def test_joins_doc_honest():
     """docs/joins.md stays honest: every API, knob, metric and constant
     the raster/adaptive-join doc names is real."""
