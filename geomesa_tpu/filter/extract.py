@@ -348,13 +348,16 @@ def _merge_windows(windows: np.ndarray) -> list[Interval]:
 
 @dataclass
 class Extraction:
-    """What the point indexes and the row estimate read of one filter,
-    extracted once: ``geoms`` and ``intervals`` (None for a type without
-    a date field) as :func:`extract_geometries` / :func:`extract_intervals`
-    give them, ``bounds`` = :func:`geometry_bounds` of the geometries (an
-    empty list where there are none, or the filter is disjoint), and
-    ``boxes_exact``: every geometry precisely extracted and its own bbox,
-    so a box test answers the spatial constraint.
+    """What the point and attribute indexes and the row estimate read of
+    one filter, extracted once: ``geoms`` and ``intervals`` (None for a
+    type without a date field) as :func:`extract_geometries` /
+    :func:`extract_intervals` give them, ``bounds`` =
+    :func:`geometry_bounds` of the geometries (an empty list where there
+    are none, or the filter is disjoint), ``boxes_exact``: every geometry
+    precisely extracted and its own bbox, so a box test answers the
+    spatial constraint; and ``filter``, the filter itself, of which an
+    attribute index takes its own attribute's value bounds
+    (:func:`extract_attribute_bounds`: a walk only that index pays).
 
     Of a filter whose spatio-temporal part is ONE :class:`Slices` carrier
     (alone, or under an ``And`` whose other conjuncts constrain neither
@@ -367,6 +370,7 @@ class Extraction:
     intervals: "FilterValues | None"
     bounds: "list | np.ndarray"
     boxes_exact: bool
+    filter: Filter
 
 
 def _sole_slices(f: Filter, geom_field: str, dtg_field: "str | None") -> "Slices | None":
@@ -409,6 +413,7 @@ def extract_filter(f: Filter, geom_field: str, dtg_field: "str | None") -> Extra
             else FilterValues(values=_merge_windows(s.windows)),
             bounds=bounds,
             boxes_exact=True,
+            filter=f,
         )
     geoms = extract_geometries(f, geom_field)
     return Extraction(
@@ -416,6 +421,7 @@ def extract_filter(f: Filter, geom_field: str, dtg_field: "str | None") -> Extra
         intervals=None if dtg_field is None else extract_intervals(f, dtg_field),
         bounds=geometry_bounds(geoms),
         boxes_exact=geoms.precise and all(_is_box(g) for g in geoms.values),
+        filter=f,
     )
 
 

@@ -195,10 +195,13 @@ class IndexKeySpace(Protocol):
     def scan_config(self, f: Filter) -> Optional[ScanConfig]:
         """Scan configuration for a filter, or None when this index cannot
         serve it (reference getIndexValues + getRanges). An index may also
-        offer ``scan_configs(extractions, max_ranges=None)`` (the point
-        indexes do): one config or None an ``filter.extract.Extraction``,
-        all of them decomposed in one native call, which the planner's
-        ``plan_many`` uses for a batch (``max_ranges``: the most ranges a
-        decomposition may emit, for the branches of one query that share
-        its range target); ``scan_config`` is then its one-member case."""
+        offer ``scan_configs(extractions, max_ranges=None)`` (z3, z2 and
+        the attribute index do; the extent indexes and s2 do not): one
+        config or None an ``filter.extract.Extraction``, all of them
+        decomposed in one pass (the point indexes' boxes and windows in
+        one native call, the attribute index's value bounds in one
+        lexicode), which the planner's ``plan_many`` uses for a batch
+        (``max_ranges``: the most ranges a decomposition may emit, for the
+        branches of one query that share its range target; no part of a
+        value range); ``scan_config`` is then its one-member case."""
         ...

@@ -20,17 +20,13 @@ from typing import Optional
 import numpy as np
 
 from geomesa_tpu import geometry as geo
-from geomesa_tpu.index.z3 import clamp_bins
 from geomesa_tpu.curve.binnedtime import BinnedTime, TimePeriod
+from geomesa_tpu.curve.zranges import stack_boxes
 from geomesa_tpu.features import FeatureCollection
-from geomesa_tpu.filter.extract import (
-    extract_attribute_bounds,
-    extract_geometries,
-    extract_intervals,
-    geometry_bounds,
-)
+from geomesa_tpu.filter.extract import extract_attribute_bounds, extract_filter
 from geomesa_tpu.filter.predicates import Filter, PointColumn
 from geomesa_tpu.index.api import ScanConfig, WriteKeys, widen_boxes
+from geomesa_tpu.index.z3 import clamp_bins
 from geomesa_tpu.sft import FeatureType
 from geomesa_tpu.utils import lexicode
 
@@ -93,72 +89,101 @@ class AttributeIndex:
 
     # -- read side -------------------------------------------------------
     def scan_config(self, f: Filter) -> Optional[ScanConfig]:
-        bounds = extract_attribute_bounds(f, self.attr)
-        if bounds.disjoint:
-            return ScanConfig.empty(self.name)
-        if not bounds.values:
-            return None  # no bound on this attribute: index cannot serve
-        los, his = [], []
-        los2, his2 = [], []
-        for b in bounds.values:
-            lo, hi = lexicode.bounds_to_range(b.lo, b.hi, self.attr_type)
-            los.append(lo)
-            his.append(hi)
-            if self._is_string:
-                lo2, hi2 = lexicode.bounds_sub_words(b.lo, b.hi)
-                los2.append(lo2)
-                his2.append(hi2)
+        return self.scan_configs([extract_filter(f, self.geom, self.dtg)])[0]
 
-        # secondary spatial predicate (device mask inside candidate tiles)
-        boxes = None
-        geom_precise = True
+    def scan_configs(
+        self, extractions: list, max_ranges: "int | None" = None
+    ) -> "list[Optional[ScanConfig]]":
+        """One scan config (None: the filter does not bound this attribute)
+        an extraction (``filter.extract.extract_filter`` of this type's
+        geom and date fields): the value bounds of ALL members (an ``IN``
+        list's values, a ``query_many``'s members: rows of one array)
+        lexicoded in one pass (``lexicode.lex_bounds``) and split by
+        member, a range a bound in the bounds' order; the secondary
+        predicates from the extraction's boxes and intervals.
+        ``scan_config`` is the one-member case. ``max_ranges`` is the point
+        indexes' (a value bound is one range whatever the target)."""
+        out: "list[Optional[ScanConfig]]" = [None] * len(extractions)
+        bound = []  # (member, its value bounds): the filters the index can serve
+        for m, ex in enumerate(extractions):
+            bounds = extract_attribute_bounds(ex.filter, self.attr)
+            if bounds.disjoint or (bounds.values and (
+                (self.geom is not None and ex.geoms.disjoint)
+                or (self.dtg is not None and ex.intervals.disjoint)
+            )):
+                out[m] = ScanConfig.empty(self.name)
+            elif bounds.values:
+                bound.append((m, bounds.values))
+        # the secondary predicates: device masks inside candidate tiles
+        live, los, his = [], [], []
+        for (m, values), windows in zip(
+            bound, self._windows([extractions[m] for m, _ in bound])
+        ):
+            if windows is not None and not len(windows):
+                # every queried time bin is absent from the store
+                out[m] = ScanConfig.empty(self.name)
+                continue
+            los.extend(b.lo for b in values)
+            his.extend(b.hi for b in values)
+            has_box = self.geom is not None and bool(extractions[m].geoms.values)
+            live.append((m, windows, has_box, len(los)))
+        if not live:
+            return out
+        lo, hi = lexicode.lex_bounds(los, his, self.attr_type)
+        range_lo, range_hi = lo[:, 0].copy(), hi[:, 0].copy()
+        range_bins = np.zeros(len(los), dtype=np.int32)
+        lo2 = hi2 = None
+        if self._is_string:
+            lo2, hi2 = np.ascontiguousarray(lo[:, 1:]), np.ascontiguousarray(hi[:, 1:])
+        wide = widen_boxes(stack_boxes(
+            [extractions[m].bounds for m, _, has_box, _ in live if has_box]
+        ))
         extent = self.geom is not None and not self.sft.is_points
-        if self.geom is not None:
-            geoms = extract_geometries(f, self.geom)
-            if geoms.disjoint:
-                return ScanConfig.empty(self.name)
-            if geoms.values:
-                from geomesa_tpu.index.z3 import _bounds_only
+        a = ba = 0
+        for m, windows, has_box, z in live:
+            ex = extractions[m]
+            bz = ba + (len(ex.bounds) if has_box else 0)
+            out[m] = ScanConfig(
+                index=self.name,
+                range_bins=range_bins[a:z],
+                range_lo=range_lo[a:z],
+                range_hi=range_hi[a:z],
+                boxes=wide[ba:bz] if has_box else None,
+                windows=windows,
+                extent_mode=extent,
+                geom_precise=not has_box or (not extent and ex.boxes_exact),
+                time_precise=windows is None or ex.intervals.precise,
+                # value-range spans are row-exact: kernel hits (block granular)
+                # must clip back to them before refinement
+                clip_rows=True,
+                range_lo2=None if lo2 is None else lo2[a:z],
+                range_hi2=None if hi2 is None else hi2[a:z],
+            )
+            a, ba = z, bz
+        return out
 
-                boxes = widen_boxes(geometry_bounds(geoms))
-                geom_precise = (
-                    not extent and geoms.precise and _bounds_only(geoms.values)
-                )
-
-        # secondary temporal predicate
-        windows = None
-        time_precise = True
-        if self.dtg is not None:
-            intervals = extract_intervals(f, self.dtg)
-            if intervals.disjoint:
-                return ScanConfig.empty(self.name)
-            if intervals.values:
-                parts = []
-                for iv in intervals.values:
-                    b, lo, hi = self.binner.bins_for_interval(iv.lo, iv.hi - 1)
-                    b, (lo, hi) = clamp_bins(self.bin_range, b, lo, hi)
-                    if len(b) == 0:
-                        continue
-                    parts.append(np.stack([b, lo, hi], axis=1))
-                if not parts:
-                    # every queried time bin is absent from the store
-                    return ScanConfig.empty(self.name)
-                windows = np.concatenate(parts).astype(np.int32)
-                time_precise = intervals.precise
-
-        return ScanConfig(
-            index=self.name,
-            range_bins=np.zeros(len(los), dtype=np.int32),
-            range_lo=np.array(los, dtype=np.uint64),
-            range_hi=np.array(his, dtype=np.uint64),
-            boxes=boxes,
-            windows=windows,
-            extent_mode=extent,
-            geom_precise=geom_precise,
-            time_precise=time_precise,
-            # value-range spans are row-exact: kernel hits (block granular)
-            # must clip back to them before refinement
-            clip_rows=True,
-            range_lo2=np.stack(los2).astype(np.uint64) if los2 else None,
-            range_hi2=np.stack(his2).astype(np.uint64) if his2 else None,
+    def _windows(self, extractions: list) -> list:
+        """An extraction's per-bin (bin, lo, hi) offset windows, i32
+        ``[n, 3]``, cut to the bins the store holds (no row: every queried
+        bin is absent), or None where it bounds no time: the intervals of
+        all of them tiled in one pass."""
+        out: list = [None] * len(extractions)
+        timed = [
+            j for j, ex in enumerate(extractions)
+            if self.dtg is not None and ex.intervals.values
+        ]
+        if not timed:
+            return out
+        iv_lo, iv_hi, iv_of = np.array([
+            (iv.lo, iv.hi, k)
+            for k, j in enumerate(timed) for iv in extractions[j].intervals.values
+        ], dtype=np.int64).T
+        bins, los, his, per_iv = self.binner.bins_for_intervals(iv_lo, iv_hi - 1)
+        bins, (los, his, row_of) = clamp_bins(
+            self.bin_range, bins, los, his, np.repeat(iv_of, per_iv)
         )
+        windows = np.stack([bins, los, his], axis=1).astype(np.int32)
+        stops = np.cumsum(np.bincount(row_of, minlength=len(timed))).tolist()
+        for j, a, z in zip(timed, [0, *stops], stops):
+            out[j] = windows[a:z]
+        return out

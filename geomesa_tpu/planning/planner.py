@@ -329,7 +329,8 @@ class QueryPlanner:
            and checked for hidden attributes on its own; its geometries,
            intervals and bounds are extracted once
            (``filter.extract.extract_filter``) for every index and the row
-           estimate.
+           estimate (an attribute index takes its own attribute's value
+           bounds of the extraction's ``filter``).
         2. *decompose*, once an index: the memo is probed a member, the
            misses go to ``idx.scan_configs`` (one native call), and enter
            the memo under the epoch check of :meth:`_scan_configs`.
@@ -342,8 +343,10 @@ class QueryPlanner:
 
         The array stages serve what they can see in the input: a type
         whose every index offers ``scan_configs`` and whose tables offer
-        ``candidate_rows_many`` (z3 and z2 over plain or delta-tiered
-        tables), and of its filters those some index serves. Any other
+        ``candidate_rows_many`` (z3, z2 and attribute indexes over plain or
+        delta-tiered tables), and of its filters those some index serves;
+        the ``plan`` span's ``costed``, ``attr_offered`` and ``attr_won``
+        count them as :meth:`_select_single` counts its own. Any other
         member (an id filter, a filter no index serves, which may plan a
         union or a full scan; every member of any other type) goes, in
         its position, through :meth:`_select`: an index at a time.
@@ -520,21 +523,22 @@ class QueryPlanner:
                     [extractions[at[k]] for k in pos], **budget
                 ),
             )
-            keep, kept = [], []
+            keep, kept, dead = [], [], []
             for m, cfg in zip(alive, cfgs):
                 if cfg is not None and cfg.disjoint:
-                    exps[m](f"Index {idx.name}: filter disjoint -> empty plan")
+                    dead.append(m)
                     plans[m] = QueryPlan(type_name, filters[m], idx.name, cfg, limit=limit)
                     continue
                 keep.append(m)
                 if cfg is not None:
                     kept.append((m, cfg))
-            served.append((idx.name, kept))
+            served.append((idx.name, kept, dead))
             alive = keep
 
         sp.event("spans")
         best: dict = {}  # member -> (cost, index name, config), the first cheapest
-        for name, kept in served:
+        offers: dict = {}  # member -> the indexes that offered it a plan, in order
+        for name, kept, dead in served:
             table = tables[name]
             mult = index_priority(name)
             if table is not None and kept:
@@ -545,14 +549,29 @@ class QueryPlanner:
                 if table is not None:
                     cost *= rows[id(cfg)] + 1
                 exps[m](f"Index {name}: {cfg.n_ranges} ranges, cost {cost:.1f}")
+                offers.setdefault(m, []).append(name)
                 if m not in best or cost < best[m][0]:
                     best[m] = (cost, name, cfg)
-            sp.add("costed", len(kept))
+            for m in dead:
+                exps[m](f"Index {name}: filter disjoint -> empty plan")
+                # the indexes before this one costed it for nothing
+                best.pop(m, None)
+                offers.pop(m, None)
 
         sp.event("estimate")
         for m, (cost, name, cfg) in best.items():
             exps[m](f"Strategy: {name} (cost {cost:.1f})")
             plans[m] = QueryPlan(type_name, filters[m], name, cfg, limit=limit)
+        # the decider's record, as _select_single writes it a plan: the
+        # indexes costed, and of the members an attribute index offered a
+        # plan for, those it won
+        sp.add("costed", sum(map(len, offers.values())))
+        offered = [
+            m for m, names in offers.items() if any(n.startswith("attr_") for n in names)
+        ]
+        if offered:
+            sp.add("attr_offered", len(offered))
+            sp.add("attr_won", sum(best[m][1].startswith("attr_") for m in offered))
         self._estimate_rows(
             [plans[m] for m in best], [exps[m] for m in best],
             [extractions[m] for m in best],

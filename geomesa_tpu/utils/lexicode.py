@@ -35,47 +35,42 @@ def lex_float(col) -> np.ndarray:
     return np.where(neg, ~b, b | SIGN)
 
 
+def _utf8_words(col, n_words: int) -> np.ndarray:
+    """u64 ``[n, n_words]``: word j is UTF-8 bytes [8j, 8j + 8) of each
+    value, big-endian, null-padded. ONE encode pass at the full width, and
+    every 8-byte window read from the same bytes (np.char.encode is per
+    element: repeating it a word made ingest pay a full-column pass each,
+    and a query's bounds sixteen calls a value)."""
+    c = np.asarray(col)
+    n = len(c)
+    if n == 0:
+        return np.zeros((0, n_words), dtype=np.uint64)
+    # a UTF-8 char is >= 1 byte, so ``width`` chars always cover the bytes
+    width = n_words * 8
+    raw = np.char.encode(c.astype(f"U{width}"), "utf-8").astype(f"S{width}")
+    return np.frombuffer(raw.tobytes(), dtype=">u8").reshape(n, n_words).astype(np.uint64)
+
+
 def lex_string(col, word: int = 0) -> np.ndarray:
     """u64 lexicode word ``word`` of a string column: UTF-8 bytes
     [8*word, 8*word+8) big-endian, null-padded. Word 0 is the primary
     sort key; word 1 the tie-breaking secondary (WriteKeys.sub). Byte
     order of UTF-8 == code-point order, so each word is weakly
     order-preserving even when truncation splits a multi-byte sequence."""
-    c = np.asarray(col)
-    n = len(c)
-    if n == 0:
-        return np.zeros(0, dtype=np.uint64)
-    # vectorized: encode enough chars to cover the byte window (a UTF-8
-    # char is >= 1 byte, so (word+1)*8 chars always cover it), then slice
-    # the window from a fixed-width bytes view
-    width = (word + 1) * 8
-    raw = np.char.encode(c.astype(f"U{width}"), "utf-8").astype(f"S{width}")
-    b = np.frombuffer(raw.tobytes(), dtype=np.uint8).reshape(n, width)
-    window = b[:, word * 8 : word * 8 + 8]
-    return np.ascontiguousarray(window).view(">u8")[:, 0].astype(np.uint64)
+    return np.ascontiguousarray(_utf8_words(col, word + 1)[:, word])
+
+
+_INT_TYPES = ("Integer", "Int", "Long", "Date")
+_FLOAT_TYPES = ("Float", "Double")
 
 
 def lex_column(col, attr_type: str) -> np.ndarray:
     """Lexicode one column according to its SFT attribute type."""
-    if attr_type in ("Integer", "Int", "Long", "Date"):
+    if attr_type in _INT_TYPES:
         return lex_int(col)
-    if attr_type in ("Float", "Double"):
+    if attr_type in _FLOAT_TYPES:
         return lex_float(col)
     return lex_string(col)
-
-
-def lex_value(v, attr_type: str):
-    """Lexicode one scalar (query bounds); None maps to the open extreme."""
-    return lex_column(np.array([v]), attr_type)[0]
-
-
-def bounds_to_range(lo, hi, attr_type: str) -> tuple[np.uint64, np.uint64]:
-    """Inclusive [lo, hi] u64 scan range for attribute value bounds; None
-    means unbounded on that side. Exclusive query bounds still map to the
-    inclusive code range (string prefixes collide; refinement is exact)."""
-    code_lo = np.uint64(0) if lo is None else lex_value(lo, attr_type)
-    code_hi = U64_MAX if hi is None else lex_value(hi, attr_type)
-    return code_lo, code_hi
 
 
 # cap on secondary sort words: 7 words -> values distinct within their
@@ -92,44 +87,49 @@ def lex_string_words(col) -> "np.ndarray | None":
     Zero-padding IS the correct order semantics: a shorter string sorts
     before any extension of it, and 0 is the pad byte."""
     c = np.asarray(col)
-    n = len(c)
-    if n == 0:
+    if len(c) == 0:
         return None
     enc = np.char.encode(c.astype(str), "utf-8")
-    max_len = int(np.char.str_len(enc).max()) if len(enc) else 0
+    max_len = int(np.char.str_len(enc).max())
     n_words = min(max(0, -(-(max_len - 8) // 8)), MAX_SUB_WORDS)
     if n_words == 0:
         return None
-    # ONE encode pass at the full width, then slice every 8-byte window
-    # from the same bytes view (np.char.encode is per-element; repeating
-    # it per word made ingest pay W+1 full-column passes)
-    width = (n_words + 1) * 8
-    raw = np.char.encode(c.astype(f"U{width}"), "utf-8").astype(f"S{width}")
-    b = np.frombuffer(raw.tobytes(), dtype=np.uint8).reshape(n, width)
-    return np.stack(
-        [
-            np.ascontiguousarray(b[:, 8 * (j + 1) : 8 * (j + 2)])
-            .view(">u8")[:, 0]
-            .astype(np.uint64)
-            for j in range(n_words)
-        ],
-        axis=1,
-    )
+    return np.ascontiguousarray(_utf8_words(c, 1 + n_words)[:, 1:])
 
 
-def bounds_sub_words(lo, hi) -> tuple[np.ndarray, np.ndarray]:
-    """[MAX_SUB_WORDS] secondary-word bounds for a string range: word j of
-    each bound value (zero-padded past the value's length — its exact
-    key), unbounded sides at the open extremes. Tables narrow with their
-    own word count; extra config words are ignored."""
-    lo_w = np.zeros(MAX_SUB_WORDS, dtype=np.uint64)
-    hi_w = np.full(MAX_SUB_WORDS, U64_MAX, dtype=np.uint64)
-    if lo is not None:
-        a = np.array([lo])
-        for j in range(MAX_SUB_WORDS):
-            lo_w[j] = lex_string(a, 1 + j)[0]
-    if hi is not None:
-        a = np.array([hi])
-        for j in range(MAX_SUB_WORDS):
-            hi_w[j] = lex_string(a, 1 + j)[0]
-    return lo_w, hi_w
+def lex_bounds(los, his, attr_type: str) -> "tuple[np.ndarray, np.ndarray]":
+    """Inclusive u64 code bounds ``(lo, hi)``, each ``[n, W]``, of n
+    attribute value bounds ``[los[k], his[k]]`` (None: unbounded on that
+    side), every value of both sides lexicoded in ONE pass (an equality's
+    ``lo is hi``: once). Column 0 is the scan range over the primary sort
+    key; W = 1 for a numeric type, and for a string 1 + MAX_SUB_WORDS: the
+    further columns are the secondary-word bounds (word j of each bound
+    value, zero-padded past the value's length: its exact key; tables
+    narrow with their own word count and ignore the rest). An unbounded
+    side is the open extreme in every column. Exclusive query bounds still
+    map to the inclusive code range (string prefixes collide; refinement
+    is exact)."""
+    n = len(los)
+    lo_at = [k for k, v in enumerate(los) if v is not None]
+    hi_at = [k for k, v in enumerate(his) if v is not None and v is not los[k]]
+    # dtype=object: each value converts on its own, as a column of one did
+    values = np.empty(len(lo_at) + len(hi_at), dtype=object)
+    values[:] = [los[k] for k in lo_at] + [his[k] for k in hi_at]
+    if attr_type in _INT_TYPES or attr_type in _FLOAT_TYPES:
+        codes = lex_column(values, attr_type)[:, None]
+    else:
+        codes = _utf8_words(values, 1 + MAX_SUB_WORDS)
+    lo = np.zeros((n, codes.shape[1]), dtype=np.uint64)
+    hi = np.full((n, codes.shape[1]), U64_MAX, dtype=np.uint64)
+    lo[lo_at] = codes[: len(lo_at)]
+    hi[hi_at] = codes[len(lo_at):]
+    same = [k for k in lo_at if his[k] is los[k]]
+    hi[same] = lo[same]
+    return lo, hi
+
+
+def bounds_to_range(lo, hi, attr_type: str) -> tuple[np.uint64, np.uint64]:
+    """Inclusive [lo, hi] u64 scan range of ONE pair of value bounds:
+    column 0 of :func:`lex_bounds`' one-row case."""
+    code_lo, code_hi = lex_bounds([lo], [hi], attr_type)
+    return code_lo[0, 0], code_hi[0, 0]

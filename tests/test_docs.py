@@ -504,7 +504,8 @@ def test_attribute_index_doc_honest():
 
     root = os.path.join(os.path.dirname(__file__), "..")
     text = open(os.path.join(root, "docs", "attribute-index.md")).read()
-    for mod, names in ((lexicode, ("bounds_to_range", "lex_string_words", "MAX_SUB_WORDS")),
+    for mod, names in ((lexicode, ("bounds_to_range", "lex_bounds", "lex_string_words",
+                                   "MAX_SUB_WORDS")),
                        (extract, ("extract_attribute_bounds",)),
                        (table, ("_rows_in_spans",)),
                        (planner, ("mask_decides_filter", "INDEX_PRIORITY"))):
@@ -513,13 +514,16 @@ def test_attribute_index_doc_honest():
     for field in ("clip_rows", "range_lo2", "range_hi2"):
         assert field in api.ScanConfig.__dataclass_fields__ and field in text, field
     assert "sub" in api.WriteKeys.__dataclass_fields__ and "WriteKeys.sub" in text
-    for fn in ("_select_single", "cost", "_post", "plan_many"):
+    for fn in ("_select_single", "_plan_arrays", "cost", "_post", "plan_many"):
         assert hasattr(planner.QueryPlanner, fn) and fn in text, fn
     assert hasattr(table.IndexTable, "_post_decode") and "_post_decode" in text
     pri = planner.INDEX_PRIORITY
     assert (pri["z3"], pri["z2"], pri["attr"]) == (1.1, 2.0, 2.5)
     assert "z3 1.1, z2 2.0, an attribute index 2.5" in text
-    assert not hasattr(attribute.AttributeIndex, "scan_configs")  # "takes no part in the array stages"
+    # "inside the array stages": the batched entry, and the one-member case through it
+    assert hasattr(attribute.AttributeIndex, "scan_configs") and "scan_configs" in text
+    assert "scan_configs" in inspect.getsource(attribute.AttributeIndex.scan_config)
+    assert "filter" in extract.Extraction.__dataclass_fields__ and "Extraction" in text
     src = inspect.getsource(planner) + inspect.getsource(table)
     for attr in ("clip_in", "clip_kept", "residual_rows", "costed", "attr_offered", "attr_won"):
         assert f'"{attr}"' in src, attr

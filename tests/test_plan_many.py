@@ -7,8 +7,10 @@ the same filter on the same store.
   filters, the shapes the batched stages hand to ``plan``'s own (ids, a
   union, an attribute predicate), a filter twice, batches of 0 and 1, and
   all of these in one batch; on a ``DataStore``, on a mesh store of four
-  virtual devices, on a table with a delta tier, and on stores whose
-  tables or indexes lack the batched entries;
+  virtual devices, on a table with a delta tier, on a type with an
+  attribute index (whose ``scan_configs`` takes the members that bound the
+  attribute, a disjoint pair of bounds and ``IN`` lists among them), and on
+  stores whose tables lack the batched entries;
 - each config's ``_spans`` slot: filled by ``plan_many`` as ``scan_spans``
   fills it, so the dispatch finds them;
 - the config memo: a second ``plan_many`` decomposes nothing; a mutation
@@ -113,6 +115,11 @@ CASES = {
     "union": [f"{_bbox((-5, -5, 5, 5))} OR name = 'c'",
               f"{_bbox((-5, -5, 5, 5))} OR {_bbox((40, 40, 50, 50))}"],
     "attribute": ["name = 'b'", f"name = 'b' AND {_bbox((0, 0, 30, 20))} AND {_during(WIN)}"],
+    "attribute-in": [f"name IN ('a', 'c') AND {_bbox((0, 0, 30, 20))} AND {_during(WIN)}",
+                     "name IN ('b', 'nobody', 'c') AND name > 'a'", "name BETWEEN 'b' AND 'bz'"],
+    # the attribute index alone sees these are empty, after z3 and z2 offered a plan
+    "attribute-disjoint": [f"name = 'a' AND name = 'b' AND {_bbox((0, 0, 30, 20))} AND {_during(WIN)}",
+                           f"name < 'a' AND name > 'b' AND {_bbox((0, 0, 30, 20))}"],
     "include": ["INCLUDE"],
     "twice": [f"{_bbox((3, 3, 9, 6))} AND {_during(WIN)}"] * 2 + [_bbox((3, 3, 9, 6))] * 2,
     "one": [f"{_bbox((3, 3, 9, 6))} AND {_during(WIN)}"],
@@ -241,7 +248,7 @@ def _plan_both(ds, filters, limit=None):
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-@pytest.mark.parametrize("kind", ["single", "mesh4", "delta"])
+@pytest.mark.parametrize("kind", ["single", "mesh4", "delta", "attr-index"])
 def test_plan_many_equals_plan(kind, case):
     ds = _store(kind)
     filters = CASES[case]
@@ -255,12 +262,11 @@ def test_plan_many_equals_plan(kind, case):
         assert len({id(p) for p in got}) == len(got)  # a plan a member, never shared
 
 
-@pytest.mark.parametrize("kind", ["host-adapter", "attr-index", "no-data"])
+@pytest.mark.parametrize("kind", ["host-adapter", "no-data"])
 def test_plan_many_equals_plan_where_the_batched_entries_are_missing(kind):
-    """A table without ``candidate_rows_many`` (the host adapter's), an
-    index without ``scan_configs`` (an attribute index): every member
-    through ``plan``'s own stages. A type with no rows yet: no table to
-    cost, the multiplier alone."""
+    """A table without ``candidate_rows_many`` (the host adapter's): every
+    member through ``plan``'s own stages. A type with no rows yet: no table
+    to cost, the multiplier alone."""
     ds = _store(kind)
     got, want = _plan_both(ds, CASES["mixed"])
     for g, w in zip(got, want):
@@ -306,7 +312,7 @@ def _plan_span(trace):
 
 
 @pytest.mark.parametrize("kind,batched", [("single", True), ("delta", True),
-                                          ("host-adapter", False), ("attr-index", False)])
+                                          ("host-adapter", False), ("attr-index", True)])
 def test_one_plan_span_counts_the_members_the_arrays_took(traced, kind, batched):
     ds = _store(kind)
     filters = CASES["mixed"]
@@ -316,17 +322,27 @@ def test_one_plan_span_counts_the_members_the_arrays_took(traced, kind, batched)
     plan, kids = _plan_span(traced()[-1])
     a = plan.attrs
     assert a["members"] == len(filters) and "cpu_s" in a
-    # ids, INCLUDE, a bare attribute predicate and the OR with one: plan()'s own
+    # ids, INCLUDE, the OR with an attribute predicate and, where no index
+    # holds the attribute, the predicates that bound it alone: plan()'s own
     own = sum(1 for p in plans if p.ids is not None or p.index is None)
-    assert 4 <= own < len(filters) or not batched
+    assert (3 if kind == "attr-index" else 6) <= own < len(filters) or not batched
     assert a["batched"] == (len(filters) - own if batched else 0)
     segs = a["segments"]
     assert set(segs) == ({"parse", "extract", "decompose", "spans", "estimate"}
                          if batched else {"parse", "estimate"})
     assert sum(segs.values()) <= plan.dur_s + 2e-4
+    if kind == "attr-index":
+        # the decider's record, as an index at a time writes it a plan
+        attr = [p for q in plans for p in (q.union or [q])
+                if p.index is not None and "name" in repr(p.filter) and not p.config.disjoint]
+        assert a["attr_offered"] == len(attr) > 4
+        assert a["attr_won"] == sum(p.index == "attr_name" for p in attr) > 0
+    else:
+        assert "attr_offered" not in a and "attr_won" not in a
     if batched:
         names = [(s.name, s.attrs["index"]) for s in kids]
-        for idx in ("z3", "z2"):  # once an index; the members plan() took come after
+        # once an index; the members plan() took come after
+        for idx in ("z3", "z2") + (("attr_name",) if kind == "attr-index" else ()):
             assert names.count(("plan.probe", idx)) >= 1
             first = next(s for s in kids if s.name == "plan.decompose"
                          and s.attrs["index"] == idx)
@@ -393,9 +409,9 @@ def test_query_many_answers_equal_query(kind):
 
 # -- the array forms against their one-member forms ------------------------
 
-@pytest.mark.parametrize("index", ["z3", "z2"])
+@pytest.mark.parametrize("index", ["z3", "z2", "attr_name"])
 def test_scan_configs_of_a_batch_are_scan_config_of_each(index):
-    ds = _store("single")
+    ds = _store("attr-index" if index == "attr_name" else "single")
     idx = next(i for i in ds.indexes(TYPE) if i.name == index)
     sft = ds.get_schema(TYPE)
     filters = [ecql.parse(f) for f in CASES["mixed"]]
@@ -404,9 +420,9 @@ def test_scan_configs_of_a_batch_are_scan_config_of_each(index):
         _assert_configs_equal(got, idx.scan_config(f))
 
 
-@pytest.mark.parametrize("index", ["z3", "z2"])
+@pytest.mark.parametrize("index", ["z3", "z2", "attr_name"])
 def test_spans_of_a_batch_are_the_spans_of_each(index):
-    ds = _store("single")
+    ds = _store("attr-index" if index == "attr_name" else "single")
     idx = next(i for i in ds.indexes(TYPE) if i.name == index)
     sk = ds.table(TYPE, index)
     cfgs = [c for c in (idx.scan_config(ecql.parse(f)) for f in CASES["mixed"])
@@ -414,7 +430,8 @@ def test_spans_of_a_batch_are_the_spans_of_each(index):
     # contained flags that do not count beside ones that do
     cfgs += [dataclasses.replace(c, contained_exact=False) for c in cfgs[:5]]
     many = sk._compute_spans([dataclasses.replace(c) for c in cfgs])
-    assert len(many) == len(cfgs) and any(len(s.contained) for s in many)
+    assert len(many) == len(cfgs)
+    assert any(len(s.contained) for s in many) or index == "attr_name"
     for got, cfg in zip(many, cfgs):
         _assert_spans_equal(got, sk._compute_spans([dataclasses.replace(cfg)])[0])
     rows = sk.candidate_rows_many([dataclasses.replace(c) for c in cfgs])

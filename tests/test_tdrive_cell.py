@@ -415,7 +415,11 @@ def test_a_batchs_plan_counts_its_members(bench, mix, first, traced):
     bench.op.embedded(store, req)
     plan = _spans(traced.traces()[-1], "plan")[0]
     assert plan.attrs["members"] == 32 and plan.attrs["index"] == "attr_taxiId"
-    assert plan.attrs["batched"] == 0  # the attribute index takes no part in the array stages
+    assert plan.attrs["batched"] == 32  # every index of the type offers scan_configs: the arrays took all
+    decomposed = [s for s in _spans(traced.traces()[-1], "plan.decompose")
+                  if s.attrs["index"] == "attr_taxiId"]
+    # one pass over the members the memo lacks (a taxi drawn twice: once), not 32 spans of one
+    assert len(decomposed) == 1 and 16 < decomposed[0].attrs["members"] <= 32
     assert (plan.attrs["attr_offered"], plan.attrs["attr_won"]) == (32, 32)
     assert plan.attrs["costed"] == 64  # the attribute index and z3, a member
 
@@ -739,6 +743,57 @@ def test_the_readers_find_nothing_on_a_program_before_pr_49(bench):
     assert all(r.read(empty) is None for r in bench.readers.values())
 
 
+def _configs_equal(got, want):
+    import dataclasses
+
+    assert (got is None) == (want is None)
+    for fld in dataclasses.fields(got) if got is not None else ():
+        a, b = getattr(got, fld.name), getattr(want, fld.name)
+        if fld.name == "_spans":
+            assert np.array_equal(a[1].union.lo, b[1].union.lo) and \
+                np.array_equal(a[1].union.hi, b[1].union.hi)
+        elif isinstance(b, np.ndarray):
+            assert a.dtype == b.dtype and a.shape == b.shape and np.array_equal(a, b), fld.name
+        else:
+            assert type(a) is type(b) and a == b, fld.name
+
+
+def test_every_request_of_the_mix_plans_as_an_index_at_a_time(bench, mix, loaded):
+    """``plan`` and ``plan_many`` through the array stages against
+    ``_select``, each index decomposing and costing the filter on its own:
+    the same index, ranges, sub-words, boxes, windows, flags, estimate and
+    explain trail, for every class of the mix."""
+    from geomesa_tpu.planning.explain import Explainer
+
+    seed, cols, store = loaded
+    ds, name = store.ds, store.type_name
+    pl = ds.planner
+    seen = set()
+    for req in _requests(bench, mix, cols, seed, 64):
+        filters = [bench.op.ecql(m) for m in req.get("members", [req])]
+        seen.add(req["klass"])
+        want, trails = [], []
+        pl.invalidate_config_memo()
+        for f in filters:
+            trails.append(Explainer())
+            prepared = pl._prepare(name, f, True)
+            trails[-1](f"Planning query on '{name}': {type(prepared).__name__}")
+            want.append(pl._select(name, prepared, req.get("limit"), trails[-1]))
+            pl._estimate_rows([want[-1]], [trails[-1]])
+        pl.invalidate_config_memo()
+        got = pl.plan_many(name, filters, limit=req.get("limit"))
+        pl.invalidate_config_memo()
+        for f, g, w, trail in zip(filters, got, want, trails):
+            exp = Explainer()
+            one = pl.plan(name, f, limit=req.get("limit"), explain=exp)
+            assert exp.lines == trail.lines, f
+            for p in (g, one):
+                assert (p.index, p.strategy, p.limit, p.estimated_rows, repr(p.filter)) == (
+                    w.index, w.strategy, w.limit, w.estimated_rows, repr(w.filter)), f
+                _configs_equal(p.config, w.config)
+    assert seen == set(CLASSES)
+
+
 # ---------------------------------------------------------------- (k) the cell
 
 
@@ -757,7 +812,7 @@ def test_the_cell_rehearses_on_the_cpu():
                                "load_rows_per_s"} <= set(read)
     assert 80.0 < read["attr_chosen_pct"]["value"] <= 100.0 * 76 / 78 + 1e-9
     assert 0 < read["attr_clip_keep_pct"]["value"] < 100
-    assert read["plan_batched_pct"]["value"] == 0.0
+    assert read["plan_batched_pct"]["value"] == 100.0
     assert read["plan_lost_ms"]["value"] > 0 and read["sort_ms"]["value"] > 0
     window = next(json.loads(s) for s in out.stdout.splitlines() if '"phase": "window"' in s)
     assert window["compile_requests_in_window"] == 0
