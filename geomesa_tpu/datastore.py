@@ -2296,9 +2296,13 @@ class DataStore:
         ``geomesa.obs.ops.host`` knob (loopback). ``lam``: the
         LambdaStore whose hot tier / WAL join the health surface
         (``LambdaStore.serve_ops`` passes itself). Idempotent while the
-        attached server is open; a closed one is replaced."""
+        attached server is open; a closed one is replaced. An ops plane
+        is somebody reading the interpreter lock's record: the probe that
+        writes it starts here (``obs.trace.arm_lock_probe``)."""
         from geomesa_tpu.obs.ops import OpsServer
+        from geomesa_tpu.obs.trace import arm_lock_probe
 
+        arm_lock_probe()
         with self._write_lock:
             ops = self.ops
             if ops is not None and not ops.closed:

@@ -33,6 +33,7 @@ import numpy as np
 
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.predicates import PointColumn
+from geomesa_tpu.obs.trace import add as _oadd
 
 BATCH_ROWS = 65536
 
@@ -320,12 +321,20 @@ class ArrowChunks:
             if batches:
                 w.write_batch(batches[0])
         head = sink.getvalue()
+        # the pyarrow calls above that run with the interpreter lock
+        # released (``with nogil`` in its source): the writer opened, the
+        # batch written, the writer closed, the sink's value taken, and the
+        # native batch's import; an array a call of the pyarrow route, and
+        # ``slice`` and ``to_pybytes``, keep the lock
+        _oadd("handoffs", 4 + self.arrow_native)
         if len(batches) < 2:
             yield head.to_pybytes()
             return
         yield head.slice(0, head.size - len(_END_OF_STREAM)).to_pybytes()
         for batch in batches[1:]:
-            yield batch.serialize().to_pybytes()
+            page = batch.serialize()
+            _oadd("handoffs", 1)
+            yield page.to_pybytes()
         yield _END_OF_STREAM
 
 

@@ -19,9 +19,12 @@ import math
 import os
 import subprocess
 import threading
+import time
 from pathlib import Path
 
 import numpy as np
+
+from geomesa_tpu.obs.trace import _tls as _trace_tls
 
 _DIR = Path(__file__).resolve().parent
 _SRC = _DIR / "geomesa_native.cpp"
@@ -29,6 +32,12 @@ _SRC = _DIR / "geomesa_native.cpp"
 _log = logging.getLogger(__name__)
 _lock = threading.Lock()
 _lib: "ctypes.CDLL | None | bool" = None  # None = untried, False = unavailable
+# (stamp_entry, stamp_return): the library's getters of the calling thread's
+# last entry and return stamps, bound through ``ctypes.PyDLL`` (its calls
+# KEEP the interpreter lock, so reading a stamp is no hand-off). None where
+# the library is not loaded or ``time.perf_counter`` is not the stamps' clock.
+_stamps = None
+_perf = time.perf_counter
 
 
 def _lib_path() -> Path:
@@ -181,12 +190,63 @@ def _load():
             ctypes.c_int64, ctypes.c_int64, u64p, u64p, u8p, ctypes.c_int64,
         ]
         lib.xz_ranges.restype = ctypes.c_int64
+        lib.nap.argtypes = [ctypes.c_double]
+        lib.nap.restype = None
+        # the stamps are CLOCK_MONOTONIC: comparable with perf_counter only
+        # where that is the same clock (Linux); elsewhere no ``reacquire_s``
+        if time.get_clock_info("perf_counter").implementation == (
+            "clock_gettime(CLOCK_MONOTONIC)"
+        ):
+            global _stamps
+            held = ctypes.PyDLL(str(path))  # the same library: one set of stamps
+            for fn in (held.stamp_entry, held.stamp_return):
+                fn.argtypes, fn.restype = [], ctypes.c_double
+            _stamps = (held.stamp_entry, held.stamp_return)
         _lib = lib
         return lib
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def _call(fn, *args):
+    """The ONE door to the library: every ctypes call below goes through
+    here, because every one of them lets the interpreter lock go and has
+    to take it back (docs/observability.md "The interpreter lock"). Under
+    an active span it counts the hand-off (``handoffs``); under a span of
+    a RETAINED trace it also reads the two stamps the library's guard left
+    on this thread (geomesa_native.cpp ``Stamp``) and adds ``native_s``,
+    the seconds the work ran with the lock released, ``reacquire_s``,
+    ``perf_counter`` now less the library's stamp of its return: the wait
+    to get the lock back, read directly, and ``native_n``, the calls that
+    wrote one. Untraced: one thread-local probe."""
+    cur = getattr(_trace_tls, "span", None)
+    if cur is None:
+        return fn(*args)
+    out = fn(*args)
+    if _stamps is not None and cur.trace.retain:
+        now = _perf()
+        back = _stamps[1]()
+        cur.add("native_s", back - _stamps[0]())
+        cur.add("reacquire_s", max(now - back, 0.0))
+        cur.add("native_n", 1)
+    cur.add("handoffs", 1)
+    return out
+
+
+def nap(seconds: float) -> "float | None":
+    """Sleep ``seconds`` inside the library, with the interpreter lock
+    released as for any call here, and return the seconds this thread then
+    waited to hold the lock again: ``perf_counter`` on return less the
+    library's stamp of the sleep's end (the timer's slack is outside it).
+    What ``obs.trace``'s hand-off probe samples. None where the library or
+    the stamps' clock is not there."""
+    lib = _load()
+    if lib is None or _stamps is None:
+        return None
+    lib.nap(seconds)
+    return max(_perf() - _stamps[1](), 0.0)
 
 
 def morton2(x, y) -> "np.ndarray | None":
@@ -196,7 +256,7 @@ def morton2(x, y) -> "np.ndarray | None":
     x = np.ascontiguousarray(x, dtype=np.uint64)
     y = np.ascontiguousarray(y, dtype=np.uint64)
     out = np.empty(len(x), dtype=np.uint64)
-    lib.morton2(x, y, len(x), out)
+    _call(lib.morton2, x, y, len(x), out)
     return out
 
 
@@ -208,7 +268,7 @@ def morton3(x, y, t) -> "np.ndarray | None":
     y = np.ascontiguousarray(y, dtype=np.uint64)
     t = np.ascontiguousarray(t, dtype=np.uint64)
     out = np.empty(len(x), dtype=np.uint64)
-    lib.morton3(x, y, t, len(x), out)
+    _call(lib.morton3, x, y, t, len(x), out)
     return out
 
 
@@ -220,7 +280,7 @@ def morton3_decode(z):
     x = np.empty(len(z), dtype=np.uint64)
     y = np.empty(len(z), dtype=np.uint64)
     t = np.empty(len(z), dtype=np.uint64)
-    lib.morton3_decode(z, len(z), x, y, t)
+    _call(lib.morton3_decode, z, len(z), x, y, t)
     return x, y, t
 
 
@@ -245,8 +305,8 @@ def z3_write_keys(x, y, millis, period: str, max_offset: int, max_bin: int):
     xf = np.empty(n, dtype=np.float32)
     yf = np.empty(n, dtype=np.float32)
     toff = np.empty(n, dtype=np.int32)
-    status = lib.z3_write_keys(
-        x, y, millis, n, bin_ms, off_div, float(max_offset), max_bin,
+    status = _call(
+        lib.z3_write_keys, x, y, millis, n, bin_ms, off_div, float(max_offset), max_bin,
         z, bins, xf, yf, toff,
     )
     if status == 1:
@@ -269,7 +329,7 @@ def z2_write_keys(x, y):
     z = np.empty(n, dtype=np.uint64)
     xf = np.empty(n, dtype=np.float32)
     yf = np.empty(n, dtype=np.float32)
-    lib.z2_write_keys(x, y, n, z, xf, yf)
+    _call(lib.z2_write_keys, x, y, n, z, xf, yf)
     return z, {"x": xf, "y": yf}
 
 
@@ -283,7 +343,7 @@ def sort_bins_z(bins, zs) -> "np.ndarray | None":
     bins = np.ascontiguousarray(bins, dtype=np.int32)
     zs = np.ascontiguousarray(zs, dtype=np.uint64)
     perm = np.empty(len(zs), dtype=np.uint32)
-    lib.sort_bins_z(bins, zs, len(zs), perm)
+    _call(lib.sort_bins_z, bins, zs, len(zs), perm)
     return perm
 
 
@@ -309,7 +369,7 @@ def take(src: np.ndarray, idx: np.ndarray) -> "np.ndarray | None":
     src = np.ascontiguousarray(src)
     idx = np.ascontiguousarray(idx, dtype=np.uint32)
     out = np.empty(len(idx), dtype=src.dtype)
-    getattr(lib, name)(src, idx, len(idx), out)
+    _call(getattr(lib, name), src, idx, len(idx), out)
     return out
 
 
@@ -388,8 +448,8 @@ def gather_columns(table: ColumnTable, idx: np.ndarray) -> "ColumnTable | None":
         idx = idx.astype(np.int64)
     idx = np.ascontiguousarray(idx)
     out = ColumnTable(table.empty(len(idx)), like=table)
-    lib.gather_columns(
-        table.srcs, table.widths, out.srcs, len(out.arrays),
+    _call(
+        lib.gather_columns, table.srcs, table.widths, out.srcs, len(out.arrays),
         idx.ctypes.data, idx.dtype.itemsize, len(idx),
     )
     return out
@@ -471,9 +531,9 @@ def geojson_features(table: GeoJSONColumns, lo: int, hi: int) -> "bytes | None":
     if hi <= lo:
         return b""
     out = ctypes.c_void_p()
-    n = _load().geojson_features(
-        table.cols, len(table.cols) // 4, table.keys, table.key_off, lo, hi,
-        ctypes.byref(out),
+    n = _call(
+        _load().geojson_features, table.cols, len(table.cols) // 4, table.keys,
+        table.key_off, lo, hi, ctypes.byref(out),
     )
     # the bytes are the calling thread's until its next call: copied here
     return None if n < 0 else ctypes.string_at(out, n)
@@ -496,8 +556,8 @@ def arrow_batch(table: GeoJSONColumns, ops, order, importer):
     then builds the table."""
     lib = _load()
     out = (ctypes.c_int64 * 10)()  # arrow/c/abi.h's ArrowArray: ten words
-    if lib.arrow_batch(
-        table.cols, (ctypes.c_int64 * len(ops))(*ops),
+    if _call(
+        lib.arrow_batch, table.cols, (ctypes.c_int64 * len(ops))(*ops),
         (ctypes.c_int64 * len(order))(*order), len(order), table.rows, out,
     ) < 0:
         return None
@@ -505,7 +565,7 @@ def arrow_batch(table: GeoJSONColumns, ops, order, importer):
         return importer(ctypes.addressof(out))
     finally:
         if out[8]:  # ``release`` still set: nothing took the buffers
-            lib.arrow_batch_release(out)
+            _call(lib.arrow_batch_release, out)
 
 
 _ROW_GATHERS = {
@@ -525,7 +585,7 @@ def take_rows(src: np.ndarray, idx: np.ndarray) -> "np.ndarray | None":
     src = np.ascontiguousarray(src)
     idx = np.ascontiguousarray(idx, dtype=np.uint32)
     out = np.empty((len(idx), src.shape[1]), dtype=src.dtype)
-    getattr(lib, name)(src, idx, len(idx), src.shape[1], out)
+    _call(getattr(lib, name), src, idx, len(idx), src.shape[1], out)
     return out
 
 
@@ -540,10 +600,10 @@ def bitmask_decode_pair(wide, inner, bids, n_real: int, block: int):
     inner = np.ascontiguousarray(inner[:n_real], dtype=np.int32)
     bids = np.ascontiguousarray(bids[:n_real], dtype=np.int64)
     pack = wide.shape[1]
-    count = lib.bitmask_count(wide, n_real, pack)
+    count = _call(lib.bitmask_count, wide, n_real, pack)
     rows = np.empty(count, dtype=np.int64)
     cert = np.empty(count, dtype=np.uint8)
-    k = lib.bitmask_decode_pair(wide, inner, bids, n_real, pack, block, rows, cert)
+    k = _call(lib.bitmask_decode_pair, wide, inner, bids, n_real, pack, block, rows, cert)
     assert k == count
     return rows, cert.astype(bool)
 
@@ -560,7 +620,7 @@ def xz_index(lo, hi, dims: int, g: int, subtree) -> "np.ndarray | None":
     sub = np.ascontiguousarray(subtree, dtype=np.int64)
     n = lo.shape[0]
     out = np.empty(n, dtype=np.int64)
-    lib.xz_index(lo.reshape(-1), hi.reshape(-1), n, int(dims), int(g), sub, out)
+    _call(lib.xz_index, lo.reshape(-1), hi.reshape(-1), n, int(dims), int(g), sub, out)
     return out
 
 
@@ -579,8 +639,8 @@ def xz_ranges(dims: int, g: int, subtree, qlo, qhi, max_ranges: int):
     lo = np.empty(cap, dtype=np.uint64)
     hi = np.empty(cap, dtype=np.uint64)
     cont = np.empty(cap, dtype=np.uint8)
-    n = lib.xz_ranges(
-        dims, g, sub, qlo.reshape(-1), qhi.reshape(-1), nq,
+    n = _call(
+        lib.xz_ranges, dims, g, sub, qlo.reshape(-1), qhi.reshape(-1), nq,
         int(max_ranges), lo, hi, cont, cap,
     )
     if n < 0:
@@ -597,9 +657,9 @@ def bitmask_decode(wide, bids, n_real: int, block: int):
     wide = np.ascontiguousarray(wide[:n_real], dtype=np.int32)
     bids = np.ascontiguousarray(bids[:n_real], dtype=np.int64)
     pack = wide.shape[1]
-    count = lib.bitmask_count(wide, n_real, pack)
+    count = _call(lib.bitmask_count, wide, n_real, pack)
     rows = np.empty(count, dtype=np.int64)
-    k = lib.bitmask_decode(wide, bids, n_real, pack, block, rows)
+    k = _call(lib.bitmask_decode, wide, bids, n_real, pack, block, rows)
     assert k == count
     return rows
 
@@ -618,7 +678,7 @@ def merge_rows_spans(lo, hi, rows, cert):
     cap = int((hi - lo).sum()) + len(rows)
     out_rows = np.empty(cap, dtype=np.int64)
     out_cert = np.empty(cap, dtype=np.uint8)
-    k = lib.merge_rows_spans(lo, hi, len(lo), rows, cert8, len(rows), out_rows, out_cert)
+    k = _call(lib.merge_rows_spans, lo, hi, len(lo), rows, cert8, len(rows), out_rows, out_cert)
     return out_rows[:k], out_cert[:k].astype(bool)
 
 
@@ -635,7 +695,7 @@ def counting_argsort(keys, n_buckets: int) -> "np.ndarray | None":
         return None
     keys = keys.astype(np.int32)
     perm = np.empty(len(keys), dtype=np.uint32)
-    lib.counting_argsort(keys, len(keys), int(n_buckets), perm)
+    _call(lib.counting_argsort, keys, len(keys), int(n_buckets), perm)
     return perm
 
 
@@ -660,8 +720,8 @@ def zranges(dims, bits_per_dim, mins, maxes, inner_mins, inner_maxes,
     hi = np.empty(cap, dtype=np.uint64)
     cont = np.empty(cap, dtype=np.uint8)
     counts = np.empty(nq, dtype=np.int64)
-    n = lib.zranges_each_cpp(
-        dims, bits_per_dim, nq, nbox,
+    n = _call(
+        lib.zranges_each_cpp, dims, bits_per_dim, nq, nbox,
         mins.reshape(-1), maxes.reshape(-1),
         inner_mins.reshape(-1), inner_maxes.reshape(-1),
         int(max_ranges), int(max_recurse), lo, hi, cont, counts, cap,
@@ -690,7 +750,7 @@ def points_in_polygon(px, py, rings, ring_part) -> "np.ndarray | None":
     ).astype(np.int64)
     part = np.ascontiguousarray(ring_part, dtype=np.int32)
     out = np.empty(len(px), dtype=np.uint8)
-    lib.points_in_polygon_cpp(
-        px, py, len(px), verts, offsets, len(rings), part, out
+    _call(
+        lib.points_in_polygon_cpp, px, py, len(px), verts, offsets, len(rings), part, out
     )
     return out.astype(bool)

@@ -26,6 +26,32 @@
 #include <omp.h>
 #endif
 
+// ------------------------------------------------------------ the stamp
+// Every exported function below opens with ``Stamp stamp_;``: the guard
+// stores CLOCK_MONOTONIC (Python's ``time.perf_counter`` on Linux) at
+// entry and at return in two doubles of the calling thread, so that the
+// caller, back in Python with the interpreter lock, can tell the seconds
+// the work ran with the lock released from the seconds it then waited to
+// get the lock back (geomesa_tpu/native/__init__.py ``_call``; the two
+// getters at the end of this file are loaded through ``ctypes.PyDLL``,
+// whose calls keep the lock). Only the outermost guard of a thread
+// writes: ``zranges_each_cpp`` calls ``zranges_cpp``.
+#include <time.h>
+
+static thread_local double g_stamp_entry = 0.0, g_stamp_return = 0.0;
+static thread_local int g_stamp_depth = 0;
+
+static inline double mono_s() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (double)ts.tv_sec + 1e-9 * (double)ts.tv_nsec;
+}
+
+struct Stamp {
+  Stamp() { if (g_stamp_depth++ == 0) g_stamp_entry = mono_s(); }
+  ~Stamp() { if (--g_stamp_depth == 0) g_stamp_return = mono_s(); }
+};
+
 extern "C" {
 
 // ---------------------------------------------------------------- morton
@@ -72,6 +98,7 @@ static inline uint64_t combine3(uint64_t z) {
 }
 
 void morton2(const uint64_t* x, const uint64_t* y, int64_t n, uint64_t* out) {
+  Stamp stamp_;
 #pragma omp parallel for
   for (int64_t i = 0; i < n; ++i) {
     out[i] = split2(x[i]) | (split2(y[i]) << 1);
@@ -79,6 +106,7 @@ void morton2(const uint64_t* x, const uint64_t* y, int64_t n, uint64_t* out) {
 }
 
 void morton2_decode(const uint64_t* z, int64_t n, uint64_t* x, uint64_t* y) {
+  Stamp stamp_;
 #pragma omp parallel for
   for (int64_t i = 0; i < n; ++i) {
     x[i] = combine2(z[i]);
@@ -88,6 +116,7 @@ void morton2_decode(const uint64_t* z, int64_t n, uint64_t* x, uint64_t* y) {
 
 void morton3(const uint64_t* x, const uint64_t* y, const uint64_t* t, int64_t n,
              uint64_t* out) {
+  Stamp stamp_;
 #pragma omp parallel for
   for (int64_t i = 0; i < n; ++i) {
     out[i] = split3(x[i]) | (split3(y[i]) << 1) | (split3(t[i]) << 2);
@@ -96,6 +125,7 @@ void morton3(const uint64_t* x, const uint64_t* y, const uint64_t* t, int64_t n,
 
 void morton3_decode(const uint64_t* z, int64_t n, uint64_t* x, uint64_t* y,
                     uint64_t* t) {
+  Stamp stamp_;
 #pragma omp parallel for
   for (int64_t i = 0; i < n; ++i) {
     x[i] = combine3(z[i]);
@@ -127,6 +157,7 @@ int32_t z3_write_keys(const double* x, const double* y, const int64_t* millis,
                       double max_off, int32_t max_bin, uint64_t* out_z,
                       int32_t* out_bin, float* out_xf, float* out_yf,
                       int32_t* out_toff) {
+  Stamp stamp_;
   const double lon_norm = 2097152.0 / 360.0;  // 2^21 / (180 - -180)
   const double lat_norm = 2097152.0 / 180.0;
   const double t_norm = 2097152.0 / max_off;  // NormalizedTime(21, max_off)
@@ -159,6 +190,7 @@ int32_t z3_write_keys(const double* x, const double* y, const int64_t* millis,
 
 void z2_write_keys(const double* x, const double* y, int64_t n, uint64_t* out_z,
                    float* out_xf, float* out_yf) {
+  Stamp stamp_;
   const double lon_norm = 2147483648.0 / 360.0;  // 2^31 / 360
   const double lat_norm = 2147483648.0 / 180.0;
   const int64_t max_index = 2147483647;  // 2^31 - 1
@@ -214,6 +246,7 @@ static int radix_pass_u64_w(const uint64_t* key, const uint32_t* idx, int64_t n,
 // 8-bit digits below 1M rows where the 512 KB histogram dominates.
 extern "C" void sort_bins_z(const int32_t* bins, const uint64_t* zs, int64_t n,
                  uint32_t* out_perm) {
+  Stamp stamp_;
   const int bits = n >= (1 << 20) ? 16 : 8;
   std::vector<int64_t> hist((size_t)1 << bits);
   std::vector<uint64_t> ka(n), kb(n);
@@ -240,18 +273,23 @@ extern "C" void sort_bins_z(const int32_t* bins, const uint64_t* zs, int64_t n,
 
 // permutation gathers for building sorted device/host columns
 extern "C" void gather_f32(const float* src, const uint32_t* idx, int64_t n, float* out) {
+  Stamp stamp_;
   for (int64_t i = 0; i < n; ++i) out[i] = src[idx[i]];
 }
 extern "C" void gather_i32(const int32_t* src, const uint32_t* idx, int64_t n, int32_t* out) {
+  Stamp stamp_;
   for (int64_t i = 0; i < n; ++i) out[i] = src[idx[i]];
 }
 extern "C" void gather_i64(const int64_t* src, const uint32_t* idx, int64_t n, int64_t* out) {
+  Stamp stamp_;
   for (int64_t i = 0; i < n; ++i) out[i] = src[idx[i]];
 }
 extern "C" void gather_u64(const uint64_t* src, const uint32_t* idx, int64_t n, uint64_t* out) {
+  Stamp stamp_;
   for (int64_t i = 0; i < n; ++i) out[i] = src[idx[i]];
 }
 extern "C" void gather_f64(const double* src, const uint32_t* idx, int64_t n, double* out) {
+  Stamp stamp_;
   for (int64_t i = 0; i < n; ++i) out[i] = src[idx[i]];
 }
 
@@ -260,6 +298,7 @@ extern "C" void gather_f64(const double* src, const uint32_t* idx, int64_t n, do
 // bound; threads hide the misses.
 extern "C" void gather_rows_f64(const double* src, const uint32_t* idx,
                                 int64_t n, int64_t width, double* out) {
+  Stamp stamp_;
 #pragma omp parallel for schedule(static) if (n > 65536)
   for (int64_t i = 0; i < n; ++i) {
     const double* s = src + (int64_t)idx[i] * width;
@@ -269,6 +308,7 @@ extern "C" void gather_rows_f64(const double* src, const uint32_t* idx,
 }
 extern "C" void gather_rows_f32(const float* src, const uint32_t* idx,
                                 int64_t n, int64_t width, float* out) {
+  Stamp stamp_;
 #pragma omp parallel for schedule(static) if (n > 65536)
   for (int64_t i = 0; i < n; ++i) {
     const float* s = src + (int64_t)idx[i] * width;
@@ -344,6 +384,7 @@ static void gather_columns_t(const char* const* srcs, const int64_t* widths,
 extern "C" void gather_columns(const char* const* srcs, const int64_t* widths,
                                char* const* outs, int64_t ncols,
                                const void* idx, int32_t idx_width, int64_t n) {
+  Stamp stamp_;
   if (idx_width == 4)
     gather_columns_t<uint32_t>(srcs, widths, outs, ncols, (const uint32_t*)idx, n);
   else
@@ -568,6 +609,7 @@ static bool gj_value(GjBuf& b, int64_t kind, const char* p, int64_t w) {
 extern "C" int64_t geojson_features(const int64_t* cols, int64_t ncols,
                                     const char* keys, const int64_t* key_off,
                                     int64_t lo, int64_t hi, const char** out) {
+  Stamp stamp_;
   static thread_local GjBuf b;
   if (b.cap > GJ_KEEP_BYTES) {
     std::free(b.p);
@@ -828,6 +870,7 @@ static void ar_release(ArrowArray* a) {
 extern "C" int64_t arrow_batch(const int64_t* cols, const int64_t* ops,
                                const int64_t* order, int64_t nout, int64_t rows,
                                ArrowArray* out) {
+  Stamp stamp_;
   ArBatch* t = new ArBatch;
   t->bytes.need(64);
   std::vector<int> columns;
@@ -864,6 +907,7 @@ extern "C" int64_t arrow_batch(const int64_t* cols, const int64_t* ops,
 
 // an ArrowArray that nothing imported: what arrow_batch made is given back
 extern "C" void arrow_batch_release(ArrowArray* a) {
+  Stamp stamp_;
   if (a->release) a->release(a);
 }
 
@@ -881,6 +925,7 @@ extern "C" void points_in_polygon_cpp(
     const double* verts /* [total_verts, 2] */,
     const int64_t* ring_offsets, int64_t n_rings,
     const int32_t* ring_part, uint8_t* out) {
+  Stamp stamp_;
 #pragma omp parallel for schedule(static) if (n > 16384)
   for (int64_t i = 0; i < n; ++i) {
     const double x = px[i], y = py[i];
@@ -1038,6 +1083,7 @@ extern "C" int64_t zranges_cpp(int32_t dims, int32_t bits_per_dim, int64_t nbox,
                     int64_t max_ranges, int64_t max_recurse,
                     uint64_t* out_lo, uint64_t* out_hi, uint8_t* out_cont,
                     int64_t cap) {
+  Stamp stamp_;
   ZCurveOps ops = dims == 2 ? ZCurveOps{2, bits_per_dim, split2, combine2}
                             : ZCurveOps{3, bits_per_dim, split3, combine3};
   int total = dims * bits_per_dim;
@@ -1183,6 +1229,7 @@ extern "C" int64_t zranges_each_cpp(int32_t dims, int32_t bits_per_dim, int64_t 
                     int64_t max_ranges, int64_t max_recurse,
                     uint64_t* out_lo, uint64_t* out_hi, uint8_t* out_cont,
                     int64_t* counts, int64_t cap) {
+  Stamp stamp_;
   int64_t total = 0, stride = nbox * dims;
   for (int64_t q = 0; q < nq; ++q) {
     int64_t n = zranges_cpp(dims, bits_per_dim, nbox,
@@ -1207,6 +1254,7 @@ extern "C" int64_t zranges_each_cpp(int32_t dims, int32_t bits_per_dim, int64_t 
 
 extern "C" int64_t bitmask_count(const int32_t* wide, int64_t n_real,
                                  int64_t pack) {
+  Stamp stamp_;
   const uint32_t* w = (const uint32_t*)wide;
   int64_t words = n_real * pack * 128;
   int64_t total = 0;
@@ -1219,6 +1267,7 @@ extern "C" int64_t bitmask_decode_pair(const int32_t* wide,
                                        const int64_t* bids, int64_t n_real,
                                        int64_t pack, int64_t block,
                                        int64_t* rows_out, uint8_t* cert_out) {
+  Stamp stamp_;
   const uint32_t* w = (const uint32_t*)wide;
   const uint32_t* in = (const uint32_t*)inner;
   int64_t k = 0;
@@ -1259,6 +1308,7 @@ extern "C" int64_t merge_rows_spans(const int64_t* span_lo,
                                     const int64_t* rows, const uint8_t* cert,
                                     int64_t n_rows, int64_t* out_rows,
                                     uint8_t* out_cert) {
+  Stamp stamp_;
   int64_t k = 0, r = 0;
   for (int64_t s = 0; s < n_spans; ++s) {
     const int64_t lo = span_lo[s], hi = span_hi[s];  // [lo, hi)
@@ -1292,6 +1342,7 @@ extern "C" int64_t merge_rows_spans(const int64_t* span_lo,
 
 extern "C" void counting_argsort(const int32_t* keys, int64_t n,
                                  int64_t n_buckets, uint32_t* perm) {
+  Stamp stamp_;
   std::vector<int64_t> offsets(static_cast<size_t>(n_buckets) + 1, 0);
   for (int64_t i = 0; i < n; ++i) ++offsets[keys[i] + 1];
   for (int64_t b = 0; b < n_buckets; ++b) offsets[b + 1] += offsets[b];
@@ -1303,6 +1354,7 @@ extern "C" void counting_argsort(const int32_t* keys, int64_t n,
 extern "C" int64_t bitmask_decode(const int32_t* wide, const int64_t* bids,
                                   int64_t n_real, int64_t pack, int64_t block,
                                   int64_t* rows_out) {
+  Stamp stamp_;
   const uint32_t* w = (const uint32_t*)wide;
   int64_t k = 0;
   for (int64_t blk = 0; blk < n_real; ++blk) {
@@ -1338,6 +1390,7 @@ extern "C" int64_t bitmask_decode(const int32_t* wide, const int64_t* bids,
 extern "C" void xz_index(const double* lo, const double* hi, int64_t n,
                          int32_t dims, int32_t g, const int64_t* subtree,
                          int64_t* out) {
+  Stamp stamp_;
 #ifdef _OPENMP
 #pragma omp parallel for schedule(static)
 #endif
@@ -1403,6 +1456,7 @@ extern "C" int64_t xz_ranges(int32_t dims, int32_t g, const int64_t* subtree,
                              int64_t max_ranges, uint64_t* out_lo,
                              uint64_t* out_hi, uint8_t* out_cont,
                              int64_t cap) {
+  Stamp stamp_;
   if (dims > 4) return -1;
   const int32_t children = 1 << dims;
   std::vector<XzCell> level_cells, nxt;
@@ -1531,3 +1585,21 @@ extern "C" int64_t xz_ranges(int32_t dims, int32_t g, const int64_t* subtree,
   }
   return (int64_t)merged.size();
 }
+
+// ----------------------------------------------------------- the lock probe
+// ``nap``: what obs/trace.py's hand-off probe sleeps in: a native call
+// that lets the interpreter lock go for ``seconds`` as any call here does,
+// so that its return stamp is the moment the sleep ended, the timer's
+// slack outside the sample.
+extern "C" void nap(double seconds) {
+  Stamp stamp_;
+  if (seconds <= 0) return;
+  struct timespec ts;
+  ts.tv_sec = (time_t)seconds;
+  ts.tv_nsec = (long)((seconds - (double)ts.tv_sec) * 1e9);
+  clock_nanosleep(CLOCK_MONOTONIC, 0, &ts, nullptr);
+}
+
+// The calling thread's last stamps. No guard: they read what it wrote.
+extern "C" double stamp_entry() { return g_stamp_entry; }
+extern "C" double stamp_return() { return g_stamp_return; }

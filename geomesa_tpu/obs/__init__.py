@@ -9,7 +9,12 @@ Three surfaces over one substrate:
   the write path (micro-flush stages, WAL append/fsync, fold slices),
   retained in a bounded ``TraceBuffer`` and exportable as Chrome
   trace-event JSON (``DataStore.dump_trace``). An always-on slow-query
-  log captures span trees over ``geomesa.obs.slow.ms``.
+  log captures span trees over ``geomesa.obs.slow.ms``. Beside the spans,
+  two process-wide records of what belongs to no span: the runtime's
+  stalls (``stalls``, ``stall_totals``: the collector's pauses, the
+  compiler's phases) and the interpreter lock (``lock_probe``: what a
+  hand-off of the lock costs, sampled by one probe thread; ``lock_cpu``:
+  the CPU seconds of the program's threads by role).
 - **live histograms** (:class:`~geomesa_tpu.metrics.Histogram`): the
   hot-path latencies record into fixed-log-bucket histograms, so "query
   p99 right now" reads straight off ``MetricsRegistry``.
@@ -44,6 +49,8 @@ from geomesa_tpu.obs.trace import (
     Tracer,
     event,
     install,
+    lock_cpu,
+    lock_probe,
     phase_breakdown,
     span,
     stall_totals,
@@ -66,6 +73,8 @@ __all__ = [
     "error_factor",
     "event",
     "install",
+    "lock_cpu",
+    "lock_probe",
     "ops_report",
     "phase_breakdown",
     "span",

@@ -12,7 +12,7 @@ already computes in-process (docs/observability.md "The ops plane"):
 | ``/stats`` | per-type StatsStore sketches as JSON |
 | ``/debug/slow?type=&n=`` | the slow-query ring (filterable) |
 | ``/debug/trace`` | Chrome trace-event export of retained traces |
-| ``/debug/stalls?n=`` | the runtime-stall ring (collections, compiles) |
+| ``/debug/stalls?n=`` | ``stalls``: the runtime-stall ring (collections, compiles); ``lock``: the interpreter lock's probe and CPU ledger |
 | ``/debug/vars?window=`` | TelemetryRecorder time-series rings |
 | ``/debug/audit?n=`` | the audit ring (trace-id cross-referenced) |
 
@@ -73,6 +73,7 @@ from urllib.parse import parse_qs, urlparse
 
 from geomesa_tpu import conf
 from geomesa_tpu.metrics import _prom, resolve
+from geomesa_tpu.obs.trace import as_role
 
 #: pending hot-tier rows over this multiple of the fold threshold flag
 #: ``hot.occupancy`` — the overlay outgrew what one fold was sized to
@@ -154,7 +155,8 @@ class TelemetryRecorder:
             # must not see it and exit before its first sample
             self._stop.clear()
             self._thread = threading.Thread(
-                target=self._loop, name="geomesa-telemetry", daemon=True
+                target=as_role("ops", self._loop), name="geomesa-telemetry",
+                daemon=True,
             )
             self._thread.start()
         return self
@@ -441,11 +443,17 @@ class OpsRoutes:
                 tracer().chrome_payload()
             )
         if path == "/debug/stalls":
-            from geomesa_tpu.obs.trace import stalls
+            from geomesa_tpu.obs import trace
 
-            ring = stalls()
+            ring = trace.stalls()
             n = int(_first(query, "n") or 0)
-            return 200, "application/json", _json_dump(ring[-n:] if n > 0 else ring)
+            return 200, "application/json", _json_dump({
+                "stalls": ring[-n:] if n > 0 else ring,
+                "lock": {
+                    "probe": trace.lock_probe(newest=n if n > 0 else 100),
+                    "cpu": trace.lock_cpu(),
+                },
+            })
         if path == "/debug/vars":
             window = _first(query, "window")
             return 200, "application/json", _json_dump(
@@ -467,11 +475,12 @@ class OpsRoutes:
 
 
 def runtime_families() -> str:
-    """The process's runtime stalls as Prometheus families, read from
-    ``obs.trace``'s totals as the scrape renders (the hooks push
-    nothing: the collector's may not touch a registry). They are the
-    process's, so every store's ``/metrics`` shows the same."""
-    from geomesa_tpu.obs.trace import stall_totals
+    """The process's runtime stalls and its interpreter lock's record as
+    Prometheus families, read from ``obs.trace``'s totals as the scrape
+    renders (the hooks and the probe push nothing: the collector's may
+    not touch a registry). They are the process's, so every store's
+    ``/metrics`` shows the same."""
+    from geomesa_tpu.obs.trace import lock_cpu, lock_probe, stall_totals
 
     tot = stall_totals()
     lines: list = []
@@ -500,6 +509,14 @@ def runtime_families() -> str:
              for ph in ("trace", "lower", "backend")])
     counter("geomesa.runtime.compile.programs", [("", sum(p["calls"] for p in programs))])
     counter("geomesa.query.compiled", [("", tot["compiled"])])
+    # the interpreter lock: "is this server out of interpreter" is two
+    # rates, the probe's wait a sample and the roles' CPU seconds a second
+    probe, cpu = lock_probe(), lock_cpu()
+    counter("geomesa.runtime.lock.handoff.seconds", [("", probe["sum_s"])])
+    counter("geomesa.runtime.lock.handoff.samples", [("", probe["n"])])
+    gauge("geomesa.runtime.lock.handoff.max_seconds", probe["max_s"])
+    counter("geomesa.runtime.cpu.seconds",
+            [(f'{{role="{role}"}}', s) for role, s in sorted(cpu["cpu_s"].items())])
     return "\n".join(lines) + "\n"
 
 
@@ -537,8 +554,8 @@ class OpsServer:
     def start(self) -> "OpsServer":
         if self._thread is None:
             self._thread = threading.Thread(
-                target=self._httpd.serve_forever, name="geomesa-ops",
-                daemon=True,
+                target=as_role("ops", self._httpd.serve_forever),
+                name="geomesa-ops", daemon=True,
             )
             self._thread.start()
             self.recorder.start()
@@ -575,6 +592,10 @@ class _Httpd(ThreadingHTTPServer):
     # EADDRINUSE while the old socket lingers in TIME_WAIT
     allow_reuse_address = True
     daemon_threads = True
+    # a scrape's thread is ``ops`` in the CPU ledger
+    process_request_thread = as_role(
+        "ops", ThreadingHTTPServer.process_request_thread
+    )
 
 
 def _handler_class(server: OpsServer):

@@ -52,6 +52,23 @@ is built. A collection stops every thread, so ``Tracer.end`` stamps
 ``gc_wait_s`` on each root it finishes: the seconds of the ring's pauses
 that overlap it.
 
+**The interpreter lock** (docs/observability.md "The interpreter
+lock"): a second process-wide record under the stall record's rules
+(:func:`lock_probe`, :func:`lock_cpu`; ``install`` and ``reset`` leave
+it, :func:`clear_lock` empties it), written by ONE daemon thread,
+``geomesa-lockprobe``: it sleeps ``PROBE_PERIOD_S`` in a native call
+that lets the lock go as any native call does and samples what it then
+waited to hold the lock again, and four times a second it reads the CPU
+clock of every thread that named its role (:func:`name_role`). The
+thread starts with the first RETAINED root or with
+``DataStore.serve_ops``; without either it never exists. On a request's
+path the program counts the places where it lets the lock go itself
+(``handoffs`` on the active span, :func:`add`), the native tier stamps
+its calls (``native_s``, ``reacquire_s``, ``native_n``:
+``native._call``), and the roots the ``with`` form opens read their
+thread's CPU clock like ``plan`` does (``cpu_s``), all three of the last
+on retained traces only.
+
 Locking: ``Tracer._lock`` (LOCKS rank 76, hot) guards only the
 retention rings and the sampling counter — it is taken once per root
 begin/end, never per child span (children append to their trace's own
@@ -61,7 +78,9 @@ spans are safe to close while arbitrary store locks are held. The
 stall record has NO lock: the collector's hook runs wherever this thread
 happens to be, under any lock it holds (``MetricsRegistry``'s and
 ``Tracer._lock`` included), so it appends to structures that need none
-and calls no registry and no logger.
+and calls no registry and no logger. The lock record has none either:
+its rings and totals have one writer, the probe's thread; a thread that
+names or retires its role makes one atomic store, pop or append.
 """
 
 from __future__ import annotations
@@ -653,6 +672,272 @@ def _stall_events(pid: int) -> list[dict]:
     return out
 
 
+# -- the interpreter lock ----------------------------------------------------
+
+#: what the probe sleeps between two samples: a hundred acquisitions a second
+PROBE_PERIOD_S = 0.010
+#: samples the probe's ring keeps: 164 s of them (a 30 s window is 3,000)
+PROBE_RING = 16384
+#: the CPU ledger is read every this many wake-ups, four times a second:
+#: twenty threads' clocks at 6 us a read are 0.05% of the lock at that rate
+LEDGER_EVERY = 25
+#: ledger samples kept: eight minutes
+LEDGER_RING = 2048
+
+
+class _LockRecord:
+    """What the probe's thread writes, and nobody else: the sample ring
+    and its totals, the ledger ring, and ``retired`` (the seconds of the
+    threads that ended, by role). ``threads`` holds one entry a thread
+    that named its role, ``{native id: [role, CPU clock id, base, last]}``
+    (``base``: the clock's reading that counts as 0, None until the probe
+    first sees a thread that named itself late; ``last``: the newest
+    reading less the base), stored by the thread itself in one step, and
+    ``ended`` what a thread leaves when it retires, ``(native id, role,
+    seconds)``: the probe drains it. No lock. :func:`clear_lock` swaps in
+    a fresh instance, which the running probe notices and ends on."""
+
+    __slots__ = ("probe", "n", "sum_s", "max_s", "exact", "cpu", "threads",
+                 "ended", "retired", "gate", "thread")
+
+    def __init__(self, threads: Optional[dict] = None):
+        self.probe = TraceBuffer(PROBE_RING)  # (t, wait_s)
+        self.n = 0
+        self.sum_s = 0.0
+        self.max_s = 0.0
+        self.exact = None  # True: the native nap's stamp; False: sleep's overshoot
+        self.cpu = TraceBuffer(LEDGER_RING)  # (t, {role: cpu_s}, process_s, {role: threads})
+        self.threads: dict = {} if threads is None else threads
+        self.ended: deque = deque()
+        self.retired: dict = {}
+        self.gate = itertools.count()  # whoever draws 0 starts the thread
+        self.thread = None
+
+
+_lock = _LockRecord()
+
+
+def name_role(role: str, late: bool = False) -> None:
+    """This thread's role in the CPU ledger, named by the thread itself
+    as it starts: ``handler``, ``dispatcher``, ``flush``, ``wal``,
+    ``replica``, ``ops``, ``probe``; ``caller`` is what :meth:`Tracer.begin`
+    names a thread that opens a root without a role while a probe runs
+    (``late``: its CPU so far is not the program's, so the ledger counts
+    it from the probe's first sight of it). One dict store: the probe
+    reads the clock from outside, so nothing is read here or on a
+    request's path."""
+    _tls.role = role
+    _lock.threads[threading.get_native_id()] = [
+        role, time.pthread_getcpuclockid(threading.get_ident()),
+        None if late else 0.0, 0.0,
+    ]
+
+
+def retire_role() -> None:
+    """A thread that ends folds its last reading into its role's retired
+    seconds first (a closed connection's handler): one clock read at the
+    thread's end, and only once a probe reads the ledger. A thread that
+    ends without it keeps the seconds the probe last read."""
+    rec = _lock
+    key = threading.get_native_id()
+    ent = rec.threads.get(key)
+    if ent is None:
+        return
+    if rec.thread is not None:
+        cpu = time.thread_time()
+        base = cpu if ent[2] is None else ent[2]
+        rec.ended.append((key, ent[0], cpu - base))  # before the pop: see _read_ledger
+    rec.threads.pop(key, None)
+    _tls.role = None
+
+
+def as_role(role: str, fn: Callable) -> Callable:
+    """``fn`` as a thread's target that names ``role`` first and retires
+    it last."""
+    def run(*args, **kwargs):
+        name_role(role)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            retire_role()
+
+    return run
+
+
+def _read_ledger(rec: _LockRecord, now: float) -> None:
+    """One ledger sample: every registered thread's CPU clock, read from
+    the probe's thread, summed by role over the retired seconds. The
+    order makes a retiring thread count once: the live threads are listed
+    first, the retirements drained second (a thread appends before it
+    pops, so one that is in neither was never listed), and a listed
+    thread that retired meanwhile is skipped."""
+    while True:
+        try:
+            live = list(rec.threads.items())
+            break
+        except RuntimeError:  # a thread named itself meanwhile
+            continue
+    retired, gone = rec.retired, set()
+    while rec.ended:
+        key, role, cpu = rec.ended.popleft()
+        gone.add(key)
+        retired[role] = retired.get(role, 0.0) + cpu
+    by_role, n_threads = dict(retired), {}
+    for key, ent in live:
+        if key in gone:
+            continue
+        role = ent[0]
+        try:
+            cpu = time.clock_gettime(ent[1])
+        except OSError:  # ended without retiring: its last reading stands
+            if rec.threads.pop(key, None) is not None:
+                retired[role] = retired.get(role, 0.0) + ent[3]
+        else:
+            if ent[2] is None:
+                ent[2] = cpu
+            ent[3] = cpu - ent[2]
+            n_threads[role] = n_threads.get(role, 0) + 1
+        by_role[role] = by_role.get(role, 0.0) + ent[3]
+    rec.cpu.append((now, by_role, time.process_time(), n_threads))
+
+
+def _probe_loop(rec: _LockRecord) -> None:
+    """The probe's thread. Opens no span and writes no profiler event
+    (``idle_named_pct`` counts every ``geomesa:`` one)."""
+    from geomesa_tpu import native
+
+    nap, perf, ring, k = native.nap, time.perf_counter, rec.probe, 0
+    _read_ledger(rec, perf())
+    while _lock is rec:
+        t0 = perf()
+        wait = nap(PROBE_PERIOD_S)
+        now = perf()
+        rec.exact = wait is not None
+        if wait is None:  # no native tier: the overshoot of ``time.sleep``
+            time.sleep(PROBE_PERIOD_S)
+            now = perf()
+            wait = max(now - t0 - PROBE_PERIOD_S, 0.0)
+        ring.append((now, wait))
+        rec.n += 1
+        rec.sum_s += wait
+        if wait > rec.max_s:
+            rec.max_s = wait
+        k += 1
+        if k % LEDGER_EVERY == 0:
+            _read_ledger(rec, now)
+
+
+def arm_lock_probe() -> None:
+    """Start ``geomesa-lockprobe``, once a record: the first retained
+    root and ``DataStore.serve_ops`` call it; nothing else does, so a
+    process that keeps no trace and serves no ops plane has no such
+    thread."""
+    rec = _lock
+    if rec.thread is None and next(rec.gate) == 0:
+        rec.thread = th = threading.Thread(
+            target=as_role("probe", _probe_loop), args=(rec,),
+            name="geomesa-lockprobe", daemon=True,
+        )
+        th.start()
+
+
+def _quantile(ordered: list, q: float) -> float:
+    return ordered[min(int(q * len(ordered)), len(ordered) - 1)] if ordered else 0.0
+
+
+def lock_probe(t_lo: Optional[float] = None, t_hi: Optional[float] = None,
+               newest: int = 0) -> dict:
+    """What a thread of this process waited to hold the interpreter lock
+    again after a native call that released it, as the probe sampled it:
+    ``{"n", "sum_s", "p50_s", "p95_s", "max_s", "exact"}``. With no bound:
+    the totals since the probe started (the percentiles are the ring's).
+    With one: the samples taken inside ``[t_lo, t_hi)`` on
+    ``time.perf_counter``, from the ring (the newest ``PROBE_RING``).
+    ``exact``: True where a sample is the native nap's own stamp against
+    ``perf_counter``, False where it is the overshoot of ``time.sleep``
+    (no native tier; timer slack included), None before the first.
+    ``newest`` > 0 adds ``samples``: that many of the cut's newest,
+    ``{"t", "wait_s"}``, oldest first."""
+    rec = _lock
+    lo = float("-inf") if t_lo is None else t_lo
+    hi = float("inf") if t_hi is None else t_hi
+    cut = [s for s in rec.probe.items() if lo <= s[0] < hi]
+    waits = sorted(w for _, w in cut)
+    if t_lo is None and t_hi is None:
+        out = {"n": rec.n, "sum_s": rec.sum_s, "max_s": rec.max_s}
+    else:
+        out = {"n": len(waits), "sum_s": sum(waits), "max_s": waits[-1] if waits else 0.0}
+    out.update(p50_s=_quantile(waits, 0.5), p95_s=_quantile(waits, 0.95), exact=rec.exact)
+    if newest > 0:
+        out["samples"] = [{"t": t, "wait_s": w} for t, w in cut[-newest:]]
+    return out
+
+
+def _ledger_at(ring: list, t: float):
+    """({role: cpu_s}, process_s) at ``t``, interpolated between the two
+    samples round it (the first or the last outside them). A role that
+    the earlier sample lacks began with the later one's reading."""
+    if t <= ring[0][0]:
+        return ring[0][1], ring[0][2]
+    if t >= ring[-1][0]:
+        return ring[-1][1], ring[-1][2]
+    lo, hi = 0, len(ring) - 1
+    while hi - lo > 1:
+        mid = (lo + hi) // 2
+        if ring[mid][0] <= t:
+            lo = mid
+        else:
+            hi = mid
+    (ta, a, pa, _), (tb, b, pb, _) = ring[lo], ring[hi]
+    f = (t - ta) / (tb - ta)
+    return (
+        {r: a.get(r, vb) + f * (vb - a.get(r, vb)) for r, vb in b.items()},
+        pa + f * (pb - pa),
+    )
+
+
+def lock_cpu(t_lo: Optional[float] = None, t_hi: Optional[float] = None) -> dict:
+    """The CPU ledger by thread role: ``{"cpu_s": {role: seconds},
+    "threads": {role: live threads}, "process_s", "samples"}``. With no
+    bound: the newest sample (seconds since each thread began, ended
+    threads included; ``process_s`` is ``time.process_time``). With
+    bounds: the CPU seconds BETWEEN them on ``time.perf_counter``,
+    interpolated between the ledger samples on either side of each
+    (a quarter of a second apart), ``threads`` as the newest sample at or
+    before ``t_hi`` has them, ``samples`` the ledger samples inside.
+    Exact over a window whatever the thread clock's tick: a 10 ms tick is
+    0.03% of 30 s. Empty (``samples`` 0) before the probe's first
+    reading."""
+    ring = _lock.cpu.items()
+    if not ring:
+        return {"cpu_s": {}, "threads": {}, "process_s": 0.0, "samples": 0}
+    if t_lo is None and t_hi is None:
+        _, by_role, process_s, threads = ring[-1]
+        return {"cpu_s": dict(by_role), "threads": dict(threads),
+                "process_s": process_s, "samples": len(ring)}
+    lo = ring[0][0] if t_lo is None else t_lo
+    hi = ring[-1][0] if t_hi is None else t_hi
+    (a, pa), (b, pb) = _ledger_at(ring, lo), _ledger_at(ring, hi)
+    inside = [r for r in ring if lo <= r[0] <= hi]
+    at_hi = [r for r in ring if r[0] <= hi]
+    return {
+        "cpu_s": {r: vb - a.get(r, vb) for r, vb in b.items()},
+        "threads": dict((at_hi[-1] if at_hi else ring[0])[3]),
+        "process_s": pb - pa, "samples": len(inside),
+    }
+
+
+def clear_lock() -> None:
+    """Forget every probe sample and ledger reading and end the probe's
+    thread, which the next arming starts anew (tests; ``Tracer.reset``
+    does not). The threads' role names stay: they are facts, not
+    readings."""
+    global _lock
+    old, _lock = _lock, _LockRecord(_lock.threads)
+    if old.thread is not None:
+        old.thread.join(2.0)
+
+
 class Tracer:
     """The process tracing runtime: sampling, retention, export.
 
@@ -712,6 +997,10 @@ class Tracer:
         tr = Trace(name, retain, capture)
         if attrs:
             tr.root.annotate(**attrs)
+        if retain and _lock.thread is None:
+            arm_lock_probe()
+        if _lock.thread is not None and getattr(_tls, "role", None) is None:
+            name_role("caller", late=True)  # somebody reads the ledger
         cur = getattr(_tls, "span", None)
         if cur is not None:
             # begun inside another operation on this thread (a request's
@@ -876,7 +1165,9 @@ class _RootCtx:
             self._name, self._capture, **self._attrs
         )
         if self._trace is not None:
-            self._act = _Activation(self._trace.root._open())
+            # retained: the root reads its thread's CPU clock, so a
+            # request's whole CPU on its own thread is a pooled reading
+            self._act = _Activation(self._trace.root._open(cpu=True))
             self._act.__enter__()
         return self._trace
 
@@ -913,8 +1204,9 @@ def phase_breakdown(trace: Optional[Trace]) -> list[str]:
     """Human-readable top-level phase lines for explain trails:
     ``trace: <phase> <dur>ms`` per phase plus the covered fraction, and
     one line more where the runtime stalled the operation: what the root
-    waited for the collector (``gc_wait_s``) and what its spans spent
-    compiling (``compile_s``)."""
+    waited for the collector (``gc_wait_s``), what its spans spent
+    compiling (``compile_s``) and, where it has any, the times it let the
+    interpreter lock go (``handoffs``)."""
     if trace is None or trace.wall_s <= 0:
         return []
     lines = []
@@ -927,11 +1219,14 @@ def phase_breakdown(trace: Optional[Trace]) -> list[str]:
         f"{100.0 * covered / trace.wall_s:.1f}%"
     )
     waited = (trace.root.attrs or {}).get("gc_wait_s", 0.0)
-    compiling = sum((s.attrs or {}).get("compile_s", 0.0) for s in trace.spans)
-    if waited or compiling:
+    spans = {trace.root, *trace.spans}  # the root is among them once it has finished
+    compiling = sum((s.attrs or {}).get("compile_s", 0.0) for s in spans)
+    handoffs = sum((s.attrs or {}).get("handoffs", 0) for s in spans)
+    if waited or compiling or handoffs:
         lines.append(
             f"trace: stalled gc {waited * 1e3:.3f}ms, "
             f"compile {compiling * 1e3:.3f}ms"
+            + (f", handoffs {handoffs}" if handoffs else "")
         )
     return lines
 

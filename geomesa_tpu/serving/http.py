@@ -73,6 +73,8 @@ import numpy as np
 
 from geomesa_tpu import conf
 from geomesa_tpu.obs.trace import NULL_SPAN
+from geomesa_tpu.obs.trace import add as _oadd
+from geomesa_tpu.obs.trace import as_role as _as_role
 from geomesa_tpu.obs.trace import span as _ospan
 from geomesa_tpu.obs.trace import tracer as _otracer
 from geomesa_tpu.serving.scheduler import ServingRejected
@@ -178,8 +180,8 @@ class DataServer:
     def start(self) -> "DataServer":
         if self._thread is None:
             self._thread = threading.Thread(
-                target=self._httpd.serve_forever, name="geomesa-serve",
-                daemon=True,
+                target=_as_role("handler", self._httpd.serve_forever),
+                name="geomesa-serve", daemon=True,
             )
             self._thread.start()
             self.ops.recorder.start()
@@ -466,6 +468,7 @@ class DataServer:
                 )
                 sp.event("future")
                 fc = fut.result()
+                sp.add("handoffs", 1)  # blocked until the dispatcher resolved it
         if limit is not None and len(fc) > limit:
             fc = fc.take(np.arange(limit))
         return fc
@@ -494,6 +497,8 @@ class DataServer:
                 chunks += 1
             wfile.write(b"0\r\n\r\n")
             if timed:
+                # every write to the socket lets the interpreter lock go
+                sp.add("handoffs", chunks + 1)
                 sp.annotate(bytes=sent, chunks=chunks, write_s=write_s)
                 native = getattr(payload, "native", None)
                 if native is not None:  # a GeoJSON answer: which route
@@ -540,8 +545,9 @@ class DataServer:
                 413, f"body {length} over the "
                 f"{self.max_body_bytes}-byte bound"
             )
-        with _ospan("ingest.read", bytes=length):
+        with _ospan("ingest.read", bytes=length) as sp:
             body = rfile.read(length)
+            sp.add("handoffs", 1)
         try:
             with _ospan("ingest.parse"):
                 fc = self._parse_ingest(type_name, body, headers)
@@ -620,6 +626,11 @@ class _Httpd(ThreadingHTTPServer):
     # not trip over the old socket's TIME_WAIT (same fix as obs/ops.py)
     allow_reuse_address = True
     daemon_threads = True
+    # a connection's thread is ``handler`` in the CPU ledger, and leaves
+    # its seconds there when the connection closes
+    process_request_thread = _as_role(
+        "handler", ThreadingHTTPServer.process_request_thread
+    )
 
 
 def _handler_class(server: DataServer):
@@ -640,6 +651,7 @@ def _handler_class(server: DataServer):
                         self.send_header(k, v)
                     self.send_header("Transfer-Encoding", "chunked")
                     self.end_headers()
+                    _oadd("handoffs", 1)  # the headers' write
                     return server.write_chunks(payload, self.wfile)
                 body = payload.encode() if isinstance(payload, str) else payload
                 self.send_response(code)
@@ -649,6 +661,7 @@ def _handler_class(server: DataServer):
                 self.send_header("Content-Length", str(len(body)))
                 self.end_headers()
                 self.wfile.write(body)
+                _oadd("handoffs", 2)  # the headers' write and the body's
                 return len(body)
             except (BrokenPipeError, ConnectionResetError):
                 return 0  # client went away mid-response

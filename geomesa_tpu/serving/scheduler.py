@@ -192,8 +192,11 @@ class QueryScheduler:
             if self._closed:
                 raise RuntimeError("scheduler is closed")
             if self._thread is None:
+                from geomesa_tpu.obs.trace import as_role
+
                 self._thread = threading.Thread(
-                    target=self._loop, name="geomesa-serving", daemon=True
+                    target=as_role("dispatcher", self._loop),
+                    name="geomesa-serving", daemon=True,
                 )
                 self._thread.start()
         return self

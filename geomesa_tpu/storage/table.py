@@ -371,13 +371,15 @@ def _await_device(arrays) -> bool:
     two: segment ``wait`` until the device has the result (the kernel and
     whatever queued before it), then ``pull``, which the caller's
     ``device_get`` fills (the rest of the copy started at dispatch).
-    Untraced, nothing: ``device_get`` waits for both, as it always did."""
+    Untraced, nothing: ``device_get`` waits for both, as it always did.
+    Both let the interpreter lock go: two ``handoffs`` on the span."""
     traced = _oevent("wait")
     if traced:
         import jax
 
         jax.block_until_ready(arrays)
         _oevent("pull")
+        _oadd("handoffs", 2)
     return traced
 
 

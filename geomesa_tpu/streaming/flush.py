@@ -56,6 +56,7 @@ import numpy as np
 from geomesa_tpu import fault
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.ingest import sort as shsort
+from geomesa_tpu.obs.trace import name_role as _name_role
 from geomesa_tpu.obs.trace import span as _ospan
 from geomesa_tpu.obs.trace import tracer as _otracer
 
@@ -164,6 +165,7 @@ class StreamFlusher:
                 self._pool = ThreadPoolExecutor(
                     max_workers=max(2, self.config.resolved_workers()),
                     thread_name_prefix="geomesa-stream",
+                    initializer=_name_role, initargs=("flush",),
                 )
             return self._pool
 

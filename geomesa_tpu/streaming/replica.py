@@ -60,6 +60,7 @@ from collections import deque
 
 from geomesa_tpu import conf, fault
 from geomesa_tpu.filter.predicates import INCLUDE
+from geomesa_tpu.obs.trace import as_role as _as_role
 from geomesa_tpu.streaming.wal import (
     _frame, _parse_frames, WalConfig, WalError, WriteAheadLog,
 )
@@ -426,7 +427,8 @@ class SegmentShipper:
         if self._thread is None:
             self._stop.clear()
             self._thread = threading.Thread(
-                target=self._loop, name="geomesa-replica-ship", daemon=True
+                target=_as_role("replica", self._loop),
+                name="geomesa-replica-ship", daemon=True,
             )
             self._thread.start()
         return self
@@ -592,7 +594,8 @@ class ReplicaStore:
         if self._thread is None:
             self._stop.clear()
             self._thread = threading.Thread(
-                target=self._loop, name="geomesa-replica-apply", daemon=True
+                target=_as_role("replica", self._loop),
+                name="geomesa-replica-apply", daemon=True,
             )
             self._thread.start()
         return self

@@ -24,6 +24,7 @@ import numpy as np
 from geomesa_tpu import fault
 from geomesa_tpu.features import FeatureCollection
 from geomesa_tpu.filter.predicates import INCLUDE
+from geomesa_tpu.obs.trace import add as _oadd
 from geomesa_tpu.obs.trace import span as _ospan
 from geomesa_tpu.streaming.cache import StreamingFeatureCache
 from geomesa_tpu.streaming.flush import StreamConfig, StreamFlusher
@@ -720,9 +721,11 @@ class LambdaStore:
                     block: bool = True) -> FeatureCollection:
         sched = getattr(self.cold, "scheduler", None)
         if sched is not None and not sched.closed:
-            return sched.submit(
+            fc = sched.submit(
                 self.type_name, f, hints=hints, block=block, tenant=tenant
             ).result()
+            _oadd("handoffs", 1)  # blocked until the dispatcher resolved it
+            return fc
         return self.cold.query(self.type_name, f, hints=hints)
 
     # -- reads -----------------------------------------------------------

@@ -82,6 +82,7 @@ import numpy as np
 from geomesa_tpu import fault
 from geomesa_tpu import geometry as geo
 from geomesa_tpu.io.varint import append_uvarint, read_uvarint
+from geomesa_tpu.obs.trace import as_role as _as_role
 from geomesa_tpu.obs.trace import span as _ospan
 
 _DIGEST_BYTES = 8
@@ -364,7 +365,7 @@ class WriteAheadLog:
             # sit unsynced indefinitely, making the documented loss
             # window unbounded instead of ~sync_interval_ms
             threading.Thread(
-                target=self._interval_loop, daemon=True,
+                target=_as_role("wal", self._interval_loop), daemon=True,
                 name="geomesa-wal-sync",
             ).start()
 
@@ -704,6 +705,8 @@ class WriteAheadLog:
             # fsync=0: another producer's fsync had covered this record
             # (group commit); covered: records this one made durable
             sp.annotate(fsync=len(fsync_s), covered=sum(covered))
+            if fsync_s:
+                sp.add("handoffs", len(fsync_s))  # an fsync lets the lock go
         if fsync_s:
             # the durability tail is a live histogram + SLO surface:
             # only REAL fsyncs record (group-committed fast returns

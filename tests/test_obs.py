@@ -44,9 +44,12 @@ Q = "BBOX(geom, -20, -20, 20, 20)"
 @pytest.fixture(autouse=True)
 def _fresh_tracer():
     """Every test gets a fresh tracer, an empty stall record (it is the
-    process's: the Chrome export holds it) and restored knobs."""
+    process's: the Chrome export holds it), no lock probe left running by
+    the test before (a thread that retires its role reads its clock only
+    while one runs) and restored knobs."""
     obs.install(obs.Tracer())
     obs.trace.clear_stalls()
+    obs.trace.clear_lock()
     yield
     for knob in (conf.OBS_TRACE_SAMPLE, conf.OBS_SLOW_MS,
                  conf.OBS_TRACE_BUFFER, conf.OBS_SLOW_MAX):
@@ -421,8 +424,10 @@ def test_every_operation_is_one_root_with_covering_phases(op):
             assert a.t0 + a.dur_s <= b.t0 + SLACK_S, (a.name, b.name)
         for s in tr.spans:
             a = s.attrs or {}
-            # the phases that never sleep by design read the CPU clock
-            assert ("cpu_s" in a) == (s.name in ("plan", "decode", "encode"))
+            # the phases that never sleep by design read the CPU clock,
+            # and so does a root that opens and closes on one thread
+            assert ("cpu_s" in a) == (
+                s.name in ("plan", "decode", "encode") or s is tr.root)
             if "cpu_s" in a:
                 assert 0.0 <= a["cpu_s"] <= s.dur_s + SLACK_S, (s.name, a)
             segs = a.get("segments", {})
@@ -520,7 +525,7 @@ def test_profiler_annotations_only_for_retained_traces(monkeypatch):
     _arm(sample=1)
     ds.query("t", Q)
     assert live == []
-    assert len(cpu_reads) == 4  # both ends of ``plan`` and of ``decode``
+    assert len(cpu_reads) == 6  # both ends of the root, of ``plan`` and of ``decode``
     names = [n for n, _ in seen]
     for want in ("geomesa:query", "geomesa:plan", "geomesa:dispatch",
                  "geomesa:dispatch.prune", "geomesa:dispatch.enqueue",

@@ -274,9 +274,9 @@ def test_metrics_and_debug_stalls_serve_the_record():
     snap = ds.metrics.snapshot()
     assert not [k for kind in ("counters", "gauges") for k in snap[kind] if ".runtime." in k]
     code, ctype, body = routes.handle("/debug/stalls", {})
-    ring = json.loads(body)
+    ring = json.loads(body)["stalls"]  # beside ``lock``: tests/test_lock_record.py
     assert code == 200 and [r["kind"] for r in ring] == ["gc", "compile", "compile"]
-    assert json.loads(routes.handle("/debug/stalls", {"n": ["1"]})[2]) == ring[-1:]
+    assert json.loads(routes.handle("/debug/stalls", {"n": ["1"]})[2])["stalls"] == ring[-1:]
     assert "/debug/stalls" in OpsRoutes.PATHS
     ds.close()
 
@@ -469,6 +469,7 @@ def test_the_six_readers_are_entries_of_the_benchmark():
     mine = [m for m in bench["per_layer"] if m["layer"] == "host runtime"]
     assert [m["name"] for m in mine[:len(NEW)]] == NEW
     cells = {w["name"] for w in bench["workloads"]}
-    assert all(set(m["workloads"]) <= cells and len(m["workloads"]) >= 5 for m in mine)
+    assert all(set(m["workloads"]) <= cells for m in mine)
+    assert all(len(m["workloads"]) >= 5 for m in mine[:len(NEW)])
     for m in mine:
         assert os.path.exists(os.path.join(BENCH, "layer_metrics", m["name"] + ".py"))
