@@ -682,16 +682,52 @@ def _join_indexed(ds, type_name, left, predicate, idx, pts, metrics):
             per_left[k] = ordinals
     if not per_left:
         return np.zeros(0, np.int64), np.zeros(0, np.int64)
-    with _ospan("join.assemble", members=len(per_left)):
-        lo_parts = []
-        ro_parts = []
-        for k in sorted(per_left):
-            ords = per_left[k]
-            if k not in ascending:
-                # decode yields TABLE-row order; perm makes that
-                # non-monotonic in feature ordinals — sort so the
-                # documented (left, right) pair order actually holds
-                ords = np.sort(ords)
-            lo_parts.append(np.full(len(ords), k, dtype=np.int64))
-            ro_parts.append(ords)
-        return np.concatenate(lo_parts), np.concatenate(ro_parts)
+    with _ospan("join.assemble", members=len(per_left)) as sp:
+        lo, ro, moved = _assemble(per_left, ascending)
+        # every broad member of ``ascending`` answered rows: the rest came off a scan
+        sp.annotate(pairs=len(ro), sorted=len(per_left) - len(ascending), moved=moved)
+        return lo, ro
+
+
+def _assemble(per_left: dict, ascending: set):
+    """The members' rows as the answer: ``(lo, ro, pairs copied out of a
+    member's array)``, sorted by (left, right), each side allocated once
+    and every pair written into it once.
+
+    A member's rows are unique ordinals; ``ascending`` names the members
+    whose rows ascend already (the broad route's ``flatnonzero``); a scan's
+    come in TABLE-row order, which ``perm`` makes non-monotonic in feature
+    ordinals, so they are ordered here for the documented pair order to
+    hold. ONE member's array IS the answer's right side (ordered in place,
+    no pair moved); several are copied into their slices of one
+    ``np.empty`` a side and ordered there.
+
+    Every array of ``per_left`` is the join's own: the ``flatnonzero`` of
+    ``join.host``, ``ordinals[keep]`` of ``join.refine``, or what a scan's
+    ``finish()`` returned to this call alone (``perm[rows].astype(...)``,
+    storage/table.py ``_post_decode``: fresh a call, on a mesh's table and
+    under a delta tier too), so ordering one in place and handing it back
+    shares nothing with the store or a later call. Both sides are written
+    in full before the span closes: no ``np.zeros`` for member 0, whose
+    untouched pages would fault at the caller's first read instead.
+    """
+    ks = sorted(per_left)
+    if len(ks) == 1:
+        (k,) = ks
+        ro = per_left[k]
+        if k not in ascending:
+            ro.sort()
+        return np.full(len(ro), k, dtype=np.int64), ro, 0
+    total = sum(len(per_left[k]) for k in ks)
+    lo = np.empty(total, np.int64)
+    ro = np.empty(total, np.int64)
+    a = 0
+    for k in ks:
+        ords = per_left[k]
+        b = a + len(ords)
+        ro[a:b] = ords
+        if k not in ascending:
+            ro[a:b].sort()
+        lo[a:b] = k
+        a = b
+    return lo, ro, total

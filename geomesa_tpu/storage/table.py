@@ -800,6 +800,10 @@ class IndexTable(SortedKeys):
           hit of the index's spatial/temporal constraint (inner predicate or
           contained range) — the planner refines only the rest.
 
+        Both arrays are fresh at every submit and the caller's own: the
+        indexed join orders a lone member's ``ordinals`` in place and hands
+        them back as its answer (sql/join.py ``_assemble``).
+
         ``deadline``: optional ``time.monotonic()`` cutoff; the scan checks
         it at stage boundaries and raises QueryTimeout when overdue
         (reference ThreadManagement scan timeouts).

@@ -615,6 +615,23 @@ def test_joins_doc_honest():
         reg.counter(c)
     assert reg.counter_value("geomesa.join.in_cap_fallback") == 1
 
+    # PR 52: who owns what an indexed join returns, and what its assembly counts
+    from geomesa_tpu.sql import join as sj
+    from geomesa_tpu.storage import table
+
+    assert "## What an indexed join returns, and whose it is" in text
+    assert hasattr(sj, "_assemble") and "`_assemble`" in text
+    assert hasattr(table.IndexTable, "_post_decode") and "_post_decode" in text
+    src = inspect.getsource(sj._join_indexed)
+    obs_text = open(os.path.join(root, "docs", "observability.md")).read()
+    row = next(line for line in obs_text.splitlines() if line.startswith("| `join` |"))
+    assert '_ospan("join.assemble"' in src and "`join.assemble`" in text
+    for attr in ("pairs", "sorted", "moved"):
+        assert f"{attr}=" in src and f"`{attr}`" in text and f"`{attr}`" in row, attr
+    for reader in ("join_assemble_ns_pair", "join_assemble_moved_pct"):
+        assert f"`{reader}`" in obs_text, reader
+        assert os.path.exists(os.path.join(root, "benchmark", "layer_metrics", reader + ".py"))
+
 
 def test_analysis_rule_catalog_documented():
     """docs/analysis.md stays honest: every shipped rule id appears in

@@ -22,7 +22,10 @@ cell ``nyc-taxi.zone-join``) at a small size on the CPU:
     the Manhattan-like borough's whole-table pass answers alike, pair for
     pair and count for count, whether it walks the table 1,024 points at a
     time or ``filter.raster.CLASSIFY_CHUNK``, and ``join.host`` counts its
-    ``chunks`` and the points that went through them (``chunked``);
+    ``chunks`` and the points that went through them (``chunked``); PR 52:
+    ``join.assemble`` counts the answer's ``pairs``, the members it ordered
+    (``sorted``) and the pairs it copied out of a member's array (``moved``:
+    none where one member answered, whose rows are the answer);
 (e) ``generators/zone_joins.py``: every seed's round is the same multiset;
     ``join_ladder`` asks alone every borough and every neighborhood a mix
     of 8,000 requests reaches, under every seed;
@@ -67,7 +70,8 @@ def bench():
         from generators import join_ladder, zone_joins
         from harness import check, reference_join
         from harness import requests as rq
-        from layer_metrics import (join_device_pct, join_host_chunked_pct, join_polygon_us,
+        from layer_metrics import (join_assemble_moved_pct, join_assemble_ns_pair,
+                                   join_device_pct, join_host_chunked_pct, join_polygon_us,
                                    join_residue_pct)
         from ops import join
         from stores import datastore_join
@@ -77,7 +81,9 @@ def bench():
             ref=reference_join, rq=rq, op=join, stores=datastore_join,
             readers={"join_device_pct": join_device_pct, "join_polygon_us": join_polygon_us,
                      "join_residue_pct": join_residue_pct,
-                     "join_host_chunked_pct": join_host_chunked_pct})
+                     "join_host_chunked_pct": join_host_chunked_pct,
+                     "join_assemble_ns_pair": join_assemble_ns_pair,
+                     "join_assemble_moved_pct": join_assemble_moved_pct})
     finally:
         sys.path.remove(BENCH)
         for k in [k for k in sys.modules if k.split(".")[0] in BENCH_PACKAGES and k not in held]:
@@ -370,6 +376,12 @@ def test_the_joins_spans_count_its_members_and_rows(klass, bench, mix, cols, sto
         for s in refines:
             assert s.attrs["certain"] + s.attrs["uncertain"] == s.attrs["rows"] > 0
         assert len(_spans(traced, "join.assemble")) == (1 if len(answer["ids"]) else 0)
+        for s in _spans(traced, "join.assemble"):  # PR 52: what the assembly wrote, and how
+            answering = len(np.unique(answer["k"]))
+            assert s.attrs["members"] == answering and s.attrs["pairs"] == root.attrs["pairs"]
+            assert s.attrs["sorted"] <= len(refines)  # a member the scan answered, if rows stayed
+            assert 0 <= answering - s.attrs["sorted"] <= plan.attrs["host_raster"]
+            assert s.attrs["moved"] == (0 if answering == 1 else s.attrs["pairs"])
         if plan.attrs["host_raster"]:
             (host,) = _spans(traced, "join.host")
             assert host.attrs["points"] == N * plan.attrs["host_raster"]
@@ -458,8 +470,20 @@ def test_the_readers_read_the_joins_spans(bench):
              span(3, "join.refine", rows=100, certain=75, uncertain=25),
              span(4, "join.refine", rows=300, certain=300, uncertain=0),
              span(5, "join.host", members=1, points=65536, decided=60000, residue=5536,
-                  chunks=64, chunked=65536)]
+                  chunks=64, chunked=65536),
+             span(6, "join.assemble", members=3, pairs=400, sorted=2, moved=400),
+             span(7, "join", parent=None, members=1, pairs=1600),
+             span(8, "join.assemble", parent=7, members=1, pairs=1600, sorted=0, moved=0)]
+    spans[0]["attrs"]["pairs"] = 400
     view = {"spans": spans}
+    # two assemblies of 10 ms over 2,000 pairs, a fifth of them copied
+    assert bench.readers["join_assemble_ns_pair"].read(view) == pytest.approx(10_000.0)
+    assert bench.readers["join_assemble_moved_pct"].read(view) == pytest.approx(20.0)
+    for s in spans[-3::2]:  # PR 52's parent: the span and the root's pairs, no count of moves
+        s["attrs"] = {"members": s["attrs"]["members"]}
+    assert bench.readers["join_assemble_ns_pair"].read(view) == pytest.approx(10_000.0)
+    assert bench.readers["join_assemble_moved_pct"].read(view) is None
+    del spans[-3:]
     assert bench.readers["join_device_pct"].read(view) == pytest.approx(80.0)
     assert bench.readers["join_polygon_us"].read(view) == pytest.approx(500.0)
     assert bench.readers["join_residue_pct"].read(view) == pytest.approx(6.25)

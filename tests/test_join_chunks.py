@@ -16,7 +16,19 @@ nothing of the answer moves:
 (c) ``join.host`` counts ``chunks`` and ``chunked``;
 (d) a broad join's traced allocations peak under the answer, 3 B a point
     and a chunk's scratch (10 B a point at 2^20 points), where the single
-    pass peaked at 31 B a point: four f64 arrays of the table.
+    pass peaked at 31 B a point: four f64 arrays of the table;
+(e) the assembly (PR 52): an answer's pairs are written once. One member's
+    rows ARE the answer's right side (ordered in place where they came off a
+    scan) beside one ``np.full``; several members are copied into their
+    slices of one ``np.empty`` a side and ordered there. The pairs are the
+    exact code's and the earlier assembly's (a sort, a fill and two lists a
+    member, two concatenations), pair for pair, for one broad member, one
+    scan member, both mixed, members with no rows among them, both
+    predicates and a mesh-sharded store; what comes back is the caller's
+    (int64, C-contiguous, writable, shared with no later call);
+    ``join.assemble`` counts ``pairs``, ``sorted`` and ``moved``; a lone
+    member's assembly allocates one answer-sized array, several members'
+    the answer's two.
 """
 
 import tracemalloc
@@ -235,3 +247,163 @@ def test_a_broad_joins_temporaries_are_a_chunks(monkeypatch):
     allowed = lo.nbytes + ro.nbytes + 3 * n + 32 * 8 * chunk
     # the single pass peaked at 31 B a point on this store (PR 42's parent, measured)
     assert peak < allowed < 31 * n, (peak, allowed)
+
+
+# ---------------------------------------------------------- (e) the assembly
+
+
+def _as_written(per_left, ascending):
+    """``join.assemble`` as it stood before PR 52."""
+    lo_parts, ro_parts = [], []
+    for k in sorted(per_left):
+        ords = per_left[k]
+        if k not in ascending:
+            ords = np.sort(ords)
+        lo_parts.append(np.full(len(ords), k, dtype=np.int64))
+        ro_parts.append(ords)
+    return np.concatenate(lo_parts), np.concatenate(ro_parts)
+
+
+def _members(shape, seed=5, table_n=1 << 16):
+    """(per_left, ascending): members' rows as ``_join_indexed`` holds them
+    before the assembly, unique ordinals below ``table_n``; a broad
+    member's ascend (and are a ``flatnonzero``: a view that owns nothing),
+    a scan's come in another order."""
+    rng = np.random.default_rng([seed, len(shape)])
+    per_left, ascending = {}, set()
+    for k, (kind, n) in shape.items():
+        rows = rng.choice(table_n, n, replace=False).astype(np.int64)
+        if kind == "broad":
+            mask = np.zeros(table_n, bool)
+            mask[rows] = True
+            rows = np.flatnonzero(mask).astype(np.int64, copy=False)
+            ascending.add(k)
+        per_left[k] = rows
+    return per_left, ascending
+
+
+SHAPES = {
+    "one-broad": {3: ("broad", 40_000)},
+    "one-scan": {0: ("scan", 9_000)},
+    "one-scan-of-one-row": {7: ("scan", 1)},
+    "scans": {0: ("scan", 900), 1: ("scan", 1), 5: ("scan", 7_000), 9: ("scan", 30)},
+    "broads": {2: ("broad", 20_000), 4: ("broad", 50_000)},
+    "mixed": {0: ("scan", 500), 2: ("broad", 30_000), 3: ("scan", 8_000), 11: ("broad", 100)},
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_the_assembly_is_the_earlier_ones_pair_for_pair(shape):
+    per_left, ascending = _members(SHAPES[shape])
+    want_lo, want_ro = _as_written({k: v.copy() for k, v in per_left.items()}, ascending)
+    lone = per_left[min(per_left)] if len(per_left) == 1 else None
+    lo, ro, moved = sj._assemble(per_left, ascending)
+    for got, want in ((lo, want_lo), (ro, want_ro)):
+        assert got.dtype == np.int64 and got.flags.c_contiguous and got.flags.writeable
+        assert np.array_equal(got, want)
+    assert moved == (0 if lone is not None else len(ro))
+    assert (ro is lone) == (lone is not None)  # one member's rows ARE the answer
+
+
+def _polys_store(case, mesh=None):
+    """(store, left, x, y, members that answer rows): (b)'s points under
+    the left side a case asks, the stars first and the near-world one
+    last as there."""
+    ds, left, big, x, y = _store(n=30_000, seed=37)
+    geoms = left.geometries()
+    far = [jagged_star(120.0 + 9 * k, 70.0, 2.0, 6, seed=k) for k in range(3)]  # no point there
+    polys = {"one": [big], "small": [geoms[1]], "four": list(geoms),
+             "with-empties": [far[0], geoms[0], far[1], big, geoms[2], far[2]]}[case]
+    if mesh is not None:
+        from geomesa_tpu.parallel import make_mesh
+
+        sharded = DataStore(mesh=make_mesh(mesh))
+        sharded.create_schema(ds.get_schema("pts"))
+        sharded.write("pts", ds.features("pts"), check_ids=False)
+        ds = sharded
+    left = FeatureCollection.from_columns(
+        FeatureType.from_spec("polys", "*geom:Polygon:srid=4326"), np.arange(len(polys)),
+        {"geom": geo.PackedGeometryColumn.from_geometries(polys)})
+    return ds, left, polys, x, y
+
+
+def _exact(polys, x, y, predicate):
+    """The pairs by the exact code over every point, sorted by (left, right)."""
+    lo, ro = [], []
+    for k, g in enumerate(polys):
+        inside = geo.points_in_polygon(x, y, g)
+        if predicate == "intersects":
+            inside |= geo.points_on_boundary(x, y, g)
+        ro.append(np.flatnonzero(inside))
+        lo.append(np.full(len(ro[-1]), k, dtype=np.int64))
+    return np.concatenate(lo), np.concatenate(ro)
+
+
+#: case: (left side, geomesa.join.broad.fraction, mesh devices, members ordered at assembly,
+#: members that answer)
+JOINS = {
+    "one-broad": ("one", 0.2, None, 0, 1),
+    "one-scan": ("one", 2.0, None, 1, 1),
+    "one-small-scan": ("small", 0.2, None, 1, 1),
+    "mixed": ("four", 0.2, None, 3, 4),
+    "scans": ("four", 2.0, None, 4, 4),
+    "with-empties": ("with-empties", 0.2, None, 2, 3),
+    "mesh-mixed": ("four", 0.2, 4, 3, 4),
+    "mesh-one-scan": ("one", 2.0, 4, 1, 1),
+}
+
+
+@pytest.mark.parametrize("predicate", ["contains", "intersects"])
+@pytest.mark.parametrize("case", sorted(JOINS))
+def test_a_joins_pairs_are_the_exact_ones_and_the_callers_own(case, predicate, traced):
+    which, fraction, mesh, n_sorted, answering = JOINS[case]
+    ds, left, polys, x, y = _polys_store(which, mesh)
+    conf.JOIN_BROAD_FRACTION.set(fraction)
+    want_lo, want_ro = _exact(polys, x, y, predicate)
+    assert len(np.unique(want_lo)) == answering
+    lo, ro = sj.spatial_join_indexed(ds, "pts", left, predicate)
+    tr = traced.traces()[-1]
+    (span,) = [s for s in tr.spans if s.name == "join.assemble"]
+    assert span.attrs["members"] == answering and span.attrs["sorted"] == n_sorted
+    assert span.attrs["pairs"] == tr.root.attrs["pairs"] == len(want_ro) > 0
+    assert span.attrs["moved"] == (0 if answering == 1 else len(want_ro))
+    hosts = [s for s in tr.spans if s.name == "join.host"]
+    assert len(hosts) == (1 if answering > n_sorted else 0)
+    for got, want in ((lo, want_lo), (ro, want_ro)):
+        assert got.dtype == np.int64 and got.flags.c_contiguous and got.flags.writeable
+        assert np.array_equal(got, want)
+    # the arrays are the caller's: overwritten, they change no later answer,
+    lo[:] = -1
+    ro[:] = -7
+    again = sj.spatial_join_indexed(ds, "pts", left, predicate)
+    assert np.array_equal(again[0], want_lo) and np.array_equal(again[1], want_ro)
+    assert not np.shares_memory(again[1], ro) and not np.shares_memory(again[0], lo)
+    # nor a row of the store, and an untraced answer is the traced one
+    col = ds.features("pts").geom_column
+    assert np.array_equal(col.x, x) and np.array_equal(col.y, y)
+    conf.OBS_TRACE_SAMPLE.clear()
+    n_traces = len(traced.traces())
+    plain = sj.spatial_join_indexed(ds, "pts", left, predicate)
+    assert len(traced.traces()) == n_traces
+    assert np.array_equal(plain[0], want_lo) and np.array_equal(plain[1], want_ro)
+
+
+@pytest.mark.parametrize("shape,arrays", [("one-broad", 1), ("one-scan", 1), ("mixed", 2),
+                                          ("scans", 2)])
+def test_an_assembly_allocates_the_answer_once(shape, arrays):
+    """A lone member: one answer-sized array (the left side; its rows are
+    the right). Several: the answer's two, and no member's copy, fill or
+    list of parts beside them (the earlier assembly peaked at four a lone
+    member, three and a member's sort several)."""
+    per_left, ascending = _members({k: (kind, 16 * n) for k, (kind, n) in SHAPES[shape].items()},
+                                   table_n=1 << 20)
+    answer = 8 * sum(len(v) for v in per_left.values())
+    tracemalloc.start()
+    try:
+        before, _ = tracemalloc.get_traced_memory()
+        lo, ro, _ = sj._assemble(per_left, ascending)
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert lo.nbytes == ro.nbytes == answer > 1_000_000
+    assert peak - before < arrays * answer + (16 << 10), (peak - before, answer)
