@@ -1782,8 +1782,31 @@ class DataStore:
         if self.metrics is not None:
             self.metrics.counter("geomesa.query.vis_fallback")
 
+    def _device_agg_ok(self, type_name: str, eligible: bool, explain,
+                       what: str) -> bool:
+        """May an aggregation whose device path is otherwise ``eligible``
+        take it? Never under row-level visibility (:meth:`_vis_active`).
+        There the operation's root span says what that cost THIS request
+        (docs/observability.md): ``vis_fallback`` 1 where visibility alone
+        took the device path away, which is also what
+        :meth:`_note_vis_fallback` counts and explains, 0 where the path
+        was not eligible anyway; 1 holds once any path of the root lost
+        (a ``density_many`` of several grids, a ``bounds`` that asks the
+        raster tier and then the bounds kernel). A store without auths,
+        or a type without a label field, writes nothing."""
+        if not self._vis_active(type_name):
+            return eligible
+        cur = _otracer().current()
+        if cur is not None:
+            root = cur.trace.root
+            if eligible or "vis_fallback" not in (root.attrs or {}):
+                root.annotate(vis_fallback=int(eligible))
+        if eligible:
+            self._note_vis_fallback(explain, what)
+        return False
+
     # -- raster aggregation push-down (PR 6 leftover; docs/joins.md) -----
-    def _raster_agg_eligible(self, type_name: str, plan) -> bool:
+    def _raster_agg_eligible(self, type_name: str, plan, explain=None) -> bool:
         """Whether a plan may take the raster aggregation path: a polygon
         config carrying a raster-interval stack whose row-scan mask
         decides the filter (full/out cells + certainty vector), on a
@@ -1797,14 +1820,16 @@ class DataStore:
 
         cfg = plan.config
         sft = self._schemas[type_name]
-        return (
+        eligible = (
             plan.index is not None
             and cfg is not None
             and not cfg.disjoint
             and cfg.rast is not None
             and sft.is_points
-            and not self._vis_active(type_name)
             and mask_decides_filter(plan.filter, cfg, sft)
+        )
+        return self._device_agg_ok(
+            type_name, eligible, explain, "raster aggregation"
         )
 
     def _raster_agg_scan(self, type_name: str, plan, explain=None):
@@ -1964,10 +1989,9 @@ class DataStore:
                     for_aggregation=True,
                 )
             )
-            device_ok = fast_eligible and not self._vis_active(type_name)
-            if not device_ok:
-                if fast_eligible:  # only visibility blocked the fast path
-                    self._note_vis_fallback(explain, "density")
+            if not self._device_agg_ok(
+                type_name, fast_eligible, explain, "density"
+            ):
                 staged.append(("host", (plan, envelope)))
             elif cfg.disjoint:
                 self.record_query(plan, 0, 0.0)
@@ -2016,7 +2040,15 @@ class DataStore:
         ``estimate=True`` takes the device fast path for a bare ``Count()``
         spec when the scan mask decides the filter: a count-only kernel with
         no row gather (loose f32-widened semantics, like the reference's
-        estimate-only stats)."""
+        estimate-only stats).
+
+        Traced as one root ``stats`` (``spec``): the raster tier's or the
+        row path's spans under it; under row-level visibility it carries
+        ``vis_fallback`` for a spec of counts (:meth:`_device_agg_ok`)."""
+        with _otracer().trace("stats", type=type_name, spec=spec):
+            return self._stats_query(type_name, spec, f, estimate, explain)
+
+    def _stats_query(self, type_name, spec, f, estimate, explain) -> list:
         from geomesa_tpu.filter import ecql
         from geomesa_tpu.planning.planner import mask_decides_filter
         from geomesa_tpu.stats import stat_spec
@@ -2045,7 +2077,7 @@ class DataStore:
                     out.append(c)
                 return out
         if all(t.kind == "count" for t in terms) and self._raster_agg_eligible(
-            type_name, plan
+            type_name, plan, explain
         ):
             # raster path: exact count (full cells certain + refined
             # residue) with no full candidate gather — serves the exact
@@ -2062,9 +2094,9 @@ class DataStore:
                 plan.filter, plan.config, self._schemas[type_name],
                 for_aggregation=True,
             )
-            if fast_eligible and self._vis_active(type_name):
-                self._note_vis_fallback(explain, "count estimate")
-            if fast_eligible and not self._vis_active(type_name):
+            if self._device_agg_ok(
+                type_name, fast_eligible, explain, "count estimate"
+            ):
                 deadline = self._agg_deadline()
                 t0 = time.perf_counter()
                 n = (
@@ -2091,7 +2123,14 @@ class DataStore:
         stats/GeoMesaStats.scala:30-110). ``estimate=True`` uses the device
         bounds kernel without a row gather when the scan mask decides the
         filter (loose f32 semantics; extent features contribute their bbox
-        midpoint); otherwise exact from the refined results' geometries."""
+        midpoint); otherwise exact from the refined results' geometries.
+
+        Traced as one root ``bounds``; under row-level visibility it
+        carries ``vis_fallback`` (:meth:`_device_agg_ok`)."""
+        with _otracer().trace("bounds", type=type_name):
+            return self._bounds(type_name, f, estimate, explain)
+
+    def _bounds(self, type_name, f, estimate, explain) -> Optional[tuple]:
         from geomesa_tpu.filter import ecql
         from geomesa_tpu.planning.planner import mask_decides_filter
 
@@ -2111,7 +2150,7 @@ class DataStore:
             plan.cache_probe_s = comp.probe_s
             self.record_query(plan, comp.count, time.perf_counter() - t0)
             return comp.bounds
-        if self._raster_agg_eligible(type_name, plan):
+        if self._raster_agg_eligible(type_name, plan, explain):
             # raster path: EXACT envelope (tighter than the loose device
             # estimate) from certain + refined-residue hit coordinates,
             # no full row gather — serves estimate and exact alike
@@ -2124,9 +2163,7 @@ class DataStore:
                 for_aggregation=True,
             )
         )
-        if bounds_eligible and self._vis_active(type_name):
-            self._note_vis_fallback(explain, "bounds")
-        if bounds_eligible and not self._vis_active(type_name):
+        if self._device_agg_ok(type_name, bounds_eligible, explain, "bounds"):
             table = self.table(type_name, plan.index)
             if plan.config.disjoint:
                 self.record_query(plan, 0, 0.0)

@@ -1256,13 +1256,14 @@ class QueryPlanner:
         auths = None if skip_visibility else getattr(self.store, "auths", None)
         if auths is not None:
             from geomesa_tpu.security import (
-                VIS_FIELD_KEY, visibility_mask, visible,
+                VIS_FIELD_KEY, mask_collection, visible,
             )
 
             sft = self.store.get_schema(plan.type_name)
             vis_field = sft.user_data.get(VIS_FIELD_KEY)
             if vis_field and len(out):
-                out = out.mask(visibility_mask(out.columns[vis_field], auths))
+                # traced as ``vis`` (under ``decode``'s ``post`` segment)
+                out = mask_collection(out, vis_field, auths)
                 exp(f"Visibility filter: {len(out)} visible")
             # attribute-level security (reference geomesa-security
             # SecurityUtils per-attribute labels): an attribute whose

@@ -152,6 +152,11 @@ def visibility_mask(labels: np.ndarray, auths) -> np.ndarray:
     a network ingest) normalize first — ``None`` is public, like the
     empty label — so a hostile payload can neither crash ``np.unique``'s
     sort nor smuggle a non-string past the parser."""
+    return _mask_and_labels(labels, auths)[0]
+
+
+def _mask_and_labels(labels, auths) -> tuple[np.ndarray, int]:
+    """(:func:`visibility_mask`'s mask, the distinct labels it evaluated)."""
     labels = np.asarray(labels)
     if labels.dtype == object:
         labels = np.array(
@@ -159,6 +164,29 @@ def visibility_mask(labels: np.ndarray, auths) -> np.ndarray:
         )
     auths = frozenset(auths)
     out = np.zeros(len(labels), dtype=bool)
-    for v in np.unique(labels):
+    distinct = np.unique(labels)
+    for v in distinct:
         out[labels == v] = visible(str(v), auths)
-    return out
+    return out, len(distinct)
+
+
+def mask_collection(fc, vis_field: str, auths):
+    """The rows of ``fc`` whose label in column ``vis_field`` the
+    ``auths`` satisfy: the row-level stage of an embedded query's
+    ``_post`` and of the served handler's per-request auths. Traced as
+    a span ``vis`` (docs/observability.md) with ``rows`` in, ``kept``
+    out and ``labels``, the distinct labels evaluated; its segments are
+    ``labels`` (the mask: ``np.unique`` over the label strings, one
+    evaluation and one comparison pass a distinct label) and ``copy``
+    (the rows kept taken out of every column). An answer that is
+    visible whole is handed back as it came, not copied."""
+    from geomesa_tpu.obs.trace import span
+
+    with span("vis", rows=len(fc)) as sp:
+        sp.event("labels")
+        m, n_labels = _mask_and_labels(fc.columns[vis_field], auths)
+        sp.event("copy")
+        if not m.all():
+            fc = fc.mask(m)
+        sp.annotate(kept=len(fc), labels=n_labels)
+    return fc

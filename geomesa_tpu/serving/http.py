@@ -414,14 +414,12 @@ class DataServer:
             return self._client_error(400, f"{type(e).__name__}: {e}")
         if req_auths is not None:
             vis_field = sft.user_data.get(VIS_FIELD_KEY)
-            if vis_field and vis_field in fc.columns:
-                from geomesa_tpu.security import visibility_mask
+            if vis_field and vis_field in fc.columns and len(fc):
+                from geomesa_tpu.security import mask_collection
 
-                m = visibility_mask(
-                    np.asarray(fc.columns[vis_field]), req_auths
-                )
-                if not m.all():
-                    fc = fc.mask(m)
+                # the same ``vis`` span an embedded query opens, here
+                # under the request's ``http`` root
+                fc = mask_collection(fc, vis_field, req_auths)
         extra = {ROWS_HEADER: str(len(fc))}
         cur = _otracer().current()
         if cur is not None:  # the request's ``http`` root

@@ -649,4 +649,6 @@ def test_the_seven_readers_are_entries_of_the_benchmark():
         mine["native_reacquire_ms"]["workloads"])
     for name in ("lock_handoff_ms", "lock_handoff_tail_ms", "python_cpu_cores",
                  "request_cpu_ms", "handoffs_per_request"):
-        assert mine[name]["workloads"] == cells[:9]
+        # the nine cells PR 51 had, then whichever later cells list them (PR 53's does)
+        assert mine[name]["workloads"][:9] == cells[:9]
+        assert set(mine[name]["workloads"][9:]) <= set(cells[9:])
