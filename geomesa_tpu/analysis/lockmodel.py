@@ -106,8 +106,8 @@ LOCKS: dict[str, LockDecl] = {d.name: d for d in [
        doc="store mutation lock: writes/compactions/folds serialize; "
            "outermost by design (long holds around device builds)"),
     _d("DataStore._id_lock", "geomesa_tpu/datastore.py", 12,
-       doc="per-chunk id-index entry cache only; readers skip the "
-           "write lock"),
+       doc="per-chunk entry caches only (id index, label "
+           "dictionaries); readers skip the write lock"),
     _d("SegmentShipper._lock", "geomesa_tpu/streaming/replica.py", 14,
        fields=("_followers", "_gave_up", "_seq"),
        doc="shipper bookkeeping only (follower table, give-up report, "

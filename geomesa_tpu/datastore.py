@@ -212,10 +212,14 @@ class DataStore:
         from geomesa_tpu.lockwitness import witness
 
         self._write_lock = witness(threading.RLock(), "DataStore._write_lock")
-        # serializes only the per-chunk id-index entry cache (_id_index);
-        # entries self-validate by chunk identity, so readers never need
-        # the write lock
+        # serializes only the per-chunk entry caches (_id_index,
+        # label_codes); entries self-validate by chunk identity, so
+        # readers never need the write lock
         self._id_lock = witness(threading.Lock(), "DataStore._id_lock")
+        # row-level visibility: (chunk, its label dictionary) entries
+        # (label_codes), serving the chunk OBJECT they were built from
+        # like the id index's; _id_lock around the entry list alone
+        self._label_codes: dict[str, list] = {}
         # seqlock for renumbering publishes (fold_upsert): odd while the
         # assignment-only swap of tables+chunks is in flight, so
         # pin_scan_state's lock-free readers can capture a CONSISTENT
@@ -406,6 +410,7 @@ class DataStore:
             self._full.pop(type_name, None)
             self._main_rows.pop(type_name, None)
             self._id_sorted.pop(type_name, None)
+            self._label_codes.pop(type_name, None)
             self._stats.pop(type_name, None)
             for idx in self._indexes.pop(type_name, []):
                 table = self._tables.pop((type_name, idx.name), None)
@@ -540,6 +545,7 @@ class DataStore:
             ):
                 self.compact(type_name)
             self._bump_cache(type_name, features)
+            self.label_codes(type_name)  # the batch's, before a query asks
         return len(features)
 
     def _bulk_commit(
@@ -588,6 +594,7 @@ class DataStore:
                 presorted=presorted if total_before == 0 else None,
             )
             self._bump_cache(type_name)
+            self.label_codes(type_name)
         return total_new
 
     def delete_features(self, type_name: str, f: "Filter | str") -> int:
@@ -727,7 +734,11 @@ class DataStore:
                 # collapse earlier folds' chunk splits (ordinal-preserving
                 # concat, no re-sort) so replaced ordinals land in chunk 0
                 # — the invariant _fold_slice_locked relies on
+                olds = self._chunks[type_name]
                 self._chunks[type_name] = [self.features(type_name)]
+                self._carry_label_codes(
+                    type_name, self._chunks[type_name][0], olds
+                )
             n_batch = len(features)
             sr = (
                 slice_rows if slice_rows is not None
@@ -965,6 +976,10 @@ class DataStore:
             if len(removed):
                 self.cache.on_mutation(type_name, removed)
             self.cache.on_mutation(type_name, features)
+        # the survivors' label dictionary from the old chunk's codes, the
+        # batch's from its strings: a fold never sorts the table's labels
+        self._carry_label_codes(type_name, survivors0, [main], keep0)
+        self.label_codes(type_name)
 
     def _validate_replacement(self, type_name: str, features) -> None:
         """Fail BEFORE any row is deleted: a replacement batch that cannot
@@ -1136,8 +1151,10 @@ class DataStore:
         keep = np.ones(len(full), dtype=bool)
         keep[ordinals] = False
         new_full = full.mask(keep)
+        olds = self._chunks[type_name]
         self._chunks[type_name] = [new_full] if len(new_full) else []
         self._full[type_name] = None
+        self._carry_label_codes(type_name, new_full, olds, keep)
         for idx in self._indexes[type_name]:
             key = (type_name, idx.name)
             parts = self._key_chunks.get(key)
@@ -1178,7 +1195,9 @@ class DataStore:
         ladder x predicate flags x projections) so first queries skip the
         XLA compile stall (about a second per variant on a local v5e,
         PERF.md "On-chip bring-up (PR 21)"). Returns total kernel calls
-        issued."""
+        issued. A store with auths also builds the label dictionaries
+        (:meth:`label_codes`) of chunks it was opened over."""
+        self.label_codes(type_name)
         total = 0
         for idx in self._indexes[type_name]:
             try:
@@ -1225,7 +1244,10 @@ class DataStore:
         with self._write_lock:
             main_rows = self._main_rows.get(type_name, 0)
             full = self.features(type_name)
+            olds = self._chunks.get(type_name, [])
             self._chunks[type_name] = [full] if len(full) else []
+            if len(olds) > 1:
+                self._carry_label_codes(type_name, full, olds)
             for idx in self._indexes[type_name]:
                 parts = self._key_chunks.get((type_name, idx.name))
                 if not parts:
@@ -1258,6 +1280,7 @@ class DataStore:
                     self.adapter.delete_table(old)
                 self._tables[(type_name, idx.name)] = table
             self._main_rows[type_name] = len(full)
+            self.label_codes(type_name)  # the old chunks' entries go
 
     def _adapter_takes_sorted_state(self) -> bool:
         """Whether this adapter's ``create_table`` accepts the optional
@@ -1527,6 +1550,83 @@ class DataStore:
         return self.auths is not None and bool(
             self._schemas[type_name].user_data.get(VIS_FIELD_KEY)
         )
+
+    def label_codes(self, type_name: str, chunks: "list | None" = None):
+        """A :class:`~geomesa_tpu.security.LabelCodes` a chunk, in chunk
+        order (the planner looks a candidate's visibility up in them by
+        its ordinal, before the gather), or None where no row-level
+        visibility applies (:meth:`_vis_active`: ONE test on a store
+        without auths, which builds nothing).
+
+        Kept like :meth:`_id_index`'s entries: each carries the chunk
+        OBJECT it was built from and serves that object only, so every
+        mutation that renumbers (delete, modify, fold, compaction swap in
+        fresh chunk objects) leaves its dictionaries behind without
+        bookkeeping, and an appended chunk gets its own. ``write``,
+        ``warmup`` and every such mutation call this under the write lock
+        (the mutations after :meth:`_carry_label_codes` has made the
+        moved rows' from the old chunks' codes), so that a query builds
+        none; ``chunks``: the :meth:`chunk_snapshot` the ordinals
+        were resolved against (its entries are kept beside the live
+        chunks', so a scan pinned across a publish evicts nothing)."""
+        if not self._vis_active(type_name):
+            return None
+        from geomesa_tpu.security import VIS_FIELD_KEY, LabelCodes
+
+        live = self._chunks.get(type_name, [])
+        if chunks is None:
+            chunks = list(live)
+        entries = self._label_codes.get(type_name, ())
+        if len(entries) == len(chunks) and all(
+            e[0] is c for e, c in zip(entries, chunks)
+        ):
+            return [e[1] for e in entries]  # every query but the first
+        field = self._schemas[type_name].user_data[VIS_FIELD_KEY]
+        held = {id(c): (c, d) for c, d in entries}
+        # built outside the lock (every label of the chunk is read): two
+        # first askers of one chunk may both build, and one entry stays
+        made = {
+            id(c): (c, LabelCodes(c.columns[field]))
+            for c in chunks if id(c) not in held
+        }
+        asked = {id(c) for c in chunks}
+        keep = asked | {id(c) for c in live}
+        with self._id_lock:
+            # what another asker wrote meanwhile, what this one found (the
+            # other may have dropped a pinned chunk's) and what it made
+            held = {
+                id(c): (c, d) for c, d in self._label_codes.get(type_name, ())
+            } | held | made
+            # the asked chunks' first and in their order (the glance above),
+            # then the live ones' where a pinned snapshot asked
+            self._label_codes[type_name] = [held[id(c)] for c in chunks] + [
+                e for k, e in held.items() if k in keep and k not in asked
+            ]
+        return [held[id(c)][1] for c in chunks]
+
+    def _carry_label_codes(self, type_name, new, olds, keep=None) -> None:
+        """A mutation (write lock held) has just swapped in the chunk
+        ``new``, which holds the rows of the chunks ``olds`` in their
+        order, ``keep`` of them (a Boolean mask over those rows; None:
+        all): its label dictionary from theirs
+        (:meth:`~geomesa_tpu.security.LabelCodes.joined`: code arrays
+        alone), so that a fold, a delete or a compaction never sorts a
+        table's label strings and the query after it builds nothing.
+        Where one of ``olds`` has no entry, :meth:`label_codes` builds
+        ``new``'s from its strings."""
+        if not len(new) or not self._vis_active(type_name):
+            return
+        from geomesa_tpu.security import LabelCodes
+
+        held = {id(c): d for c, d in self._label_codes.get(type_name, ())}
+        parts = [held.get(id(c)) for c in olds]
+        if not parts or None in parts:
+            return
+        made = (new, LabelCodes.joined(parts, keep))
+        with self._id_lock:
+            self._label_codes[type_name] = [
+                *self._label_codes.get(type_name, ()), made
+            ]
 
     def apply_interceptors(self, type_name: str, f: Filter) -> Filter:
         """Run filter-rewriting interceptors in order (reference

@@ -866,7 +866,7 @@ class QueryPlanner:
         plan: QueryPlan,
         explain: Explainer | None = None,
         hints=None,
-        skip_visibility: bool = False,
+        branch: bool = False,
         deadline=None,
     ) -> FeatureCollection:
         exp = explain or ExplainNull()
@@ -885,7 +885,7 @@ class QueryPlanner:
         if plan.union is not None:
             return self._execute_union(plan, exp, hints, deadline)
 
-        certain = None
+        hidden, masked = (0, 0), False  # did _visible decide the rows
         if plan.ids is not None:  # id lookup
             # one snapshot resolves AND gathers: a fold publishing in
             # between cannot shift the ordinals under the gather
@@ -893,6 +893,8 @@ class QueryPlanner:
             ordinals = self.store.id_lookup(
                 plan.type_name, plan.ids, chunks=chunks
             )
+            ordinals, _, hidden = self._visible(plan, ordinals, None, chunks, exp)
+            masked = True
             candidates = self.store.gather(
                 plan.type_name, ordinals, chunks=chunks
             )
@@ -904,25 +906,39 @@ class QueryPlanner:
                     mask = plan.filter.evaluate(fc.batch)
             check_deadline(deadline, "full-table scan")
             self._note_actual(plan, int(mask.sum()), exp)
-            with _ospan("decode", candidates=plan.actual_rows):
+            with _ospan("decode", candidates=plan.actual_rows) as sp:
+                if getattr(self.store, "auths", None) is None:
+                    return self._post(fc.mask(mask), plan, hints, exp, branch)
+                # a table of one chunk has that chunk's dictionary: the
+                # labels hide rows of the filter's mask before the ONE copy
+                # of the rows kept. The concatenation of several chunks is
+                # no chunk and has none: ``_post`` masks it by its strings
+                kept = np.flatnonzero(mask)
+                coded = any(fc is c for c in self.store.chunk_snapshot(plan.type_name))
+                if coded:
+                    kept = self._visible(plan, kept, None, [fc], exp, sp)[0]
+                sp.event("post")
                 return self._post(
-                    fc.mask(mask), plan, hints, exp, skip_visibility
+                    fc.take(kept), plan, hints, exp, branch, rows_masked=coded,
                 )
         elif plan.index is not None and self.store.row_count(plan.type_name) == 0:
-            # schema exists but nothing written yet: no index tables
+            # schema exists but nothing written yet: no index tables. Rows
+            # that a first write lands between the two reads came with no
+            # ordinals: ``_post`` masks them by their strings
             candidates = self.store.features(plan.type_name)
         else:
             # simple index scan: the shared dispatch/finish implementation
             # (finish runs immediately here; query_many defers it)
             return self._submit_simple(
-                plan, exp, hints, skip_visibility, deadline=deadline
+                plan, exp, hints, branch, deadline=deadline
             )()
 
         return self._refine_and_post(
-            plan, candidates, certain, hints, exp, deadline, skip_visibility
+            plan, candidates, None, hints, exp, deadline, branch,
+            hidden=hidden, rows_masked=masked,
         )
 
-    def _submit_simple(self, plan, exp, hints, skip_visibility=False,
+    def _submit_simple(self, plan, exp, hints, branch=False,
                        finish_scan=None, deadline=None, chunks=None,
                        member=None):
         """Dispatch a simple index-scan plan's device work now; return
@@ -973,6 +989,9 @@ class QueryPlanner:
             with _ospan(
                 "decode", cpu=True, candidates=len(ordinals), **tag
             ) as sp:
+                ordinals, certain, hidden = self._visible(
+                    plan, ordinals, certain, chunks, exp, sp
+                )
                 sp.event("gather")
                 candidates = self.store.gather(
                     plan.type_name, ordinals, chunks=chunks
@@ -980,14 +999,45 @@ class QueryPlanner:
                 sp.add("gather_native", int(candidates.gathered_native))
                 return self._refine_and_post(
                     plan, candidates, certain, hints, exp, deadline,
-                    skip_visibility, span=sp,
+                    branch, span=sp, hidden=hidden, rows_masked=True,
                 )
 
         return finish
 
+    def _visible(self, plan, ordinals, certain, chunks, exp, span=_NULL_SPAN):
+        """Row-level security on a route's ORDINALS, before its gather:
+        ``(ordinals, certain, hidden)`` narrowed to the candidates whose
+        label the store's auths satisfy, looked up from the label codes
+        of the ``chunks`` the ordinals number (``security.mask_ordinals``,
+        the span ``vis``; ``span``: the caller's ``decode``, which gets a
+        ``vis`` segment). ``hidden``: the candidates dropped, (those the
+        device mask was certain of, the others), for
+        :meth:`_refine_and_post`'s accuracy record alone. A store without
+        auths, or a type without a label field, leaves all as it came:
+        ONE ``auths is None`` test and nothing built."""
+        auths = getattr(self.store, "auths", None)
+        codes = None
+        if auths is not None and len(ordinals):
+            span.event("vis")
+            codes = self.store.label_codes(plan.type_name, chunks)
+        if codes is None:
+            return ordinals, certain, (0, 0)
+        from geomesa_tpu.security import mask_ordinals
+
+        seen = mask_ordinals(codes, ordinals, auths)
+        n_hidden = len(seen) - int(np.count_nonzero(seen))
+        exp(f"Visibility filter: {len(seen) - n_hidden} visible")
+        if not n_hidden:
+            return ordinals, certain, (0, 0)
+        sure = 0 if certain is None else int(np.count_nonzero(certain & ~seen))
+        return (
+            ordinals[seen], None if certain is None else certain[seen],
+            (sure, n_hidden - sure),
+        )
+
     def _refine_and_post(
         self, plan, candidates, certain, hints, exp, deadline,
-        skip_visibility=False, span=_NULL_SPAN,
+        branch=False, span=_NULL_SPAN, hidden=(0, 0), rows_masked=False,
     ):
         """Refinement tiers (reference Z3IndexKeySpace.useFullFilter,
         Z3IndexKeySpace.scala:240-254, automatic since round 3):
@@ -998,21 +1048,30 @@ class QueryPlanner:
         - otherwise: exact full-filter refinement over all candidates.
 
         ``span``: the caller's ``decode`` span, cut here into its
-        ``refine`` and ``post`` segments."""
+        ``refine`` and ``post`` segments. ``rows_masked``: ``candidates``
+        are what :meth:`_visible` left of the route's ordinals, gathered
+        (``hidden``: the (certain, other) candidates it dropped); false
+        for rows that came by no ordinals, which ``_post`` then masks."""
         span.event("refine")
         decided = mask_decides_filter(
             plan.filter, plan.config, self.store.get_schema(plan.type_name)
         )
         loose_ok = hints is not None and getattr(hints, "loose", False) and decided
+        # of the hidden candidates: those the filter matches for sure and
+        # those it was still to decide; ``undecided``: what it decided of
+        # such rows among the visible (None: the mask is accepted whole)
+        (hid_sure, hid_open), undecided = hidden, None
         if loose_ok or (decided and isinstance(plan.filter, Include)):
             exp("Loose mode: device mask accepted without refinement")
         elif decided and certain is not None:
             unc = np.flatnonzero(~certain)
             exp(f"Refinement: {len(unc)} uncertain of {len(certain)} candidates")
+            undecided = unc
             if len(unc):
                 check_deadline(deadline, "boundary refinement start")
                 with exp.span("Boundary refinement"):
                     sub_mask = plan.filter.evaluate(candidates.take(unc).batch)
+                undecided = sub_mask
                 keep = certain.copy()
                 keep[unc] = sub_mask
                 # all-true keep: `candidates` is already a fresh gather
@@ -1027,18 +1086,32 @@ class QueryPlanner:
             span.add("residual_rows", len(candidates))
             with exp.span("Residual filter refinement"):
                 mask = plan.filter.evaluate(candidates.batch)
+            hid_sure, hid_open, undecided = 0, hid_sure + hid_open, mask
             if not bool(np.all(mask)):  # see all-true note above
                 candidates = candidates.mask(mask)
         check_deadline(deadline, "refinement")
         # estimate accountability: the POST-refinement row count — what
         # the sketch estimate actually predicts (filter selectivity) —
-        # before _post's limit/visibility stages distort it. The
+        # before _post's limit stage distorts it. The
         # pre-refinement candidate count would charge index
         # over-selection (a z2 scan serving a temporal filter) to the
-        # sketches, flagging fresh stats stale forever.
-        self._note_actual(plan, len(candidates), exp)
+        # sketches, flagging fresh stats stale forever. The sketches count
+        # rows whatever their label, so the candidates the labels hid
+        # before refinement are counted too, as the filter would have
+        # decided them (the undecided ones at the share it kept of such
+        # rows among the visible, one half where it decided none): a
+        # secured store's estimates must not look stale for the rows its
+        # callers may not read. This number enters no answer.
+        if hid_open:
+            share = 1.0 if undecided is None else (
+                float(np.mean(undecided)) if len(undecided) else 0.5
+            )
+            hid_sure += int(round(hid_open * share))
+        self._note_actual(plan, len(candidates) + hid_sure, exp)
         span.event("post")
-        return self._post(candidates, plan, hints, exp, skip_visibility)
+        return self._post(
+            candidates, plan, hints, exp, branch, rows_masked=rows_masked
+        )
 
     @staticmethod
     def _note_actual(plan, actual: int, exp) -> None:
@@ -1148,8 +1221,8 @@ class QueryPlanner:
         """submit_many's staging, under its ``dispatch`` span (a member
         that dispatches alone nests its own ``dispatch`` inside).
         ``branch``: the plans are the simple branches of ONE union
-        (:meth:`_execute_union`), which audits the query once and applies
-        visibility once over the merged rows: no branch does either."""
+        (:meth:`_execute_union`), which audits the query once and hides
+        attributes once over the merged rows: no branch does either."""
         finishes: list = [None] * len(plans)
         groups: dict[tuple, list[int]] = {}
         for j, plan in enumerate(plans):
@@ -1203,8 +1276,9 @@ class QueryPlanner:
         table, as ``query_many``'s members are) with a ``scan`` and a
         ``decode`` each; any other branch executes on its own. The query's
         ONE deadline bounds all branches: each gets the remaining budget,
-        not a fresh one. Branches skip visibility — it runs once over the
-        union in the final _post."""
+        not a fresh one. Each branch drops the candidates its labels hide
+        on their ordinals (:meth:`_visible`), so the merged rows need no
+        mask; attribute-level visibility runs once, in the final _post."""
         from geomesa_tpu.planning.hints import QueryHints
 
         def alone(sp):
@@ -1212,7 +1286,7 @@ class QueryPlanner:
             if deadline is not None:
                 check_deadline(deadline, f"union branch [{sp.strategy}]")
                 sub_hints = QueryHints(timeout=max(deadline.remaining(), 1e-9))
-            return self._execute(sp, explain=exp, hints=sub_hints, skip_visibility=True)
+            return self._execute(sp, explain=exp, hints=sub_hints, branch=True)
 
         # the branches that are simple index scans dispatch together,
         # fused a table; their pulls and every other branch follow in order
@@ -1231,29 +1305,40 @@ class QueryPlanner:
                 finish = staged.get(id(sp))
                 parts.append(alone(sp) if finish is None else finish())
         check_deadline(deadline, "union merge")
+        # rows the branches' filters matched that their labels hid before
+        # the gather (each branch's record less its answer: see
+        # _refine_and_post), for the accuracy record alone
+        hid = max(sum(sp.actual_rows or 0 for sp in plan.union) - sum(map(len, parts)), 0)
         nonempty = [p for p in parts if len(p)]
         if not nonempty:
-            self._note_actual(plan, 0, exp)
-            return self._post(parts[0], plan, hints, exp)
+            self._note_actual(plan, hid, exp)
+            return self._post(parts[0], plan, hints, exp, rows_masked=True)
         out = nonempty[0] if len(nonempty) == 1 else FeatureCollection.concat(nonempty)
+        n_parts = len(out)
         _, first = np.unique(np.asarray(out.ids), return_index=True)
         if len(first) != len(out):
             exp(f"Union dedup: {len(out)} -> {len(first)} rows")
             out = out.take(np.sort(first))
-        # the union's matched rows BEFORE _post's limit/visibility
-        # stages: record_query's hits fallback would compare the sketch
-        # estimate against a truncated result (see _note_actual)
-        self._note_actual(plan, len(out), exp)
-        return self._post(out, plan, hints, exp)
+        # the union's matched rows BEFORE _post's limit
+        # stage: record_query's hits fallback would compare the sketch
+        # estimate against a truncated result (see _note_actual); the
+        # hidden rows at the share of the visible that were distinct
+        self._note_actual(
+            plan, len(out) + int(round(hid * len(out) / n_parts)), exp
+        )
+        return self._post(out, plan, hints, exp, rows_masked=True)
 
-    def _post(self, out, plan, hints, exp, skip_visibility: bool = False):
+    def _post(self, out, plan, hints, exp, branch: bool = False,
+              rows_masked: bool = False):
         """Client-side reduce pipeline: visibility -> sample -> sort ->
         offset -> limit -> project (reference QueryPlanner.scala:66-102
         runs the same stages after the scan: reducer, sort, startIndex,
-        maxFeatures, projection)."""
-        # row-level security: mask rows whose visibility label the store's
-        # auths cannot satisfy (reference VisibilityEvaluator tier)
-        auths = None if skip_visibility else getattr(self.store, "auths", None)
+        maxFeatures, projection). ``rows_masked``: the route decided
+        row-level visibility on its ordinals, before the gather
+        (:meth:`_visible`); a collection that came without that is masked
+        here, by its label strings. ``branch``: the rows of one branch of
+        a union, whose own ``_post`` hides attributes once."""
+        auths = getattr(self.store, "auths", None)
         if auths is not None:
             from geomesa_tpu.security import (
                 VIS_FIELD_KEY, mask_collection, visible,
@@ -1261,15 +1346,17 @@ class QueryPlanner:
 
             sft = self.store.get_schema(plan.type_name)
             vis_field = sft.user_data.get(VIS_FIELD_KEY)
-            if vis_field and len(out):
-                # traced as ``vis`` (under ``decode``'s ``post`` segment)
+            if vis_field and len(out) and not rows_masked:
+                # row-level security (reference VisibilityEvaluator tier)
+                # for rows no ordinals came with: traced as ``vis`` too
+                # (``coded`` 0, under ``decode``'s ``post`` segment)
                 out = mask_collection(out, vis_field, auths)
                 exp(f"Visibility filter: {len(out)} visible")
             # attribute-level security (reference geomesa-security
             # SecurityUtils per-attribute labels): an attribute whose
             # ``vis=<label>`` option the auths cannot satisfy is PROJECTED
             # OUT of the result — rows stay visible, the value does not
-            hidden = [
+            hidden = [] if branch else [
                 a.name
                 for a in out.sft.attributes
                 if a.options.get("vis")

@@ -20,13 +20,15 @@
 (f) the density comparison admits both semantics the configuration states
     (the exact f64 host route, the device aggregation's f32 one) and refuses
     a grid that holds one row the caller may not read;
-(g) the ``vis`` span's ``rows``, ``kept`` and ``labels`` against NumPy
-    counts, under an embedded query and under the served handler's
-    per-request auths; ``vis_fallback`` on the roots of ``count``,
-    ``density``, ``bounds`` and a ``Count()`` estimate; nothing without auths;
+(g) the ``vis`` span's ``rows``, ``kept``, ``labels`` and ``coded``
+    against NumPy counts, under an embedded query (inside ``decode``, before
+    its gather: PR 54) and under the served handler's per-request auths;
+    ``vis_fallback`` on the roots of ``count``, ``density``, ``bounds`` and a
+    ``Count()`` estimate; nothing without auths;
 (h) ``datagen/gdelt_secured.py``: ``datagen/gdelt.py``'s columns value for
     value, the replayed groups, the label shares the configuration states;
-(i) the four new readers over hand-made spans, None where there is nothing;
+(i) the new readers (PR 53's four, PR 54's ``vis_coded_pct``) over hand-made
+    spans, None where there is nothing;
 (j) the cell itself through ``benchmark/rehearse.py``.
 """
 
@@ -51,7 +53,8 @@ BENCH_PACKAGES = ("harness", "ops", "datagen", "generators", "clients", "stores"
                   "layer_metrics", "kernels")
 ROUND = {"z3": 14, "z2": 8, "pip": 3, "raster": 3, "count": 4, "density": 4, "query_many": 4}
 CLASSES = tuple(ROUND)
-NEW_METRICS = ("vis_ms", "vis_keep_pct", "vis_share_pct", "agg_vis_fallback_pct")
+NEW_METRICS = ("vis_ms", "vis_keep_pct", "vis_share_pct", "agg_vis_fallback_pct",
+               "vis_coded_pct")
 TOKENS = ("user", "ops", "intel", "admin", "partner", "legal")
 AUTH_SETS = {"user-ops": ("user", "ops"), "nobody": (), "everybody": TOKENS}
 #: (data seed, auth set) of the stores the classes are asked of
@@ -250,7 +253,8 @@ def test_the_configuration_is_the_issues(config, sibling, entry):
                          "density_windowed_pct", "density_rows_per_s"}
     for m in entry["per_layer"]:
         if m["name"] in NEW_METRICS:
-            assert m["workloads"] == [CELL] and m["moves"] == "queries_per_s"
+            assert m["workloads"] == [CELL] and m["moves"] == (
+                "query_p95_ms" if m["name"] == "vis_coded_pct" else "queries_per_s")
     ends = {m["name"] for m in entry["end_to_end"] if CELL in m.get("workloads", [CELL])}
     assert ends == {"queries_per_s", "query_p95_ms", "single_mean_ms", "setup_s"}
 
@@ -612,8 +616,12 @@ def _vis_spans(tr):
 
 
 def test_the_vis_span_counts_rows_kept_and_labels(bench, mix, first, traced):
+    """The span lies inside ``decode``, BEFORE its gather: ``rows`` are the
+    scan's candidates (the filter's rows and the boundary band the
+    refinement then drops), ``kept`` those of them the auths may read."""
     cols, store = first
     seen = _seen(cols)
+    exact = 0
     for req in _of_class(bench, mix, cols, SEEDS[0], "z3", 3) + \
             _of_class(bench, mix, cols, SEEDS[0], "raster", 1):
         bench.rq.op_of(req).embedded(store, req)
@@ -622,13 +630,20 @@ def test_the_vis_span_counts_rows_kept_and_labels(bench, mix, first, traced):
         (span,) = _vis_spans(tr)
         decode = next(s for s in tr.spans if s.name == "decode")
         assert tr.name == "query" and span.parent_id == decode.span_id
-        assert span.attrs["rows"] == len(inside) and span.attrs["kept"] == seen[inside].sum()
-        assert span.attrs["labels"] == len(np.unique(cols.label_code[inside]))
-        segments = span.attrs["segments"]  # the mask, then the copy of the rows kept
-        assert set(segments) == {"labels", "copy"}
-        assert sum(segments.values()) == pytest.approx(span.dur_s, abs=2e-4)
-        t_post = decode.t0 + decode.dur_s - decode.attrs["segments"]["post"]
-        assert t_post <= span.t0 and span.t0 + span.dur_s <= decode.t0 + decode.dur_s + 1e-6
+        got = span.attrs
+        assert got["coded"] == 1 and "segments" not in got  # no strings sorted, no copy
+        assert got["rows"] == decode.attrs["candidates"] >= len(inside)
+        band = got["rows"] - len(inside)
+        assert 0 <= got["kept"] - seen[inside].sum() <= band
+        assert len(np.unique(cols.label_code[inside])) <= got["labels"] <= 12
+        if band == 0:
+            exact += 1
+            assert got["labels"] == len(np.unique(cols.label_code[inside]))
+        segments = decode.attrs["segments"]  # the mask, then the gather of the rows kept
+        assert list(segments) == ["vis", "gather", "refine", "post"]
+        assert decode.t0 <= span.t0
+        assert span.t0 + span.dur_s <= decode.t0 + segments["vis"] + 1e-4
+    assert exact >= 1
 
 
 def test_every_member_and_every_aggregation_opens_its_vis_span(bench, mix, first, traced):
@@ -638,16 +653,23 @@ def test_every_member_and_every_aggregation_opens_its_vis_span(bench, mix, first
     tr = traced.traces()[-1]
     inside = [bench.plain.ref_ids(cols, m["box"], m["win"]) for m in req["members"]]
     spans = _vis_spans(tr)
-    assert tr.name == "query_many" and len(spans) == sum(len(i) > 0 for i in inside)
-    assert sorted(s.attrs["rows"] for s in spans) == sorted(len(i) for i in inside if len(i))
-    assert sum(s.attrs["kept"] for s in spans) == sum(_seen(cols)[i].sum() for i in inside)
+    decodes = {s.span_id: s for s in tr.spans if s.name == "decode"}
+    reached = sorted(d.attrs["candidates"] for d in decodes.values() if d.attrs["candidates"])
+    assert tr.name == "query_many" and len(spans) == len(reached) >= sum(len(i) > 0 for i in inside)
+    assert sorted(s.attrs["rows"] for s in spans) == reached
+    assert all(s.attrs["coded"] == 1 and s.parent_id in decodes for s in spans)
+    band = sum(reached) - sum(map(len, inside))
+    assert 0 <= sum(s.attrs["kept"] for s in spans) - sum(
+        _seen(cols)[i].sum() for i in inside) <= band
     for klass in ("count", "density"):
         (req,) = _of_class(bench, mix, cols, SEEDS[0], klass, 1)
         bench.rq.op_of(req).embedded(store, req)
         tr = traced.traces()[-1]
         (span,) = _vis_spans(tr)
-        assert tr.name == klass
-        assert span.attrs["rows"] == len(bench.plain.ref_ids(cols, req["box"], req["win"]))
+        decode = next(s for s in tr.spans if s.name == "decode")
+        assert tr.name == klass and span.attrs["coded"] == 1
+        assert span.attrs["rows"] == decode.attrs["candidates"] >= len(
+            bench.plain.ref_ids(cols, req["box"], req["win"]))
 
 
 def test_an_empty_answer_opens_no_vis_span(bench, first, traced):
@@ -752,12 +774,14 @@ def test_the_served_handlers_mask_opens_the_same_span(traced):
         http = next(t for t in traced.traces() if t.name == "http")
         (span,) = _vis_spans(http)
         assert span.parent_id == http.root.span_id
-        assert {k: span.attrs[k] for k in ("rows", "kept", "labels")} == {
-            "rows": int((labels != "admin").sum()), "kept": len(rows), "labels": 3}
+        assert {k: span.attrs[k] for k in ("rows", "kept", "labels", "coded")} == {
+            "rows": int((labels != "admin").sum()), "kept": len(rows), "labels": 3, "coded": 0}
+        assert set(span.attrs["segments"]) == {"labels", "copy"}  # an answer's strings
         query = next(t for t in traced.traces() if t.name == "query")
-        (inner,) = _vis_spans(query)  # the store's own auths, in ``_post``
+        (inner,) = _vis_spans(query)  # the store's own auths, on the scan's ordinals
         assert (inner.attrs["rows"], inner.attrs["kept"], inner.attrs["labels"]) == (
             n, span.attrs["rows"], 4)
+        assert inner.attrs["coded"] == 1
     finally:
         ds.close()
 
@@ -842,13 +866,15 @@ def _span(i, trace, root, name, ms, parent=None, **attrs):
             "dur_s": ms / 1e3, "self_s": ms / 1e3, "attrs": attrs}
 
 
-def _view(with_vis=True):
+def _view(with_vis=True, coded=True):
     """Four requests: a query (10 ms, 2 of them ``vis``), a ``query_many`` of
     two members (30 ms; 3 + 1), a count and a density (20 ms each, 4 + 6),
-    and a density whose filter had no device path (5 ms)."""
+    and a density whose filter had no device path (5 ms). ``coded``: the
+    ``vis`` spans say how they decided (PR 54), as PR 53's do not."""
     def vis(i, trace, root, ms, parent, rows, kept):
-        return [_span(i, trace, root, "vis", ms, parent, rows=rows, kept=kept, labels=12)] \
-            if with_vis else []
+        how = {"coded": 1} if coded else {}
+        return [_span(i, trace, root, "vis", ms, parent, rows=rows, kept=kept, labels=12,
+                      **how)] if with_vis else []
 
     fb = (lambda v: {"vis_fallback": v}) if with_vis else (lambda v: {})
     q, many = _span(1, 1, "query", "query", 10.0), _span(10, 2, "query_many", "query_many", 30.0)
@@ -877,6 +903,10 @@ def test_the_readers_read_the_new_spans_and_counters(bench):
     assert read["vis_keep_pct"] == pytest.approx(100.0 * 6000 / 10000)
     assert read["vis_share_pct"] == pytest.approx(100.0 * 16.0 / 85.0)
     assert read["agg_vis_fallback_pct"] == pytest.approx(100.0 / 3)
+    assert read["vis_coded_pct"] == 100.0
+    # PR 53's program has the span and not the word on how it decided
+    before = {m: r.read(_view(coded=False)) for m, r in bench.readers.items()}
+    assert before == dict(read, vis_coded_pct=None)
 
 
 def test_the_readers_find_nothing_on_a_program_before_pr_53(bench):
@@ -906,6 +936,7 @@ def test_the_cell_rehearses_on_the_cpu():
                                "gather_ms", "load_rows_per_s"} <= set(read)
     assert 55.0 <= read["vis_keep_pct"]["value"] <= 65.0
     assert 0 < read["vis_share_pct"]["value"] < 100 and read["vis_ms"]["value"] > 0
+    assert read["vis_coded_pct"]["value"] == 100.0  # every route of the mix has ordinals
     # a round holds four counts (no device path to lose) and four densities (lost)
     assert 40.0 <= read["agg_vis_fallback_pct"]["value"] <= 60.0
     window = next(json.loads(s) for s in out.stdout.splitlines() if '"phase": "window"' in s)
